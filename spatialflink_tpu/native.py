@@ -31,7 +31,9 @@ def ensure_built(quiet: bool = True) -> bool:
     Always invokes make (an incremental no-op when up to date): merely
     checking for the .so would leave a STALE prebuilt library fatal when
     _load() looks up a newly added symbol (AttributeError instead of the
-    documented graceful fallback)."""
+    documented graceful fallback). A failed build means UNAVAILABLE even
+    when an older .so is lying around — a binary this tree could not
+    rebuild is never loaded."""
     global _build_failed
     if _build_failed:
         return False
@@ -41,10 +43,10 @@ def ensure_built(quiet: bool = True) -> bool:
             check=True,
             capture_output=quiet,
         )
-        return os.path.exists(_LIB_PATH)
     except (subprocess.CalledProcessError, FileNotFoundError):
         _build_failed = True
-        return os.path.exists(_LIB_PATH)
+        return False
+    return os.path.exists(_LIB_PATH)
 
 
 def _load() -> Optional[ctypes.CDLL]:

@@ -169,7 +169,7 @@ class _PointStreamRangeQuery(SpatialOperator):
 
         # Attach (= load any checkpoint) BEFORE touching the device: a
         # run resumed after failover (backend "fallback") means the
-        # device path already died — often a dead tunnel, where even the
+        # device path already died — often a dead device, where even the
         # setup transfers below would hang the resume at a device_put.
         drv = driver if driver is not None else strict_driver()
         drv.attach(self)

@@ -36,9 +36,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from spatialflink_tpu.utils.shardmap_compat import shard_map
 
 from spatialflink_tpu.faults import faults
 from spatialflink_tpu.ops.halo import (

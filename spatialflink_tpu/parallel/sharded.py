@@ -25,10 +25,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-# Handles both the symbol's home and the check_rep→check_vma rename.
-from spatialflink_tpu.utils.shardmap_compat import shard_map
 
 from spatialflink_tpu.ops.distances import point_point_distance
 from spatialflink_tpu.ops.join import JoinResult, join_kernel
@@ -502,8 +500,7 @@ def sharded_traj_stats(
 
     def local(xy_l, ts_l, oid_l, valid_l):
         # The ppermute ring needs a STATIC shard count; read it from the
-        # mesh (lax.axis_size only exists on newer jax releases — same era
-        # as the check_vma rename, see utils/shardmap_compat.py).
+        # mesh.
         n_shards = int(mesh.shape["data"])
         # Ring halo: receive the previous shard's last (xy, ts, oid, valid).
         perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]

@@ -180,16 +180,23 @@ def contains_any_zone(zones: Sequence[BufferedZone], xy_metric: np.ndarray) -> n
     verts = np.zeros((len(zones), v, 2))
     evs = np.zeros((len(zones), v - 1), bool)
     bufs = np.zeros(len(zones))
+    # Centre everything on the first zone vertex, in float64, BEFORE the
+    # device cast: with x64 off (the TPU default) UTM northings ~5.6e6 m
+    # have a float32 ulp of 0.5 m — enough to flip containment within a
+    # 20 m buffer. Zone-local magnitudes (tens of km) keep the ulp at
+    # millimetres; distances are translation-invariant (the
+    # operators/base.py:center_coords idiom).
+    origin = np.asarray(zones[0].rings_metric[0][0], np.float64)
     for i, z in enumerate(zones):
         pv, pe = z.packed(pad_to=v)
-        verts[i] = pv
+        verts[i] = pv - origin
         evs[i] = pe
         bufs[i] = z.buffer_m
     n = len(xy_metric)
     # Pad the point batch to a bucket so window-size jitter reuses programs;
     # padded lanes land far outside every zone (coordinates 1e12 m).
     b = next_bucket(n)
-    pts = pad_to_bucket(np.asarray(xy_metric, float), b, fill=1e12)
+    pts = pad_to_bucket(np.asarray(xy_metric, float) - origin, b, fill=1e12)
     hit = _zone_hit_jit(
         jnp.asarray(pts), jnp.asarray(verts), jnp.asarray(evs), jnp.asarray(bufs)
     )

@@ -18,5 +18,5 @@ def host_helper(x, scale):
     t0 = time.time()  # hotpath: ok (legacy pragma still honored)
     s = float(scale)  # sfcheck: ok=trace-hygiene -- fixture: host-side scalar by contract
     idx = jnp.nonzero(x)  # sfcheck: ok=fixed-shape,trace-hygiene -- fixture: multi-pass pragma list
-    jax.block_until_ready(x)  # sfcheck: ok=sync-discipline -- fixture: CPU-only path, no tunnel
+    jax.block_until_ready(x)  # sfcheck: ok=sync-discipline -- fixture: CPU-only path
     return f"t={t0:.3f} s={s:.1f}", idx  # sfcheck: ok=fstring-numpy -- fixture: known Python floats

@@ -225,18 +225,6 @@ def test_diff_gate_rejects_tainted_ledger(tmp_path, capsys):
     assert "REJECT" in capsys.readouterr().out
 
 
-def test_last_good_store_refuses_tainted_records(tmp_path, monkeypatch):
-    import bench
-
-    store = tmp_path / "last_good.json"
-    monkeypatch.setenv("SFT_BENCH_LAST_GOOD", str(store))
-    bench._record_last_good({"value": 5.0, "tainted": {
-        "kind": "ablation", "kernels": ["k"]}})
-    assert not store.exists()
-    bench._record_last_good({"value": 5.0})
-    assert store.exists()
-
-
 def test_cpu_baseline_refuses_armed_ablation(monkeypatch, capsys):
     import bench_suite
 

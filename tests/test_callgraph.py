@@ -200,7 +200,7 @@ def test_fn_passed_to_jit_wrapper_is_device_entry_and_callees_reachable():
 
 def test_shard_map_closure_is_device():
     p, g = _project({"m.py": """
-        from spatialflink_tpu.utils.shardmap_compat import shard_map
+        from jax import shard_map
         def wrapper(mesh, x):
             def local(x_l):
                 return x_l

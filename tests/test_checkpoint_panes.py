@@ -1,5 +1,5 @@
-"""Kill-and-resume for the incremental pane-carry pipelines (VERDICT r2
-item: the ListState-analog state in query_panes lived in generator locals
+"""Kill-and-resume for the incremental pane-carry pipelines (the
+ListState-analog state in query_panes lived in generator locals
 and could not be checkpointed). A stream is cut mid-way, the operator is
 snapshotted (assembler + pane digests/blocks + interner), a FRESH operator
 is restored in a "new process" (pickle round-trip through disk), and the

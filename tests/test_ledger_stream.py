@@ -209,7 +209,7 @@ def test_recover_stream_killed_before_first_checkpoint(tmp_path):
 
 
 def test_recover_honors_epilogue_past_partial_tail(tmp_path):
-    """The supervisor-seal shape: valid records, a half-written line,
+    """The external-seal shape: valid records, a half-written line,
     then an epilogue appended on its own line. The epilogue's reason
     must survive; any OTHER record past the corruption stays skipped
     (no silent re-synchronization)."""
@@ -224,21 +224,21 @@ def test_recover_honors_epilogue_past_partial_tail(tmp_path):
             {"name": "window.fake", "ph": "X", "ts": 0, "dur": 1,
              "pid": 1, "tid": 1}]}).encode() + b"\n"  # must NOT re-sync
         + json.dumps({"t": "epilogue", "unix": 9.0,
-                      "reason": "terminated (SIGTERM)",
-                      "sealed_by": "supervisor"}).encode() + b"\n"
+                      "reason": "dial_timeout",
+                      "sealed_by": "watchdog"}).encode() + b"\n"
     )
     doc, info = stream_mod.recover(str(cut))
     assert ledger_mod.validate(doc) == []
     assert info["sealed"] is True
-    assert info["sealed_by"] == "supervisor"
-    assert info["reason"] == "terminated (SIGTERM)"
+    assert info["sealed_by"] == "watchdog"
+    assert info["reason"] == "dial_timeout"
     assert info["partial_tail"] is True and info["truncated"] is True
     assert info["skipped_lines"] == 1  # the post-corruption spans batch
     assert all(e["name"] != "window.fake" for e in doc["events"])
 
 
-def test_supervisor_seal_on_clean_boundary_still_truncated(tmp_path):
-    """A supervisor epilogue on a clean line boundary (child killed
+def test_external_seal_on_clean_boundary_still_truncated(tmp_path):
+    """A watchdog epilogue on a clean line boundary (run wedged
     BETWEEN flushes) attributes the crash but must not masquerade as a
     complete capture: truncated stays True, child seals stay not."""
     full = _run_stream(tmp_path, windows=2, seal=None)
@@ -247,11 +247,11 @@ def test_supervisor_seal_on_clean_boundary_still_truncated(tmp_path):
     telemetry.disable()
     crashed = tmp_path / "crashed.jsonl"
     crashed.write_bytes(raw + json.dumps(
-        {"t": "epilogue", "unix": 9.0, "reason": "deadline",
-         "sealed_by": "supervisor"}).encode() + b"\n")
+        {"t": "epilogue", "unix": 9.0, "reason": "dial_timeout",
+         "sealed_by": "driver-watchdog"}).encode() + b"\n")
     _, info = stream_mod.recover(str(crashed))
     assert info["sealed"] is True and info["truncated"] is True
-    assert info["sealed_by"] == "supervisor"
+    assert info["sealed_by"] == "driver-watchdog"
     assert "one flush interval" in info["loss_bound"]
     # A CHILD seal ("complete"/"disabled") is the complete-capture case.
     complete = _run_stream(tmp_path, name="done.jsonl", seal="disable")

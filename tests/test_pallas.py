@@ -2,16 +2,10 @@
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from spatialflink_tpu.ops.distances import point_polyline_distance
-from spatialflink_tpu.ops.pallas_kernels import (
-    pallas_available,
-    point_polyline_min_dist_pallas,
-)
+from spatialflink_tpu.ops.pallas_kernels import point_polyline_min_dist_pallas
 from spatialflink_tpu.ops.polygon import pack_rings
-
-pytestmark = pytest.mark.skipif(not pallas_available(), reason="no pallas")
 
 
 def test_pallas_min_dist_matches_xla(rng):

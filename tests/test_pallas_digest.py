@@ -5,7 +5,6 @@ a runtime self-check — bench.py)."""
 import numpy as np
 import pytest
 
-from conftest import pallas_int64_xfail
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +44,6 @@ def _oracle(wf, wire_t, q, radius, nseg):
     )
 
 
-@pallas_int64_xfail
 def test_wire_digest_pallas_matches_oracle(rng):
     n, nseg, radius = 4096, 512, 0.05
     wf, wire_t = _wire(rng, n, nseg)
@@ -72,7 +70,6 @@ def test_wire_digest_pallas_matches_oracle(rng):
     assert np.array_equal(ra[same], rb[same])
 
 
-@pallas_int64_xfail
 def test_wire_digest_pallas_count_overflow_flagged(rng):
     n, nseg = 2048, 64
     wf, wire_t = _wire(rng, n, nseg)
@@ -85,7 +82,6 @@ def test_wire_digest_pallas_count_overflow_flagged(rng):
     assert int(cnt) == n  # honest count even though output truncated
 
 
-@pallas_int64_xfail
 def test_wire_digest_pallas_empty_radius(rng):
     n, nseg = 2048, 64
     wf, wire_t = _wire(rng, n, nseg)
@@ -99,7 +95,6 @@ def test_wire_digest_pallas_empty_radius(rng):
     assert np.all(np.asarray(dig.seg_min) == big)
 
 
-@pallas_int64_xfail
 def test_wire_digest_pallas_non_divisible_n(rng):
     """The headline SLIDE (500k) is not a blk multiple — padding lanes
     must never enter the candidate set."""

@@ -12,7 +12,6 @@ of join/PointPointJoinQuery.java:124-183's windowed distance filter).
 import numpy as np
 import pytest
 
-from conftest import pallas_int64_xfail
 import jax.numpy as jnp
 
 from spatialflink_tpu.grid import UniformGrid
@@ -72,7 +71,6 @@ def data():
     return axy, av, bxy, bv
 
 
-@pallas_int64_xfail
 def test_matches_bruteforce_and_distances(data):
     axy, av, bxy, bv = data
     r = 0.7
@@ -94,7 +92,6 @@ def test_matches_bruteforce_and_distances(data):
         assert abs(dm[k] - d[k]) < 1e-5
 
 
-@pallas_int64_xfail
 def test_matches_xla_bucketed(data):
     axy, av, bxy, bv = data
     r = 0.9
@@ -110,7 +107,6 @@ def test_matches_xla_bucketed(data):
     assert int(res_p.overflow) == int(res_x.overflow)
 
 
-@pallas_int64_xfail
 def test_two_layer_radius(data):
     axy, av, bxy, bv = data
     r = 1.6  # ceil(1.6 / 1.0) = 2 grid layers
@@ -120,14 +116,12 @@ def test_two_layer_radius(data):
     assert int(res.count) == len(want)
 
 
-@pallas_int64_xfail
 def test_overflow_reported_when_cap_exceeded(data):
     axy, av, bxy, bv = data
     res = _pallas(axy, av, bxy, bv, 0.7, cap=2)
     assert int(res.overflow) > 0  # 260 pts / 64 cells >> cap 2
 
 
-@pallas_int64_xfail
 def test_count_exceeding_budget_reports_true_total(data):
     axy, av, bxy, bv = data
     r = 0.9
@@ -137,7 +131,6 @@ def test_count_exceeding_budget_reports_true_total(data):
     assert int(res.count) == len(want)  # retry contract: true total
 
 
-@pallas_int64_xfail
 def test_empty_side():
     axy = np.zeros((16, 2), np.float32)
     av = np.zeros(16, bool)
@@ -148,7 +141,6 @@ def test_empty_side():
     assert _pairs(res) == set()
 
 
-@pallas_int64_xfail
 def test_operator_pallas_backend_matches_default():
     rng = np.random.default_rng(3)
     grid = UniformGrid(20, 0.0, 10.0, 0.0, 10.0)

@@ -1,7 +1,7 @@
 """End-to-end operator execution on the 8-device CPU mesh.
 
-VERDICT round-1 gap: the sharded kernels existed but no operator could run
-on a mesh. These tests drive the OPERATOR layer (windows → batches →
+The sharded kernels alone are not enough: operators must run on a
+mesh. These tests drive the OPERATOR layer (windows → batches →
 shard_mapped kernels → decoded results) with ``mesh=`` and require results
 identical to the single-device run — the framework analog of the
 reference's parallelism default (StreamingJob.java:177,
@@ -326,7 +326,7 @@ def test_run_multi_mesh_matches_single(rng, mesh):
 
 
 def test_tstats_pane_engine_mesh_bit_matches_single(rng, mesh):
-    """VERDICT r4 weak #6: the device tStats pane engine on the 8-device
+    """The device tStats pane engine on the 8-device
     mesh (trajectory-parallel oid blocks,
     parallel/sharded.py:sharded_traj_stats_pane) must be BIT-identical
     to the single-device kernel at x64 — not the dryrun's f32
@@ -375,7 +375,7 @@ def test_tstats_pane_mesh_rejects_bad_config(rng, mesh):
 
 
 def test_tjoin_pane_engine_mesh_bit_matches_single(rng, mesh):
-    """VERDICT r4 weak #5/#6: the pane-carry tJoin engine on the
+    """The pane-carry tJoin engine on the
     8-device mesh (probe-parallel points, replicated window/digest
     state, all-gathered contributions — ops/tjoin_panes.py) must be
     BIT-identical to single-device at x64, through the operator path."""

@@ -52,7 +52,6 @@ class TestPolicy:
 
     @pytest.mark.parametrize("bad", [
         {"depth": 0}, {"fetch_lag": -1}, {"codec": "lz4"},
-        {"codec_strategy": "mosaic"},
     ])
     def test_invalid_values_raise(self, bad):
         with pytest.raises(ValueError):
@@ -178,7 +177,7 @@ class TestExecutor:
 
     def test_breaker_collapse_and_resume(self):
         """An OPEN overload circuit collapses the executor to the
-        synchronous cadence (no stacking onto a dead tunnel), emits the
+        synchronous cadence (no stacking onto a dead device path), emits the
         transition events, and re-opens when the breaker closes."""
         pol = overload.OverloadPolicy(breaker_failures=1)
         ctrl = overload.install(
@@ -393,18 +392,6 @@ class TestRunWirePanesPipelined:
         # the decode kernel rides the compiled-shape ladder
         assert telemetry.distinct_shapes("wire_pane_decode") <= 8
 
-    def test_codec_kind_recorded(self, rng):
-        from spatialflink_tpu.operators.knn_query import (
-            PointPointKNNQuery,
-        )
-
-        wf, panes = _wire_fixture(rng, with_gap=False)
-        pipeline.install(pipeline.PipelinePolicy(codec="delta",
-                                                 codec_strategy="jnp"))
-        op = PointPointKNNQuery(CONF, GRID)
-        _collect_wire(op, panes, wf)
-        assert op.last_wire_codec_kind == "jnp"
-
 
 # ---------------------------------------------------------------------------
 # tjoin segmented scan parity
@@ -548,7 +535,7 @@ class TestDriverPipelined:
         """An open circuit during a pipelined driver run must leave the
         same observable trail as the executor's collapse: the
         pipeline_collapsed instant, the collapses counter, and sync
-        window counts — a tunnel death mid-overlap may not be
+        window counts — a device-path death mid-overlap may not be
         invisible in the ledger."""
         telemetry.enable()
         pol = overload.OverloadPolicy(breaker_failures=1)

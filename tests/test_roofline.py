@@ -56,7 +56,7 @@ def _one_window_events():
 
 
 def test_link_bound():
-    # 2.3 MB over a 28 MB/s tunnel ≈ 82 ms of a 100 ms span.
+    # 2.3 MB over a 28 MB/s link ≈ 82 ms of a 100 ms span.
     doc = _doc(snapshot={
         "bytes_h2d": 2_000_000, "bytes_d2h": 300_000,
         "link_probe": {"roundtrip_mbps_p50": 28.0},

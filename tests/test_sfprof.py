@@ -450,7 +450,7 @@ def test_diff_gate_fails_when_candidate_loses_a_metric(tmp_path):
 
 
 def test_diff_link_annotation_never_gates(tmp_path, capsys):
-    """Link-probe gauges ANNOTATE a diff (tunnel degraded vs chip slow)
+    """Link-probe gauges ANNOTATE a diff (link degraded vs chip slow)
     but never gate it, and never widen the bands: two ledgers identical
     except for a 2x-degraded link must still self-diff clean — with the
     degradation called out in the output."""
@@ -467,9 +467,9 @@ def test_diff_link_annotation_never_gates(tmp_path, capsys):
     fast, slow = str(tmp_path / "fast.json"), str(tmp_path / "slow_link.json")
     assert sfprof_main(["diff", fast, slow, "--gate"]) == 0  # not gated
     out = capsys.readouterr().out
-    assert "DEGRADED" in out and "tunnel" in out
+    assert "DEGRADED" in out and "link" in out
     assert sfprof_main(["diff", fast, fast, "--gate"]) == 0
-    assert "comparable tunnels" in capsys.readouterr().out
+    assert "comparable links" in capsys.readouterr().out
 
 
 def test_diff_guards_cpu_baseline_medians(tmp_path):

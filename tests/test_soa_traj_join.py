@@ -1,6 +1,6 @@
 """Round-2 SoA fast paths: TStats / TKnn / two-stream join, plus the
 device-side tJoin pair dedup — each pinned bit-for-bit (or to f64 eps)
-against the object path it accelerates (VERDICT round-1 item 4: the host
+against the object path it accelerates (the host
 Python loops in the trajectory operators capped throughput)."""
 
 import numpy as np

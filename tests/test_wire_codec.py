@@ -6,6 +6,7 @@ uniform panes, empty/gap panes, wraparound teleports), the host/device
 predictor-table lockstep, the np reference twin, the ladder-bounded
 compiled-shape contract, and the Pallas extraction's self-check."""
 
+import functools
 import os
 import sys
 
@@ -28,13 +29,13 @@ def rng():
     return np.random.default_rng(1234)
 
 
-def _device_decode(enc, px, py, *, n_bucket=None, extract=None):
+def _device_decode(enc, px, py, *, n_bucket=None):
     """One jitted decode at a bucket; returns (pane(3, nb), px2, py2)
     as numpy."""
     nb = n_bucket or max(8, enc.n)
     wb = max(wc.WORD_BUCKET_MIN, len(enc.words))
-    step = jax.jit(wc.functools_partial_decode(
-        extract or wc.extract_streams, n=nb, num_segments=len(px),
+    step = jax.jit(functools.partial(
+        wc.decode_wire_pane, n=nb, num_segments=len(px),
     ))
     pane, px2, py2 = step(
         jnp.asarray(wc.pad_words(enc.words, wb)), jnp.int32(enc.n),
@@ -276,47 +277,3 @@ class TestContracts:
             assert logged  # picks recorded like the pane ladder's
         finally:
             telemetry.disable()
-
-    def test_select_wire_decoder_cpu_default_is_jnp(self):
-        kind, fn = wc.select_wire_decoder("auto")
-        assert kind == "jnp" and fn is wc.extract_streams
-        kind, fn = wc.select_wire_decoder("jnp")
-        assert kind == "jnp"
-
-
-class TestPallasExtraction:
-    def test_interpret_mode_agrees_bit_exact(self, rng):
-        """The Pallas extraction (interpret mode on CPU) must decode a
-        sample pane bit-identically — the adoption self-check."""
-        nseg = 32
-        enc = wc.WirePaneEncoder(nseg)
-        pane = _random_walk_panes(rng, nseg, n_panes=1, max_n=50)[0]
-        e = enc.encode(pane)
-        if e.n == 0:  # pragma: no cover - rng safeguard
-            pytest.skip("empty sample pane")
-        px = np.zeros(nseg, np.uint16)
-        py = np.zeros(nseg, np.uint16)
-        pallas_extract = wc.make_pallas_extract(interpret=True)
-        a = _device_decode(e, px, py, n_bucket=64,
-                           extract=pallas_extract)
-        b = _device_decode(e, px, py, n_bucket=64)
-        for xa, xb in zip(a, b):
-            assert np.array_equal(xa, xb)
-
-    def test_select_adopts_pallas_under_interpret_with_self_check(
-            self, rng):
-        nseg = 16
-        enc = wc.WirePaneEncoder(nseg)
-        pane = _random_walk_panes(rng, nseg, n_panes=1, max_n=30)[0]
-        e = enc.encode(pane)
-        wb = max(wc.WORD_BUCKET_MIN, len(e.words))
-        sample = (
-            jnp.asarray(wc.pad_words(e.words, wb)), jnp.int32(e.n),
-            jnp.int32(e.bx), jnp.int32(e.by), jnp.int32(e.bo),
-            jnp.zeros(nseg, jnp.uint16), jnp.zeros(nseg, jnp.uint16),
-        )
-        kind, _fn = wc.select_wire_decoder(
-            "pallas", interpret=True, sample_args=sample, n=64,
-            num_segments=nseg,
-        )
-        assert kind == "pallas"

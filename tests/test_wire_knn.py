@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import pallas_int64_xfail
 
 from spatialflink_tpu.grid import UniformGrid
 from spatialflink_tpu.models.objects import Point
@@ -78,7 +77,6 @@ def test_xla_step_matches_operator_soa_digest(rng):
     assert live.sum() > 3, "degenerate: almost nothing in radius"
 
 
-@pallas_int64_xfail
 def test_pallas_interpret_matches_xla(rng):
     wire, _, _ = _wire(rng, 700)
     args = _args(wire)
@@ -89,7 +87,6 @@ def test_pallas_interpret_matches_xla(rng):
     assert digests_agree(d_p.seg_min, d_p.rep, d_x.seg_min, d_x.rep)
 
 
-@pallas_int64_xfail
 def test_pallas_overflow_fallback_exact(rng):
     """More hits than max_cand ⇒ the lax.cond reruns the full XLA
     scatter digest in-program — results stay exact."""
@@ -107,7 +104,7 @@ def test_pallas_overflow_fallback_exact(rng):
 
 
 @pytest.mark.parametrize("strategy", [
-    "xla", pytest.param("pallas", marks=pallas_int64_xfail),
+    "xla", "pallas",
 ])
 def test_n_valid_padding_never_matches(rng, strategy):
     """Bucket padding (u16 zeros → the grid ORIGIN, deliberately within
@@ -151,7 +148,6 @@ def test_select_auto_on_cpu_stays_xla(rng):
     assert kind == "xla"
 
 
-@pallas_int64_xfail
 def test_select_forced_pallas_self_checks(rng):
     wire, _, _ = _wire(rng, 256)
     kind, step = select_wire_digest_step(
@@ -171,7 +167,7 @@ def _soa_chunks(ts, xyf, oid):
 
 
 @pytest.mark.parametrize("strategy", [
-    "xla", pytest.param("pallas", marks=pallas_int64_xfail),
+    "xla", "pallas",
 ])
 def test_run_wire_panes_matches_run_soa_panes(rng, strategy):
     """The shipped wire-ingest operator path fires the same windows with

@@ -37,8 +37,7 @@ from typing import Dict, List, Optional, Set, Tuple
 from tools.sfcheck.project import MODULE_FN, FileFacts, FunctionFacts, Project
 
 #: Terminal names of calls whose function-valued arguments enter a
-#: traced/compiled region. ``shard_map`` matches both the jax symbol and
-#: the repo's utils/shardmap_compat re-export.
+#: traced/compiled region.
 JIT_WRAPPER_TERMINALS = frozenset({
     "jit", "jitted", "vmap", "pmap", "shard_map", "scan", "map",
     "fori_loop", "while_loop", "cond", "switch", "checkpoint", "remat",

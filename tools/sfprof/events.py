@@ -27,7 +27,7 @@ INSTANT_EVENTS = frozenset({
     # the dataflow driver's self-healing (driver.py via telemetry.py)
     "driver_retry",
     "failover",
-    # tunnel link-health probe (telemetry.LinkProbe)
+    # host↔device link-health probe (telemetry.LinkProbe)
     "link_probe",
     # device-path circuit breaker (overload.CircuitBreaker)
     "circuit_open",

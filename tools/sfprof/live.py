@@ -10,7 +10,7 @@ fault-firing instant events as they land in span batches.
 Reading REUSES :func:`tools.sfprof.stream.read_records` on every poll —
 one copy of the truncation grammar (a half-written tail is dropped and
 re-read whole on the next poll; past a genuinely undecodable line only
-sealing epilogues are honored, the supervisor-seal rule). ``live``
+sealing epilogues are honored, the external-seal rule). ``live``
 therefore survives mid-run truncation exactly as ``recover`` does: it
 reports what the prefix says and keeps following.
 

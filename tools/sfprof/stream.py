@@ -24,9 +24,9 @@ bytes) instead of pretending the artifact is complete.
 Tolerance: a half-written line (the only corruption a kill can produce)
 is dropped and counted, and it marks the truncation point — ordinary
 records after it are ignored, never silently re-synchronized. The ONE
-exception is the epilogue: bench.py's supervisor seals a crashed
-child's stream by appending an epilogue AFTER the partial tail (on its
-own line), and that termination reason must survive recovery — so past
+exception is the epilogue: a dial watchdog (bench.py, driver.py) seals
+a wedged run's stream by appending an epilogue AFTER the partial tail
+(on its own line), and that termination reason must survive recovery — so past
 the truncation point only ``t == "epilogue"`` records are honored.
 """
 
@@ -68,7 +68,7 @@ _EMPTY_SNAPSHOT: Dict[str, Any] = {
 def read_records(path: str) -> Tuple[List[dict], Dict[str, Any]]:
     """(records, tail_info): every decodable record up to the first
     undecodable line — plus, PAST that truncation point, epilogue
-    records only (the supervisor-seal case: bench.py appends the
+    records only (the external-seal case: a watchdog appends the
     termination reason after a half-written tail; see module
     docstring). ``tail_info``: ``partial_tail`` (a truncated line was
     dropped), ``skipped_lines``/``skipped_bytes`` (non-epilogue content
@@ -184,17 +184,18 @@ def recover(path: str) -> Tuple[Dict[str, Any], Dict[str, Any]]:
             bb_folded += 1
 
     sealed = epilogue is not None
-    # A SUPERVISOR seal (bench.py's failure paths) marks an attributable
-    # crash, not a complete capture: the child died without its final
-    # flush, so the stream is truncated even on a clean line boundary.
-    supervisor_sealed = (epilogue or {}).get("sealed_by") == "supervisor"
-    truncated = tail["partial_tail"] or not sealed or supervisor_sealed
+    # An EXTERNAL seal (telemetry's own epilogues carry no ``sealed_by``;
+    # the dial watchdogs' do) marks an attributable crash, not a complete
+    # capture: the run died without its final flush, so the stream is
+    # truncated even on a clean line boundary.
+    external_seal = "sealed_by" in (epilogue or {})
+    truncated = tail["partial_tail"] or not sealed or external_seal
     snapshot = (checkpoint or {}).get("snapshot") or dict(_EMPTY_SNAPSHOT)
     kernels = (checkpoint or {}).get("kernels") or []
     env = dict(prologue.get("env") or {})
     env.setdefault("recovered_from_stream", True)
 
-    # Supervisor epilogues carry no seq; fall back to the checkpoint's.
+    # External epilogues carry no seq; fall back to the checkpoint's.
     ep_seq = (epilogue or {}).get("seq")
     last_seq = ep_seq if ep_seq is not None \
         else (checkpoint or {}).get("seq", 0)
