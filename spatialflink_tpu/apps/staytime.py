@@ -159,9 +159,10 @@ def stay_time_window_soa(ts, oid, xy, grid: UniformGrid, kernel):
     """One window's (cell_ids, dwell_ms) via the segment-sum kernel —
     the device core shared by ``cell_stay_time_soa`` and the composed
     DAG's StayTime node (dag.py). ``ts``/``oid`` int64 arrays, ``xy``
-    (N, 2) float64; ``kernel`` a jitted stay_time_cells_kernel."""
-    import jax.numpy as jnp
-
+    (N, 2) float64; ``kernel`` a jitted stay_time_cells_kernel. Crosses
+    the link through ``ship`` / ``telemetry.fetch`` like the operators."""
+    from spatialflink_tpu.operators.base import ship
+    from spatialflink_tpu.telemetry import telemetry
     from spatialflink_tpu.utils.padding import next_bucket
 
     if len(ts) < 2:
@@ -177,12 +178,10 @@ def stay_time_window_soa(ts, oid, xy, grid: UniformGrid, kernel):
     cp = np.concatenate(
         [cells, np.full(pad, grid.num_cells, np.int64)]).astype(np.int32)
     vp = np.concatenate([np.ones(len(ts), bool), np.zeros(pad, bool)])
-    dwell, cnt = kernel(
-        jnp.asarray(tp), jnp.asarray(cp), jnp.asarray(op_),
-        jnp.asarray(vp), num_cells=grid.num_cells,
-    )
-    dwell = np.asarray(dwell).astype(np.int64)
-    hit = np.nonzero(np.asarray(cnt))[0].astype(np.int32)
+    dwell, cnt = telemetry.fetch(
+        kernel(*ship(tp, cp, op_, vp), num_cells=grid.num_cells))
+    dwell = dwell.astype(np.int64)
+    hit = np.nonzero(cnt)[0].astype(np.int32)
     return hit, dwell[hit]
 
 

@@ -51,6 +51,7 @@ from typing import Any, Dict
 import numpy as np
 
 from spatialflink_tpu.streams.windows import WindowAssembler, WindowSpec
+from spatialflink_tpu.telemetry import telemetry
 from spatialflink_tpu.utils.interning import Interner
 
 
@@ -368,24 +369,28 @@ def save_checkpoint(path: str, **components) -> None:
 
     dirname = os.path.dirname(os.path.abspath(path))
     os.makedirs(dirname, exist_ok=True)
-    payload = pickle.dumps(components, protocol=pickle.HIGHEST_PROTOCOL)
+    with telemetry.span("checkpoint.pickle"):
+        payload = pickle.dumps(components, protocol=pickle.HIGHEST_PROTOCOL)
     tmp = path + ".tmp"
-    with open(tmp, "wb") as f:
-        f.write(CHECKPOINT_MAGIC)
-        f.write(struct.pack(">IIQ", CHECKPOINT_VERSION,
-                            zlib.crc32(payload) & 0xFFFFFFFF, len(payload)))
-        f.write(payload)
-        f.flush()
-        os.fsync(f.fileno())
-    os.replace(tmp, path)  # atomic publish
-    try:
-        dfd = os.open(dirname, os.O_RDONLY)
+    framed = len(CHECKPOINT_MAGIC) + struct.calcsize(">IIQ") + len(payload)
+    with telemetry.span("checkpoint.write", bytes=framed):
+        with open(tmp, "wb") as f:
+            f.write(CHECKPOINT_MAGIC)
+            f.write(struct.pack(">IIQ", CHECKPOINT_VERSION,
+                                zlib.crc32(payload) & 0xFFFFFFFF,
+                                len(payload)))
+            f.write(payload)
+            f.flush()
+            os.fsync(f.fileno())
+        os.replace(tmp, path)  # atomic publish
         try:
-            os.fsync(dfd)
-        finally:
-            os.close(dfd)
-    except OSError:  # pragma: no cover - platform without dir fsync
-        pass
+            dfd = os.open(dirname, os.O_RDONLY)
+            try:
+                os.fsync(dfd)
+            finally:
+                os.close(dfd)
+        except OSError:  # pragma: no cover - platform without dir fsync
+            pass
 
 
 def load_checkpoint(path: str) -> Dict[str, Any]:

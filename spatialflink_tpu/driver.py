@@ -790,24 +790,31 @@ class WindowedDataflowDriver:
             if final:
                 self._commit_sink_only()
             return
-        egress = None
-        if self.sink is not None and hasattr(self.sink, "commit"):
-            egress = self.sink.commit()
-        components: Dict[str, Any] = {
-            "op": operator_state(self.op),
-            "driver": {
-                "events_consumed": self._consumed,
-                "windows": self.stats["windows"],
-                "backend": self.backend,
-            },
-        }
-        if egress is not None:
-            components["egress"] = egress
-        if self.overload is not None:
-            components["overload"] = self.overload.state()
-        if self.extra_state is not None:
-            components.update(self.extra_state())
-        save_checkpoint(self.checkpoint_path, **components)
+        # One ``commit`` span a published checkpoint, its parts as
+        # children: ``commit.egress`` (the sinks' fsync'd appends),
+        # ``commit.state`` (gathering the components), and inside
+        # save_checkpoint ``checkpoint.pickle`` / ``checkpoint.write``.
+        with telemetry.span("commit"):
+            egress = None
+            with telemetry.span("commit.egress"):
+                if self.sink is not None and hasattr(self.sink, "commit"):
+                    egress = self.sink.commit()
+            with telemetry.span("commit.state"):
+                components: Dict[str, Any] = {
+                    "op": operator_state(self.op),
+                    "driver": {
+                        "events_consumed": self._consumed,
+                        "windows": self.stats["windows"],
+                        "backend": self.backend,
+                    },
+                }
+                if egress is not None:
+                    components["egress"] = egress
+                if self.overload is not None:
+                    components["overload"] = self.overload.state()
+                if self.extra_state is not None:
+                    components.update(self.extra_state())
+            save_checkpoint(self.checkpoint_path, **components)
         self.stats["checkpoints"] += 1
         self._since_ckpt = 0
         self._stamp_committed()

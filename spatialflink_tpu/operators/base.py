@@ -164,15 +164,11 @@ class SpatialOperator:
     def device_q(self, coords, dtype):
         """Device-ready coordinates (any (..., 2) array-like): origin-
         centered before sub-f64 casts. The one centering entry point —
-        device_xy/device_verts are shape-documenting aliases. Telemetry's
-        host→device byte accounting hooks here (the host array's nbytes,
-        read BEFORE the ship — no extra device traffic)."""
-        import jax.numpy as jnp
-
+        device_xy/device_verts are shape-documenting aliases. Crosses the
+        link through ``_h2d`` like ``ship`` (same accounting, same leaf
+        span), minus ``ship``'s chaos injection point."""
         host = center_coords(self.grid, np.asarray(coords, np.float64), dtype)
-        if telemetry.enabled:
-            telemetry.account_h2d(host.nbytes)
-        return jnp.asarray(host)
+        return _h2d((host,))[0]
 
     def device_xy(self, batch: PointBatch, dtype):
         """Device-ready point-batch coordinates."""
@@ -278,24 +274,45 @@ def check_oid_range(oid, num_segments: int) -> None:
         )
 
 
+def _link_nbytes(a) -> int:
+    """Bytes ``a`` occupies once it has crossed: ``jnp.asarray`` lands a
+    float64/int64 host array as its 32-bit twin while x64 is off (the TPU
+    default), so the host array's own ``nbytes`` would count double."""
+    if not hasattr(a, "dtype"):
+        a = np.asarray(a)
+    return int(a.size) * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+
+
+def _h2d(arrays):
+    """``jnp.asarray`` each host array (``None`` lanes pass through): THE
+    host→device crossing of ``ship`` and ``device_q``. Enabled telemetry
+    counts the crossing bytes (read from host metadata before the
+    transfer — no extra device traffic) and times the conversions as one
+    ``h2d`` leaf span; disabled, the span is the shared null span."""
+    import jax.numpy as jnp
+
+    args = {}
+    if telemetry.enabled:
+        live = [a for a in arrays if a is not None]
+        args = {"bytes": sum(_link_nbytes(a) for a in live),
+                "arrays": len(live)}
+        telemetry.account_h2d(args["bytes"])
+    with telemetry.span("h2d", **args):
+        return tuple(None if a is None else jnp.asarray(a) for a in arrays)
+
+
 def ship(*arrays):
     """``jnp.asarray`` each host array with host→device byte accounting.
 
     THE ship entry point for telemetry: tallies are taken here — at the
     conversion that actually crosses the host→device link — never inside batch
     builders, so ``bytes_h2d`` counts exactly the lanes a path ships
-    (``None`` lanes pass through unconverted and uncounted). Reads host
-    ``nbytes`` before the transfer — no extra device traffic.
+    (``None`` lanes pass through unconverted and uncounted), in the dtype
+    that lands on the device (``_link_nbytes``).
     """
-    import jax.numpy as jnp
-
     if faults.armed:  # chaos injection point (faults.py)
         faults.hit("device.ship")
-    if telemetry.enabled:
-        telemetry.account_h2d(
-            sum(np.asarray(a).nbytes for a in arrays if a is not None)
-        )
-    return tuple(None if a is None else jnp.asarray(a) for a in arrays)
+    return _h2d(arrays)
 
 
 def device_point_args(grid: UniformGrid, xy64: np.ndarray, oid, dtype):

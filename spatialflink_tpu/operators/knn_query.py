@@ -35,8 +35,22 @@ from spatialflink_tpu.ops.knn import (
     knn_polygon_fused,
     knn_polyline_fused,
 )
-from spatialflink_tpu.telemetry import telemetry
+from spatialflink_tpu.telemetry import instrument_jit, telemetry
 from spatialflink_tpu.utils.padding import next_bucket
+
+
+def _wire_digest_program(kind: str, step):
+    """The per-pane digest step as one instrumented program. ``step`` is a
+    ``functools.partial`` (ops/wire_knn.py binds the statics), which has no
+    ``__name__``: jitted as it is, it reaches the device trace as
+    ``jit__unknown`` and the kernel table not at all. Wrapped in a function
+    that carries the name, the ``XLA Modules`` line reads
+    ``jit_wire_digest_<kind>`` and ``instrument_jit`` counts every pane."""
+    def wire_digest(wire_s, n_valid, query_xy, scale, origin, radius):
+        return step(wire_s, n_valid, query_xy, scale, origin, radius)
+
+    name = wire_digest.__name__ = f"wire_digest_{kind}"
+    return instrument_jit(jax.jit(wire_digest), name=name)
 
 
 @dataclass
@@ -978,8 +992,6 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
                         wc.decode_wire_pane, n=nb,
                         num_segments=num_segments,
                     )
-                    from spatialflink_tpu.telemetry import instrument_jit
-
                     # Deliberately NOT donated: the px/py chain crosses
                     # MULTIPLE compiled instances (one per (pane,
                     # word-bucket) pair — empty gap panes alternate
@@ -1007,7 +1019,7 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
                     interpret=interpret, strategy=strategy,
                 )
                 self.last_wire_digest_kind = kind
-                jstep = jax.jit(step)
+                jstep = _wire_digest_program(kind, step)
 
             def compute_stage(item, staged):
                 i, _ = item
@@ -1106,7 +1118,7 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
                     interpret=interpret, strategy=strategy,
                 )
                 self.last_wire_digest_kind = kind
-                jstep = jax.jit(step)
+                jstep = _wire_digest_program(kind, step)
             d = jstep(wire_d, jnp.int32(n), q, scale, origin, r32)
             digests.append((d.seg_min, d.rep))
             del digests[:-ppw]

@@ -159,22 +159,17 @@ def _zone_hit_kernel(pts, verts, evs, bufs):
     return jnp.any(hit, axis=0)
 
 
-_zone_hit_jit = None
-
-
 def contains_any_zone(zones: Sequence[BufferedZone], xy_metric: np.ndarray) -> np.ndarray:
     """(N,) bool: point within any buffered zone — one jitted program
-    (compiled per point-bucket/zone-shape, cached)."""
-    global _zone_hit_jit
-    import jax
-    import jax.numpy as jnp
-
+    (compiled per point-bucket/zone-shape, cached), through the operators'
+    own choke points (``ship`` / ``jitted`` / ``telemetry.fetch``) so the
+    byte counters and the kernel table see it."""
+    from spatialflink_tpu.operators.base import jitted, ship
+    from spatialflink_tpu.telemetry import telemetry
     from spatialflink_tpu.utils.padding import next_bucket, pad_to_bucket
 
     if not zones or not len(xy_metric):
         return np.zeros(len(xy_metric), bool)
-    if _zone_hit_jit is None:
-        _zone_hit_jit = jax.jit(_zone_hit_kernel)
     vmax = max(sum(len(r) + 1 for r in z.rings_metric) for z in zones)
     v = next_bucket(vmax, minimum=8)
     verts = np.zeros((len(zones), v, 2))
@@ -197,10 +192,8 @@ def contains_any_zone(zones: Sequence[BufferedZone], xy_metric: np.ndarray) -> n
     # padded lanes land far outside every zone (coordinates 1e12 m).
     b = next_bucket(n)
     pts = pad_to_bucket(np.asarray(xy_metric, float) - origin, b, fill=1e12)
-    hit = _zone_hit_jit(
-        jnp.asarray(pts), jnp.asarray(verts), jnp.asarray(evs), jnp.asarray(bufs)
-    )
-    return np.asarray(hit)[:n]
+    hit = jitted(_zone_hit_kernel)(*ship(pts, verts, evs, bufs))
+    return telemetry.fetch(hit)[:n]
 
 
 def contains_any_zone_np(zones: Sequence[BufferedZone],

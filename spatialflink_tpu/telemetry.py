@@ -42,6 +42,7 @@ diffs, and gates on.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import sys
@@ -174,7 +175,15 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tel", "name", "args", "_t0")
+    """One timed phase. Besides the Chrome-trace event it emits on exit,
+    the span enters a ``jax.profiler.TraceAnnotation`` of the same name:
+    a level check with no profiler session, and under
+    ``jax.profiler.start_trace`` the span lands on the host plane of the
+    same ``.xplane.pb`` as the device's ``XLA Modules`` / ``XLA Ops``
+    lines, on the profiler's clock. ``dur_ns`` is readable after exit
+    (``instrument_jit`` feeds the kernel table from it)."""
+
+    __slots__ = ("_tel", "name", "args", "_t0", "_ann", "dur_ns")
 
     def __init__(self, tel: "Telemetry", name: str, args: Dict[str, Any]):
         self._tel = tel
@@ -182,13 +191,17 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        self._tel._emit_span(
-            self.name, self._t0, time.perf_counter_ns() - self._t0, self.args
-        )
+        self.dur_ns = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(*exc)
+        self._tel._emit_span(self.name, self._t0, self.dur_ns, self.args)
         return False
 
 
@@ -359,6 +372,15 @@ class Telemetry:
         self._e2e_evicted = 0
         self._e2e_stages: Dict[str, FixedBucketLatency] = {}
         self._e2e_nodes: Dict[str, Dict[str, FixedBucketLatency]] = {}
+        # Cyclic-GC accounting (_gc_callback, installed by enable()):
+        # every full (generation-2) pass is a ``gc.full`` span; the
+        # young generations only add to these integers (no event per
+        # young pass — the event buffer is capped).
+        self._gc_t0: Optional[int] = None
+        self.gc_full_passes = 0
+        self.gc_full_ns = 0
+        self.gc_young_passes = 0
+        self.gc_young_ns = 0
         # Flight recorder (the crash black box): bounded ring of the
         # last-N window-span summaries + instant events, dumped to
         # <stream>.blackbox.json on fault fire and stream seal (which
@@ -379,7 +401,9 @@ class Telemetry:
                recompile_warn_threshold: int = 8,
                stream_path: Optional[str] = None,
                stream_flush_interval_s: Optional[float] = None):
-        """Reset all state and start recording. ``trace_path``: optional
+        """Reset all state, start recording, and hook the cyclic GC
+        (``_gc_callback``; ``disable()`` unhooks it, so a disabled
+        process leaves ``gc.callbacks`` alone). ``trace_path``: optional
         Chrome-trace JSON-lines file (events also buffer in memory, capped
         at ``max_events``). ``stream_path``: optional append-only ledger
         stream (JSONL) — a versioned prologue now, checkpoint + span-batch
@@ -436,6 +460,8 @@ class Telemetry:
                              + os.path.basename(sys.argv[0] or "python")},
                 })
             self.enabled = True
+            if self._gc_callback not in gc.callbacks:
+                gc.callbacks.append(self._gc_callback)
         # A plan armed BEFORE telemetry came up (the SFT_FAULT_PLAN
         # import-time path every chaos subprocess uses) would otherwise
         # never record its fault_armed event — emit it now so any
@@ -464,6 +490,8 @@ class Telemetry:
         never strand ``_since_flush`` buffered events."""
         with self._lock:
             self.enabled = False
+            if self._gc_callback in gc.callbacks:
+                gc.callbacks.remove(self._gc_callback)
             self.seal_stream("disabled")
             if self._trace_file is not None:
                 self._trace_file.flush()
@@ -577,6 +605,33 @@ class Telemetry:
             self._stream_file.close()
             self._stream_file = None
             self._stream_sealed = True
+
+    # -- cyclic GC -------------------------------------------------------------
+
+    def _gc_callback(self, phase: str, info: Dict[str, int]):
+        """``gc.callbacks`` hook, installed only while enabled. The
+        interpreter runs one collection at a time and calls this on the
+        thread that triggered it, possibly while that thread is inside
+        ``_emit`` (the lock is an RLock): clock reads, integer adds and
+        one ``_emit_span`` only. The scope tags each full pass with the
+        node it interrupted."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        if t0 is None:  # enabled between a pass's start and its stop
+            return
+        dur_ns = time.perf_counter_ns() - t0
+        if info["generation"] < 2:
+            self.gc_young_passes += 1
+            self.gc_young_ns += dur_ns
+            return
+        self.gc_full_passes += 1
+        self.gc_full_ns += dur_ns
+        self._emit_span("gc.full", t0, dur_ns, {
+            "collected": info["collected"],
+            "uncollectable": info["uncollectable"],
+        })
 
     # -- node-attribution scope ------------------------------------------------
 
@@ -783,7 +838,8 @@ class Telemetry:
                 })
 
     def fetch(self, x):
-        """True-sync device→host fetch with timing + byte accounting.
+        """True-sync device→host fetch with timing + byte accounting
+        (one ``d2h`` span carrying ``bytes``).
 
         The tree's ONE synchronization point (sfcheck sync-discipline):
         a real ``jax.device_get``, so every wait on the device is timed
@@ -797,23 +853,16 @@ class Telemetry:
             faults.hit("device.fetch")
         if not self.enabled:
             return jax.device_get(x)
-        t0 = time.perf_counter_ns()
-        out = jax.device_get(x)
-        dur_ns = time.perf_counter_ns() - t0
-        nbytes = 0
-        for leaf in jax.tree_util.tree_leaves(out):
-            nbytes += getattr(leaf, "nbytes", 0)
+        # The leaf is named ``d2h``, not ``fetch``: the operators' phase
+        # spans own that name, and a sum by name must not count the wait
+        # twice.
+        with _Span(self, "d2h", {}) as sp:
+            out = jax.device_get(x)
+            nbytes = 0
+            for leaf in jax.tree_util.tree_leaves(out):
+                nbytes += getattr(leaf, "nbytes", 0)
+            sp.args["bytes"] = int(nbytes)
         self.account_d2h(nbytes)
-        fetch_args: Dict[str, Any] = {"bytes": int(nbytes)}
-        node = self.current_node()
-        if node is not None:
-            fetch_args["node"] = node
-        self._emit({
-            "name": "fetch", "cat": "telemetry", "ph": "X",
-            "ts": t0 // 1000, "dur": dur_ns // 1000,
-            "pid": os.getpid(), "tid": threading.get_ident(),
-            "args": fetch_args,
-        })
         return out
 
     # -- recompile detection --------------------------------------------------
@@ -1572,6 +1621,13 @@ class Telemetry:
             )
             if self.fault_fires:
                 out["faults"] = dict(self.fault_fires)
+            if self.gc_full_passes or self.gc_young_passes:
+                out["gc"] = {
+                    "full_passes": self.gc_full_passes,
+                    "full_ns": self.gc_full_ns,
+                    "young_passes": self.gc_young_passes,
+                    "young_ns": self.gc_young_ns,
+                }
             if self.shed_events or self.shed_bytes:
                 out["shed"] = {"events": self.shed_events,
                                "bytes": self.shed_bytes}
@@ -1806,8 +1862,10 @@ def instrument_jit(fn, name: Optional[str] = None):
     ``operators/base.py:jitted`` routes every operator kernel through this;
     bench.py wraps its hand-jitted steps the same way. Disabled-path cost:
     one attribute check per call (calls here are per WINDOW, never per
-    record). Enabled, each call adds two clock reads and a locked table
-    update; a NEW signature additionally stashes ShapeDtypeStruct avals
+    record). Enabled, each call is one ``dispatch:<kernel>`` span (the
+    ``compile:<kernel>`` instant's naming) whose duration also feeds the
+    locked table update; a NEW signature additionally stashes
+    ShapeDtypeStruct avals
     so ``telemetry.capture_costs()`` can lower/compile host-side later —
     nothing device-facing happens on the call path. Attributes of the
     underlying jit object (``lower``, …) pass through.
@@ -1834,11 +1892,11 @@ def instrument_jit(fn, name: Optional[str] = None):
                 return fn(*args, **kwargs)
             sig = abstract_signature(args, kwargs)
             is_new = telemetry.record_jit_call(label, sig)
-            t0 = time.perf_counter_ns()
-            out = fn(*args, **kwargs)
-            dur_ns = time.perf_counter_ns() - t0
+            with _Span(telemetry, f"dispatch:{label}",
+                       {"new_signature": is_new}) as sp:
+                out = fn(*args, **kwargs)
             telemetry.record_kernel_time(
-                label, sig, dur_ns,
+                label, sig, sp.dur_ns,
                 lower_ctx=_lower_ctx(fn, args, kwargs) if is_new else None,
             )
             return out

@@ -478,6 +478,79 @@ class TestNodeParity:
 
 
 # ---------------------------------------------------------------------------
+# Leaf spans inside the node walk (h2d / dispatch:<kernel> / d2h)
+
+
+LEAVES = ("h2d", "d2h")
+
+
+def _is_leaf(e):
+    return e["name"] in LEAVES or e["name"].startswith("dispatch:")
+
+
+@pytest.fixture(scope="module")
+def sncb_traced(tmp_path_factory):
+    """One 7-node run with telemetry on: (sink bytes, events, kernel
+    table, snapshot) — taken before any other test resets the singleton."""
+    d = tmp_path_factory.mktemp("dag_traced")
+    telemetry.enable()
+    try:
+        _run_sncb_leg(str(d))
+        got = (_sink_bytes(str(d)), list(telemetry.events),
+               telemetry.kernel_table(), telemetry.snapshot())
+    finally:
+        telemetry.disable()
+    return got
+
+
+class TestLeafSpans:
+    def test_every_leaf_inside_the_walk_names_its_node(self, sncb_traced):
+        _, events, _, _ = sncb_traced
+        spans = [e for e in events if e.get("ph") == "X"]
+        walks = [e for e in spans if e["name"] == "window.dag"]
+        assert walks
+        seen = set()
+        for w in walks:
+            lo, hi = w["ts"], w["ts"] + w["dur"]
+            for e in spans:
+                if _is_leaf(e) and lo <= e["ts"] and \
+                        e["ts"] + e["dur"] <= hi + 1:
+                    assert e["args"].get("node") in SNCB_SINKS, e
+                    seen.add((e["args"]["node"], e["name"].split(":")[0]))
+        # the zone nodes, StayTime and qserve: every node with a device
+        # path crosses the link through the choke points
+        for node in ("q1", "q2", "q5", "staytime", "qserve"):
+            for kind in ("h2d", "dispatch", "d2h"):
+                assert (node, kind) in seen, (node, kind)
+
+    def test_zone_kernel_is_in_the_kernel_table(self, sncb_traced):
+        _, events, table, _ = sncb_traced
+        rows = [r for r in table if r["kernel"] == "_zone_hit_kernel"]
+        assert {r["node"] for r in rows} == {"q1", "q2", "q5"}
+        spans = [e for e in events
+                 if e["name"] == "dispatch:_zone_hit_kernel"]
+        assert sum(r["calls"] for r in rows) == len(spans) > 0
+        # every program the walk dispatched went through the one wrapper
+        assert sum(r["calls"] for r in table) == sum(
+            e["name"].startswith("dispatch:") for e in events)
+
+    def test_zone_ships_are_counted(self, sncb_traced):
+        _, events, _, snap = sncb_traced
+        h2d = [e for e in events if e["name"] == "h2d"]
+        assert snap["h2d_transfers"] == len(h2d)
+        assert snap["bytes_h2d"] == sum(e["args"]["bytes"] for e in h2d)
+        for node in ("q1", "q2", "q5", "staytime"):
+            mine = [e for e in h2d if e["args"].get("node") == node]
+            assert mine and all(e["args"]["arrays"] == 4 for e in mine)
+            assert snap["nodes"][node]["h2d_transfers"] == len(mine)
+            assert snap["nodes"][node]["d2h_transfers"] == len(mine)
+
+    def test_committed_lines_identical_with_telemetry_on(
+            self, sncb_traced, sncb_clean):
+        assert sncb_traced[0] == sncb_clean
+
+
+# ---------------------------------------------------------------------------
 # CheckIn node (stateful: occupancy + per-user last-event carry)
 
 

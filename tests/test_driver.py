@@ -468,6 +468,60 @@ class TestCheckpointResume:
         with pytest.raises(CheckpointCorruptError, match="truncated"):
             _range_pipeline(str(d))
 
+    def test_commit_span_holds_its_four_parts_in_order(self, tmp_path):
+        """Every published checkpoint is one ``commit`` span; egress
+        append, state gathering, pickle and the durable write lie
+        inside it, in that order, and account for it."""
+        d = tmp_path / "p"
+        d.mkdir()
+        telemetry.enable()
+        drv = _range_pipeline(str(d))
+        spans = [e for e in telemetry.events if e.get("ph") == "X"]
+        commits = [e for e in spans if e["name"] == "commit"]
+        assert len(commits) == drv.stats["checkpoints"] > 1
+        parts = ("commit.egress", "commit.state", "checkpoint.pickle",
+                 "checkpoint.write")
+        for c in commits:
+            lo, hi = c["ts"], c["ts"] + c["dur"]
+            inside = [e for e in spans if e["name"] in parts
+                      and lo - 1 <= e["ts"] and e["ts"] + e["dur"] <= hi + 1]
+            assert [e["name"] for e in inside] == list(parts)
+            for a, b in zip(inside, inside[1:]):
+                assert a["ts"] + a["dur"] <= b["ts"] + 1
+        # one of each part per commit: nothing strays outside a commit
+        for name in parts:
+            assert sum(e["name"] == name for e in spans) == len(commits)
+
+    def test_checkpoint_write_span_carries_the_files_size(self, tmp_path):
+        d = tmp_path / "p"
+        d.mkdir()
+        telemetry.enable()
+        _range_pipeline(str(d))
+        writes = [e for e in telemetry.events
+                  if e["name"] == "checkpoint.write"]
+        assert writes[-1]["args"]["bytes"] == \
+            os.path.getsize(str(d / "ckpt.bin"))
+        assert all(type(e["args"]["bytes"]) is int for e in writes)
+
+    def test_commit_without_a_checkpoint_path_emits_no_commit_span(self):
+        telemetry.enable()
+        _run_range(driver=WindowedDataflowDriver())
+        assert not [e for e in telemetry.events
+                    if e["name"].startswith(("commit", "checkpoint."))]
+
+    def test_checkpoints_identical_with_telemetry_on_and_off(self, tmp_path):
+        off, on = tmp_path / "off", tmp_path / "on"
+        off.mkdir()
+        on.mkdir()
+        _range_pipeline(str(off))
+        telemetry.enable()
+        _range_pipeline(str(on))
+        telemetry.disable()
+        assert (on / "egress.csv").read_bytes() == \
+            (off / "egress.csv").read_bytes()
+        a, b = (load_checkpoint(str(x / "ckpt.bin")) for x in (off, on))
+        assert a["driver"] == b["driver"] and a["egress"] == b["egress"]
+
     def test_run_windows_rejects_checkpointing(self):
         drv = WindowedDataflowDriver(checkpoint_path="x.bin")
         drv.op = object()

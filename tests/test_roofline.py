@@ -166,6 +166,36 @@ def test_per_operator_breakdown():
     assert per["window.a"]["phases_us"]["transfer"] == 30_000
 
 
+def test_link_leaves_nested_or_bare_keep_the_transfer_total():
+    """Leaves under their phase spans change no total (top-level children
+    only); a bare leaf — a path that fetches outside any ``fetch`` phase
+    span — still counts as transfer (it was named ``fetch`` before)."""
+    phases_only = [
+        _ev("window.a", 0, 50_000),
+        _ev("ship", 0, 20_000),
+        _ev("compute", 20_000, 10_000),
+        _ev("fetch", 30_000, 10_000),
+    ]
+    with_leaves = phases_only + [
+        _ev("h2d", 1_000, 18_000),
+        _ev("dispatch:k", 21_000, 8_000),
+        _ev("d2h", 31_000, 8_000),
+    ]
+    want = roofline.classify(_doc(), phases_only)["per_operator"]
+    assert roofline.classify(_doc(), with_leaves)["per_operator"] == want
+    assert want["window.a"]["phases_us"]["transfer"] == 30_000
+    bare = [
+        _ev("window.b", 0, 50_000),
+        _ev("h2d", 0, 5_000),
+        _ev("dispatch:k", 5_000, 10_000),
+        _ev("d2h", 15_000, 30_000),
+    ]
+    per = roofline.classify(_doc(), bare)["per_operator"]["window.b"]
+    assert per["phases_us"]["transfer"] == 35_000
+    assert per["phases_us"]["compute"] == 10_000
+    assert per["verdict"] == "link-bound"
+
+
 def test_verdict_vocabulary_is_closed():
     # Dashboards and the trend store key on the verdict strings.
     assert set(roofline.BOUND_KINDS) == {

@@ -36,12 +36,27 @@ from spatialflink_tpu.telemetry import (
 GRID = UniformGrid(20, 0.0, 10.0, 0.0, 10.0)
 
 
+def _inside(child, parent, slack_us=1):
+    """Chrome-trace containment (ts and dur are floored to µs apart)."""
+    return (child["ts"] >= parent["ts"] - slack_us
+            and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + slack_us)
+
+
+def _named(name):
+    return [e for e in telemetry.events if e["name"] == name]
+
+
 @pytest.fixture(autouse=True)
 def _telemetry_off():
-    """Every test leaves the process-global singleton disabled, with the
+    """Every test starts AND leaves the process-global singleton disabled
+    with zero counters (an earlier file of the same xdist worker may have
+    left them non-zero: disable() keeps what a run counted), and with the
     event-buffer cap restored (enable() resets counters but deliberately
     not the configured cap — a test shrinking it must not leak that into
     later files)."""
+    telemetry.disable()
+    telemetry._reset_state()
     cap = telemetry.max_events
     yield
     telemetry.max_events = cap
@@ -331,8 +346,11 @@ def test_fetch_accounts_bytes_and_emits_event():
     np.testing.assert_array_equal(out[0], np.arange(1024, dtype=np.float32))
     assert telemetry.d2h_transfers == 1
     assert telemetry.d2h_bytes == 2 * 1024 * 4
-    (ev,) = [e for e in telemetry.events if e["name"] == "fetch"]
-    assert ev["args"]["bytes"] == 2 * 1024 * 4
+    # The byte-carrying leaf is named "d2h": "fetch" is the operators'
+    # phase span, and a sum by name must not count the wait twice.
+    (ev,) = [e for e in telemetry.events if e["name"] == "d2h"]
+    assert ev["ph"] == "X" and ev["args"]["bytes"] == 2 * 1024 * 4
+    assert not [e for e in telemetry.events if e["name"] == "fetch"]
 
 
 def test_operator_ship_path_accounts_h2d():
@@ -426,12 +444,15 @@ def test_range_query_results_identical_with_telemetry(rng, tmp_path):
         assert phase in names, phase
     assert telemetry.window_latency.count == names.count("window.range")
     # Instrumentation rides the operator's own fetches, never adds one:
-    # exactly one counted d2h transfer per "fetch" phase span (the byte-
-    # carrying fetch events and the phase spans share the name; tell them
-    # apart by the args payload).
-    fetch_spans = [e for e in doc["traceEvents"]
-                   if e["name"] == "fetch" and "bytes" not in e.get("args", {})]
-    assert telemetry.d2h_transfers == len(fetch_spans)
+    # exactly one counted d2h transfer — one byte-carrying "d2h" leaf —
+    # per "fetch" phase span, each leaf inside its phase span.
+    fetch_spans = [e for e in doc["traceEvents"] if e["name"] == "fetch"]
+    d2h = [e for e in doc["traceEvents"] if e["name"] == "d2h"]
+    assert all("bytes" not in e.get("args", {}) for e in fetch_spans)
+    assert telemetry.d2h_transfers == len(fetch_spans) == len(d2h)
+    for leaf, phase in zip(d2h, fetch_spans):
+        assert _inside(leaf, phase)
+    assert sum(e["args"]["bytes"] for e in d2h) == telemetry.d2h_bytes
     assert telemetry.h2d_bytes > 0 and telemetry.d2h_bytes > 0
 
 
@@ -485,3 +506,170 @@ def test_reporter_line_gains_telemetry_columns(tmp_path):
     # Off → the reference's exact column set, no telemetry columns.
     line = rep.report(now=1_700_000_001.0)
     assert "watermark_lag_ms_max" not in line
+
+
+# -- leaf spans at the link's choke points ------------------------------------
+
+
+def test_ship_emits_one_h2d_leaf_with_the_counted_bytes():
+    telemetry.enable()
+    before = telemetry.h2d_bytes
+    base_mod.ship(np.ones(16, bool), None, np.zeros(16, np.int32))
+    (ev,) = _named("h2d")
+    assert ev["ph"] == "X" and ev["cat"] == "telemetry"
+    assert ev["args"] == {"bytes": 16 + 16 * 4, "arrays": 2}
+    assert ev["args"]["bytes"] == telemetry.h2d_bytes - before
+    assert telemetry.h2d_transfers == 1
+
+
+def test_device_q_emits_one_h2d_leaf_with_the_counted_bytes():
+    telemetry.enable()
+    conf = QueryConfiguration(QueryType.WindowBased, window_size=10,
+                              slide_step=5)
+    PointPointRangeQuery(conf, GRID).device_q(np.zeros((16, 2)), np.float32)
+    (ev,) = _named("h2d")
+    assert ev["args"] == {"bytes": 16 * 2 * 4, "arrays": 1}
+    assert telemetry.h2d_bytes == 16 * 2 * 4 and telemetry.h2d_transfers == 1
+
+
+def test_h2d_counts_the_dtype_that_crosses():
+    """With x64 off (the chip) a float64 / int64 host array lands as its
+    32-bit twin: the counter holds what crossed, not the host's nbytes."""
+    telemetry.enable()
+    f64, i64 = np.zeros(8, np.float64), np.zeros(8, np.int64)
+    base_mod.ship(f64, i64)  # the tests run x64 ON: 8 bytes each
+    assert telemetry.h2d_bytes == 2 * 8 * 8
+    jax.config.update("jax_enable_x64", False)
+    try:
+        base_mod.ship(f64, i64, np.zeros(8, np.uint16))
+    finally:
+        jax.config.update("jax_enable_x64", True)
+    assert _named("h2d")[-1]["args"]["bytes"] == 2 * 8 * 4 + 8 * 2
+
+
+def test_link_sites_hold_the_shared_null_span_when_disabled():
+    null = telemetry.span("anything")
+    for name in ("h2d", "commit", "commit.egress", "commit.state",
+                 "checkpoint.pickle", "checkpoint.write"):
+        assert telemetry.span(name) is null
+    # ...and the sites themselves leave no trace behind.
+    base_mod.ship(np.zeros(4, np.int32))
+    f = instrument_jit(jax.jit(lambda x: x + 1), name="off_kernel")
+    telemetry.fetch(f(jnp.arange(4)))
+    assert telemetry.events == [] and telemetry.kernel_table() == []
+    assert telemetry.h2d_transfers == 0 and telemetry.d2h_transfers == 0
+
+
+def test_instrument_jit_emits_one_dispatch_span_per_call():
+    telemetry.enable()
+    f = instrument_jit(jax.jit(lambda x: x * 2), name="leaf_kernel")
+    f(jnp.arange(4))
+    f(jnp.arange(4))
+    f(jnp.arange(8))
+    spans = _named("dispatch:leaf_kernel")
+    assert [e["args"]["new_signature"] for e in spans] == [True, False, True]
+    assert all(e["ph"] == "X" for e in spans)
+    rows = [r for r in telemetry.kernel_table()
+            if r["kernel"] == "leaf_kernel"]
+    assert sum(r["calls"] for r in rows) == len(spans) == 3
+    # The table's dispatch time IS the spans' (one pair of clock reads).
+    assert sum(r["dispatch_ns"] for r in rows) // 1000 >= \
+        sum(e["dur"] for e in spans)
+    # Same naming as the compile instant.
+    assert len(_named("compile:leaf_kernel")) == 2
+
+
+def test_leaves_carry_the_node_tag():
+    telemetry.enable()
+    f = instrument_jit(jax.jit(lambda x: x + 1), name="tagged")
+    with telemetry.scope("q7"):
+        (x,) = base_mod.ship(np.arange(4, dtype=np.int32))
+        telemetry.fetch(f(x))
+    for name in ("h2d", "dispatch:tagged", "d2h"):
+        (ev,) = _named(name)
+        assert ev["args"]["node"] == "q7", name
+
+
+# -- the cyclic GC ------------------------------------------------------------
+
+
+def test_full_gc_pass_is_one_span_young_passes_only_count():
+    import gc
+
+    telemetry.enable()
+    full0 = len(_named("gc.full"))
+    gc.collect()
+    full = _named("gc.full")
+    assert len(full) == full0 + 1
+    args = full[-1]["args"]
+    assert type(args["collected"]) is int
+    assert type(args["uncollectable"]) is int
+    assert telemetry.gc_full_passes == len(full)
+    young0, spans0 = telemetry.gc_young_passes, len(telemetry.events)
+    gc.collect(0)
+    assert telemetry.gc_young_passes == young0 + 1
+    assert len(telemetry.events) == spans0  # no event per young pass
+    block = telemetry.snapshot()["gc"]
+    assert block["full_passes"] == telemetry.gc_full_passes
+    assert block["young_passes"] == telemetry.gc_young_passes
+    assert block["full_ns"] >= full[-1]["dur"] * 1000
+    assert block["young_ns"] > 0
+
+
+def test_gc_full_span_names_the_node_it_interrupted():
+    import gc
+
+    telemetry.enable()
+    with telemetry.scope("staytime"):
+        gc.collect()
+    assert _named("gc.full")[-1]["args"]["node"] == "staytime"
+
+
+def test_gc_callback_installed_once_and_removed_on_disable():
+    import gc
+
+    n0 = len(gc.callbacks)
+    telemetry.enable()
+    telemetry.enable()
+    assert len(gc.callbacks) == n0 + 1
+    telemetry.disable()
+    assert len(gc.callbacks) == n0
+    gc.collect()  # off: nothing installed, nothing counted
+    assert telemetry.gc_full_passes == 0 and "gc" not in telemetry.snapshot()
+
+
+# -- one clock with the device trace ------------------------------------------
+
+
+def test_span_lands_on_a_host_plane_of_the_profilers_trace(tmp_path):
+    """Under jax.profiler.start_trace a telemetry span is a TraceMe of the
+    same name: the .xplane.pb holds it on a host plane, on the profiler's
+    clock, beside whatever the device planes hold."""
+    import glob
+
+    telemetry.enable()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        f = instrument_jit(jax.jit(lambda x: x + 1), name="on_the_trace")
+        with telemetry.span("window.clock_probe"):
+            telemetry.fetch(f(jnp.arange(4)))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    on_host = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    on_host[ev.name] = (ev.start_ns, ev.duration_ns)
+    for name in ("window.clock_probe", "dispatch:on_the_trace", "d2h"):
+        assert name in on_host, (name, sorted(on_host)[:40])
+    # On the profiler's clock the leaves lie inside the window span, as
+    # they do on telemetry's own.
+    w0, wd = on_host["window.clock_probe"]
+    for name in ("dispatch:on_the_trace", "d2h"):
+        t0, d = on_host[name]
+        assert w0 <= t0 and t0 + d <= w0 + wd, name

@@ -279,6 +279,63 @@ def test_wire_panes_producer_feeds_run_wire_panes(rng):
                                    atol=0)
 
 
+@pytest.mark.parametrize("strategy,interpret,kind", [
+    ("xla", False, "xla"),
+    ("pallas", True, "pallas"),
+])
+def test_wire_digest_step_goes_through_the_one_wrapper(
+        rng, strategy, interpret, kind):
+    """The per-pane digest program is named and instrumented: it sits in
+    the kernel table as ``wire_digest_<kind>``, one ``dispatch:`` span a
+    non-empty pane — and tracing it changes no result."""
+    from spatialflink_tpu.telemetry import telemetry
+
+    conf = QueryConfiguration(QueryType.WindowBased, window_size=10,
+                              slide_step=5)
+    panes = [_wire(rng, n)[0] for n in (300, 300, 260, 300)]
+
+    def run():
+        op = PointPointKNNQuery(conf, GRID)
+        out = [
+            (s, e, list(map(int, oo)), np.asarray(dd).tolist(), nv)
+            for s, e, oo, dd, nv in op.run_wire_panes(
+                panes, Point(x=5.0, y=5.0), 2.0, 6, NSEG, WF,
+                strategy=strategy, interpret=interpret)
+        ]
+        assert op.last_wire_digest_kind == kind
+        return out
+
+    plain = run()
+    telemetry.enable()
+    try:
+        traced = run()
+        rows = [r for r in telemetry.kernel_table()
+                if r["kernel"] == f"wire_digest_{kind}"]
+        spans = [e for e in telemetry.events
+                 if e["name"] == f"dispatch:wire_digest_{kind}"]
+        h2d = [e for e in telemetry.events if e["name"] == "h2d"]
+    finally:
+        telemetry.disable()
+    assert traced == plain and len(plain) >= len(panes)
+    assert sum(r["calls"] for r in rows) == len(spans) == len(panes)
+    # one ship a pane, 6 B a point at the pane's bucket
+    assert len(h2d) == len(panes)
+    assert all(e["args"]["bytes"] % 6 == 0 for e in h2d)
+
+
+def test_wire_digest_program_is_named_on_the_device_trace():
+    """A functools.partial has no __name__, so jax.jit names its module
+    ``jit__unknown``; the wrapper's module carries the step's name (what the
+    trace's ``XLA Modules`` line shows)."""
+    from spatialflink_tpu.operators.knn_query import _wire_digest_program
+
+    step = make_wire_digest_step(num_segments=NSEG, cand=256)
+    wire = np.zeros((3, 64), np.uint16)
+    assert "@jit__unknown" in jax.jit(step).lower(*_args(wire)).as_text()
+    prog = _wire_digest_program("xla", step)
+    assert "@jit_wire_digest_xla" in prog.lower(*_args(wire)).as_text()
+
+
 def test_wire_panes_rejects_out_of_order():
     from spatialflink_tpu.streams.wire import wire_panes
 

@@ -363,8 +363,13 @@ def classify(doc: Optional[Dict[str, Any]], events: List[dict],
     }
 
 
-#: Phase names that are boundary transfers in the PR 1 span convention.
-_LINK_PHASES = ("ship", "fetch")
+#: Phase names that are boundary transfers in the PR 1 span convention,
+#: and the link's own leaf spans (``operators/base.py:_h2d``,
+#: ``telemetry.fetch``). Only top-level children are summed
+#: (attribution.py), so a leaf nested in its phase span is not counted
+#: again; one that a path emits outside any phase span (a DAG node's
+#: ``ship``, the pane paths' bare ``telemetry.fetch``) still reads as link.
+_LINK_PHASES = ("ship", "fetch", "h2d", "d2h")
 
 
 def _per_operator(ops: Dict[str, dict]) -> Dict[str, dict]:
