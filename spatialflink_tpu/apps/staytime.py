@@ -158,8 +158,9 @@ def cell_stay_time_soa(
 def stay_time_window_soa(ts, oid, xy, grid: UniformGrid, kernel):
     """One window's (cell_ids, dwell_ms) via the segment-sum kernel —
     the device core shared by ``cell_stay_time_soa`` and the composed
-    DAG's StayTime node (dag.py). ``ts``/``oid`` int64 arrays, ``xy``
-    (N, 2) float64; ``kernel`` a jitted stay_time_cells_kernel. Crosses
+    DAG's StayTime node (dag.py, straight from the window's columns).
+    ``ts``/``oid`` integer arrays, ``xy`` (N, 2) float64; ``kernel`` a
+    jitted stay_time_cells_kernel. Crosses
     the link through ``ship`` / ``telemetry.fetch`` like the operators."""
     from spatialflink_tpu.operators.base import ship
     from spatialflink_tpu.telemetry import telemetry

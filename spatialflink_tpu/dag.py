@@ -15,6 +15,10 @@ module composes it:
   (the CIKM 2020 grid design assumes exactly this sharing — a
   throughput win by construction, and the deliberate deviation from the
   reference's per-query window configs; PARITY.md "Composed dataflow").
+  The fired window itself is shared the same way: the DAG turns it into
+  columns ONCE (streams/columns.py, span ``window.columns``) and the
+  SNCB nodes compute from those arrays — no node walks ``win.events``
+  attribute by attribute (:meth:`DataflowDAG.columns`).
 - **The atomic unit checkpoint**: source position + the shared
   assembler + interner + EVERY node's backend/counters/substate
   (qserve registry, checkin occupancy) + EVERY sink's committed marker
@@ -77,6 +81,7 @@ from spatialflink_tpu.driver import (
 from spatialflink_tpu.faults import faults
 from spatialflink_tpu.mn.metrics import FixedBucketLatency, json_safe
 from spatialflink_tpu.models.objects import Point
+from spatialflink_tpu.streams.columns import WindowColumns
 from spatialflink_tpu.streams.sinks import MultiSink, TransactionalFileSink
 from spatialflink_tpu.streams.windows import (
     SlidingEventTimeWindows,
@@ -107,6 +112,10 @@ class DagNode:
     #: Numpy/host twin; ``None`` = no failover route for this node
     #: (an exhausted device path crashes the run for resume).
     fallback_process = None
+    #: True = this node computes from the window's columnar view
+    #: (``self.dag.columns(win)``); the DAG builds the view once per
+    #: fired window iff some node of it says so.
+    reads_columns = False
 
     def __init__(self, name: str, upstream: Optional[str] = None):
         if not name:
@@ -124,6 +133,17 @@ class DagNode:
         self.dag = dag
 
     def process(self, win: WindowBatch, results: Dict[str, Any]):
+        """One fired window in, this node's result out (what
+        :meth:`render` formats). A node may read: ``win.start`` /
+        ``win.end``; ``results`` (its upstream's window result);
+        ``self.dag.columns(win)`` when it declares ``reads_columns`` —
+        the window's point-like events as arrays in window order plus
+        its few non-point events (streams/columns.py), built ONCE and
+        shared by every node; and ``win.events`` itself for events the
+        view keeps as objects (CheckIn's door events) or to hand back
+        the few objects a result holds (``events[cols.pos[i]]``). A
+        node that walks ``win.events`` attribute by attribute pays
+        per event per node what the view pays once."""
         raise NotImplementedError
 
     def render(self, result, start: int, end: int) -> Iterator[str]:
@@ -136,14 +156,10 @@ class DagNode:
         pass
 
 
-def _gps_events(win: WindowBatch) -> list:
-    from spatialflink_tpu.sncb.common import GpsEvent
-
-    return [e for e in win.events if isinstance(e, GpsEvent)]
-
-
 class Q1Node(DagNode):
     """High-risk-zone proximity (Q1_HighRisk) — zone kernel + numpy twin."""
+
+    reads_columns = True
 
     def __init__(self, name: str, zones, radius_m: float = 20.0):
         super().__init__(name)
@@ -151,15 +167,16 @@ class Q1Node(DagNode):
 
         self.zones = buffer_q1_zones(zones, radius_m)
 
-    def process(self, win, results):
-        from spatialflink_tpu.sncb.queries import q1_window
+    def _run(self, win, backend):
+        from spatialflink_tpu.sncb.queries import q1_columns
 
-        return q1_window(_gps_events(win), self.zones)
+        return q1_columns(self.dag.columns(win), self.zones, backend=backend)
+
+    def process(self, win, results):
+        return self._run(win, "device")
 
     def fallback_process(self, win, results):
-        from spatialflink_tpu.sncb.queries import q1_window
-
-        return q1_window(_gps_events(win), self.zones, backend="numpy")
+        return self._run(win, "numpy")
 
     def render(self, result, start, end):
         for ev in result:
@@ -170,6 +187,8 @@ class Q1Node(DagNode):
 class Q2Node(DagNode):
     """Brake-pressure variation outside maintenance zones (Q2)."""
 
+    reads_columns = True
+
     def __init__(self, name: str, zones, var_fa_min: float = 0.6,
                  var_ff_max: float = 0.5):
         super().__init__(name)
@@ -178,10 +197,11 @@ class Q2Node(DagNode):
         self.var_ff_max = var_ff_max
 
     def _run(self, win, backend):
-        from spatialflink_tpu.sncb.queries import q2_window
+        from spatialflink_tpu.sncb.queries import q2_columns
 
-        return q2_window(_gps_events(win), self.zones, win.start, win.end,
-                         self.var_fa_min, self.var_ff_max, backend=backend)
+        return q2_columns(self.dag.columns(win), self.zones,
+                          win.start, win.end,
+                          self.var_fa_min, self.var_ff_max, backend=backend)
 
     def process(self, win, results):
         return self._run(win, "device")
@@ -196,12 +216,14 @@ class Q2Node(DagNode):
 
 
 class Q3Node(DagNode):
-    """Per-device window trajectory WKT (Q3) — pure host walk."""
+    """Per-device window trajectory WKT (Q3) — pure host work."""
+
+    reads_columns = True
 
     def process(self, win, results):
-        from spatialflink_tpu.sncb.queries import q3_window
+        from spatialflink_tpu.sncb.queries import q3_columns
 
-        return q3_window(_gps_events(win), win.start, win.end)
+        return q3_columns(self.dag.columns(win), win.start, win.end)
 
     def render(self, result, start, end):
         for o in result:
@@ -209,7 +231,9 @@ class Q3Node(DagNode):
 
 
 class Q4Node(DagNode):
-    """Q3 with bbox/time-range pushdown (Q4) — pure host walk."""
+    """Q3 with bbox/time-range pushdown (Q4) — pure host work."""
+
+    reads_columns = True
 
     def __init__(self, name: str, min_lon, max_lon, min_lat, max_lat,
                  t_min: int = 0, t_max: int = 2**62):
@@ -219,11 +243,10 @@ class Q4Node(DagNode):
         self.t_range = (int(t_min), int(t_max))
 
     def process(self, win, results):
-        from spatialflink_tpu.sncb.queries import q4_window
+        from spatialflink_tpu.sncb.queries import q4_columns
 
-        lo, hi, la, ha = self.bbox
-        return q4_window(_gps_events(win), win.start, win.end,
-                         lo, hi, la, ha, *self.t_range)
+        return q4_columns(self.dag.columns(win), win.start, win.end,
+                          *self.bbox, *self.t_range)
 
     def render(self, result, start, end):
         for o in result:
@@ -233,6 +256,8 @@ class Q4Node(DagNode):
 class Q5Node(DagNode):
     """Geofenced trajectory + speed thresholds (Q5)."""
 
+    reads_columns = True
+
     def __init__(self, name: str, zones, avg_threshold: float = 50.0,
                  min_threshold: float = 20.0):
         super().__init__(name)
@@ -241,11 +266,12 @@ class Q5Node(DagNode):
         self.min_threshold = min_threshold
 
     def _run(self, win, backend):
-        from spatialflink_tpu.sncb.queries import q5_window
+        from spatialflink_tpu.sncb.queries import q5_columns
 
-        return q5_window(_gps_events(win), self.zones, win.start, win.end,
-                         self.avg_threshold, self.min_threshold,
-                         backend=backend)
+        return q5_columns(self.dag.columns(win), self.zones,
+                          win.start, win.end,
+                          self.avg_threshold, self.min_threshold,
+                          backend=backend)
 
     def process(self, win, results):
         return self._run(win, "device")
@@ -265,6 +291,8 @@ class StayTimeNode(DagNode):
     Result: sorted (cellName, dwell_ms) rows; parity between the two
     routes is the tests/test_apps.py contract."""
 
+    reads_columns = True
+
     def __init__(self, name: str):
         super().__init__(name)
         self._kernel = None
@@ -276,17 +304,12 @@ class StayTimeNode(DagNode):
 
         if self._kernel is None:
             self._kernel = jitted(stay_time_cells_kernel, "num_cells")
-        evs = _gps_events(win)
-        if not evs:
+        gps = self.dag.columns(win).gps()
+        if not len(gps):
             return []
         grid = self.dag.grid
-        ts = np.array([e.ts for e in evs], np.int64)
-        oid = np.asarray(
-            self.dag.interner.intern_many(e.device_id for e in evs),
-            np.int64,
-        )
-        xy = np.array([[e.lon, e.lat] for e in evs], np.float64)
-        hit, dwell = stay_time_window_soa(ts, oid, xy, grid, self._kernel)
+        hit, dwell = stay_time_window_soa(gps.ts, gps.oid, gps.lonlat(),
+                                          grid, self._kernel)
         return [
             (grid.cell_name(int(c)) if int(c) < grid.num_cells else "out",
              int(d))
@@ -294,9 +317,13 @@ class StayTimeNode(DagNode):
         ]
 
     def fallback_process(self, win, results):
+        # The INDEPENDENT host walk (tests/test_apps.py holds the device
+        # path to it): it reads the event objects, not the view the
+        # device path computed from. Failover only.
         from spatialflink_tpu.apps.staytime import stay_time_window
+        from spatialflink_tpu.sncb.common import GpsEvent
 
-        evs = _gps_events(win)
+        evs = [e for e in win.events if isinstance(e, GpsEvent)]
         if not evs:
             return []
         pts = [Point(obj_id=e.device_id, timestamp=e.ts, x=e.lon, y=e.lat)
@@ -370,11 +397,13 @@ class CheckInNode(DagNode):
 
 class QServeNode(DagNode):
     """Multi-tenant standing-query serving (qserve.py) on the shared
-    stream: Point/GpsEvent items serve the registered queries,
-    QServeCommands register/unregister exactly once. The registry
+    stream: the view's point rows (Point/GpsEvent) serve the registered
+    queries, QServeCommands register/unregister exactly once. The registry
     interns into the DAG's table (ONE intern home) and its state rides
     the unit checkpoint as substate; retries are safe (the registry's
     retry-idempotent accumulators), so the node stays idempotent."""
+
+    reads_columns = True
 
     def __init__(self, name: str = "qserve", cap_max: Optional[int] = None,
                  dtype=np.float64):
@@ -406,24 +435,13 @@ class QServeNode(DagNode):
         from spatialflink_tpu.ops.query_registry import (
             registry_bucket_kernel,
         )
-        from spatialflink_tpu.qserve import QServeCommand
-        from spatialflink_tpu.sncb.common import GpsEvent
 
         if self._kernel is None:
             self._kernel = jitted(
                 registry_bucket_kernel, "k", "num_segments", "query_block"
             )
-        events = []
-        for e in win.events:
-            if isinstance(e, QServeCommand):
-                events.append(e)
-            elif isinstance(e, GpsEvent):
-                events.append(Point(obj_id=e.device_id, timestamp=e.ts,
-                                    x=e.lon, y=e.lat))
-            elif isinstance(e, Point):
-                events.append(e)
-        return self.op.serve_window(
-            WindowBatch(win.start, win.end, events), self._kernel,
+        return self.op.serve_columns(
+            self.dag.columns(win), win.start, win.end, self._kernel,
             dtype=self.dtype,
         )
 
@@ -539,6 +557,15 @@ class DataflowDAG:
             for n in nodes
         }
         self._driver: Optional[WindowedDataflowDriver] = None
+        #: The fired window's columnar view, alive for the walk only:
+        #: ``(win, view)``. Derived state — never checkpointed.
+        self._columns: Optional[Tuple[WindowBatch, WindowColumns]] = None
+        self._reads_columns = any(n.reads_columns for n in nodes)
+        #: Views built by the walk / node reads of a view (7 a window in
+        #: the SNCB DAG): the evidence that the one view is what the
+        #: nodes computed from. Process-local, like the breakers.
+        self.window_columns_built = 0
+        self.window_columns_reads = 0
         for n in nodes:
             n.bind(self)
 
@@ -710,6 +737,23 @@ class DataflowDAG:
 
     # -- per-window node walk --------------------------------------------------
 
+    def columns(self, win: WindowBatch) -> WindowColumns:
+        """``win`` as columns (streams/columns.py) over this DAG's
+        interner — the one view the walk built for the window it is
+        walking. Outside a walk (a test or tool calling a node's
+        ``process`` by hand) the view is built for the call and not
+        kept."""
+        self.window_columns_reads += 1
+        held = self._columns
+        if held is not None and held[0] is win:
+            return held[1]
+        return self._build_columns(win)
+
+    def _build_columns(self, win: WindowBatch) -> WindowColumns:
+        cols = WindowColumns.from_events(win.events, self.interner)
+        cols.oid  # dense ids assigned HERE, in window order
+        return cols
+
     def _process_window(self, win: WindowBatch) -> DagWindowResult:
         asm = getattr(self, "checkpoint_assembler", None)
         wm = getattr(asm, "_max_ts", None)
@@ -717,6 +761,16 @@ class DataflowDAG:
         counts: Dict[str, int] = {}
         with telemetry.span("window.dag", start=win.start,
                             events=len(win.events)):
+            if self._reads_columns:
+                # The window → columns ONCE, before any node runs: every
+                # node of the walk reads this one view (self.columns).
+                with telemetry.span("window.columns",
+                                    events=len(win.events)) as sp:
+                    cols = self._build_columns(win)
+                    # (the disabled-telemetry null span has no args)
+                    getattr(sp, "args", {})["gps"] = len(cols.gps())
+                self.window_columns_built += 1
+                self._columns = (win, cols)
             for name in self.dag_nodes:
                 node = self._nodes[name]
                 # Node-scoped attribution (PR 16): the scope tags every
@@ -749,6 +803,7 @@ class DataflowDAG:
                     if wm is not None:
                         st["lag"].observe(
                             float(max(int(wm) - win.end, 0)))
+            self._columns = None
         return DagWindowResult(win.start, win.end, counts)
 
     def _run_node(self, node: DagNode, win, results):
@@ -898,10 +953,16 @@ class DataflowDAG:
             if st["breaker"] is not None:
                 rec["breaker"] = st["breaker"].snapshot()
             nodes[name] = rec
-        return json_safe({
-            "version": DAG_VERSION,
-            "nodes": nodes,
-        })
+        out: Dict[str, Any] = {"version": DAG_VERSION, "nodes": nodes}
+        if self._reads_columns:
+            # Additive: views built / node reads of them (7 a window in
+            # the SNCB DAG) — absent from a DAG no node of which reads
+            # the view.
+            out["window_columns"] = {
+                "built": int(self.window_columns_built),
+                "reads": int(self.window_columns_reads),
+            }
+        return json_safe(out)
 
 
 # -- module-level wiring (the telemetry/overload singleton idiom) --------------

@@ -70,7 +70,9 @@ from spatialflink_tpu.operators.base import (
     jitted,
     ship,
 )
+from spatialflink_tpu.models.batch import PointBatch
 from spatialflink_tpu.models.objects import Point
+from spatialflink_tpu.streams.columns import WindowColumns
 from spatialflink_tpu.telemetry import telemetry
 from spatialflink_tpu.utils.padding import next_bucket
 
@@ -603,23 +605,40 @@ class QServeOperator(SpatialOperator):
 
     def serve_window(self, win, kernel, dtype=np.float64,
                      mesh=None) -> QServeWindowResult:
+        """Event-list entry of :meth:`serve_columns` — :meth:`run`'s
+        process: the window's Points and commands as one list. Builds
+        the same columns the composed DAG shares between its nodes
+        (streams/columns.py) and serves from them."""
+        return self.serve_columns(
+            WindowColumns.from_events(win.events, self.interner),
+            win.start, win.end, kernel, dtype=dtype, mesh=mesh,
+        )
+
+    def serve_columns(self, cols: WindowColumns, start: int, end: int,
+                      kernel, dtype=np.float64,
+                      mesh=None) -> QServeWindowResult:
         """One window's serving pass: apply the window's commands
         exactly once, evaluate every bucket as one program, ONE true
         sync for all buckets, per-tenant-class result budgets. The
         shared core of :meth:`run`'s process and the composed DAG's
         qserve node (dag.py) — both route retries through the
         retry-idempotent accumulators (record_range_overflow,
-        tenant_result_allowance), so re-running a window is safe."""
+        tenant_result_allowance), so re-running a window is safe.
+
+        ``cols`` is the window's columnar view over THIS operator's
+        interner: its point rows (``Point`` or ``GpsEvent``) are the
+        served batch, straight from the arrays — no ``Point`` is built,
+        no list walked — and the ``QServeCommand``s among its
+        ``others`` are the window's commands."""
         from spatialflink_tpu.ops.compaction import pick_capacity
 
         reg = self.qserve_registry
-        with telemetry.span("window.qserve", start=win.start,
-                            events=len(win.events)):
-            cmds = sorted(
-                (e for e in win.events
-                 if isinstance(e, QServeCommand)),
-                key=lambda c: (c.timestamp, c.uid),
-            )
+        cmds = sorted(
+            (e for e in cols.others if isinstance(e, QServeCommand)),
+            key=lambda c: (c.timestamp, c.uid),
+        )
+        n_events = len(cmds) + len(cols)
+        with telemetry.span("window.qserve", start=start, events=n_events):
             for cmd in cmds:
                 reg.apply(cmd)
             # The exactly-once uid set only needs to reach as far
@@ -627,13 +646,11 @@ class QServeOperator(SpatialOperator):
             # lateness + slide behind this fire) — prune beyond it
             # so checkpoints don't grow with lifetime command count.
             reg.prune_applied(
-                win.start,
+                start,
                 self.conf.window_size_ms
                 + self.conf.allowed_lateness_ms
                 + self.conf.slide_step_ms,
             )
-            pts = [e for e in win.events
-                   if not isinstance(e, QServeCommand)]
             buckets = reg.buckets()
             # Evict device arrays of buckets churn has emptied —
             # a dead bucket must not pin its (cap, num_cells+1)
@@ -643,9 +660,14 @@ class QServeOperator(SpatialOperator):
                 del self._bucket_dev[key]
             rows: List[Tuple[str, str, str, Any, float]] = []
             win_overflow = 0
-            if pts and buckets:
+            if len(cols) and buckets:
                 with telemetry.span("assemble"):
-                    batch = self.point_batch(pts)
+                    # Host batch in float64 (centering/casting happens
+                    # at the device boundary, see point_batch).
+                    batch = PointBatch.from_arrays(
+                        cols.lonlat(), cols.ts, cols.oid,
+                        dtype=np.float64,
+                    ).with_cells(self.grid)
                     nseg = next_bucket(
                         max(self.interner.num_segments, 1),
                         minimum=64,
@@ -722,7 +744,7 @@ class QServeOperator(SpatialOperator):
                                 ),
                                 float(dists[lane, r_]),
                             ))
-            reg.record_range_overflow(win.start, win_overflow)
+            reg.record_range_overflow(start, win_overflow)
             # Per-tenant-class result budgets: each class keeps its
             # first `allowance` rows (deterministic bucket/qid/rank
             # order), the excess is counted against THE CLASS only.
@@ -731,7 +753,7 @@ class QServeOperator(SpatialOperator):
                 counts[row[0]] = counts.get(row[0], 0) + 1
             allow = {
                 cls: overload.tenant_result_allowance(
-                    cls, n, window_start=win.start)
+                    cls, n, window_start=start)
                 for cls, n in sorted(counts.items())
             }
             kept: List[Tuple[str, str, str, Any, float]] = []
@@ -740,9 +762,7 @@ class QServeOperator(SpatialOperator):
                 used[row[0]] = used.get(row[0], 0) + 1
                 if used[row[0]] <= allow[row[0]]:
                     kept.append(row)
-            return QServeWindowResult(
-                win.start, win.end, kept, len(win.events)
-            )
+            return QServeWindowResult(start, end, kept, n_events)
 
 
 

@@ -16,6 +16,7 @@ proximity tests run in meters.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Dict, Iterable, Iterator, List, Sequence
 
@@ -36,6 +37,7 @@ from spatialflink_tpu.sncb.ops import (
     trajectory_wkt,
     variation,
 )
+from spatialflink_tpu.streams.columns import WindowColumns
 from spatialflink_tpu.streams.windows import (
     SlidingEventTimeWindows,
     TumblingEventTimeWindows,
@@ -263,22 +265,24 @@ def q2_brake_monitor_batch(
 
 
 # ---------------------------------------------------------------------------
-# Window-scoped query cores — one fired window's events in, result
+# Window-scoped query cores — one fired window's COLUMNS in, result
 # records out. These are the node bodies of the composed SNCB DAG
 # (spatialflink_tpu/dag.py): the DAG shares ONE window clock across all
 # queries (amortizing ingest/interning — the deliberate deviation from
 # the per-query window configs above, PARITY.md "Composed dataflow"),
-# so each query's per-window core is factored out here. ``backend``
-# routes the zone kernels: "device" (contains_any_zone) or "numpy"
+# and turns each fired window into a streams/columns.py WindowColumns
+# ONCE; every core below computes from those arrays — no core walks the
+# event objects. Zone containment is a boolean mask over the view's
+# shared metric coordinates (one UTM pass a window, however many zone
+# queries read it); per-device work is a stable sort by dense id and
+# segment reductions / one join per device. ``backend`` routes the zone
+# kernels: "device" (contains_any_zone) or "numpy"
 # (contains_any_zone_np) — the per-node failover route; results match
-# to float ulps.
-
-
-def _by_device(events: Sequence[GpsEvent]) -> Dict[str, List[GpsEvent]]:
-    groups: Dict[str, List[GpsEvent]] = {}
-    for e in events:
-        groups.setdefault(e.device_id, []).append(e)
-    return groups
+# to float ulps. The records are the per-event walk's, byte for byte
+# (tests/test_window_columns.py holds each core to the walk it
+# replaced). ``q1_window`` is the one event-list entry left: the
+# streaming ``q1_high_risk`` above calls it, and it builds the same
+# columns for the same core.
 
 
 def buffer_q1_zones(high_risk_zones: Sequence[BufferedZone],
@@ -290,72 +294,143 @@ def buffer_q1_zones(high_risk_zones: Sequence[BufferedZone],
     ]
 
 
-def q1_window(events: Sequence[GpsEvent],
-              zones: Sequence[BufferedZone],
-              backend: str = "device") -> List[EnrichedEvent]:
-    """Q1 core: events near the (pre-buffered) high-risk zones,
-    enriched to metric coords (Q1_HighRisk.java:73-78)."""
-    return [
-        CRSUtils.enrich(e)
-        for e in _zone_filter(events, zones, keep_inside=True,
-                              backend=backend)
-    ]
+def _zone_mask(gps: WindowColumns, zones,
+               backend: str = "device") -> np.ndarray:
+    """(N,) bool over the GPS rows: inside any buffered zone. The
+    column form of ``_zone_filter``: same kernel, same ``xy`` across
+    the link, a mask instead of a rebuilt object list."""
+    n = len(gps)
+    if not n:
+        return np.zeros(0, bool)
+    from spatialflink_tpu.ops.counters import counters
+
+    if counters.enabled:
+        counters.record_candidates(n, n * len(zones))
+    if backend == "numpy":
+        from spatialflink_tpu.sncb.common import contains_any_zone_np
+
+        return contains_any_zone_np(zones, gps.metric_xy())
+    return contains_any_zone(zones, gps.metric_xy())
 
 
-def q2_window(events: Sequence[GpsEvent],
-              maintenance_zones: Sequence[BufferedZone],
-              start: int, end: int,
-              var_fa_min: float = 0.6, var_ff_max: float = 0.5,
-              backend: str = "device") -> List[VarOut]:
-    """Q2 core: maintenance-zone exclude → per-device brake-pressure
-    variation → varFA > a ∧ varFF ≤ b filter (Q2_BrakeMonitor.java)."""
-    kept = _zone_filter(events, maintenance_zones, keep_inside=False,
-                        backend=backend)
-    out: List[VarOut] = []
-    for dev in sorted(groups := _by_device(kept)):
-        evs = groups[dev]
-        var_fa, var_ff = variation(evs)
-        if var_fa > var_fa_min and var_ff <= var_ff_max:
-            out.append(VarOut(dev, var_fa, var_ff, start, end, len(evs)))
+def _trajectory_wkts(gps: WindowColumns, rows: np.ndarray) -> List[tuple]:
+    """``(device_id, wkt)`` per device present in ``rows``, in device-id
+    order: points sorted by timestamp, ties in window order
+    (``trajectory_wkt`` over columns — one ``%g`` pass over the sorted
+    coordinates, one join per device)."""
+    grouped, starts, ends, by_name = gps.by_device(rows, by_ts=True)
+    # Python floats (.tolist()) through %g: the walk's f"{float(v):g}".
+    pts = ["%g %g" % xy for xy in zip(gps.lon[grouped].tolist(),
+                                      gps.lat[grouped].tolist())]
+    starts, ends = starts.tolist(), ends.tolist()
+    out = []
+    for dev, k in by_name:
+        s, e = starts[k], ends[k]
+        out.append((dev, f"POINT ({pts[s]})" if e - s == 1
+                    else "LINESTRING (" + ", ".join(pts[s:e]) + ")"))
     return out
 
 
-def q3_window(events: Sequence[GpsEvent],
-              start: int, end: int) -> List[TrajOut]:
-    """Q3 core: per-device window trajectory WKT (Q3_Trajectory.java)."""
-    groups = _by_device(events)
+def q1_columns(cols: WindowColumns, zones: Sequence[BufferedZone],
+               backend: str = "device") -> List[EnrichedEvent]:
+    """Q1 core: events near the (pre-buffered) high-risk zones,
+    enriched to metric coords (Q1_HighRisk.java:73-78)."""
+    gps = cols.gps()
+    hit = np.flatnonzero(_zone_mask(gps, zones, backend))
+    metric = gps.metric_xy()[hit].tolist()
+    out = []
+    for p, (x_m, y_m) in zip(gps.pos[hit].tolist(), metric):
+        raw = gps.events[p]
+        out.append(EnrichedEvent(raw=raw, x_wgs84=raw.lon, y_wgs84=raw.lat,
+                                 x_metric=x_m, y_metric=y_m))
+    return out
+
+
+def q1_window(events: Sequence[GpsEvent],
+              zones: Sequence[BufferedZone],
+              backend: str = "device") -> List[EnrichedEvent]:
+    """Event-list entry of :func:`q1_columns` (the streaming
+    ``q1_high_risk``'s per-window call)."""
+    return q1_columns(WindowColumns.from_events(events), zones, backend)
+
+
+def q2_columns(cols: WindowColumns,
+               maintenance_zones: Sequence[BufferedZone],
+               start: int, end: int,
+               var_fa_min: float = 0.6, var_ff_max: float = 0.5,
+               backend: str = "device") -> List[VarOut]:
+    """Q2 core: maintenance-zone exclude → per-device brake-pressure
+    variation → varFA > a ∧ varFF ≤ b filter (Q2_BrakeMonitor.java).
+    ``variation`` as segment reductions: absent fields skipped, an
+    all-absent device reads −inf, ``count`` counts events."""
+    gps = cols.gps()
+    rows = np.flatnonzero(~_zone_mask(gps, maintenance_zones, backend))
+    if not len(rows):
+        return []
+    grouped, starts, ends, by_name = gps.by_device(rows)
+
+    def spread(col):
+        v = col[grouped]
+        absent = np.isnan(v)
+        lo = np.minimum.reduceat(np.where(absent, np.inf, v), starts)
+        hi = np.maximum.reduceat(np.where(absent, -np.inf, v), starts)
+        return np.where(hi >= lo, hi - lo, -np.inf).tolist()
+
+    var_fa, var_ff = spread(gps.fa), spread(gps.ff)
+    counts = (ends - starts).tolist()
     return [
-        TrajOut(dev, trajectory_wkt(groups[dev]), start, end)
-        for dev in sorted(groups)
+        VarOut(dev, var_fa[k], var_ff[k], start, end, counts[k])
+        for dev, k in by_name
+        if var_fa[k] > var_fa_min and var_ff[k] <= var_ff_max
     ]
 
 
-def q4_window(events: Sequence[GpsEvent], start: int, end: int,
-              min_lon: float, max_lon: float,
-              min_lat: float, max_lat: float,
-              t_min: int, t_max: int) -> List[TrajOut]:
+def q3_columns(cols: WindowColumns, start: int, end: int) -> List[TrajOut]:
+    """Q3 core: per-device window trajectory WKT (Q3_Trajectory.java)."""
+    gps = cols.gps()
+    return [TrajOut(dev, wkt, start, end)
+            for dev, wkt in _trajectory_wkts(gps, np.arange(len(gps)))]
+
+
+def q4_columns(cols: WindowColumns, start: int, end: int,
+               min_lon: float, max_lon: float,
+               min_lat: float, max_lat: float,
+               t_min: int, t_max: int) -> List[TrajOut]:
     """Q4 core: Q3 with bbox/time-range predicate pushdown
     (Q4_TrajectoryRestricted.java)."""
-    return q3_window(
-        [e for e in events
-         if min_lon <= e.lon <= max_lon and min_lat <= e.lat <= max_lat
-         and t_min <= e.ts <= t_max],
-        start, end,
-    )
+    gps = cols.gps()
+    keep = ((gps.lon >= min_lon) & (gps.lon <= max_lon)
+            & (gps.lat >= min_lat) & (gps.lat <= max_lat)
+            & (gps.ts >= t_min) & (gps.ts <= t_max))
+    return [TrajOut(dev, wkt, start, end)
+            for dev, wkt in _trajectory_wkts(gps, np.flatnonzero(keep))]
 
 
-def q5_window(events: Sequence[GpsEvent],
-              fence_zones: Sequence[BufferedZone],
-              start: int, end: int,
-              avg_threshold: float = 50.0, min_threshold: float = 20.0,
-              backend: str = "device") -> List[TrajSpeedOut]:
+def q5_columns(cols: WindowColumns,
+               fence_zones: Sequence[BufferedZone],
+               start: int, end: int,
+               avg_threshold: float = 50.0, min_threshold: float = 20.0,
+               backend: str = "device") -> List[TrajSpeedOut]:
     """Q5 core: geofence include → per-device trajectory + speed stats,
-    avg > a ∨ min > m filter (Q5_TrajAndSpeedFence.java)."""
-    fenced = _zone_filter(events, fence_zones, keep_inside=True,
-                          backend=backend)
+    avg > a ∨ min > m filter (Q5_TrajAndSpeedFence.java). The average
+    is ``traj_speed``'s: a left-to-right sum over the device's present
+    speeds in WINDOW order (a pairwise numpy sum is another float)."""
+    gps = cols.gps()
+    rows = np.flatnonzero(_zone_mask(gps, fence_zones, backend))
+    if not len(rows):
+        return []
+    grouped, starts, ends, by_name = gps.by_device(rows)
+    speed = gps.gps_speed[grouped]
+    starts, ends = starts.tolist(), ends.tolist()
     out: List[TrajSpeedOut] = []
-    for dev in sorted(groups := _by_device(fenced)):
-        wkt, avg_speed, min_speed = traj_speed(groups[dev])
+    # Same rows, same devices: the two groupings agree on device order.
+    for (dev, wkt), (_dev, k) in zip(_trajectory_wkts(gps, rows), by_name):
+        v = speed[starts[k]:ends[k]]
+        speeds = v[~np.isnan(v)].tolist()
+        if speeds:
+            avg_speed, min_speed = sum(speeds) / len(speeds), min(speeds)
+        else:
+            avg_speed, min_speed = 0.0, math.nan
         if avg_speed > avg_threshold or (
             min_speed == min_speed and min_speed > min_threshold
         ):

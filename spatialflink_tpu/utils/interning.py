@@ -32,6 +32,13 @@ class Interner:
         return i
 
     def intern_many(self, keys: Iterable[Hashable]) -> np.ndarray:
+        if isinstance(keys, (list, tuple)):
+            # Steady state: every key is known, one C-level lookup each.
+            try:
+                return np.fromiter(map(self._to_int.__getitem__, keys),
+                                   dtype=np.int32, count=len(keys))
+            except KeyError:
+                pass  # a first-seen key: intern in order, below
         return np.fromiter(
             (self.intern(k) for k in keys), dtype=np.int32, count=-1
         )
