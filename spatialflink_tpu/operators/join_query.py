@@ -28,11 +28,14 @@ import numpy as np
 
 from spatialflink_tpu.models.objects import LineString, Point, Polygon, SpatialObject
 from spatialflink_tpu.operators.base import SpatialOperator, jitted, ship
-from spatialflink_tpu.telemetry import telemetry
+from spatialflink_tpu.ops.compaction import max_cell_count, pick_capacity
+from spatialflink_tpu.telemetry import instrument_jit, telemetry
+from spatialflink_tpu.utils.padding import next_bucket
 from spatialflink_tpu.ops.join import (
     cross_join_kernel,
     geometry_geometry_join_kernel,
     geometry_geometry_join_pruned_kernel,
+    head_pairs,
     join_kernel,
     join_kernel_compact,
     join_window_bucketed,
@@ -71,6 +74,32 @@ class _TaggedEvent:
         self.timestamp = timestamp
         self.tag = tag
         self.event = event
+
+
+@functools.lru_cache(maxsize=None)
+def window_join_program(backend: str | None = None):
+    """``(program, name)`` of the one-window dense-bucket join — the one
+    home of the backend choice for ``run_soa`` (point join and tJoin) and
+    ``grid_hash_join_batches``. ``backend``: None=auto (the Pallas hit
+    extraction on a TPU at any budget — its outputs live in HBM; the banded
+    XLA kernel elsewhere), or 'xla' | 'pallas' | 'pallas_interpret' (tests).
+    Both programs go through ``instrument_jit``; ``name`` is 'pallas' or
+    'xla'."""
+    if backend is None:
+        backend = "pallas" if pallas_join_supported() else "xla"
+    if backend == "xla":
+        return jitted(
+            join_window_bucketed,
+            "grid_n", "layers", "cap_left", "cap_right", "max_pairs",
+        ), "xla"
+    if backend not in ("pallas", "pallas_interpret"):
+        raise ValueError(f"unknown join backend {backend!r}")
+    from spatialflink_tpu.ops.pallas_join import join_window_pallas
+
+    fn = instrument_jit(join_window_pallas, name="join_window_pallas")
+    if backend == "pallas_interpret":
+        fn = functools.partial(fn, interpret=True)
+    return fn, "pallas"
 
 
 def grid_hash_join_batches(grid, left_batch, right_batch, radius, cap, offsets,
@@ -116,24 +145,14 @@ def grid_hash_join_batches(grid, left_batch, right_batch, radius, cap, offsets,
                 offsets, grid_n=grid.n, radius=fr, cap=cap,
                 max_pairs=max_pairs,
             )
-        if backend is None:
-            # The Pallas kernel keeps its (max_pairs,) outputs VMEM-resident
-            # (12 B/slot); past the budget the XLA compaction path takes
-            # over rather than blowing the ~16 MB VMEM budget.
-            from spatialflink_tpu.ops.pallas_join import PALLAS_JOIN_MAX_PAIRS
-
-            backend = (
-                "pallas"
-                if pallas_join_supported() and max_pairs <= PALLAS_JOIN_MAX_PAIRS
-                else "xla"
-            )
-        if backend in ("pallas", "pallas_interpret"):
-            from spatialflink_tpu.ops.pallas_join import join_window_pallas
-
+        if backend is None and not pallas_join_supported():
+            backend = "xla"
+        if backend != "xla":
             # f32 explicitly: centering must run before any sub-f64 cast
             # (center_coords skips it when asked for the effective f64), and
             # the Pallas kernel computes in f32 regardless.
-            return join_window_pallas(
+            fn, _ = window_join_program(backend)
+            return fn(
                 jnp.asarray(center_coords(grid, left_batch.xy, np.float32)),
                 jnp.asarray(left_batch.valid),
                 jnp.asarray(left_batch.cell),
@@ -142,18 +161,14 @@ def grid_hash_join_batches(grid, left_batch, right_batch, radius, cap, offsets,
                 jnp.asarray(right_batch.cell),
                 grid_n=grid.n, layers=layers, radius=fr,
                 cap_left=cap, cap_right=cap, max_pairs=max_pairs,
-                interpret=backend == "pallas_interpret",
             )
         span2 = (2 * layers + 1) ** 2
         lanes = grid.num_cells * cap * cap * span2
         if lanes <= 300_000_000:
-            # Dense-bucket join: static roll shifts, no per-candidate
-            # gathers — the fast path while the cells×cap²×span² mask
-            # stack stays bounded.
-            jk = jitted(
-                join_window_bucketed,
-                "grid_n", "layers", "cap_left", "cap_right", "max_pairs",
-            )
+            # Dense-bucket join: static shifts, no per-candidate gathers
+            # — the fast path while cells×cap²×span² lanes stay few (past
+            # that the gather join below costs less for a small window).
+            jk, _ = window_join_program("xla")
             return jk(
                 jnp.asarray(center_coords(grid, left_batch.xy, dtype)),
                 jnp.asarray(left_batch.valid),
@@ -223,7 +238,56 @@ class PointPointJoinQuery(SpatialOperator):
         super().__init__(conf, grid, mesh=mesh)
         self.cap = cap
         self.join_backend = join_backend  # None=auto, 'xla', 'pallas[_interpret]'
-        self._max_pairs = 0  # grown budget persists across windows
+        #: The bucket capacity in use: ``cap`` is its first rung, a window
+        #: whose fullest cell holds more climbs it (``_climb_cap``); like
+        #: the pair budget it only grows and persists across windows.
+        self.join_cap = cap
+        self.join_budget = 0  # grown pair budget, persists across windows
+        #: 'pallas' | 'xla': the extraction ``run_soa`` last ran
+        #: (``last_wire_digest_kind``'s twin); None before the first window.
+        self.last_join_backend = None
+
+    def _climb_cap(self, live: int) -> None:
+        """Climb to the capacity rung that holds ``live`` points in a
+        cell: the smallest power of two from the rung in use upward."""
+        self.join_cap = pick_capacity(
+            live, self.join_cap, minimum=self.join_cap, open_top=True
+        )
+
+    def _grow_budget(self, count: int) -> None:
+        """Headroom policy of the pair budget: at least the next power of
+        two of 1.25 × ``count``."""
+        self.join_budget = max(
+            self.join_budget, next_bucket(-(-5 * count // 4), minimum=1024)
+        )
+
+    def _join_until_held(self, lcell, lvalid, rcell, rvalid, call):
+        """``call(cap, budget)`` — one bucketed join of the two batches
+        whose cells these are — until its result holds them: the capacity
+        first climbs to the fullest cell (one bincount a side), then a
+        result that still reports overflow (the safety net under that
+        pick) is run again one rung up, and one with more pairs than the
+        budget under a grown budget. Returns (result, count, cap re-runs,
+        budget re-runs); the result's overflow is 0."""
+        num_cells = self.grid.num_cells
+        self._climb_cap(max(
+            max_cell_count(lcell, lvalid, num_cells),
+            max_cell_count(rcell, rvalid, num_cells),
+        ))
+        cap_retries = budget_retries = 0
+        while True:
+            res = call(self.join_cap, self.join_budget)
+            count, overflow = (
+                int(v) for v in telemetry.fetch((res.count, res.overflow))
+            )
+            if overflow > 0:
+                self._climb_cap(2 * self.join_cap)
+                cap_retries += 1
+            elif count > self.join_budget:
+                self._grow_budget(count)
+                budget_retries += 1
+            else:
+                return res, count, cap_retries, budget_retries
 
     def _filter_radius(self, radius):
         """Distance-predicate radius: in approximate mode every grid
@@ -388,27 +452,27 @@ class PointPointJoinQuery(SpatialOperator):
 
 
     def _compact_block(self, lb, rb, radius, offsets, dtype, mesh):
-        """One bucketed join with the persistent-budget retry contract;
-        returns host (left_idx, right_idx, dist, overflow)."""
-        self._max_pairs = max(
-            self._max_pairs, 1024, min(4 * lb.capacity, 262_144)
+        """One bucketed join under the persistent capacity and budget
+        contract (``_join_until_held``); returns host (left_idx,
+        right_idx, dist, overflow), the overflow always 0."""
+        self.join_budget = max(
+            self.join_budget, 1024, min(4 * lb.capacity, 262_144)
         )
-        while True:
-            res = grid_hash_join_batches(
-                self.grid, lb, rb, radius, self.cap, offsets,
-                max_pairs=self._max_pairs, dtype=dtype,
+        res, count, _, _ = self._join_until_held(
+            lb.cell, lb.valid, rb.cell, rb.valid,
+            lambda cap, budget: grid_hash_join_batches(
+                self.grid, lb, rb, radius, cap, offsets,
+                max_pairs=budget, dtype=dtype,
                 backend=self.join_backend, mesh=mesh,
                 filter_radius=self._filter_radius(radius),
-            )
-            count = int(res.count)
-            if count <= self._max_pairs:
-                break
-            self._max_pairs = int(2 ** np.ceil(np.log2(count)))
-        li = np.asarray(res.left_index)[:count]
-        ri = np.asarray(res.right_index)[:count]
-        dd = np.asarray(res.dist)[:count]
+            ),
+        )
+        li, ri, dd = (
+            a[:count] for a in telemetry.fetch(
+                (res.left_index, res.right_index, res.dist))
+        )
         keep = li >= 0
-        return li[keep], ri[keep], dd[keep], int(res.overflow)
+        return li[keep], ri[keep], dd[keep], 0
 
     def query_panes(
         self,
@@ -528,35 +592,35 @@ class PointPointJoinQuery(SpatialOperator):
         """High-rate SoA path: two chunk streams of {"ts","x","y",...}
         arrays → per-window (start, end, left_index, right_index, dist,
         count, overflow) raw compact-join arrays (indices into each side's
-        window arrays; -1 padding past ``count``). Windows of the two sides
+        window arrays; -1 / inf padding past ``count``, the arrays as long
+        as the padding bucket of ``count``). Windows of the two sides
         align on their shared slide grid; a window present on only one side
         yields zero pairs. The kernels receive the assembler's pre-centered
-        coordinates directly (Pallas extraction on TPU)."""
+        coordinates directly (Pallas extraction on TPU).
+
+        Exact on every yielded window (``overflow == 0``): the bucket
+        capacity comes from the window's fullest cell (``_climb_cap``) and
+        the pair budget keeps a quarter of headroom over the last count
+        (``_grow_budget``, ``max_pairs`` its first value); a window either
+        one fails to hold is run again, never yielded short. Two fetches a
+        window: count and overflow, then the pairs found."""
         from spatialflink_tpu.operators.base import soa_point_batches
         from spatialflink_tpu.ops.counters import (
             count_join_candidates,
             counters as opcounters,
         )
-        from spatialflink_tpu.ops.pallas_join import (
-            PALLAS_JOIN_MAX_PAIRS,
-            join_window_pallas,
-        )
 
-        def kernel_for(budget):
-            # Same backend policy as grid_hash_join_batches: Pallas only
-            # within its VMEM-resident output budget, XLA beyond.
-            if pallas_join_supported() and budget <= PALLAS_JOIN_MAX_PAIRS:
-                return join_window_pallas
-            return jitted(
-                join_window_bucketed,
-                "grid_n", "layers", "cap_left", "cap_right", "max_pairs",
-            )
-
+        fn, self.last_join_backend = window_join_program(self.join_backend)
+        head = jitted(head_pairs, "bucket")
         layers = self.grid.candidate_layers(radius)
         fr = self._filter_radius(radius)
         gen_l = soa_point_batches(self.grid, left_chunks, self.conf, dtype)
-        gen_r = soa_point_batches(self.grid, right_chunks, self.conf, dtype)
-        budget = max_pairs  # grown budget persists across windows
+        gen_r = _spanned(
+            soa_point_batches(self.grid, right_chunks, self.conf, dtype),
+            "join.assemble",
+        )
+        self.join_budget = max(self.join_budget, max_pairs)
+        warmed = 0  # the budget whose head programs are compiled
         for kind, wl, wr in _aligned_soa_windows(
             gen_l, gen_r, lambda w: w[0].start, lambda w: w[0].start
         ):
@@ -573,27 +637,46 @@ class PointPointJoinQuery(SpatialOperator):
                     int(rvalid.sum()), layers,
                 )
                 opcounters.record_candidates(cand, cand)
-            # Ship once, outside the budget-retry loop (lanes are reused by
-            # every retry; counted once in bytes_h2d).
+            # Ship once, outside the retry loop (lanes are reused by every
+            # re-run; counted once in bytes_h2d).
             lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d = ship(
                 lxy, lvalid, lcell, rxy, rvalid, rcell
             )
-            while True:
-                fn = kernel_for(budget)
-                res = fn(
+            res, count, cap_retries, budget_retries = self._join_until_held(
+                lcell, lvalid, rcell, rvalid,
+                lambda cap, budget: fn(
                     lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d,
                     grid_n=self.grid.n, layers=layers, radius=fr,
-                    cap_left=self.cap, cap_right=self.cap, max_pairs=budget,
-                )
-                count = int(res.count)
-                if count <= budget:
-                    break
-                budget = int(2 ** np.ceil(np.log2(count)))
-            yield (
-                win.start, win.end,
-                np.asarray(res.left_index), np.asarray(res.right_index),
-                np.asarray(res.dist), count, int(res.overflow),
+                    cap_left=cap, cap_right=cap, max_pairs=budget,
+                ),
             )
+            pairs = (res.left_index, res.right_index, res.dist)
+            if self.join_budget != warmed:
+                # A new budget: compile the two head programs a count under
+                # it asks for now, not inside a later window.
+                warmed = self.join_budget
+                for b in (warmed // 2, warmed):
+                    head(*pairs, bucket=min(b, len(res.dist)))
+            bucket = min(next_bucket(count), len(res.dist))
+            li, ri, dd = telemetry.fetch(head(*pairs, bucket=bucket))
+            telemetry.record_join(
+                pairs=count, cap_retries=cap_retries,
+                budget_retries=budget_retries, cap=self.join_cap,
+                budget=self.join_budget,
+            )
+            self._grow_budget(count)  # headroom for the next window
+            yield (win.start, win.end, li, ri, dd, count, 0)
+
+
+def _spanned(gen, name: str):
+    """``gen``, with each of its steps inside a telemetry span ``name``."""
+    it = iter(gen)
+    while True:
+        with telemetry.span(name):
+            item = next(it, None)
+        if item is None:
+            return
+        yield item
 
 
 def _aligned_soa_windows(gen_l, gen_r, start_l, start_r):

@@ -391,27 +391,13 @@ class TJoinQuery(SpatialOperator):
             check_oid_range,
             soa_point_batches,
         )
-        from spatialflink_tpu.operators.join_query import _aligned_soa_windows
-        from spatialflink_tpu.ops.join import (
-            join_window_bucketed,
-            pallas_join_supported,
+        from spatialflink_tpu.operators.join_query import (
+            _aligned_soa_windows,
+            window_join_program,
         )
         from spatialflink_tpu.utils.padding import next_bucket as _nb
 
-        def kernel_for(budget):
-            if pallas_join_supported():
-                from spatialflink_tpu.ops.pallas_join import (
-                    PALLAS_JOIN_MAX_PAIRS,
-                    join_window_pallas,
-                )
-
-                if budget <= PALLAS_JOIN_MAX_PAIRS:
-                    return join_window_pallas
-            return jitted(
-                join_window_bucketed,
-                "grid_n", "layers", "cap_left", "cap_right", "max_pairs",
-            )
-
+        fn, _ = window_join_program()
         dedup = jitted(
             traj_pair_dedup_kernel, "num_left", "num_right", "max_tpairs"
         )
@@ -448,7 +434,6 @@ class TJoinQuery(SpatialOperator):
             )
             l_loc_d, r_loc_d = ship(l_loc, r_loc)
             while True:
-                fn = kernel_for(budget)
                 res = fn(
                     lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d,
                     grid_n=self.grid.n, layers=layers, radius=radius,

@@ -20,6 +20,10 @@ the LIVE count:
   ``cap_w``), a stream sweeping any occupancy compiles at most
   ladder-many programs per engine — the recompile detector
   (telemetry.py) sees a handful of STABLE signatures, not churn.
+- ``max_cell_count``: a batch's largest per-cell count — the point
+  join's bucket capacity climbs the same ladder past its first rung
+  (``pick_capacity(..., open_top=True)``), so a hot cell costs one more
+  compiled rung and never a short join.
 - ``max_window_cell_count``: exact per-cell window occupancy bound for
   a bounded stream (vectorized two-pointer over the (cell, pane)-sorted
   events), so ``run_soa_panes`` picks the bucket before the scan and
@@ -73,10 +77,15 @@ def capacity_ladder(cap: int, minimum: int = CAP_LADDER_MIN) -> Tuple[int, ...]:
     return tuple(out)
 
 
-def pick_capacity(live: int, cap: int, minimum: int = CAP_LADDER_MIN) -> int:
+def pick_capacity(live: int, cap: int, minimum: int = CAP_LADDER_MIN,
+                  open_top: bool = False) -> int:
     """Smallest ladder rung ≥ ``live`` (the bucketed probe capacity).
     ``live`` beyond the ladder top clamps to ``cap`` — the ring capacity
-    bounds live occupancy anyway (the cap_overflow retry contract).
+    bounds live occupancy anyway (the cap_overflow retry contract) —
+    unless ``open_top``: then the ladder goes on past ``cap`` in powers of
+    two, for a capacity nothing bounds but what the window holds (the
+    point join's per-cell buckets, whose first rung is ``cap`` itself:
+    pass ``minimum=cap``).
 
     Under an active overload ``clamp_compaction`` rung
     (spatialflink_tpu/overload.py) the pick is FLOORED: occupancy churn
@@ -89,11 +98,24 @@ def pick_capacity(live: int, cap: int, minimum: int = CAP_LADDER_MIN) -> int:
 
     clamp = overload.compaction_clamp()
     if clamp is not None:
-        live = cap if clamp <= 0 else max(live, clamp)
+        live = max(live, cap) if clamp <= 0 else max(live, clamp)
     for b in capacity_ladder(cap, minimum):
         if b >= live:
             return b
-    return cap
+    if not open_top:
+        return cap
+    from spatialflink_tpu.utils.padding import next_bucket
+
+    return next_bucket(live, minimum=1)
+
+
+def max_cell_count(cells, valid, num_cells: int) -> int:
+    """Largest number of valid in-grid points any one cell holds — what a
+    side's dense bucket capacity has to hold (one ``np.bincount``; invalid
+    and out-of-grid lanes are counted into the slot past the grid)."""
+    cells = np.where(valid, cells, num_cells)
+    counts = np.bincount(np.minimum(cells, num_cells), minlength=num_cells + 1)
+    return int(counts[:num_cells].max()) if num_cells else 0
 
 
 def max_window_cell_count(pane: np.ndarray, cell: np.ndarray,

@@ -222,11 +222,19 @@ def bucketize_planes(xy, valid, cells, grid_n: int, cap: int):
     cells = jnp.where(valid, cells, num_cells)
     order = jnp.argsort(cells).astype(jnp.int32)
     sorted_cells = cells[order]
-    # Rank within cell = position − first position of that cell.
-    first = jnp.searchsorted(sorted_cells, sorted_cells, side="left")
-    rank = (jnp.arange(n, dtype=jnp.int32) - first).astype(jnp.int32)
+    # Rank within cell = position − first position of that cell: a running
+    # maximum over the positions at which a new cell starts (a binary
+    # search per point is a 19-step loop of gathers at 2¹⁹ points, 75 ms
+    # a side on a v5e: my chip run, PR 27).
+    pos = jnp.arange(n, dtype=jnp.int32)
+    starts = jnp.concatenate(
+        [jnp.ones(1, bool), sorted_cells[1:] != sorted_cells[:-1]]
+    )
+    rank = pos - jax.lax.cummax(jnp.where(starts, pos, 0))
     ok = (sorted_cells < num_cells) & (rank < cap)
-    overflow = jnp.sum((sorted_cells < num_cells) & (rank >= cap))
+    overflow = jnp.sum(
+        (sorted_cells < num_cells) & (rank >= cap), dtype=jnp.int32
+    )
     slot = jnp.where(ok, sorted_cells * cap + rank, num_cells * cap)
     bx = jnp.zeros(num_cells * cap + 1, f_dtype).at[slot].set(xy[order, 0])
     by = jnp.zeros(num_cells * cap + 1, f_dtype).at[slot].set(xy[order, 1])
@@ -236,6 +244,12 @@ def bucketize_planes(xy, valid, cells, grid_n: int, cap: int):
         bx[:-1].reshape(shape), by[:-1].reshape(shape),
         bidx[:-1].reshape(shape), overflow,
     )
+
+
+#: Pair-mask lanes one band of grid rows may hold in join_window_bucketed
+#: (span² · rows · grid_n · capL · capR booleans, and the prefix sum
+#: ``jnp.nonzero`` runs over them): 2²⁵ keeps a band at 32 MB of flags.
+BUCKETED_BAND_LANES = 1 << 25
 
 
 def join_window_bucketed(
@@ -251,24 +265,37 @@ def join_window_bucketed(
     cap_left: int,
     cap_right: int,
     max_pairs: int,
+    band_rows: int | None = None,
 ) -> CompactJoinResult:
-    """Dense-bucket grid join — the TPU-native formulation.
+    """Dense-bucket grid join — the XLA formulation (off the TPU; on it
+    the Pallas extraction of ops/pallas_join.py takes the same planes).
 
     TPU gathers with computed indices run on the scalar core (~10⁸
     elements/s), so the searchsorted+gather join costs seconds per
     million-point window. Here BOTH sides scatter once into dense
-    (grid_n, grid_n, cap) bucket planes and every neighbor lookup becomes a
-    static ``jnp.roll`` shift — fully vectorized, no per-candidate gather.
-    Per (2·layers+1)² shift: one (cells, capL, capR) distance block on the
-    VPU, compacted with ``jnp.nonzero(size=max_pairs)``.
+    (grid_n, grid_n, cap) bucket planes and every neighbor lookup is a
+    static shift of the (padded) right planes — fully vectorized, no
+    per-candidate gather. Per (2·layers+1)² shift: one (cells, capL, capR)
+    distance block, compacted with ``jnp.nonzero(size=max_pairs)``.
+
+    The pair mask is never whole: the grid is walked in bands of
+    ``band_rows`` cell rows (default: as many as keep a band's
+    span² · rows · grid_n · capL · capR flags within
+    ``BUCKETED_BAND_LANES``), each band compacted on its own and laid
+    behind the one before it. A grid that fits one band gives the pair
+    order it always gave (shift-major).
 
     ``left_cells``/``right_cells``: flat cell ids (num_cells = out-of-grid).
     Overflow counts points beyond a side's bucket capacity (result is exact
     iff overflow == 0, same contract as join_kernel).
     """
-    num_cells = grid_n * grid_n
     span = 2 * layers + 1
     f_dtype = left_xy.dtype
+    capl, capr = cap_left, cap_right
+    if band_rows is None:
+        band_rows = BUCKETED_BAND_LANES // (span * span * grid_n * capl * capr)
+    band_rows = max(1, min(int(band_rows), grid_n))  # sfcheck: ok=trace-hygiene -- static band shape, a Python int at trace time (never traced)
+    n_bands = -(-grid_n // band_rows)
 
     lx, ly, lidx, l_over = bucketize_planes(
         left_xy, left_valid, left_cells, grid_n, cap_left
@@ -276,66 +303,116 @@ def join_window_bucketed(
     rx, ry, ridx, r_over = bucketize_planes(
         right_xy, right_valid, right_cells, grid_n, cap_right
     )
-    lvalid = lidx >= 0
+    # Left rows padded to whole bands, right planes by `layers` all round
+    # (and by the same rows): every neighbour access is an in-bounds slice,
+    # and a padding slot carries idx = -1, which never matches.
+    extra = n_bands * band_rows - grid_n
+    lpad = ((0, extra), (0, 0), (0, 0))
+    rpad = ((layers, layers + extra), (layers, layers), (0, 0))
+    lxp, lyp = jnp.pad(lx, lpad), jnp.pad(ly, lpad)
+    lidxp = jnp.pad(lidx, lpad, constant_values=-1)
+    rxp, ryp = jnp.pad(rx, rpad), jnp.pad(ry, rpad)
+    ridxp = jnp.pad(ridx, rpad, constant_values=-1)
+    cpad = grid_n + 2 * layers
+    lflat = (lxp.reshape(-1), lyp.reshape(-1), lidxp.reshape(-1))
+    rflat = (rxp.reshape(-1), ryp.reshape(-1), ridxp.reshape(-1))
+    block = band_rows * grid_n * capl * capr
+    r2 = radius * radius
 
-    # One pair-mask plane per neighbor shift, stacked: (span², cells, capL,
-    # capR) bools. Distances are NOT materialized — they're recomputed only
-    # at the compacted hit positions.
-    masks = []
-    ii = jnp.arange(grid_n)
-    for dx in range(-layers, layers + 1):
-        for dy in range(-layers, layers + 1):
-            sx = jnp.roll(rx, (-dx, -dy), axis=(0, 1))
-            sy = jnp.roll(ry, (-dx, -dy), axis=(0, 1))
-            sidx = jnp.roll(ridx, (-dx, -dy), axis=(0, 1))
-            row_ok = (ii + dx >= 0) & (ii + dx < grid_n)
-            col_ok = (ii + dy >= 0) & (ii + dy < grid_n)
-            edge_ok = row_ok[:, None] & col_ok[None, :]
-            ddx = lx[:, :, :, None] - sx[:, :, None, :]
-            ddy = ly[:, :, :, None] - sy[:, :, None, :]
-            d2 = ddx * ddx + ddy * ddy
-            pair = (
-                lvalid[:, :, :, None]
-                & (sidx[:, :, None, :] >= 0)
-                & edge_ok[:, :, None, None]
-                & (d2 <= radius * radius)
-            )
-            masks.append(pair.reshape(-1))
+    def band(r0):
+        """Rows [r0, r0 + band_rows): (left, right, dist) of up to
+        ``max_pairs`` hits, -1 / inf past them, and the band's true count."""
+        lrows = lambda p, c: jax.lax.dynamic_slice(
+            p, (r0, 0, 0), (band_rows, grid_n, c))
+        blx, bly = lrows(lxp, capl), lrows(lyp, capl)
+        blvalid = lrows(lidxp, capl) >= 0
+        # One pair-mask plane per neighbor shift, stacked: (span², band
+        # cells, capL, capR) bools. Distances are NOT materialized —
+        # they're recomputed only at the compacted hit positions.
+        masks = []
+        for dx in range(-layers, layers + 1):
+            for dy in range(-layers, layers + 1):
+                rrows = lambda p: jax.lax.dynamic_slice(
+                    p, (r0 + layers + dx, layers + dy, 0),
+                    (band_rows, grid_n, capr))
+                sx, sy, sidx = rrows(rxp), rrows(ryp), rrows(ridxp)
+                ddx = blx[:, :, :, None] - sx[:, :, None, :]
+                ddy = bly[:, :, :, None] - sy[:, :, None, :]
+                pair = (
+                    blvalid[:, :, :, None]
+                    & (sidx[:, :, None, :] >= 0)
+                    & (ddx * ddx + ddy * ddy <= r2)
+                )
+                masks.append(pair.reshape(-1))
+        flat = jnp.concatenate(masks)  # (span² · band cells · capL · capR,)
+        n_hit = jnp.sum(flat, dtype=jnp.int32)
+        (hit,) = jnp.nonzero(flat, size=max_pairs, fill_value=-1)
+        found = hit >= 0
+        hit_c = jnp.maximum(hit, 0)
+        shift_id = hit_c // block
+        within = hit_c % block
+        cell = within // (capl * capr)
+        l_lane = (within // capr) % capl
+        r_lane = within % capr
+        # The shift mapped cell (i, j) → right cell (i+dx, j+dy); in the
+        # padded right plane that is (i + layers + dx, j + layers + dy),
+        # and shift_id counts dx + layers, dy + layers.
+        ci = r0 + cell // grid_n
+        cj = cell % grid_n
+        ri = ci + shift_id // span
+        rj = cj + shift_id % span
+        l_slot = (ci * grid_n + cj) * capl + l_lane
+        r_slot = (ri * cpad + rj) * capr + r_lane
+        # Recompute distances at the (≤ max_pairs) hits only.
+        ddx = lflat[0][l_slot] - rflat[0][r_slot]
+        ddy = lflat[1][l_slot] - rflat[1][r_slot]
+        return (
+            jnp.where(found, lflat[2][l_slot], -1),
+            jnp.where(found, rflat[2][r_slot], -1),
+            jnp.where(found, jnp.sqrt(ddx * ddx + ddy * ddy),
+                      jnp.asarray(jnp.inf, f_dtype)),
+            n_hit,
+        )
 
-    flat = jnp.concatenate(masks)  # (span² · cells · capL · capR,)
-    count = jnp.sum(flat.astype(jnp.int32))
-    (hit,) = jnp.nonzero(flat, size=max_pairs, fill_value=-1)
-    found = hit >= 0
-    hit_c = jnp.maximum(hit, 0)
-    capl, capr = cap_left, cap_right
-    block = num_cells * capl * capr
-    shift_id = hit_c // block
-    within = hit_c % block
-    cell = within // (capl * capr)
-    l_lane = (within // capr) % capl
-    r_lane = within % capr
-    # Decode shifted right slot back to the unshifted plane: the shift
-    # mapped cell (i, j) → right cell (i+dx, j+dy).
-    sdx = shift_id // span - layers
-    sdy = shift_id % span - layers
-    ci = cell // grid_n
-    cj = cell % grid_n
-    rcell = (ci + sdx) * grid_n + (cj + sdy)
-    l_slot = cell * capl + l_lane
-    r_slot = jnp.clip(rcell, 0, num_cells - 1) * capr + r_lane
-    left_out = jnp.where(found, lidx.reshape(-1)[l_slot], -1)
-    right_out = jnp.where(found, ridx.reshape(-1)[r_slot], -1)
-    # Recompute distances at the (≤ max_pairs) hits only.
-    dlx = lx.reshape(-1)[l_slot]
-    dly = ly.reshape(-1)[l_slot]
-    drx = rx.reshape(-1)[r_slot]
-    dry = ry.reshape(-1)[r_slot]
-    dist_out = jnp.where(
-        found,
-        jnp.sqrt((dlx - drx) ** 2 + (dly - dry) ** 2),
-        jnp.asarray(jnp.inf, f_dtype),
+    if n_bands == 1:
+        left_out, right_out, dist_out, count = band(0)
+        return CompactJoinResult(
+            left_out, right_out, dist_out, count, l_over + r_over)
+
+    def lay(b, carry):
+        # Each band's hits go behind the ones before: its padding is
+        # overwritten by the next band, and the buffers are two budgets
+        # long so that an update never has to be clamped back.
+        outl, outr, outd, count = carry
+        bl, br, bd, n_hit = band(b * band_rows)
+        at = jnp.minimum(count, max_pairs)
+        return (
+            jax.lax.dynamic_update_slice(outl, bl, (at,)),
+            jax.lax.dynamic_update_slice(outr, br, (at,)),
+            jax.lax.dynamic_update_slice(outd, bd, (at,)),
+            count + n_hit,
+        )
+
+    outl, outr, outd, count = jax.lax.fori_loop(
+        0, n_bands, lay,
+        (
+            jnp.full(2 * max_pairs, -1, jnp.int32),
+            jnp.full(2 * max_pairs, -1, jnp.int32),
+            jnp.full(2 * max_pairs, jnp.inf, f_dtype),
+            jnp.zeros((), jnp.int32),
+        ),
     )
-    return CompactJoinResult(left_out, right_out, dist_out, count, l_over + r_over)
+    return CompactJoinResult(
+        outl[:max_pairs], outr[:max_pairs], outd[:max_pairs], count,
+        l_over + r_over,
+    )
+
+
+def head_pairs(left_index, right_index, dist, bucket: int):
+    """The first ``bucket`` slots of a CompactJoinResult's three pair
+    arrays — sliced on the device, so that the host fetches a padding
+    bucket of the count it has just read and not the whole budget."""
+    return left_index[:bucket], right_index[:bucket], dist[:bucket]
 
 
 def point_geometry_join_kernel(

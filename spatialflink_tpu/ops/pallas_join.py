@@ -4,14 +4,21 @@ The XLA dense-bucket join (ops.join.join_window_bucketed) evaluates the
 pair predicate over span²·cells·capL·capR lanes essentially for free, but
 compacting the hits with ``jnp.nonzero`` costs ~9 ns/lane on the TPU scalar
 core (~2 s for a 131k×131k window at cap 48) because the cumsum+scatter
-touches every lane. Real joins are sparse — ~68k hits out of 207M lanes —
-so this kernel walks the bucket planes once and extracts each hit with an
-argmin-over-mask loop whose cost is proportional to the HIT count:
+touches every lane. Real joins are sparse — ~1 M hits out of 1.5 G lanes at
+two 500k-point sides — so this kernel walks the bucket planes once and
+extracts each hit with an argmin-over-mask loop whose cost is proportional
+to the HIT count:
 
-  grid step = one cell row; per column, the (2L+1)² neighbor buckets of the
-  right side are concatenated into one (capL, K) candidate block, the pair
-  mask is evaluated on the VPU, and a while-loop peels off set lanes one at
-  a time (vector min-reduce + scalar store via an SMEM cursor).
+  grid step = one cell row; per column and per neighbour bucket of the
+  right side, one (capL, capR) pair mask is evaluated on the VPU and a
+  while-loop peels off its set lanes one at a time (vector min-reduce over
+  that one block, scalar store via an SMEM cursor).
+
+The three output arrays live in HBM, not in VMEM: hits collect in a
+128-lane register row, full rows in a ``STAGE_ROWS``-row VMEM stage, and a
+full stage is copied out (one DMA per array) at the running offset. What
+the outputs may hold is bounded by HBM; VMEM holds 3 × STAGE_ROWS × 512 B
+whatever the budget.
 
 Replaces the reference's replicate+shuffle+filter join
 (join/JoinQuery.java:73-137, join/PointPointJoinQuery.java:124-183) as the
@@ -32,39 +39,117 @@ from jax.experimental.pallas import tpu as pltpu
 
 from spatialflink_tpu.ops.join import CompactJoinResult, bucketize_planes
 
-# The three (max_pairs,) outputs are VMEM-resident for the whole grid
-# (12 B per pair slot). Auto backend selection falls back to the XLA
-# compaction path past this budget (~6 MB of the ~16 MB VMEM).
-PALLAS_JOIN_MAX_PAIRS = 524_288
+#: Rows (of 128 pair slots) the VMEM stage holds before it is copied out to
+#: HBM: 3 arrays × 512 rows × 512 B = 768 KB of VMEM at any budget.
+STAGE_ROWS = 512
 
 
 def _extract_kernel(
     radius_ref,
     lx_ref, ly_ref, lidx_ref,
     *rest,
-    grid_n: int, layers: int, cap_left: int, cap_right: int, max_pairs: int,
+    grid_n: int, layers: int, cap_left: int, cap_right: int,
+    max_rows: int, stage_rows: int,
 ):
     span = 2 * layers + 1
     n_right = 3 * span  # rx, ry, ridx per dx
     right_refs = rest[:n_right]
     outl_ref, outr_ref, outd_ref, cnt_ref = rest[n_right:n_right + 4]
-    sm, accl, accr, accd = rest[n_right + 4:]
-    k_cand = span * span * cap_right
-    max_rows = max_pairs // 128
+    sm, accl, accr, accd, stl, str_, std, sem = rest[n_right + 4:]
     lane_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    outs = ((stl, outl_ref), (str_, outr_ref), (std, outd_ref))
 
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
-        outl_ref[:] = jnp.full((max_rows, 128), -1, jnp.int32)
-        outr_ref[:] = jnp.full((max_rows, 128), -1, jnp.int32)
-        outd_ref[:] = jnp.full((max_rows, 128), jnp.inf, jnp.float32)
         sm[0] = 0  # total hit count
-        sm[1] = 0  # flushed element count (multiple of 128)
+        sm[1] = 0  # lane of the register row the next hit takes (0..127)
+        sm[2] = 0  # row of the stage the register row goes to
+        sm[3] = 0  # stages copied out to HBM so far
+
+    def copy_out():
+        """The stage → rows [chunk·stage_rows, +stage_rows) of the outputs.
+        Past the budget nothing is written; the count still runs on."""
+        chunk = sm[3]
+
+        @pl.when((chunk + 1) * stage_rows <= max_rows)
+        def _dma():
+            copies = [
+                pltpu.make_async_copy(
+                    st, out.at[pl.ds(chunk * stage_rows, stage_rows), :],
+                    sem.at[k],
+                )
+                for k, (st, out) in enumerate(outs)
+            ]
+            for c in copies:
+                c.start()
+            for c in copies:
+                c.wait()
+
+        sm[3] = chunk + 1
+
+    def stage_row():
+        """The register row → the stage; a full stage → HBM."""
+        srow = sm[2]
+        stl[pl.ds(srow, 1), :] = accl[:]
+        str_[pl.ds(srow, 1), :] = accr[:]
+        std[pl.ds(srow, 1), :] = accd[:]
+        sm[2] = srow + 1
+
+        @pl.when(srow + 1 == stage_rows)
+        def _full():
+            copy_out()
+            sm[2] = 0
 
     r2 = radius_ref[0, 0] * radius_ref[0, 0]
     row_any = jnp.sum((lidx_ref[0, :, :] >= 0).astype(jnp.int32)) > 0
+    # Codes of one (capL, capR) block, row-major: the peel's order.
+    code_iota = (
+        jax.lax.broadcasted_iota(jnp.int32, (cap_left, cap_right), 0)
+        * cap_right
+        + jax.lax.broadcasted_iota(jnp.int32, (cap_left, cap_right), 1)
+    )
+    big = cap_left * cap_right
+
+    def peel(mask, nhit, lidxv, sidx, d2):
+        """Extract the ``nhit`` set lanes of one block, ascending code."""
+
+        def cond(st):
+            return st[1] > 0
+
+        def body(st):
+            # Scalar-only carry (last extracted code): Mosaic cannot
+            # carry the (capL, capR) i1 mask through a while loop.
+            last, remaining = st
+            code = jnp.min(
+                jnp.where(mask & (code_iota > last), code_iota, big)
+            )
+            # One-hot reduces instead of dynamic_slice (which Mosaic
+            # does not lower): exactly one lane has code_iota == code.
+            hot = code_iota == code
+            lval = jnp.sum(jnp.where(hot, lidxv, 0))
+            rval = jnp.sum(jnp.where(hot, sidx, 0))
+            dval = jnp.sqrt(jnp.sum(jnp.where(hot, d2, 0.0)))
+            # Scalar stores to VMEM are impossible on TPU; instead
+            # accumulate into a 128-lane register row (one-hot
+            # select) and hand full rows on with a vector store.
+            lane = sm[1]
+            lane_hot = lane_iota == lane
+            accl[:] = jnp.where(lane_hot, lval, accl[:])
+            accr[:] = jnp.where(lane_hot, rval, accr[:])
+            accd[:] = jnp.where(lane_hot, dval.astype(jnp.float32), accd[:])
+            sm[0] = sm[0] + 1
+            sm[1] = lane + 1
+
+            @pl.when(lane == 127)
+            def _row_full():
+                stage_row()
+                sm[1] = 0
+
+            return (code, remaining - 1)
+
+        jax.lax.while_loop(cond, body, (jnp.int32(-1), nhit))
 
     @pl.when(row_any)
     def _row():
@@ -72,78 +157,30 @@ def _extract_kernel(
             lxv = lx_ref[0, j, :].reshape(cap_left, 1)
             lyv = ly_ref[0, j, :].reshape(cap_left, 1)
             lidxv = lidx_ref[0, j, :].reshape(cap_left, 1)
-            sx_parts, sy_parts, sidx_parts = [], [], []
-            for di in range(span):
-                rx_ref = right_refs[3 * di]
-                ry_ref = right_refs[3 * di + 1]
-                ridx_ref = right_refs[3 * di + 2]
-                for dy in range(-layers, layers + 1):
-                    c = j + layers + dy  # column in the col-padded plane
-                    sx_parts.append(rx_ref[0, c, :].reshape(1, cap_right))
-                    sy_parts.append(ry_ref[0, c, :].reshape(1, cap_right))
-                    sidx_parts.append(ridx_ref[0, c, :].reshape(1, cap_right))
-            sx = jnp.concatenate(sx_parts, axis=1)  # (1, k_cand)
-            sy = jnp.concatenate(sy_parts, axis=1)
-            sidx = jnp.concatenate(sidx_parts, axis=1)
-            ddx = lxv - sx
-            ddy = lyv - sy
-            d2 = ddx * ddx + ddy * ddy
-            mask = (lidxv >= 0) & (sidx >= 0) & (d2 <= r2)
-            nhit = jnp.sum(mask.astype(jnp.int32))
+            lvalid = lidxv >= 0
 
-            @pl.when(nhit > 0)
-            def _extract():
-                code_iota = (
-                    jax.lax.broadcasted_iota(
-                        jnp.int32, (cap_left, k_cand), 0
-                    ) * k_cand
-                    + jax.lax.broadcasted_iota(
-                        jnp.int32, (cap_left, k_cand), 1
-                    )
-                )
-                big = cap_left * k_cand
+            @pl.when(jnp.sum(lvalid.astype(jnp.int32)) > 0)
+            def _cell():
+                # One neighbour bucket at a time: a hit's cost is a pass
+                # over its (capL, capR) block, not over all span² of them.
+                for di in range(span):
+                    rx_ref = right_refs[3 * di]
+                    ry_ref = right_refs[3 * di + 1]
+                    ridx_ref = right_refs[3 * di + 2]
+                    for dy in range(-layers, layers + 1):
+                        c = j + layers + dy  # column in the col-padded plane
+                        sx = rx_ref[0, c, :].reshape(1, cap_right)
+                        sy = ry_ref[0, c, :].reshape(1, cap_right)
+                        sidx = ridx_ref[0, c, :].reshape(1, cap_right)
+                        ddx = lxv - sx
+                        ddy = lyv - sy
+                        d2 = ddx * ddx + ddy * ddy
+                        mask = lvalid & (sidx >= 0) & (d2 <= r2)
+                        nhit = jnp.sum(mask.astype(jnp.int32))
 
-                def cond(st):
-                    return st[1] > 0
-
-                def body(st):
-                    # Scalar-only carry (last extracted code): Mosaic cannot
-                    # carry the (capL, k_cand) i1 mask through a while loop.
-                    last, remaining = st
-                    code = jnp.min(
-                        jnp.where(mask & (code_iota > last), code_iota, big)
-                    )
-                    # One-hot reduces instead of dynamic_slice (which Mosaic
-                    # does not lower): exactly one lane has code_iota == code.
-                    hot = code_iota == code
-                    lval = jnp.sum(jnp.where(hot, lidxv, 0))
-                    rval = jnp.sum(jnp.where(hot, sidx, 0))
-                    dval = jnp.sqrt(jnp.sum(jnp.where(hot, d2, 0.0)))
-                    # Scalar stores to VMEM are impossible on TPU; instead
-                    # accumulate into a 128-lane register row (one-hot
-                    # select) and flush full rows with a vector store.
-                    s = sm[0]
-                    base = sm[1]
-                    lane = s - base  # 0..127 unless the budget overflowed
-                    lane_hot = lane_iota == lane
-                    accl[:] = jnp.where(lane_hot, lval, accl[:])
-                    accr[:] = jnp.where(lane_hot, rval, accr[:])
-                    accd[:] = jnp.where(
-                        lane_hot, dval.astype(jnp.float32), accd[:]
-                    )
-                    sm[0] = s + 1
-
-                    @pl.when((lane == 127) & (base // 128 < max_rows))
-                    def _flush():
-                        row = base // 128
-                        outl_ref[pl.ds(row, 1), :] = accl[:]
-                        outr_ref[pl.ds(row, 1), :] = accr[:]
-                        outd_ref[pl.ds(row, 1), :] = accd[:]
-                        sm[1] = base + 128
-
-                    return (code, remaining - 1)
-
-                jax.lax.while_loop(cond, body, (jnp.int32(-1), nhit))
+                        @pl.when(nhit > 0)
+                        def _extract():
+                            peel(mask, nhit, lidxv, sidx, d2)
 
             return carry
 
@@ -151,18 +188,17 @@ def _extract_kernel(
 
     @pl.when(i == grid_n - 1)
     def _fin():
-        cnt = sm[0]
-        base = sm[1]
+        # What is left in the register row and the stage goes out whole;
+        # the caller masks every slot past the count.
+        @pl.when(sm[1] > 0)
+        def _partial_row():
+            stage_row()
 
-        @pl.when((cnt > base) & (base // 128 < max_rows))
-        def _partial_flush():
-            ok = lane_iota < (cnt - base)
-            row = base // 128
-            outl_ref[pl.ds(row, 1), :] = jnp.where(ok, accl[:], -1)
-            outr_ref[pl.ds(row, 1), :] = jnp.where(ok, accr[:], -1)
-            outd_ref[pl.ds(row, 1), :] = jnp.where(ok, accd[:], jnp.inf)
+        @pl.when(sm[2] > 0)
+        def _partial_stage():
+            copy_out()
 
-        cnt_ref[0, 0] = cnt
+        cnt_ref[0, 0] = sm[0]
 
 
 @functools.partial(
@@ -194,8 +230,10 @@ def join_window_pallas(
     """
     f32 = jnp.float32
     max_pairs = int(max_pairs)  # sfcheck: ok=trace-hygiene -- static shape budget, a Python int at trace time (never traced)
-    max_pairs += (-max_pairs) % 128  # whole 128-lane output rows
-    max_rows = max_pairs // 128
+    # Whole 128-lane rows, and whole stages of them once there are several.
+    max_rows = -(-max_pairs // 128)
+    stage_rows = min(STAGE_ROWS, max_rows)
+    max_rows += (-max_rows) % stage_rows
     span = 2 * layers + 1
     lx, ly, lidx, l_over = bucketize_planes(
         left_xy.astype(f32), left_valid, left_cells, grid_n, cap_left
@@ -231,8 +269,11 @@ def join_window_pallas(
     kernel = functools.partial(
         _extract_kernel,
         grid_n=grid_n, layers=layers,
-        cap_left=cap_left, cap_right=cap_right, max_pairs=max_pairs,
+        cap_left=cap_left, cap_right=cap_right,
+        max_rows=max_rows, stage_rows=stage_rows,
     )
+    hbm = lambda: pl.BlockSpec(memory_space=pl.ANY)
+    stage = lambda dtype: pltpu.VMEM((stage_rows, 128), dtype)
     outl, outr, outd, cnt = pl.pallas_call(
         kernel,
         grid=(grid_n,),
@@ -241,18 +282,7 @@ def join_window_pallas(
             left_spec(), left_spec(), left_spec(),
             *right_specs,
         ],
-        out_specs=[
-            pl.BlockSpec(
-                (max_rows, 128), lambda i: (0, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (max_rows, 128), lambda i: (0, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (max_rows, 128), lambda i: (0, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
+        out_specs=[hbm(), hbm(), hbm(), pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_shape=[
             jax.ShapeDtypeStruct((max_rows, 128), jnp.int32),
             jax.ShapeDtypeStruct((max_rows, 128), jnp.int32),
@@ -260,10 +290,12 @@ def join_window_pallas(
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.SMEM((2,), jnp.int32),
+            pltpu.SMEM((4,), jnp.int32),
             pltpu.VMEM((1, 128), jnp.int32),
             pltpu.VMEM((1, 128), jnp.int32),
             pltpu.VMEM((1, 128), jnp.float32),
+            stage(jnp.int32), stage(jnp.int32), stage(jnp.float32),
+            pltpu.SemaphoreType.DMA((3,)),
         ],
         interpret=interpret,
     )(
@@ -271,7 +303,13 @@ def join_window_pallas(
         lx, ly, lidx,
         *right_args,
     )
+    # The kernel writes whole rows and stages; every slot past the count
+    # (never written, or the tail of the last stage) gets the padding here.
+    count = cnt[0, 0]
+    found = jnp.arange(max_rows * 128, dtype=jnp.int32) < count
     return CompactJoinResult(
-        outl.reshape(-1), outr.reshape(-1), outd.reshape(-1),
-        cnt[0, 0], l_over + r_over,
+        jnp.where(found, outl.reshape(-1), -1),
+        jnp.where(found, outr.reshape(-1), -1),
+        jnp.where(found, outd.reshape(-1), jnp.inf),
+        count, l_over + r_over,
     )

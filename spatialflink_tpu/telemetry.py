@@ -324,6 +324,12 @@ class Telemetry:
         self.wire_raw_bytes = 0
         self.wire_coded_bytes = 0
         self.wire_panes = 0
+        # Window point join (operators/join_query.py:run_soa via
+        # record_join): counters pairs / windows / cap_retries /
+        # budget_retries and the gauges cap / budget (the rung and the
+        # pair budget in use) — snapshot()["join"], empty until the first
+        # joined window.
+        self._join: Dict[str, int] = {}
         # Pipelined-ingest executor counters (spatialflink_tpu/
         # pipeline.py via record_pipeline): overlapped vs collapsed
         # windows, checkpoint drains — sfprof health's stall notes.
@@ -1132,6 +1138,24 @@ class Telemetry:
             b["wire_coded_bytes"] += int(coded_bytes)
             b["wire_panes"] += 1
 
+    def record_join(self, pairs: int, cap_retries: int, budget_retries: int,
+                    cap: int, budget: int):
+        """One window of the SoA point join, fetched: ``pairs`` found,
+        the re-runs it took (a bucket capacity or a pair budget the window
+        did not fit), and the capacity rung and budget it ended on. Lands
+        in ``snapshot()["join"]`` as the counters ``pairs``, ``windows``,
+        ``cap_retries``, ``budget_retries`` and the gauges ``cap``,
+        ``budget``. Per window, never per event."""
+        if not self.enabled:
+            return
+        with self._lock:
+            j = self._join
+            for key, n in (("pairs", pairs), ("windows", 1),
+                           ("cap_retries", cap_retries),
+                           ("budget_retries", budget_retries)):
+                j[key] = j.get(key, 0) + int(n)
+            j["cap"], j["budget"] = int(cap), int(budget)
+
     def record_pipeline(self, **counts: int):
         """Accumulate pipelined-executor counters (windows, overlapped,
         sync, drains, collapses — pipeline.py documents each). Lands in
@@ -1633,6 +1657,8 @@ class Telemetry:
                                "bytes": self.shed_bytes}
             if self._pipeline:
                 out["pipeline"] = dict(self._pipeline)
+            if self._join:
+                out["join"] = dict(self._join)
             if self.wire_panes:
                 out["wire_codec"] = {
                     "panes": self.wire_panes,
