@@ -10,7 +10,10 @@ not load files from untrusted sources).
 
 Snapshottable components:
   - WindowAssembler: open window buffers, fired flags, max event-time,
-    late-drop count;
+    late-drop count; the DAG's ColumnarWindowAssembler
+    (streams/columns.py): its pane buffers — arrays, id tables, the few
+    non-point objects, arrival marks — with the same clock fields (a
+    checkpoint in the generic form still restores into it);
   - SoA sliding assemblers (streams/soa.py): buffered chunks + watermark
     state machine;
   - TAggregateQuery: the per-(cell, objID) min/max timestamp MapState;
@@ -55,7 +58,9 @@ from spatialflink_tpu.telemetry import telemetry
 from spatialflink_tpu.utils.interning import Interner
 
 
-def assembler_state(asm: WindowAssembler) -> Dict[str, Any]:
+def assembler_state(asm) -> Dict[str, Any]:
+    if not isinstance(asm, WindowAssembler):
+        return asm.state()  # ColumnarWindowAssembler: its pane buffers
     return {
         "buffers": [
             ((spec.start, spec.end), events)
@@ -69,7 +74,10 @@ def assembler_state(asm: WindowAssembler) -> Dict[str, Any]:
     }
 
 
-def restore_assembler(asm: WindowAssembler, state: Dict[str, Any]) -> None:
+def restore_assembler(asm, state: Dict[str, Any]) -> None:
+    if not isinstance(asm, WindowAssembler):
+        asm.restore(state)  # either form (streams/columns.py)
+        return
     asm._buffers = {
         WindowSpec(s, e): list(events) for (s, e), events in state["buffers"]
     }

@@ -9,16 +9,19 @@ PER OPERATOR — is a multi-operator dataflow sharing one ingest. This
 module composes it:
 
 - **One shared source / interner / window clock**: a
-  :class:`DataflowDAG` owns one :class:`WindowAssembler` and one
+  :class:`DataflowDAG` owns one window assembler (the columnar
+  :class:`ColumnarWindowAssembler`: windows buffered as arrays per
+  pane, not as event objects per window) and one
   ``Interner``; every node processes the SAME fired windows, so ingest,
   window assembly, and string interning are paid ONCE for N queries
   (the CIKM 2020 grid design assumes exactly this sharing — a
   throughput win by construction, and the deliberate deviation from the
   reference's per-query window configs; PARITY.md "Composed dataflow").
-  The fired window itself is shared the same way: the DAG turns it into
-  columns ONCE (streams/columns.py, span ``window.columns``) and the
-  SNCB nodes compute from those arrays — no node walks ``win.events``
-  attribute by attribute (:meth:`DataflowDAG.columns`).
+  The fired window itself is shared the same way: the DAG puts its
+  panes' columns together ONCE (streams/columns.py, span
+  ``window.columns``) and the SNCB nodes compute from those arrays — no
+  node walks ``win.events`` attribute by attribute
+  (:meth:`DataflowDAG.columns`).
 - **The atomic unit checkpoint**: source position + the shared
   assembler + interner + EVERY node's backend/counters/substate
   (qserve registry, checkin occupancy) + EVERY sink's committed marker
@@ -81,11 +84,14 @@ from spatialflink_tpu.driver import (
 from spatialflink_tpu.faults import faults
 from spatialflink_tpu.mn.metrics import FixedBucketLatency, json_safe
 from spatialflink_tpu.models.objects import Point
-from spatialflink_tpu.streams.columns import WindowColumns
+from spatialflink_tpu.streams.columns import (
+    ColumnarWindowAssembler,
+    PaneEvents,
+    WindowColumns,
+)
 from spatialflink_tpu.streams.sinks import MultiSink, TransactionalFileSink
 from spatialflink_tpu.streams.windows import (
     SlidingEventTimeWindows,
-    WindowAssembler,
     WindowBatch,
 )
 from spatialflink_tpu.telemetry import telemetry
@@ -141,9 +147,13 @@ class DagNode:
         its few non-point events (streams/columns.py), built ONCE and
         shared by every node; and ``win.events`` itself for events the
         view keeps as objects (CheckIn's door events) or to hand back
-        the few objects a result holds (``events[cols.pos[i]]``). A
-        node that walks ``win.events`` attribute by attribute pays
-        per event per node what the view pays once."""
+        the few objects a result holds (``events[cols.pos[i]]``).
+        ``win.events`` is a sequence, not a list: a window fired from
+        the DAG's panes builds the ``GpsEvent`` / ``Point`` of a
+        position when it is asked for, and all of them when it is
+        walked (the host twins do, on failover). A node that walks it
+        attribute by attribute pays per event per node what the view
+        pays once — and the objects' making on top."""
         raise NotImplementedError
 
     def render(self, result, start: int, end: int) -> Iterator[str]:
@@ -563,9 +573,14 @@ class DataflowDAG:
         self._reads_columns = any(n.reads_columns for n in nodes)
         #: Views built by the walk / node reads of a view (7 a window in
         #: the SNCB DAG): the evidence that the one view is what the
-        #: nodes computed from. Process-local, like the breakers.
+        #: nodes computed from — and how the built ones were made: from
+        #: the assembler's panes or from a list of events, and how many
+        #: of the former needed the arrival-order sort. Process-local,
+        #: like the breakers.
         self.window_columns_built = 0
         self.window_columns_reads = 0
+        self.window_columns_source = {"panes": 0, "events": 0}
+        self.window_columns_reordered = 0
         for n in nodes:
             n.bind(self)
 
@@ -603,19 +618,21 @@ class DataflowDAG:
 
     # -- operator protocol (the driver's op) -----------------------------------
 
-    def _assembler(self) -> WindowAssembler:
+    def _assembler(self) -> ColumnarWindowAssembler:
         # max_out_of_orderness only — NO allowed-lateness refires: a
         # refire would re-run windows already charged to the qserve
         # node's per-window accumulators (the QServeOperator.run rule,
-        # enforced for the whole DAG).
-        return WindowAssembler(
+        # enforced for the whole DAG). The DAG's stream is point-like
+        # rows plus a handful of commands, so its windows are buffered
+        # as columns per pane (streams/columns.py) and the fired
+        # window's view is a concatenation (_build_columns).
+        return ColumnarWindowAssembler(
             SlidingEventTimeWindows(self.conf.window_size_ms,
                                     self.conf.slide_step_ms),
-            timestamp_fn=lambda e: e.timestamp,
             max_out_of_orderness_ms=self.conf.allowed_lateness_ms,
         )
 
-    def _adopt_assembler(self, asm) -> WindowAssembler:
+    def _adopt_assembler(self, asm) -> ColumnarWindowAssembler:
         # THE restore-and-expose protocol (operators/base.py is its
         # home; borrowed unbound so there is exactly one implementation).
         from spatialflink_tpu.operators.base import SpatialOperator
@@ -750,7 +767,12 @@ class DataflowDAG:
         return self._build_columns(win)
 
     def _build_columns(self, win: WindowBatch) -> WindowColumns:
-        cols = WindowColumns.from_events(win.events, self.interner)
+        if isinstance(win.events, PaneEvents):
+            # A window this DAG's assembler fired: its panes' columns.
+            cols = WindowColumns.from_panes(win.events, self.interner)
+        else:
+            # A window handed in as a list (a test, a tool).
+            cols = WindowColumns.from_events(win.events, self.interner)
         cols.oid  # dense ids assigned HERE, in window order
         return cols
 
@@ -768,8 +790,11 @@ class DataflowDAG:
                                     events=len(win.events)) as sp:
                     cols = self._build_columns(win)
                     # (the disabled-telemetry null span has no args)
-                    getattr(sp, "args", {})["gps"] = len(cols.gps())
+                    getattr(sp, "args", {}).update(
+                        gps=len(cols.gps()), source=cols.source)
                 self.window_columns_built += 1
+                self.window_columns_source[cols.source] += 1
+                self.window_columns_reordered += cols.reordered
                 self._columns = (win, cols)
             for name in self.dag_nodes:
                 node = self._nodes[name]
@@ -961,6 +986,9 @@ class DataflowDAG:
             out["window_columns"] = {
                 "built": int(self.window_columns_built),
                 "reads": int(self.window_columns_reads),
+                "from_panes": int(self.window_columns_source["panes"]),
+                "from_events": int(self.window_columns_source["events"]),
+                "reordered": int(self.window_columns_reordered),
             }
         return json_safe(out)
 

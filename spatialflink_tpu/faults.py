@@ -67,8 +67,9 @@ INJECTION_POINTS: Dict[str, str] = {
                        "dispatch (jitted, mesh window programs, bench "
                        "steps)",
     "device.fetch": "telemetry.fetch — device→host true-sync fetch",
-    "window.feed": "streams/windows.py:WindowAssembler.feed — per-event "
-                   "window assembly",
+    "window.feed": "streams/windows.py:WindowAssembler.feed and "
+                   "streams/columns.py:ColumnarWindowAssembler.feed — "
+                   "per-event window assembly",
     "soa.feed": "streams/soa.py sliding assemblers — per-chunk SoA "
                 "window assembly",
     "kafka.fetch": "streams/kafka.py:WireKafkaSource — per-partition "

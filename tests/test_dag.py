@@ -478,6 +478,162 @@ class TestNodeParity:
 
 
 # ---------------------------------------------------------------------------
+# Windows buffered as columns per pane (streams/columns.py): the DAG's
+# assembler holds arrays, the object path (the generic WindowAssembler +
+# WindowColumns.from_events) is the reference.
+
+
+def _pane_stream(n=420, out_of_order=False):
+    """Boot commands, then ``n`` full-schema GpsEvents at a 100 ms
+    cadence near the bundled zones (every node has egress); with
+    ``out_of_order`` a sixth of them arrive up to 4.5 s late — inside
+    the 5 s bound, so they land, in panes older than the one being
+    filled."""
+    from spatialflink_tpu.dag import default_sncb_queries
+    from spatialflink_tpu.qserve import QServeCommand
+    from spatialflink_tpu.sncb.common import GpsEvent
+
+    rng = np.random.default_rng(77)
+    out = [QServeCommand(timestamp=0, action="register",
+                         uid=f"boot:{q.qid}", query=q)
+           for q in default_sncb_queries()]
+    for i in range(n):
+        cx, cy = ((4.354, 50.854), (4.404, 50.854),
+                  (4.375, 50.85))[i % 3]
+        ts = i * 100
+        if out_of_order and i % 6 == 0:
+            ts -= int(rng.integers(1, 4_500))
+        out.append(GpsEvent(
+            device_id=f"dev{i % 7}",
+            lon=cx + float(rng.normal(0.0, 0.004)),
+            lat=cy + float(rng.normal(0.0, 0.004)), ts=max(ts, 0),
+            gps_speed=float(rng.uniform(20.0, 110.0)),
+            fa=float(rng.uniform(0.0, 1.0)),
+            ff=None if i % 11 == 0 else float(rng.uniform(0.0, 0.4))))
+    return out
+
+
+def _pane_leg(workdir, events, objects=False, plan=None):
+    """One 7-node run with a unit checkpoint at every window;
+    ``objects`` swaps in the generic assembler (the object path)."""
+    from spatialflink_tpu.streams.windows import (
+        SlidingEventTimeWindows,
+        WindowAssembler,
+    )
+
+    dag = build_sncb_dag(os.path.join(workdir, "egress"),
+                         retry=RetryPolicy(max_retries=1, backoff_s=0.0))
+    if objects:
+        conf = dag.conf
+        dag._assembler = lambda: WindowAssembler(
+            SlidingEventTimeWindows(conf.window_size_ms,
+                                    conf.slide_step_ms),
+            timestamp_fn=lambda e: e.timestamp,
+            max_out_of_orderness_ms=conf.allowed_lateness_ms)
+    driver = WindowedDataflowDriver(
+        checkpoint_path=os.path.join(workdir, "ckpt.bin"),
+        checkpoint_every=1, sink=None,
+        retry=RetryPolicy(max_retries=1, backoff_s=0.0), failover=False)
+    if plan:
+        faults.arm(plan)
+    try:
+        for _ in dag.run(iter(events), driver=driver):
+            pass
+    finally:
+        faults.disarm()
+        qserve.uninstall()
+        dag_mod.uninstall()
+    return driver, dag
+
+
+@pytest.fixture(scope="module", params=["in_order", "out_of_order"])
+def pane_clean(request, tmp_path_factory):
+    """(events, the object path's committed bytes) per stream."""
+    events = _pane_stream(out_of_order=request.param == "out_of_order")
+    d = tmp_path_factory.mktemp("pane_objects")
+    _, dag = _pane_leg(str(d), events, objects=True)
+    want = _sink_bytes(str(d))
+    assert all(len(v) > 0 for v in want.values()), {
+        k: len(v) for k, v in want.items()}
+    counts = dag.snapshot()["window_columns"]
+    assert counts["from_events"] == counts["built"] > 3
+    assert counts["from_panes"] == 0
+    return request.param, events, want
+
+
+class TestPaneBuffers:
+    def test_sinks_byte_identical_to_the_object_path(self, tmp_path,
+                                                     pane_clean):
+        order, events, want = pane_clean
+        _, dag = _pane_leg(str(tmp_path), events)
+        assert _sink_bytes(str(tmp_path)) == want
+        counts = dag.snapshot()["window_columns"]
+        assert counts["from_panes"] == counts["built"] > 3
+        assert counts["from_events"] == 0
+        assert (counts["reordered"] > 0) == (order == "out_of_order")
+
+    @pytest.mark.parametrize("plan", [
+        [{"point": "window.feed", "at": 260, "times": 10_000}],
+        [{"point": "dag.commit", "at": 17, "times": 10_000}],
+        [{"point": "dag.node", "at": 25, "times": 10_000}],
+    ], ids=lambda p: p[0]["point"])
+    def test_kill_and_resume_from_the_pane_state(self, tmp_path,
+                                                 pane_clean, plan):
+        _, events, want = pane_clean
+        with pytest.raises(InjectedFault):
+            _pane_leg(str(tmp_path), events, plan=plan)
+        ck = os.path.join(str(tmp_path), "ckpt.bin")
+        asm = load_checkpoint(ck)["op"]["assembler"]
+        assert asm["panes"] and "buffers" not in asm
+        with open(ck, "rb") as f:
+            assert b"GpsEvent" not in f.read()  # arrays, not objects
+        drv, _ = _pane_leg(str(tmp_path), events)  # resume
+        assert drv.stats["resumed"] is True
+        assert _sink_bytes(str(tmp_path)) == want
+
+    def test_a_checkpoint_in_the_object_form_restores(self, tmp_path,
+                                                      pane_clean):
+        """The parent's checkpoint (``"buffers"``: lists of events per
+        window) resumes into panes, byte-identically."""
+        _, events, want = pane_clean
+        with pytest.raises(InjectedFault):
+            _pane_leg(str(tmp_path), events, objects=True, plan=[
+                {"point": "window.feed", "at": 260, "times": 10_000}])
+        ck = os.path.join(str(tmp_path), "ckpt.bin")
+        asm = load_checkpoint(ck)["op"]["assembler"]
+        assert sum(len(evs) for _, evs in asm["buffers"]) > 50
+        drv, dag = _pane_leg(str(tmp_path), events)  # columnar resume
+        assert drv.stats["resumed"] is True
+        assert _sink_bytes(str(tmp_path)) == want
+        counts = dag.snapshot()["window_columns"]
+        assert counts["from_panes"] == counts["built"] > 0
+        assert "panes" in load_checkpoint(ck)["op"]["assembler"]
+
+    def test_lazy_events_serve_q1_and_a_fallback_twin(self, tmp_path,
+                                                      pane_clean):
+        """``win.events`` holds no objects, yet q1 hands back the raw
+        event of each hit and StayTime's host twin walks the window
+        after a forced failover — as on the object path."""
+        _, events, _ = pane_clean
+        # first window's 6th device attempt = staytime; + its one retry
+        plan = [{"point": "dag.node", "at": 6, "times": 2}]
+        (tmp_path / "objects").mkdir()
+        (tmp_path / "panes").mkdir()
+        _, ref = _pane_leg(str(tmp_path / "objects"), events,
+                           objects=True, plan=plan)
+        _, dag = _pane_leg(str(tmp_path / "panes"), events, plan=plan)
+        for d in (ref, dag):
+            nodes = d.snapshot()["nodes"]
+            assert nodes["staytime"]["backend"] == "fallback"
+            assert nodes["staytime"]["failovers"] == 1
+            assert all(st["backend"] == "device"
+                       for n, st in nodes.items() if n != "staytime")
+        got = _sink_bytes(str(tmp_path / "panes"))
+        assert got == _sink_bytes(str(tmp_path / "objects"))
+        assert got["q1"] and got["staytime"]
+
+
+# ---------------------------------------------------------------------------
 # Leaf spans inside the node walk (h2d / dispatch:<kernel> / d2h)
 
 

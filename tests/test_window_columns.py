@@ -44,8 +44,15 @@ from spatialflink_tpu.sncb.ops import (  # noqa: E402
     variation,
 )
 from spatialflink_tpu.sncb.queries import _zone_filter  # noqa: E402
-from spatialflink_tpu.streams.columns import WindowColumns  # noqa: E402
-from spatialflink_tpu.streams.windows import WindowBatch  # noqa: E402
+from spatialflink_tpu.streams.columns import (  # noqa: E402
+    ColumnarWindowAssembler,
+    PaneEvents,
+    WindowColumns,
+)
+from spatialflink_tpu.streams.windows import (  # noqa: E402
+    SlidingEventTimeWindows,
+    WindowBatch,
+)
 from spatialflink_tpu.telemetry import telemetry  # noqa: E402
 from spatialflink_tpu.utils.interning import Interner  # noqa: E402
 
@@ -309,6 +316,71 @@ def test_node_lines_equal_the_object_walk(sncb_dags, case, name):
     assert got == want
 
 
+def _fired_from_panes(events):
+    """``events`` through the DAG's assembler (10 s / 5 s panes; a bound
+    wide enough that the shuffled case lands whole): the windows it
+    fires hold no event object, only cuts of its panes."""
+    asm = ColumnarWindowAssembler(SlidingEventTimeWindows(10_000, 5_000),
+                                  max_out_of_orderness_ms=60_000)
+    fired = list(asm.stream(events))
+    assert all(isinstance(w.events, PaneEvents) for w in fired)
+    return fired
+
+
+@pytest.mark.parametrize("name", NODES)
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_node_lines_from_panes_equal_the_object_walk(sncb_dags, case, name):
+    """The same seeded streams, fired from panes: every node computes
+    from the concatenated columns what the walk computes from the
+    window's events as a plain list."""
+    dag, ref = sncb_dags
+    node, ref_node = dag.node(name), ref.node(name)
+    if name in ("staytime", "qserve"):
+        node.process(WindowBatch(0, 1, []), {})  # make the kernel
+        ref_node._kernel = node._kernel
+    fired = _fired_from_panes(CASES[case]())
+    assert fired or case == "empty"
+    for win in fired:
+        got = _lines(node, node.process(win, {}), win)
+        listed = WindowBatch(win.start, win.end, list(win.events))
+        assert got == _lines(ref_node, walk(ref_node, listed), listed)
+
+
+def _assert_views_equal(got, want):
+    for col in ("ts", "lon", "lat", "gps_speed", "fa", "ff", "is_gps", "pos",
+                "oid"):
+        a, b = getattr(got, col), getattr(want, col)
+        assert a.dtype == b.dtype, col
+        assert np.array_equal(a, b, equal_nan=a.dtype.kind == "f"), col
+    assert got.ids == want.ids
+    assert got.others == want.others
+    assert got.interner._to_key == want.interner._to_key
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_view_from_panes_equals_the_view_of_its_events(case):
+    """``from_panes`` against ``from_events`` of the very events the
+    lazy sequence hands out: all columns, ``pos``, ``ids``, ``oid``
+    and the table behind it, ``gps()``, ``by_device``."""
+    it_panes, it_events = Interner(), Interner()
+    for win in _fired_from_panes(CASES[case]()):
+        events = list(win.events)
+        got = WindowColumns.from_panes(win.events, it_panes)
+        want = WindowColumns.from_events(events, it_events)
+        _assert_views_equal(got, want)
+        _assert_views_equal(got.gps(), want.gps())
+        assert got.gps().gps() is got.gps()
+        assert [type(win.events[p]) for p in got.pos.tolist()] == \
+            [type(events[p]) for p in want.pos.tolist()]
+        rows = np.arange(len(got.gps()))
+        for by_ts in (False, True):
+            a = got.gps().by_device(rows, by_ts=by_ts)
+            b = want.gps().by_device(rows, by_ts=by_ts)
+            assert all(np.array_equal(x, y) for x, y in zip(a[:3], b[:3]))
+            assert a[3] == b[3]
+        assert np.array_equal(got.gps().metric_xy(), want.gps().metric_xy())
+
+
 def test_cases_are_not_vacuous(sncb_dags):
     """Every node speaks in some case, the zone nodes where they must."""
     dag, _ = sncb_dags
@@ -507,9 +579,38 @@ def test_one_view_per_window_read_by_all_seven(tmp_path):
     assert views[0]["ts"] + views[0]["dur"] <= first_node + 1
     assert dag.window_columns_built == len(fired)
     assert dag.window_columns_reads == 7 * len(fired)
+    # every view came from the assembler's panes; the toy stream's
+    # stragglers put some windows through the arrival-order sort
+    sorted_back = sum(
+        w.events.resolved().reordered for w in ColumnarWindowAssembler(
+            SlidingEventTimeWindows(10_000, 5_000), 5_000,
+        ).stream(_toy_sncb_stream(240)()))
+    assert sorted_back > 0
     assert dag.snapshot()["window_columns"] == {
-        "built": len(fired), "reads": 7 * len(fired)}
+        "built": len(fired), "reads": 7 * len(fired),
+        "from_panes": len(fired), "from_events": 0,
+        "reordered": sorted_back}
+    assert {v["args"]["source"] for v in views} == {"panes"}
     assert dag._columns is None  # alive for the walk only
+
+
+def test_a_window_handed_in_as_a_list_counts_as_from_events(tmp_path):
+    dag = build_sncb_dag(str(tmp_path / "egress"))
+    telemetry.enable()
+    try:
+        dag._process_window(WindowBatch(0, 10_000, _boot() + _gps(60, 81)))
+        fired = _fired_from_panes(_gps(60, 82))
+        for win in fired:
+            dag._process_window(win)
+        views = [e for e in telemetry.events
+                 if e.get("ph") == "X" and e["name"] == "window.columns"]
+    finally:
+        telemetry.disable()
+    assert dag.snapshot()["window_columns"] == {
+        "built": 1 + len(fired), "reads": 7 * (1 + len(fired)),
+        "from_panes": len(fired), "from_events": 1, "reordered": 0}
+    assert [v["args"]["source"] for v in views] == \
+        ["events"] + ["panes"] * len(fired)
 
 
 def test_a_dag_without_column_readers_builds_no_view(tmp_path):
