@@ -1,7 +1,6 @@
 """Extended benchmark suite — the five BASELINE.json configs.
 
-``bench.py`` stays the driver's single-line headline (continuous kNN k=50,
-1M-pt windows). This script exercises every configuration listed in
+This script exercises every configuration listed in
 BASELINE.json's ``configs`` and prints one JSON line per config plus a
 summary line. All rates are distinct-ingested-points/sec on the current
 default device.
@@ -234,7 +233,7 @@ def bench_knn_k(jax, jnp, grid, k, quick):
 
     Measures the shipped operator program — run_wire_panes
     (operators/knn_query.py), whose wire→digest step is the ONE shared
-    implementation in ops/wire_knn.py (also bench.py's headline): each
+    implementation in ops/wire_knn.py: each
     1s pane (200k points at the 200k EPS event rate) of 6 B/pt
     plane-major wire records is digested ONCE (top-k compaction on XLA;
     the fused Pallas extraction on TPU after a first-pane self-check —
@@ -421,7 +420,7 @@ def bench_join(jax, jnp, grid, quick):
     On TPU the Pallas hit-extraction join runs (compaction cost ∝ matches);
     elsewhere the XLA dense-bucket kernel. The dispatch loop is pipelined
     lag-1 (fetch window i−1 after dispatching i) so the device round trip
-    overlaps compute — the same double-buffering bench.py uses.
+    overlaps compute.
     """
     from spatialflink_tpu.ops.cells import assign_cells
     from spatialflink_tpu.ops.join import join_window_bucketed, pallas_join_supported
@@ -765,8 +764,7 @@ def bench_sncb_dag(jax, jnp, grid, quick):
     # Per-node EPS columns from the attribution buckets (telemetry is
     # enabled by the suite's capture loop; plain runs skip the column).
     # Each node's rate is ITS events over ITS accumulated span time, so
-    # the table survives the record↔ledger round trip bit-identically
-    # (the SFT_BENCH_SMOKE contract twin in bench.py).
+    # the table survives the record↔ledger round trip bit-identically.
     from spatialflink_tpu.telemetry import telemetry
 
     rollup = telemetry.node_rollup() if telemetry.enabled else {}
@@ -1325,51 +1323,6 @@ def bench_tstats_pane(jax, jnp, grid, quick):
     )
 
 
-def bench_headline_knn_1m(jax, jnp, grid):
-    """bench.py's headline PROGRAM (bench.build_headline_step: 6 B/pt wire
-    records in RAM, top-k-compacted pane digest, window merge + top-50) on
-    the current backend — run by --cpu-baseline so bench.py can report
-    vs_measured_cpu for the exact same program, ingest excluded."""
-    from bench import NUM_SEGMENTS, SLIDE, build_headline_step
-    from spatialflink_tpu.streams.wire import WireFormat
-
-    wf = WireFormat.for_grid(grid)
-    n_slides = 8
-    rng = np.random.default_rng(42)
-    total = SLIDE * (n_slides + 1)
-    xyq = wf.quantize(np.stack(
-        [rng.uniform(115.5, 117.6, total), rng.uniform(39.6, 41.1, total)],
-        axis=1,
-    ))
-    oid16 = rng.integers(0, NUM_SEGMENTS, total).astype(np.int16)
-    wire = np.concatenate([xyq, oid16.view(np.uint16)[:, None]], axis=1)
-    jstep = _instr(jax.jit(build_headline_step(jnp, wf)),
-                   "headline_step")
-    q = jnp.asarray(np.array([116.40, 40.19], np.float32))
-    big = np.float32(np.finfo(np.float32).max)
-    sp0 = jnp.full((NUM_SEGMENTS,), big, jnp.float32)
-    rp0 = jnp.full((NUM_SEGMENTS,), np.iinfo(np.int32).max, jnp.int32)
-    slides = [
-        jnp.asarray(np.ascontiguousarray(wire[i * SLIDE:(i + 1) * SLIDE].T))
-        for i in range(n_slides + 1)
-    ]
-    seg0, rep0, res = jstep(sp0, rp0, slides[0], q)
-    jax.device_get(res.num_valid)  # compile
-    times = []
-    for _ in range(3):
-        sp, rp = seg0, rep0
-        fired = []
-        t0 = time.perf_counter()
-        for i in range(1, n_slides + 1):
-            sp, rp, res = jstep(sp, rp, slides[i], q)
-            fired.append(res.num_valid)
-        jax.device_get(fired)
-        times.append(time.perf_counter() - t0)
-    dt = float(np.median(times))
-    return _result("continuous_knn_k50_1M_window", n_slides * SLIDE, dt,
-                   spread=(min(times), max(times)))
-
-
 def bench_tknn(jax, jnp, grid, quick):
     """Config 5: trajectory kNN, per-objID grouped, k=20. Same streamed
     double-buffered dispatch model as the other configs (int16 oid wire,
@@ -1921,7 +1874,7 @@ def main():
             except Exception as e:
                 # A ledger failure (disk full, NaN in a result dict) must
                 # not abort a multi-hour suite run and lose every other
-                # config's result — same degrade-to-stderr as bench.py.
+                # config's result — degrade to stderr.
                 import sys
 
                 sys.stderr.write(f"ledger for {name} not written: {e!r}\n")
@@ -1931,7 +1884,6 @@ def main():
             res = fn()
         results.append(res)
     if args.cpu_baseline:
-        results.append(bench_headline_knn_1m(jax, jnp, grid))
         payload = {
             "note": (
                 "Measured CPU-backend throughput of the same fused window "

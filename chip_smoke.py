@@ -55,7 +55,7 @@ SNCB_WINDOW_S, SNCB_SLIDE_S = 10, 5
 #: x64 off, and int32 time arithmetic must survive real timestamps).
 T0_MS = 1_700_000_000_000
 
-# Headline kNN settings (bench.py / BASELINE.json config 2).
+# Headline kNN settings (BASELINE.json config 2).
 KNN_WINDOW_POINTS = 1_000_000
 KNN_SLIDE_POINTS = 500_000
 KNN_WINDOWS = 4

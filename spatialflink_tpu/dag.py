@@ -1204,12 +1204,12 @@ def run_chaos_child(workdir: str) -> int:
     """One (possibly fault-armed) 7-node SNCB DAG run: per-node
     exactly-once CSV egress + the atomic unit checkpoint under
     ``workdir``. Resumes automatically when the checkpoint exists.
-    ``SFT_OVERLOAD_POLICY``/``SFT_PIPELINE``/``SFT_FAULT_PLAN`` arm via
+    ``SFT_OVERLOAD_POLICY``/``SFT_FAULT_PLAN`` arm via
     env (faults at import; the policy is installed on the driver here
     with ``source_pausable=False`` so its shed path really sheds).
 
-    ``SFT_LEDGER_STREAM``/``SFT_LEDGER_PATH`` arm telemetry the way
-    bench.py does: per-node attribution from the DAG's node scopes
+    ``SFT_LEDGER_STREAM``/``SFT_LEDGER_PATH`` arm telemetry: per-node
+    attribution from the DAG's node scopes
     rides the stream's checkpoints, so a kill mid-run leaves a
     recoverable capture WITH node blocks. Each child invocation needs
     its OWN stream path — ``enable`` truncates, so a resume reusing the
@@ -1320,7 +1320,6 @@ def chaos_smoke() -> int:
 
     env_base = dict(os.environ)
     env_base.pop("SFT_FAULT_PLAN", None)
-    env_base.pop("SFT_PIPELINE", None)
     env_base.pop("SFT_LEDGER_PATH", None)
     # CPU-only: the smoke never takes the chip.
     env_base["JAX_PLATFORMS"] = "cpu"

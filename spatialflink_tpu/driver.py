@@ -58,7 +58,6 @@ from __future__ import annotations
 import contextlib
 import itertools
 import time
-from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterable, Iterator, Optional
 
@@ -107,7 +106,7 @@ def _dial_timeout_exit(code: int) -> None:
     os._exit(code)  # pragma: no cover - replaced by tests
 
 
-DIAL_TIMEOUT_EXIT_CODE = 3  # bench.py's dial-failure exit code
+DIAL_TIMEOUT_EXIT_CODE = 3  # the dial-failure exit code
 
 
 def _seal_stream_dial_timeout(label: str) -> None:
@@ -206,7 +205,6 @@ class WindowedDataflowDriver:
                  failover: bool = True,
                  overload=None,
                  source_pausable: Optional[bool] = None,
-                 pipeline=None,
                  dial_deadline_s: Optional[float] = None):
         self.checkpoint_path = checkpoint_path
         self.checkpoint_every = max(1, int(checkpoint_every))
@@ -234,15 +232,6 @@ class WindowedDataflowDriver:
         self.source_pausable = (bool(skip_on_resume)
                                 if source_pausable is None
                                 else bool(source_pausable))
-        #: Optional :class:`spatialflink_tpu.pipeline.PipelinePolicy` —
-        #: overlapped window processing for processors exposing the
-        #: split protocol (``pipeline_compute``/``pipeline_fetch``
-        #: attributes): up to ``fetch_lag`` windows stay in flight
-        #: between dispatch and their ordered fetch, drained to a
-        #: consistent frontier before every checkpoint commit. ``None``
-        #: falls back to the module policy (``SFT_PIPELINE``); with
-        #: neither, behavior is bit-identical to the synchronous loop.
-        self.pipeline = pipeline
         #: Bounded first device touch (the bench dial-deadline semantics
         #: brought to the driver): the FIRST device-path window process
         #: after construction or resume runs under a watchdog — a
@@ -381,10 +370,8 @@ class WindowedDataflowDriver:
             )
         self._reset_fresh_sink()
         with self._installed_controller():
-            pipe = self._pipeline_state()
             for win in windows:
-                yield from self._pipe_process(pipe, win)
-            yield from self._pipe_drain(pipe)
+                yield self._process_window(win)
             self._commit_sink_only()
 
     def _reset_fresh_sink(self) -> None:
@@ -443,7 +430,6 @@ class WindowedDataflowDriver:
                 # reflected in the restored assembler/operator state.
                 next(itertools.islice(it, self._skip - 1, self._skip), None)
                 self._skip = 0
-            pipe = self._pipeline_state()
             for item in it:
                 if faults.armed:  # chaos injection point (faults.py)
                     faults.hit("source.stall")
@@ -458,19 +444,12 @@ class WindowedDataflowDriver:
                     continue
                 fired = feed(item)
                 for win in fired:
-                    yield from self._pipe_process(pipe, win)
+                    yield self._process_window(win)
                 if fired and self._since_ckpt >= self.checkpoint_every:
-                    # Drain to a consistent frontier FIRST: every
-                    # in-flight window is yielded (so the consumer has
-                    # staged its egress) before the checkpoint counts
-                    # it — committed and replayed are the only states a
-                    # window can be in after a crash, never half.
-                    yield from self._pipe_drain(pipe)
                     self._commit()
             if flush is not None:
                 for win in flush():
-                    yield from self._pipe_process(pipe, win)
-            yield from self._pipe_drain(pipe)
+                    yield self._process_window(win)
             self._commit(final=True)
 
     # -- bounded first device touch (the dial watchdog) ------------------------
@@ -482,7 +461,7 @@ class WindowedDataflowDriver:
         ``--checkpoint`` resume) makes. On deadline: seal any armed
         ledger stream with reason ``dial_timeout`` (bounded-lock seal —
         :func:`_seal_stream_dial_timeout` never blocks the watchdog)
-        and kill the process with bench.py's dial exit code; a wedged
+        and kill the process with the dial exit code; a wedged
         device call cannot be un-wedged from Python, only reported and
         abandoned. Disarmed (no deadline / already dialed / fallback
         path) cost: one attribute check."""
@@ -518,43 +497,9 @@ class WindowedDataflowDriver:
         finally:
             ok.set()
 
-    # -- pipelined window processing (spatialflink_tpu/pipeline.py) ------------
+    # -- per-window processing (retry → failover → crash) ----------------------
 
-    def _pipeline_state(self) -> Optional[Dict[str, Any]]:
-        """Pipelined processing applies only when a policy is armed
-        (explicit ``pipeline=`` or the module slot), the bound DEVICE
-        process exposes the split protocol (``pipeline_compute`` /
-        ``pipeline_fetch`` attributes), and the process is idempotent
-        (a failed in-flight window is recomputed synchronously — a
-        stateful processor cannot re-run). Anything else → ``None`` and
-        the loop is the exact PR 10 synchronous path."""
-        from spatialflink_tpu import pipeline as pipeline_mod
-
-        pol = self.pipeline if self.pipeline is not None \
-            else pipeline_mod.policy()
-        if pol is None or int(pol.fetch_lag) < 1:
-            return None
-        proc = self.process
-        if self.backend != "device" or proc is None:
-            return None
-        compute = getattr(proc, "pipeline_compute", None)
-        fetch = getattr(proc, "pipeline_fetch", None)
-        if compute is None or fetch is None:
-            return None
-        if not getattr(proc, "idempotent", True):
-            return None
-        return {"pol": pol, "compute": compute, "fetch": fetch,
-                "inflight": deque()}
-
-    def _pipe_process(self, pipe, win) -> Iterator:
-        """Process one window, possibly deferring its fetch; yields any
-        results whose lagged fetch came due. The synchronous
-        ``_process_window`` (retry → failover → crash) remains the
-        error path: any pipelined dispatch/fetch failure drains the
-        healthy in-flight prefix and reprocesses the failed window
-        through it, so retry/failover/breaker semantics are unchanged."""
-        from spatialflink_tpu.pipeline import breaker_collapsed
-
+    def _process_window(self, win):
         if telemetry.enabled:
             # Latency lineage, stage "assemble": the window just fired
             # at the source clock — its event-time staleness starts the
@@ -563,117 +508,6 @@ class WindowedDataflowDriver:
             if end is not None:
                 telemetry.record_e2e(end, "assemble",
                                      node=self._node_label)
-        if pipe is None:
-            yield self._process_window(win)
-            return
-        if self.backend != "device":
-            # A failover mid-overlap (a fetch failure flipped the
-            # backend while later windows sat in flight) must not
-            # reorder egress: drain the in-flight prefix BEFORE this
-            # window, exactly like the compute-failure path below.
-            yield from self._pipe_drain(pipe)
-            yield self._process_window(win)
-            return
-        if breaker_collapsed():
-            # Circuit open: no stacking windows onto a dead device path —
-            # drain and hand the window to the routing/fallback logic.
-            # The transition is instrumented like the executor's
-            # (literal event names — the contract-twin rule), so a
-            # device-path death mid-overlap is visible in the ledger and
-            # `sfprof health` can print its STALLED note.
-            yield from self._pipe_drain(pipe)
-            if not pipe.get("collapsed"):
-                pipe["collapsed"] = True
-                telemetry.record_pipeline(collapses=1)
-                telemetry.emit_instant("pipeline_collapsed",
-                                       label="driver")
-                telemetry.maybe_flush_stream(force=True)
-            result = self._process_window(win)
-            telemetry.record_pipeline(windows=1, sync=1)
-            yield result
-            return
-        if pipe.get("collapsed"):
-            pipe["collapsed"] = False
-            telemetry.record_pipeline(resumes=1)
-            telemetry.emit_instant("pipeline_resumed", label="driver")
-            telemetry.maybe_flush_stream(force=True)
-        try:
-            # The injection point sits INSIDE the dial guard: a
-            # hang-kind fault here rehearses exactly the wedge the
-            # watchdog bounds (a device stalling the overlapped ship).
-            # Scope the dispatch only (never across a yield — a
-            # suspended generator must not leak its node tag to the
-            # consumer's thread-local stack).
-            with telemetry.scope(self._node_label), \
-                    self._dial_guard(True):
-                if faults.armed:  # chaos injection point (faults.py)
-                    faults.hit("pipeline.ship")
-                work = pipe["compute"](win)
-        except (KeyboardInterrupt, SystemExit):
-            raise
-        except CheckpointCorruptError:
-            raise
-        except Exception:
-            yield from self._pipe_drain(pipe)
-            yield self._process_window(win)
-            return
-        if telemetry.enabled:
-            # Stage "ship": the overlapped encode + host→device stage +
-            # async dispatch returned — the pane is on the wire.
-            end = getattr(win, "end", None)
-            if end is not None:
-                telemetry.record_e2e(end, "ship", node=self._node_label)
-        pipe["inflight"].append((win, work))
-        while len(pipe["inflight"]) > int(pipe["pol"].fetch_lag):
-            yield from self._pipe_fetch_one(pipe)
-
-    def _pipe_fetch_one(self, pipe) -> Iterator:
-        win, work = pipe["inflight"].popleft()
-        ctrl = self.overload
-        breaker = ctrl.breaker if ctrl is not None else None
-        try:
-            with telemetry.scope(self._node_label):
-                if faults.armed:  # chaos injection point (faults.py)
-                    faults.hit("pipeline.fetch")
-                result = pipe["fetch"](work)
-        except (KeyboardInterrupt, SystemExit):
-            raise
-        except CheckpointCorruptError:
-            raise
-        except Exception:
-            # The in-flight handle is dead; recompute this window
-            # synchronously with the full retry/failover ladder.
-            yield self._process_window(win)
-            return
-        if breaker is not None:
-            breaker.record_success()
-        telemetry.record_pipeline(windows=1, overlapped=1)
-        if telemetry.enabled:
-            # Stage "fetch": the lagged true-sync device→host drain —
-            # the result exists host-side from here on.
-            end = getattr(win, "end", None)
-            if end is not None:
-                telemetry.record_e2e(end, "fetch", node=self._node_label)
-        # NEVER degraded: this window was computed AND fetched on the
-        # device path — a backend that flipped to fallback after its
-        # dispatch does not make it a degraded window (charging it
-        # would inflate degraded_window_budget for device-answered
-        # results).
-        yield self._finish_window(result, degraded=False, win=win)
-
-    def _pipe_drain(self, pipe) -> Iterator:
-        """Fetch every in-flight window now — the consistent frontier
-        every checkpoint commit (and end-of-stream) requires."""
-        if pipe is None:
-            return
-        if pipe["inflight"]:
-            telemetry.record_pipeline(drains=1)
-        while pipe["inflight"]:
-            yield from self._pipe_fetch_one(pipe)
-
-    # -- per-window processing (retry → failover → crash) ----------------------
-
-    def _process_window(self, win):
         # Operator-level node attribution: everything in the retry →
         # failover ladder (device bytes, compiles, kernel rows, fault
         # hits) tags the bound operator's label. The DAG's per-node
@@ -766,10 +600,7 @@ class WindowedDataflowDriver:
             end = getattr(win, "end", None)
             if end is not None:
                 # Stage "compute": the window's result is materialized
-                # host-side (sync path: processor returned; pipelined
-                # path: observed at its ordered fetch — compute finished
-                # at-or-before that moment, so the stamp is the honest
-                # conservative bound).
+                # host-side (the processor returned).
                 telemetry.record_e2e(end, "compute",
                                      node=self._node_label)
                 if self.sink is not None or \

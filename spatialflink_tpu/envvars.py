@@ -43,22 +43,12 @@ ENV_VARS = {
         "owner": "spatialflink_tpu/overload.py", "hazard": "armed",
         "doc": "overload policy (inline JSON or path) the driver installs",
     },
-    "SFT_PIPELINE": {
-        "owner": "spatialflink_tpu/pipeline.py", "hazard": "armed",
-        "doc": "pipelined-ingest policy (inline JSON or path), armed at "
-               "import; results stay bit-identical but an ambient value "
-               "would flip the gate's pipeline-off baselines",
-    },
     "SFT_QSERVE": {
         "owner": "spatialflink_tpu/qserve.py", "hazard": "armed",
         "doc": "qserve serving config (inline JSON or path): standing "
                "queries + per-tenant-class budgets; an ambient value "
                "would register ghost queries / arm QoS budgets in runs "
                "that never asked for them",
-    },
-    "SFT_SLO_SPEC": {
-        "owner": "bench.py", "hazard": "armed",
-        "doc": "SLO spec evaluated LIVE during a bench run",
     },
     "SFT_ABLATE": {
         "owner": "spatialflink_tpu/ablation.py", "hazard": "armed",
@@ -68,12 +58,8 @@ ENV_VARS = {
                "every measurement (the run is tainted, but the gate "
                "must never run tainted in the first place)",
     },
-    "SFT_BENCH_DIAL_HANG": {
-        "owner": "bench.py", "hazard": "armed",
-        "doc": "wedges the first device op (dial-deadline tests)",
-    },
     "SFT_LEDGER_PATH": {
-        "owner": "bench.py", "hazard": "capture",
+        "owner": "spatialflink_tpu/dag.py", "hazard": "capture",
         "doc": "run-ledger output path",
     },
     "SFT_LEDGER_STREAM": {
@@ -94,33 +80,12 @@ ENV_VARS = {
         "owner": "bench_suite.py", "hazard": "capture",
         "doc": "per-config ledger directory for suite runs",
     },
-    "SFT_TRACE_PATH": {
-        "owner": "bench.py", "hazard": "capture",
-        "doc": "Chrome-trace JSONL output path",
-    },
-    "SFT_PROFILE_DIR": {
-        "owner": "bench.py", "hazard": "capture",
-        "doc": "jax profiler trace directory",
-    },
-    "SFT_BENCH_SMOKE": {
-        "owner": "bench.py", "hazard": "tuning",
-        "doc": "toy-size smoke mode for the CI gate",
-    },
     "SFT_DIAL_DEADLINE_S": {
-        "owner": "bench.py", "hazard": "tuning",
-        "doc": "first-device-touch deadline; timeout seals the stream. "
-               "Also read by spatialflink_tpu/driver.py: when SET it "
-               "bounds the driver's first device-path window (a "
-               "--checkpoint resume on an unreachable device), same "
-               "dial_timeout seal",
-    },
-    "SFT_NO_LINK_PROBE": {
-        "owner": "bench.py", "hazard": "tuning",
-        "doc": "disables the host↔device link-health probe",
-    },
-    "SFT_NO_PALLAS_DIGEST": {
-        "owner": "bench.py", "hazard": "tuning",
-        "doc": "disables the pallas digest path on TPU",
+        "owner": "spatialflink_tpu/driver.py", "hazard": "tuning",
+        "doc": "first-device-touch deadline: when SET it bounds the "
+               "driver's first device-path window (a --checkpoint "
+               "resume on an unreachable device); timeout seals the "
+               "ledger stream with reason dial_timeout",
     },
 }
 

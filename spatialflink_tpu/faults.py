@@ -83,10 +83,6 @@ INJECTION_POINTS: Dict[str, str] = {
                       "source→assembler admission decision",
     "source.stall": "driver.py:_drive — per-item source pull (the "
                     "slow-consumer / wedged-upstream hang point)",
-    "pipeline.ship": "pipeline.py:PipelinedExecutor — overlapped "
-                     "host→device pane ship (encode + stage ahead)",
-    "pipeline.fetch": "pipeline.py:PipelinedExecutor — lagged "
-                      "device→host result fetch (ordered drain)",
     "qserve.register": "qserve.py:QueryRegistry.apply — standing-query "
                        "register/unregister command application (the "
                        "kill-mid-registration-churn point)",

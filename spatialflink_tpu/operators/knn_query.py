@@ -715,9 +715,8 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
         flush_at_end: bool = True,
     ):
         """Wire-plane pane-carry kNN — the HEADLINE program as a shipped
-        operator path (ops/wire_knn.py; bench.py and bench_suite's kNN
-        configs run this same step, so the measured program is the
-        shipped one).
+        operator path (ops/wire_knn.py; bench_suite's kNN configs run
+        this same step, so the measured program is the shipped one).
 
         ``slides``: iterable of (3, n_i) uint16 PLANE-MAJOR pane arrays
         in the 6 B/pt wire format (streams/wire.py) — rows x_q, y_q,
@@ -742,19 +741,10 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
 
         ``strategy``: 'auto' adopts the fused Pallas extraction on TPU
         only after a first-pane self-check against the XLA step (set
-        equality + ≤1 ulp — bench.py's contract; overflow beyond the
-        candidate budget falls back IN-PROGRAM, so results are exact
-        either way); 'xla'/'pallas' force. The chosen kind is recorded
-        on ``self.last_wire_digest_kind``.
-
-        **Pipelined mode** (``SFT_PIPELINE`` /
-        spatialflink_tpu/pipeline.py:install): the same per-pane
-        programs run through the bounded ship/compute/fetch executor —
-        pane N+1 ships while window N computes and window N−1's result
-        fetch lags — optionally with the delta-bitpacked wire codec
-        (ops/wire_codec.py) shrinking the shipped bytes. Results are
-        bit-identical to this synchronous loop and the checkpoint
-        carry still advances only with YIELDED windows.
+        equality + ≤1 ulp — select_wire_digest_step's contract; overflow
+        beyond the candidate budget falls back IN-PROGRAM, so results
+        are exact either way); 'xla'/'pallas' force. The chosen kind is
+        recorded on ``self.last_wire_digest_kind``.
         """
         from spatialflink_tpu.operators.query_config import QueryType
         from spatialflink_tpu.ops.compaction import wire_pane_bucket
@@ -912,192 +902,6 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
                     f"panes, got {wire_p.dtype} {wire_p.shape}"
                 )
             check_oid_range(wire_p[2].view(np.int16), num_segments)
-
-        def _pipelined(pol):
-            """The SFT_PIPELINE branch: ship(N+1)/compute(N)/fetch(N−1)
-            through the shared executor (spatialflink_tpu/pipeline.py),
-            with the delta-bitpacked codec (ops/wire_codec.py) on the
-            wire when the policy arms it. Results are bit-identical to
-            the synchronous loop below — same programs, same order,
-            lagged sync points — and the checkpoint carry publishes per
-            YIELDED window exactly like the batch_slides path, so a
-            kill mid-overlap replays the in-flight windows. The
-            overload ``batch_slides`` rung is superseded here (the
-            executor owns fetch batching). Codec predictor state is
-            deliberately NOT checkpointed: encode and decode tables
-            start equal (zero) in any process, so a resume re-encodes
-            replayed panes self-consistently — compression continuity
-            resets, results cannot (PARITY.md "Pipelined ingest")."""
-            nonlocal jstep
-            from spatialflink_tpu.ops import wire_codec as wc
-            from spatialflink_tpu.pipeline import PipelinedExecutor
-
-            use_codec = pol.codec == "delta"
-            encoder = wc.WirePaneEncoder(num_segments) if use_codec \
-                else None
-            dec = {"px": None, "py": None, "steps": {}}
-            if use_codec:
-                # COPIES, not the live tables: on XLA:CPU jnp.asarray
-                # zero-copy-aliases host buffers ≥ ~128 B, and
-                # encoder.encode() mutates pred_x/pred_y IN PLACE — a
-                # shipped alias would see post-encode predictors and
-                # decode garbage (regression-pinned at num_segments ≥
-                # the aliasing threshold, tests/test_pipeline.py).
-                dec["px"], dec["py"] = ship(encoder.pred_x.copy(),
-                                            encoder.pred_y.copy())
-            state = {"last_i": pane0 - 1,
-                     "last_carry": self._wire_pane_carry}
-
-            def items():
-                for i, wire_p in enumerate(slides, start=pane0):
-                    state["last_i"] = i
-                    yield (i, np.asarray(wire_p))
-                if flush_at_end and (state["last_i"] >= pane0
-                                     or pane0 > 0):
-                    for j in range(1, ppw):
-                        yield (state["last_i"] + j, None)
-
-            def ship_stage(item):
-                _i, wire_p = item
-                if wire_p is None:  # synthetic trailing flush pane
-                    return None
-                check_pane(wire_p)
-                n = wire_p.shape[1]
-                if use_codec:
-                    enc = encoder.encode(wire_p)
-                    nb = wire_pane_bucket(n)
-                    wb = wc.wire_word_bucket(len(enc.words), nb)
-                    # Charge the PADDED bucket — the bytes that
-                    # actually cross the link (account_h2d at the
-                    # ship below agrees), never the tight payload.
-                    telemetry.account_wire(
-                        enc.raw_bytes, 4 * wb + wc.HEADER_BYTES
-                    )
-                    (words_d,) = ship(wc.pad_words(enc.words, wb))
-                    return ("coded", words_d, n, nb,
-                            enc.bx, enc.by, enc.bo)
-                nb = wire_pane_bucket(n)
-                if nb != n:
-                    wire_p = np.concatenate(
-                        [wire_p, np.zeros((3, nb - n), np.uint16)],
-                        axis=1,
-                    )
-                (wire_d,) = ship(wire_p)
-                return ("raw", wire_d, n)
-
-            def decode_step(nb, wb):
-                key = (nb, wb)
-                if key not in dec["steps"]:
-                    step = functools.partial(
-                        wc.decode_wire_pane, n=nb,
-                        num_segments=num_segments,
-                    )
-                    # Deliberately NOT donated: the px/py chain crosses
-                    # MULTIPLE compiled instances (one per (pane,
-                    # word-bucket) pair — empty gap panes alternate
-                    # with real ones), and donating a buffer produced
-                    # by one executable into another corrupts it
-                    # non-deterministically on XLA:CPU (observed live:
-                    # predictor drift after an event-time gap; the
-                    # per-yield cut test pins the stream). The tables
-                    # are KiB-scale — the copy is noise. Donation
-                    # stays where it is safe and pays: the single-
-                    # instance carry-donating digest steps (bench.py).
-                    dec["steps"][key] = instrument_jit(
-                        jax.jit(step), name="wire_pane_decode",
-                    )
-                return dec["steps"][key]
-
-            def select_steps(pane_d, n):
-                """First-pane strategy selection — the digest exactly
-                as the synchronous loop does it (the decoded pane is a
-                valid sample wire pane)."""
-                nonlocal jstep
-                kind, step = select_wire_digest_step(
-                    pane_d, jnp.int32(n), q, scale, origin, r32,
-                    num_segments=num_segments, cand=cand,
-                    interpret=interpret, strategy=strategy,
-                )
-                self.last_wire_digest_kind = kind
-                jstep = _wire_digest_program(kind, step)
-
-            def compute_stage(item, staged):
-                i, _ = item
-                if staged is None:
-                    digests.append(empty)
-                    counts.append(0)
-                else:
-                    if staged[0] == "coded":
-                        _, words_d, n, nb, bx, by, bo = staged
-                        pane_d, dec["px"], dec["py"] = decode_step(
-                            nb, words_d.shape[0]
-                        )(words_d, jnp.int32(n), jnp.int32(bx),
-                          jnp.int32(by), jnp.int32(bo), dec["px"],
-                          dec["py"])
-                    else:
-                        _, pane_d, n = staged
-                    if jstep is None:
-                        select_steps(pane_d, n)
-                    d = jstep(pane_d, jnp.int32(n), q, scale, origin,
-                              r32)
-                    digests.append((d.seg_min, d.rep))
-                    counts.append(n)
-                del digests[:-ppw]
-                del counts[:-ppw]
-                if staged is not None:
-                    # Synthetic panes never advance the carry (the
-                    # sync loop's rule) — entries keep the last REAL
-                    # pane's ring.
-                    state["last_carry"] = carry_now(i + 1)
-                out = merge_window(i)
-                if out is None:
-                    return None
-                return (out, state["last_carry"])
-
-            def fetch_stage(works):
-                # ONE true sync per drain batch — full (k,) lanes
-                # fetched, host-sliced by num_valid (the flush_pending
-                # idiom: identical values, round trips ÷ batch width).
-                # Carries ride OUT with their windows, unpublished: a
-                # multi-window drain batch must not advance the carry
-                # past windows the consumer has not received yet.
-                handles = [
-                    (r.num_valid, r.segment, r.dist)
-                    for (_w, r), _c in works
-                ]
-                fetched = telemetry.fetch(handles)
-                res = []
-                for ((w_start, _r), carry), (nv_a, seg_a, dist_a) in zip(
-                        works, fetched):
-                    nv = int(nv_a)
-                    res.append((carry, (w_start, w_start + size,
-                                        np.asarray(seg_a)[:nv],
-                                        np.asarray(dist_a)[:nv], nv)))
-                return res
-
-            ex = PipelinedExecutor(
-                pol, ship=ship_stage, compute=compute_stage,
-                fetch=fetch_stage, label="wire_panes",
-            )
-            for carry, out in ex.run(items()):
-                # Publish the ring state as of THIS window right before
-                # ITS yield (the sync flush_pending contract): a
-                # checkpoint taken at any yield must never count a
-                # fetched-but-unyielded batch sibling as emitted — the
-                # carry would skip past it on resume (lost egress;
-                # per-yield cut regression in tests/test_pipeline.py).
-                self._wire_pane_carry = carry
-                yield out
-            # End-of-call invariant (unchanged): every consumed REAL
-            # pane is in the carry, emitted or not.
-            self._wire_pane_carry = state["last_carry"]
-
-        from spatialflink_tpu import pipeline as pipeline_mod
-
-        pol = pipeline_mod.policy()
-        if pol is not None:
-            yield from _pipelined(pol)
-            return
 
         i = pane0 - 1
         last_carry = self._wire_pane_carry

@@ -208,55 +208,11 @@ class _PointStreamRangeQuery(SpatialOperator):
                     keep, dist = evaluate(common)
                 with telemetry.span("fetch"):
                     keep, dist = telemetry.fetch((keep, dist))
-                return _decode(win, keep, dist)
-
-        def _decode(win, keep, dist) -> RangeResult:
-            idx = np.nonzero(keep)[0]
-            objs = [win.events[i] for i in idx]
-            return RangeResult(
-                win.start, win.end, objs, dist[idx], len(win.events)
-            )
-
-        def pipeline_compute(win):
-            """The overlap twin of ``process`` (the driver's split
-            protocol, spatialflink_tpu/pipeline.py): assemble → ship →
-            dispatch WITHOUT the sync — the driver fetches via
-            ``pipeline_fetch`` up to ``fetch_lag`` windows later, so
-            the device computes window N while window N+1 assembles
-            and ships. Same programs in the same order; results are
-            bit-identical to ``process`` (tests/test_driver.py)."""
-            with telemetry.span(
-                "window.range", start=win.start, events=len(win.events)
-            ):
-                with telemetry.span("assemble"):
-                    batch = self.point_batch(win.events)
-                    if counters.enabled:
-                        cand = count_candidates(
-                            flags, batch.cell, len(win.events)
-                        )
-                        counters.record_window(
-                            len(win.events), cand, cand * len(query_set)
-                        )
-                with telemetry.span("ship"):
-                    valid_d, cell_d = ship(batch.valid, batch.cell)
-                    common = (
-                        self.device_xy(batch, dtype),
-                        valid_d,
-                        cell_d,
-                        flags_d,
-                    )
-                with telemetry.span("compute"):
-                    keep, dist = evaluate(common)
-            return (win, keep, dist)
-
-        def pipeline_fetch(staged) -> RangeResult:
-            win, keep, dist = staged
-            with telemetry.span("fetch"):
-                keep, dist = telemetry.fetch((keep, dist))
-            return _decode(win, keep, dist)
-
-        process.pipeline_compute = pipeline_compute
-        process.pipeline_fetch = pipeline_fetch
+                idx = np.nonzero(keep)[0]
+                objs = [win.events[i] for i in idx]
+                return RangeResult(
+                    win.start, win.end, objs, dist[idx], len(win.events)
+                )
 
         fallback = None
         if self.query_kind == "point":

@@ -747,115 +747,33 @@ class TJoinQuery(SpatialOperator):
             "cap_c", "mesh",
         )
 
-        from spatialflink_tpu import pipeline as pipeline_mod
-
-        pipe_pol = pipeline_mod.policy()
-
         def run_scan(carry, statics):
-            """One full scan pass: monolithic, or — under an armed
-            SFT_PIPELINE policy (spatialflink_tpu/pipeline.py) —
-            segmented through the shared executor so segment N's
-            (S_seg, K²) result fetch overlaps segment N+1's field ship
-            + scan dispatch. Segments chain the ring carry, all pad to
-            ONE static length (trailing pad panes are empty — they
-            cannot fire, overflow, or perturb the ring), and the
-            concatenated rows are bit-identical to the monolithic
-            scan's (tests/test_pipeline.py pins it). Mesh runs stay
-            monolithic — segment chaining under shard_map is untested
-            territory, and correctness beats overlap."""
-            if pipe_pol is None or mesh is not None or n_slides <= 1:
-                ts_dev = jnp.asarray(np.arange(n_slides, dtype=np.int32))
-                if mesh is not None:
-                    # Mesh scans route through the ACCOUNTED parallel/
-                    # entry: its host side feeds the all-gather/psum
-                    # footprint to telemetry.account_collective from
-                    # static shapes (the collective-accounting
-                    # invariant), then runs the same cached program.
-                    from spatialflink_tpu.parallel.sharded import (
-                        sharded_tjoin_pane_scan,
-                    )
+            """One full (monolithic) scan pass over the batch's panes."""
+            ts_dev = jnp.asarray(np.arange(n_slides, dtype=np.int32))
+            if mesh is not None:
+                # Mesh scans route through the ACCOUNTED parallel/
+                # entry: its host side feeds the all-gather/psum
+                # footprint to telemetry.account_collective from
+                # static shapes (the collective-accounting
+                # invariant), then runs the same cached program.
+                from spatialflink_tpu.parallel.sharded import (
+                    sharded_tjoin_pane_scan,
+                )
 
-                    return sharded_tjoin_pane_scan(
-                        mesh, carry, ts_dev,
-                        tuple(jnp.asarray(a) for a in lfields),
-                        tuple(jnp.asarray(a) for a in rfields),
-                        radius,
-                        **{k: v for k, v in statics.items()
-                           if k != "mesh"},
-                    )
-                return scan(
-                    carry, ts_dev,
+                return sharded_tjoin_pane_scan(
+                    mesh, carry, ts_dev,
                     tuple(jnp.asarray(a) for a in lfields),
                     tuple(jnp.asarray(a) for a in rfields),
-                    radius, **statics,
+                    radius,
+                    **{k: v for k, v in statics.items()
+                       if k != "mesh"},
                 )
-            from spatialflink_tpu.operators.base import ship
-            from spatialflink_tpu.pipeline import PipelinedExecutor
-
-            n_seg = min(n_slides, max(2, 2 * int(pipe_pol.depth)))
-            seg_len = -(-n_slides // n_seg)
-            n_seg = -(-n_slides // seg_len)
-            total = n_seg * seg_len
-
-            def padded(fields):
-                if total == n_slides:
-                    return fields
-                return tuple(
-                    np.concatenate(
-                        [a, np.zeros((total - n_slides,) + a.shape[1:],
-                                     a.dtype)]
-                    ) for a in fields
-                )
-
-            lf, rf = padded(lfields), padded(rfields)
-            state = {"carry": carry}
-
-            def expire_slice(fields, s0):
-                # (cell, valid) of the pane expiring at each slide of
-                # the segment — pane s−ppw from the FULL batch, zeros
-                # during warmup. A chained carry is non-empty, so the
-                # scan's own-batch default would expire the wrong panes
-                # (expired_pane_fields' documented contract).
-                cells_arr, valid_arr = fields[4], fields[7]
-                idx = np.arange(s0, s0 + seg_len) - ppw
-                take = idx >= 0
-                cells = np.zeros((seg_len,) + cells_arr.shape[1:],
-                                 cells_arr.dtype)
-                valid = np.zeros((seg_len,) + valid_arr.shape[1:],
-                                 valid_arr.dtype)
-                cells[take] = cells_arr[idx[take]]
-                valid[take] = valid_arr[idx[take]]
-                return cells, valid
-
-            def ship_stage(seg):
-                s0 = seg * seg_len
-                (ts_d,) = ship(np.arange(s0, s0 + seg_len,
-                                         dtype=np.int32))
-                return (
-                    ship(*(a[s0:s0 + seg_len] for a in lf)),
-                    ship(*(a[s0:s0 + seg_len] for a in rf)),
-                    ship(*expire_slice(lf, s0)),
-                    ship(*expire_slice(rf, s0)),
-                    ts_d,
-                )
-
-            def compute_stage(seg, staged):
-                lfd, rfd, lxd, rxd, ts_d = staged
-                state["carry"], w = scan(
-                    state["carry"], ts_d, lfd, rfd, radius,
-                    lps_expire=lxd, rps_expire=rxd, **statics,
-                )
-                return w
-
-            def fetch_stage(works):
-                return list(telemetry.fetch(works))  # ONE sync per batch
-
-            ex = PipelinedExecutor(
-                pipe_pol, ship=ship_stage, compute=compute_stage,
-                fetch=fetch_stage, label="tjoin_scan",
+            return scan(
+                carry, ts_dev,
+                tuple(jnp.asarray(a) for a in lfields),
+                tuple(jnp.asarray(a) for a in rfields),
+                radius, **statics,
             )
-            rows = list(ex.run(range(n_seg)))
-            return state["carry"], np.concatenate(rows)[:n_slides]
 
         while wmins is None:  # device engine + overflow retry
             carry = tjoin_pane_init(

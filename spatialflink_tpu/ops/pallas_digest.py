@@ -19,8 +19,9 @@ Exactness contract: ``count`` > ``max_cand`` means truncation — the
 caller must fall back to the XLA digest (same retry family as the
 compact path's ``cand``). Distances are the same explicit
 mul-add/sqrt f32 ops as the headline step; XLA's FMA fusion may differ
-by ≤1 ulp from Mosaic's, so the bench self-checks one slide against the
-XLA path before trusting the kernel (bench.py).
+by ≤1 ulp from Mosaic's, so the selector self-checks one pane against
+the XLA path before trusting the kernel
+(ops/wire_knn.py:select_wire_digest_step).
 """
 
 from __future__ import annotations
@@ -222,10 +223,8 @@ def wire_candidates_pallas(
 
 def digest_from_candidates(d, o, idx, num_segments: int):
     """Compacted (dist, oid, index) candidates → KnnPaneDigest — ONE
-    home for the candidate segment-min reduction (shared by
-    wire_digest_pallas and bench.py's pallas step; the sentinel clamp
-    and representative tie-break must stay bit-identical between the
-    library path and the measured path)."""
+    home for the candidate segment-min reduction (the sentinel clamp
+    and representative tie-break live here only)."""
     from spatialflink_tpu.ops.knn import KnnPaneDigest
 
     valid = idx >= 0
@@ -258,8 +257,8 @@ def wire_digest_pallas(
 
     Returns (digest, count): exact iff ``count <= max_cand`` — the
     caller owns the fallback (ops/wire_knn.py wraps this with the
-    in-program lax.cond fallback; bench.py additionally self-checks one
-    slide and falls back to the XLA step wholesale)."""
+    in-program lax.cond fallback, and its selector self-checks one
+    pane before adopting the kernel)."""
     consts = jnp.asarray(
         [[radius, scale[0], origin[0], query_xy[0],
           scale[1], origin[1], query_xy[1], 0.0]], jnp.float32,

@@ -1,13 +1,10 @@
-"""Wire-plane kNN pane digest — ONE program for operator, bench, suite.
+"""Wire-plane kNN pane digest — ONE program for operator and suite.
 
-The headline benchmark measures 6 B/pt wire ingest (streams/wire.py)
-fused straight into the kNN pane digest. Round 4 left that program
-living only in bench.py while the shipped operator
-(operators/knn_query.py:run_soa_panes) digested SoA floats — exactly
-the measured-vs-shipped drift ops/tjoin_panes.py warns about. This
-module is the single home of the wire→digest step; bench.py's headline,
-bench_suite's kNN configs, and PointPointKNNQuery.run_wire_panes all
-call it, so the measured program IS the shipped program.
+The headline deployment ingests the 6 B/pt wire (streams/wire.py) fused
+straight into the kNN pane digest. This module is the single home of
+the wire→digest step; bench_suite's kNN configs and
+PointPointKNNQuery.run_wire_panes both call it, so the measured program
+IS the shipped program.
 
 Two interchangeable strategies (bit-compatible candidate SETS, distance
 values within 1 ulp — Mosaic vs XLA FMA freedom; tests/test_wire_knn.py
@@ -21,7 +18,7 @@ pins parity):
   the full XLA scatter digest whenever the hit count exceeds the
   candidate budget — exact either way.
 
-``select_wire_digest_step`` implements the bench.py self-check contract
+``select_wire_digest_step`` owns the self-check contract
 (run one pane both ways, require exact in-radius-set equality and ≤1 ulp
 distances before trusting the Pallas lowering) for any caller.
 
@@ -100,7 +97,7 @@ def wire_digest_pallas_step(wire_s, n_valid, query_xy, scale, origin,
     ops/pallas_digest.py) to ``wire_digest_pallas``; if the hit count
     exceeds ``max_cand`` (truncated output) a ``lax.cond`` reruns the
     pane through the full XLA scatter digest — the step is exact either
-    way, matching bench.py's overflow contract."""
+    way."""
     d_pallas, cnt = wire_digest_pallas(
         wire_s, query_xy, scale, origin, radius, num_segments,
         max_cand=max_cand, interpret=interpret, n_valid=n_valid,
@@ -144,7 +141,7 @@ def make_wire_digest_step(*, num_segments: int, cand: int = 8192,
 
 
 def digests_agree(seg_a, rep_a, seg_b, rep_b) -> bool:
-    """The bench.py self-check predicate: identical in-radius object
+    """The self-check predicate: identical in-radius object
     SETS, distances within 1 ulp (Mosaic vs XLA FMA freedom), and
     identical representatives wherever the distances agree exactly.
     Host-side (fetches both digests)."""
@@ -171,7 +168,7 @@ def select_wire_digest_step(sample_wire, sample_n, query_xy, scale,
                             max_cand: int = PALLAS_DIGEST_MAX_CAND,
                             interpret: bool = False,
                             strategy: str = "auto"):
-    """Pick the digest strategy with bench.py's self-check contract.
+    """Pick the digest strategy under the self-check contract.
 
     ``auto``: on TPU (or with ``interpret=True``), run ONE sample pane
     through both strategies and adopt Pallas only if ``digests_agree``;

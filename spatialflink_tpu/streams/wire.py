@@ -101,9 +101,8 @@ class WirePaneAssembler:
     """Stateful SoA → (3, n) uint16 PLANE-MAJOR pane binner.
 
     The producer half of the wire-pane operator seam: feeds
-    ``PointPointKNNQuery.run_wire_panes`` (and the bench.py headline
-    program) from any SoA chunk stream ``{"ts", "x", "y", "oid"}`` —
-    e.g. the native CSV parser's arrays or a batched Kafka consumer.
+    ``PointPointKNNQuery.run_wire_panes`` from any SoA chunk stream
+    ``{"ts", "x", "y", "oid"}`` — e.g. the native CSV parser's arrays or a batched Kafka consumer.
     Pane i covers [start_ms + i·slide_ms, start_ms + (i+1)·slide_ms);
     EVERY pane in order is emitted, including empty (3, 0) panes in
     event-time gaps, so downstream window indexing stays aligned.

@@ -28,7 +28,7 @@ Spans emit Chrome-trace/Perfetto-compatible complete events ("ph": "X",
 microsecond ts/dur) as JSON-lines; ``load_trace`` wraps a trace file into
 the standard ``{"traceEvents": [...]}`` document. Spans named
 ``window.*`` additionally feed a ``FixedBucketLatency`` histogram, so
-p50/p95 window latency lands in NES reporter lines and bench.py's JSON.
+p50/p95 window latency lands in NES reporter lines and ``summary()``.
 
 On top of the raw signals sits the **run ledger** (``write_ledger``): a
 per-(kernel, signature) runtime table fed by ``instrument_jit`` (call
@@ -317,23 +317,12 @@ class Telemetry:
         # "cost", "lower"} — the per-kernel runtime table behind
         # kernel_table()/capture_costs() (fed by instrument_jit).
         self._kernel_stats: Dict[Tuple[str, Tuple], Dict[str, Any]] = {}
-        # Wire-codec compression gauges (ops/wire_codec.py via
-        # account_wire): raw vs post-codec bytes per encoded pane — the
-        # h2d counter keeps counting what actually ships, these keep the
-        # what-it-WOULD-have-cost denominator.
-        self.wire_raw_bytes = 0
-        self.wire_coded_bytes = 0
-        self.wire_panes = 0
         # Window point join (operators/join_query.py:run_soa via
         # record_join): counters pairs / windows / cap_retries /
         # budget_retries and the gauges cap / budget (the rung and the
         # pair budget in use) — snapshot()["join"], empty until the first
         # joined window.
         self._join: Dict[str, int] = {}
-        # Pipelined-ingest executor counters (spatialflink_tpu/
-        # pipeline.py via record_pipeline): overlapped vs collapsed
-        # windows, checkpoint drains — sfprof health's stall notes.
-        self._pipeline: Dict[str, int] = {}
         # tids already named via a ph:"M" thread_name metadata event.
         self._named_tids: set = set()
         # Per-node attribution buckets: node name (or None = unscoped) →
@@ -511,7 +500,7 @@ class Telemetry:
         """Drain the buffered trace writer NOW. Call before a timed
         region: emits inside it then start from a fresh FLUSH_EVERY
         budget, so the periodic disk flush can't land mid-measurement
-        (bench.py's latency probe)."""
+        (a latency probe)."""
         with self._lock:
             if self._trace_file is not None:
                 self._trace_file.flush()
@@ -666,8 +655,6 @@ class Telemetry:
                 "window_latency": FixedBucketLatency(),
                 "h2d_bytes": 0, "h2d_transfers": 0,
                 "d2h_bytes": 0, "d2h_transfers": 0,
-                "wire_raw_bytes": 0, "wire_coded_bytes": 0,
-                "wire_panes": 0,
                 "compiles": 0, "instants": 0, "fault_fires": 0,
                 "shed_events": 0, "shed_bytes": 0,
                 "collective_calls": 0, "collective_bytes": 0,
@@ -1119,25 +1106,6 @@ class Telemetry:
                 for k, v in self._compaction.get(engine, {}).items()
             }
 
-    # -- pipelined ingest (spatialflink_tpu/pipeline.py) -----------------------
-
-    def account_wire(self, raw_bytes: int, coded_bytes: int):
-        """One encoded wire pane: what the raw 6 B/pt wire would have
-        shipped vs what the codec actually did (header included). The
-        ship-site ``account_h2d`` keeps counting the true shipped bytes
-        — this pair exists so the compression ratio has an honest
-        denominator in the record/ledger (``snapshot()["wire_codec"]``)."""
-        if not self.enabled:
-            return
-        with self._lock:
-            self.wire_raw_bytes += int(raw_bytes)
-            self.wire_coded_bytes += int(coded_bytes)
-            self.wire_panes += 1
-            b = self._node_bucket(self.current_node())
-            b["wire_raw_bytes"] += int(raw_bytes)
-            b["wire_coded_bytes"] += int(coded_bytes)
-            b["wire_panes"] += 1
-
     def record_join(self, pairs: int, cap_retries: int, budget_retries: int,
                     cap: int, budget: int):
         """One window of the SoA point join, fetched: ``pairs`` found,
@@ -1155,35 +1123,6 @@ class Telemetry:
                            ("budget_retries", budget_retries)):
                 j[key] = j.get(key, 0) + int(n)
             j["cap"], j["budget"] = int(cap), int(budget)
-
-    def record_pipeline(self, **counts: int):
-        """Accumulate pipelined-executor counters (windows, overlapped,
-        sync, drains, collapses — pipeline.py documents each). Lands in
-        ``snapshot()["pipeline"]`` so `sfprof health` can note stalls."""
-        if not self.enabled:
-            return
-        with self._lock:
-            for key, n in counts.items():
-                self._pipeline[key] = self._pipeline.get(key, 0) + int(n)
-
-    def pipeline_counters(self) -> Dict[str, int]:
-        """Current executor counters (empty dict before the first
-        pipelined window) — bench.py stamps these into its record."""
-        with self._lock:
-            return dict(self._pipeline)
-
-    def wire_codec_gauges(self) -> Optional[Dict[str, Any]]:
-        """Compression summary (None before the first encoded pane)."""
-        with self._lock:
-            if not self.wire_panes:
-                return None
-            return {
-                "panes": self.wire_panes,
-                "raw_bytes": self.wire_raw_bytes,
-                "coded_bytes": self.wire_coded_bytes,
-                "ratio": (self.wire_raw_bytes / self.wire_coded_bytes
-                          if self.wire_coded_bytes else None),
-            }
 
     # -- mesh-collective accounting (parallel/) --------------------------------
 
@@ -1335,9 +1274,9 @@ class Telemetry:
 
     def link_gauges(self) -> Optional[Dict[str, Any]]:
         """Rolling link-health summary (None before the first sample):
-        sample count + p50/last latency and round-trip bandwidth. bench.py
-        stamps this into its record; ``sfprof diff`` uses it to ANNOTATE
-        (never widen) its tolerance bands — a degraded link explains an
+        sample count + p50/last latency and round-trip bandwidth.
+        ``sfprof diff`` uses it to ANNOTATE (never widen) its tolerance
+        bands — a degraded link explains an
         e2e EPS drop without excusing a device-resident one."""
         with self._lock:
             samples = list(self._link_samples)
@@ -1364,8 +1303,10 @@ class Telemetry:
     # -- event-time end-to-end latency (latency lineage) -----------------------
 
     #: Stage vocabulary, pipeline order. ``assemble`` = window fired at
-    #: the source clock; ``ship``/``compute``/``fetch`` = the pipelined
-    #: boundary crossings; ``commit`` = the sink's transactional append
+    #: the source clock; ``ship``/``compute``/``fetch`` = the device
+    #: boundary crossings (the driver's synchronous loop stamps
+    #: ``compute`` once, when the processor returns);
+    #: ``commit`` = the sink's transactional append
     #: — the only number that answers "how stale is a committed result
     #: relative to the event time that produced it?".
     E2E_STAGES = ("assemble", "ship", "compute", "fetch", "commit")
@@ -1604,7 +1545,7 @@ class Telemetry:
         )
 
     def summary(self) -> Dict[str, Any]:
-        """The bench.py JSON block: strictly JSON-safe (numpy scalars →
+        """The summary JSON block: strictly JSON-safe (numpy scalars →
         builtins, NaN percentiles → None so strict parsers never choke)."""
         with self._lock:
             p50 = self.window_latency.percentile(0.50)
@@ -1655,20 +1596,8 @@ class Telemetry:
             if self.shed_events or self.shed_bytes:
                 out["shed"] = {"events": self.shed_events,
                                "bytes": self.shed_bytes}
-            if self._pipeline:
-                out["pipeline"] = dict(self._pipeline)
             if self._join:
                 out["join"] = dict(self._join)
-            if self.wire_panes:
-                out["wire_codec"] = {
-                    "panes": self.wire_panes,
-                    "raw_bytes": self.wire_raw_bytes,
-                    "coded_bytes": self.wire_coded_bytes,
-                    "ratio": (
-                        self.wire_raw_bytes / self.wire_coded_bytes
-                        if self.wire_coded_bytes else None
-                    ),
-                }
         if self.overload_provider is not None:
             try:
                 out["overload"] = json_safe(self.overload_provider())  # sfcheck: ok=lock-discipline -- stream-flush checkpoints call this under Telemetry._lock by design; the provider contract (documented at overload.OverloadController._lock) forbids providers from taking telemetry's lock — overload queues transition emits for after release
@@ -1754,9 +1683,9 @@ class LinkProbe:
     Call ``sample()`` only at phase boundaries — never inside a window
     span — so probe traffic lands in host gaps, not in measured windows.
     Samples feed the rolling gauges in ``telemetry`` (snapshot's
-    ``link_probe`` block); bench.py stamps them into its record, and
-    ``sfprof diff`` annotates its verdicts with the link ratio so "chip
-    slow" is distinguishable from "link degraded"."""
+    ``link_probe`` block), and ``sfprof diff`` annotates its verdicts
+    with the link ratio so "chip slow" is distinguishable from "link
+    degraded"."""
 
     def __init__(self, device=None, payload_bytes: int = 262_144,
                  tel: Optional[Telemetry] = None):
@@ -1886,8 +1815,8 @@ def instrument_jit(fn, name: Optional[str] = None):
     per-(kernel, signature) runtime table.
 
     ``operators/base.py:jitted`` routes every operator kernel through this;
-    bench.py wraps its hand-jitted steps the same way. Disabled-path cost:
-    one attribute check per call (calls here are per WINDOW, never per
+    bench_suite.py wraps its hand-jitted steps the same way.
+    Disabled-path cost: one attribute check per call (calls here are per WINDOW, never per
     record). Enabled, each call is one ``dispatch:<kernel>`` span (the
     ``compile:<kernel>`` instant's naming) whose duration also feeds the
     locked table update; a NEW signature additionally stashes

@@ -11,7 +11,7 @@ flushes). The real-process SIGKILL analog (``abort`` kind,
 ``os._exit(137)``) is pinned by the slow subprocess test below and runs
 on every commit as tools/ci's chaos-smoke stage.
 
-Seven pipeline harnesses cover the sixteen points:
+Seven pipeline harnesses cover the fifteen points:
 
 - range-query driver pipeline (collection source): device.ship,
   device.dispatch, device.fetch, window.feed, driver.window, sink.write,
@@ -26,20 +26,14 @@ Seven pipeline harnesses cover the sixteen points:
   driver.run_precomputed): source.stall — the scan recomputes
   deterministically on resume and the driver skips the committed
   window prefix;
-- PIPELINED range driver subprocess (SFT_PIPELINE armed, abort kind —
-  the kill -9 analog; on the DRIVER path in-process raise kinds are
-  CONTAINED by its sync-fallback, so only a real process death
-  exercises the crash contract there): pipeline.ship, pipeline.fetch —
-  killed mid-overlap, the resumed pipelined child converges to the
-  clean child's bytes, which equal a pipeline-OFF run's bytes too
-  (hang kinds have their own legs: bounded hangs are contained
-  in-process, a wedge past SFT_DIAL_DEADLINE_S dies on the driver's
-  dial watchdog);
 - composed SNCB DAG subprocess (7 nodes, 7 transactional sinks, one
-  atomic unit checkpoint, SFT_OVERLOAD_POLICY + SFT_PIPELINE armed):
+  atomic unit checkpoint, SFT_OVERLOAD_POLICY armed):
   dag.commit — killed BETWEEN two sink commits of a unit commit —
   and dag.node (mid-node-walk), plus a qserve.register leg inside the
-  DAG; every sink must converge byte-identically on resume.
+  DAG; every sink must converge byte-identically on resume;
+- grid-partitioned range driver subprocess (8-device CPU mesh):
+  shard.exchange — killed mid-halo-exchange, the resumed child restores
+  the checkpointed partition plan.
 """
 
 import json
@@ -457,58 +451,8 @@ def chaos_kafka(tmp_path, point, kind="raise"):
 
 
 # ---------------------------------------------------------------------------
-# Harness 5: pipelined range driver (subprocess, SFT_PIPELINE armed).
-# The DRIVER path contains in-process raise-kind faults (drain + sync
-# reprocess — tests/test_pipeline.py pins that), so the crash legs use
-# the abort kind: os._exit(137) mid-overlap, nothing flushes, and the
-# resumed pipelined child must still converge byte-exactly.
-
-
-def chaos_pipeline(tmp_path, point):
-    env_base = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    env_base.pop("SFT_FAULT_PLAN", None)
-    env_base.pop("SFT_PIPELINE", None)
-
-    def child(workdir, pipelined=True, plan=None):
-        env = dict(env_base)
-        if pipelined:
-            env["SFT_PIPELINE"] = json.dumps(
-                {"depth": 2, "fetch_lag": 2}
-            )
-        if plan:
-            env["SFT_FAULT_PLAN"] = json.dumps(plan)
-        return subprocess.run(
-            [sys.executable, "-m", "spatialflink_tpu.driver",
-             "--chaos-child", str(workdir)],
-            env=env, capture_output=True, text=True, timeout=600,
-            cwd=REPO,
-        )
-
-    sync_dir = tmp_path / "sync"
-    clean = tmp_path / "clean"
-    chaos = tmp_path / "chaos"
-    for d in (sync_dir, clean, chaos):
-        d.mkdir()
-    p = child(sync_dir, pipelined=False)
-    assert p.returncode == 0, p.stderr[-2000:]
-    p = child(clean)
-    assert p.returncode == 0, p.stderr[-2000:]
-    want = (clean / "egress.csv").read_bytes()
-    assert want, "vacuous matrix entry: clean egress is empty"
-    # Overlap itself must not move results:
-    assert want == (sync_dir / "egress.csv").read_bytes()
-    at = 5 if point == "pipeline.ship" else 3
-    p = child(chaos, plan=[{"point": point, "kind": "abort", "at": at}])
-    assert p.returncode == ABORT_EXIT_CODE, (p.returncode,
-                                             p.stderr[-2000:])
-    p = child(chaos)  # resume, still pipelined
-    assert p.returncode == 0, p.stderr[-2000:]
-    assert (chaos / "egress.csv").read_bytes() == want
-
-
-# ---------------------------------------------------------------------------
-# Harness 6: the composed SNCB DAG (subprocess, armed overload +
-# pipeline policies). Seven nodes, seven transactional sinks, ONE unit
+# Harness 6: the composed SNCB DAG (subprocess, armed overload
+# policy). Seven nodes, seven transactional sinks, ONE unit
 # checkpoint: the abort kind kills the child at the named point —
 # including BETWEEN two sink commits of a unit commit (dag.commit at 9
 # = the second unit commit's 2nd sub-append) — and the resumed child
@@ -520,12 +464,9 @@ def chaos_dag(tmp_path, point, at):
 
     env_base = {**os.environ, "JAX_PLATFORMS": "cpu"}
     env_base.pop("SFT_FAULT_PLAN", None)
-    # Armed overload (the shed schedule CHANGES egress and must replay
-    # exactly across the kill) + armed pipeline policy (result-
-    # transparent by contract; arming it proves the DAG path tolerates
-    # it).
+    # Armed overload: the shed schedule CHANGES egress and must replay
+    # exactly across the kill.
     env_base["SFT_OVERLOAD_POLICY"] = json.dumps(SMOKE_OVERLOAD_POLICY)
-    env_base["SFT_PIPELINE"] = json.dumps({"depth": 2, "fetch_lag": 2})
 
     def child(workdir, plan=None):
         env = dict(env_base)
@@ -565,56 +506,25 @@ def chaos_dag(tmp_path, point, at):
 def test_dag_qserve_register_kill_under_armed_policies(tmp_path):
     """The acceptance's fourth cut: kill -9 at qserve.register INSIDE
     the composed DAG (mid-registration-churn of the qserve node), same
-    armed overload + pipeline env, every sink byte-identical after
-    resume."""
+    armed overload env, every sink byte-identical after resume."""
     chaos_dag(tmp_path, "qserve.register", at=11)
 
 
 # ---------------------------------------------------------------------------
-# Pipeline hang legs: the wedged (not killed) device mid-overlap.
-# In-process, a hang-kind fault on the DRIVER's pipelined path is
-# CONTAINED (sleep → raise → drain + synchronous reprocess) — results
-# must not move. The WEDGE-past-any-patience mode is bounded by the
-# driver's dial watchdog (SFT_DIAL_DEADLINE_S): the first device
-# window hangs, the watchdog seals and kills the child with bench's
-# dial exit code, and a resumed child still converges byte-exactly.
+# The wedged (not killed) device: a hang past any patience on the first
+# device window is bounded by the driver's dial watchdog
+# (SFT_DIAL_DEADLINE_S) — the watchdog seals and kills the child with
+# the dial exit code, and a resumed child still converges byte-exactly.
 
 
-@pytest.mark.parametrize("point", ["pipeline.ship", "pipeline.fetch"])
-def test_pipeline_hang_kind_is_contained_in_process(tmp_path, point):
-    from spatialflink_tpu import pipeline
-
-    clean = tmp_path / "clean"
-    chaos = tmp_path / "chaos"
-    clean.mkdir()
-    chaos.mkdir()
-    pipeline.install(pipeline.PipelinePolicy(depth=2, fetch_lag=2))
-    try:
-        run_range_leg(str(clean))
-        want = (clean / "egress.csv").read_bytes()
-        assert want
-        # Bounded hangs (10 ms each), MORE than the retry budget: the
-        # pipelined driver path must drain and reprocess synchronously,
-        # not crash — and the egress must not move.
-        drv = run_range_leg(str(chaos), fault_plan=[
-            {"point": point, "kind": "hang", "hang_s": 0.01, "at": 2,
-             "times": 3},
-        ])
-        assert drv.stats["resumed"] is False
-        assert (chaos / "egress.csv").read_bytes() == want
-    finally:
-        pipeline.uninstall()
-
-
-def test_pipeline_hang_wedge_is_bounded_by_dial_deadline(tmp_path):
-    """A hang far past any retry patience on the FIRST overlapped ship:
+def test_hang_wedge_is_bounded_by_dial_deadline(tmp_path):
+    """A hang far past any retry patience on the FIRST device ship:
     the driver's dial watchdog (SFT_DIAL_DEADLINE_S) must kill the
-    child with bench's dial exit code in bounded time — not ride out
+    child with the dial exit code in bounded time — not ride out
     the wedge — and a fresh child must still converge to the clean
     bytes."""
     env_base = {**os.environ, "JAX_PLATFORMS": "cpu"}
     env_base.pop("SFT_FAULT_PLAN", None)
-    env_base["SFT_PIPELINE"] = json.dumps({"depth": 2, "fetch_lag": 2})
 
     def child(workdir, plan=None, deadline=None):
         env = dict(env_base)
@@ -638,7 +548,7 @@ def test_pipeline_hang_wedge_is_bounded_by_dial_deadline(tmp_path):
     want = (clean / "egress.csv").read_bytes()
     assert want
     p = child(chaos, deadline="0.3", plan=[
-        {"point": "pipeline.ship", "kind": "hang", "hang_s": 60,
+        {"point": "device.ship", "kind": "hang", "hang_s": 60,
          "at": 1},
     ])
     from spatialflink_tpu.driver import DIAL_TIMEOUT_EXIT_CODE
@@ -715,13 +625,11 @@ MATRIX = {
     "overload.admit": lambda tp: chaos_range(tp, "overload.admit", at=60,
                                              with_overload=True),
     "source.stall": lambda tp: chaos_tjoin_panes(tp, "source.stall"),
-    "pipeline.ship": lambda tp: chaos_pipeline(tp, "pipeline.ship"),
-    "pipeline.fetch": lambda tp: chaos_pipeline(tp, "pipeline.fetch"),
     "qserve.register": lambda tp: chaos_qserve(tp, "qserve.register"),
     # kill -9 mid-halo-exchange on the grid-partitioned path; resume
     # restores the checkpointed partition plan (8-device subprocess).
     "shard.exchange": lambda tp: chaos_sharded(tp, "shard.exchange"),
-    # The 7-node SNCB DAG under armed overload + pipeline policies:
+    # The 7-node SNCB DAG under an armed overload policy:
     # at=9 is the SECOND unit commit's 2nd sub-append — the between-
     # sink-commits cut the atomic unit checkpoint exists to close.
     "dag.commit": lambda tp: chaos_dag(tp, "dag.commit", at=9),

@@ -72,8 +72,7 @@ class TestPlanParsing:
             "window.feed", "soa.feed", "kafka.fetch", "kafka.leader",
             "sink.write", "driver.window",
             "overload.admit", "source.stall",
-            "pipeline.ship", "pipeline.fetch", "qserve.register",
-            "dag.node", "dag.commit", "shard.exchange",
+            "qserve.register", "dag.node", "dag.commit", "shard.exchange",
         }
 
 

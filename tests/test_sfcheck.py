@@ -98,7 +98,7 @@ def test_cli_json_breakdown_over_subtree():
     # absent (they would see an incomplete world — no tests/, missing
     # callers — and manufacture findings). The full ten-pass verdict is
     # the default no-args run (test_repo_tree_is_clean_whole_program).
-    res = _cli("--json", "spatialflink_tpu", "bench.py", "tools")
+    res = _cli("--json", "spatialflink_tpu", "bench_suite.py", "tools")
     assert res.returncode == 0, res.stdout + res.stderr
     data = json.loads(res.stdout)
     assert data["findings"] == []
@@ -1000,7 +1000,7 @@ def test_no_block_until_ready_outside_telemetry():
     sync = get_pass("sync-discipline")
     report = core.run_paths(
         [os.path.join(REPO, p) for p in
-         ("__graft_entry__.py", "bench.py", "bench_suite.py", "tests",
+         ("__graft_entry__.py", "bench_suite.py", "tests",
           os.path.join("spatialflink_tpu", "slo.py"),
           os.path.join("tools", "sfprof"))],
         [sync], force_files=True,
@@ -1018,8 +1018,7 @@ def test_egress_fstrings_are_numpy_safe():
     # (report/diff/health/recover print parsed ledger values).
     fstr = get_pass("fstring-numpy")
     report = core.run_paths(
-        [os.path.join(REPO, "bench.py"),
-         os.path.join(REPO, "spatialflink_tpu", "sncb"),
+        [os.path.join(REPO, "spatialflink_tpu", "sncb"),
          os.path.join(REPO, "spatialflink_tpu", "mn"),
          os.path.join(REPO, "spatialflink_tpu", "telemetry.py"),
          os.path.join(REPO, "spatialflink_tpu", "slo.py"),

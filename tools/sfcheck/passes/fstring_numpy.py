@@ -6,7 +6,7 @@ numpy ≥2 leak vectors are repr contexts — a scalar inside a container
 (``f"{results[:3]}"`` → ``[np.int32(50), …]``, the bug that shipped
 twice) or ``!r`` — which no cheap static check can prove safe. So the
 enforced rule is the CONVENTION that keeps the boundary uniformly safe:
-in the known egress layers (bench.py, sncb/, mn/, telemetry.py), any
+in the known egress layers (sncb/, mn/, telemetry.py), any
 f-string ``FormattedValue`` or constant-string ``.format(…)`` argument
 carrying a float presentation spec (``f``/``e``/``g``/``%``) must be an
 obviously-host scalar — a numeric literal or a call to
@@ -133,7 +133,7 @@ class FstringNumpyPass(Pass):
         # byte-compares them), and fault events land in the ledger
         # stream. overload.py joined with the overload work — its
         # transition events and smoke output are egress surfaces too.
-        return (relpath in ("bench.py", "spatialflink_tpu/telemetry.py",
+        return (relpath in ("spatialflink_tpu/telemetry.py",
                             "spatialflink_tpu/slo.py",
                             "spatialflink_tpu/driver.py",
                             "spatialflink_tpu/faults.py",

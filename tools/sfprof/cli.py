@@ -243,9 +243,8 @@ def cmd_report(args) -> int:
             # throughput loops, staging), while only the latency-probe
             # windows carry spans — so this is run-total ÷ traced
             # windows, an upper bound on true per-window traffic. These
-            # are WIRE bytes — what actually crossed the link, i.e.
-            # post-codec when the delta-bitpacked pane codec ran.
-            print("\n-- device-boundary wire bytes, post-codec "
+            # are WIRE bytes — what actually crossed the link.
+            print("\n-- device-boundary wire bytes "
                   "(run totals ÷ traced windows) --")
             print(f"h2d {float(snap.get('bytes_h2d', 0) / n_win):.1f} "
                   f"B/traced-win  "
@@ -253,12 +252,6 @@ def cmd_report(args) -> int:
                   f"B/traced-win  over {int(n_win)} traced windows "
                   f"(run totals: h2d {int(snap.get('bytes_h2d', 0))} B, "
                   f"d2h {int(snap.get('bytes_d2h', 0))} B)")
-            wc = snap.get("wire_codec") or {}
-            if wc.get("ratio"):
-                print(f"wire codec: {int(wc.get('panes', 0))} panes, "
-                      f"raw {int(wc.get('raw_bytes', 0))} B → coded "
-                      f"{int(wc.get('coded_bytes', 0))} B  "
-                      f"(ratio {float(wc['ratio']):.3f}x)")
             _print_link_utilization(snap, events)
         # Per-tenant-class QoS, next to the device-boundary numbers
         # (the health CLI prints the same rows as notes).
@@ -474,7 +467,7 @@ def _print_link_utilization(snap: Dict[str, Any], events: List[dict]):
     transferred bytes over the traced span vs what the probe says this
     run's link could actually move. Both sides are honest run-wide
     aggregates (the span includes compute time), so this is a floor on
-    utilization — a pipeline that overlaps well pushes it toward 1."""
+    utilization."""
     lp = snap.get("link_probe") or {}
     bw = lp.get("roundtrip_mbps_p50")
     spans = complete_spans_ts_range(events)
@@ -778,7 +771,6 @@ def cmd_health(args) -> int:
                 "tenants": (snap.get("overload") or {}).get("tenants")
                 or {},
                 "qserve": snap.get("qserve") or {},
-                "pipeline": snap.get("pipeline") or {},
                 "faults": snap.get("faults") or {},
                 "dag": snap.get("dag") or {},
                 "nodes": snap.get("nodes") or {},
@@ -913,21 +905,6 @@ def cmd_health(args) -> int:
                       f"{int(split['halo_state_bytes'])} B of live "
                       "boundary-pane state the halo wrappers declared "
                       "(telemetry.account_halo_state)")
-    # Pipelined-ingest visibility (informational, the overload idiom):
-    # a collapse means the circuit breaker forced the executor back to
-    # the synchronous cadence mid-run — a stalled pipeline, worth a
-    # loud note even though the run survived with identical results.
-    pipe = snap.get("pipeline") or {}
-    if pipe:
-        print(f"note pipeline: windows={int(pipe.get('windows') or 0)} "
-              f"overlapped={int(pipe.get('overlapped') or 0)} "
-              f"sync={int(pipe.get('sync') or 0)} "
-              f"drains={int(pipe.get('drains') or 0)}")
-        if pipe.get("collapses"):
-            print(f"note pipeline STALLED: collapsed to the synchronous "
-                  f"cadence {int(pipe['collapses'])}x (circuit breaker "
-                  f"open — see circuit notes; results stay identical, "
-                  f"overlap throughput was lost)")
     if snap.get("faults"):
         fired = ", ".join(f"{k}×{int(v)}"
                           for k, v in sorted(snap["faults"].items()))

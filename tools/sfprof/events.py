@@ -41,10 +41,6 @@ INSTANT_EVENTS = frozenset({
     "overload_shedding:oldest",
     "overload_recovered:admission",
     "overload_recovered:lag",
-    # pipelined-ingest executor (spatialflink_tpu/pipeline.py): the
-    # breaker-driven collapse to the synchronous cadence and back
-    "pipeline_collapsed",
-    "pipeline_resumed",
     # kernel-ablation harness armed (spatialflink_tpu/ablation.py) —
     # the event that marks a capture's numbers as deliberately wrong
     "ablation_armed",
@@ -88,7 +84,6 @@ _GROUPS = (
     ("overload", ("overload_",)),
     ("dag", ("dag_node_failover:",)),
     ("qserve", ("qserve_",)),
-    ("pipeline", ("pipeline_collapsed", "pipeline_resumed")),
     ("slo", ("slo_violation:", "slo_recovered:")),
     ("ablation", ("ablation_armed",)),
     ("blackbox", ("blackbox_dumped",)),

@@ -4,7 +4,7 @@ The ledger stream is append-only JSONL flushed at window/phase
 boundaries (``telemetry.maybe_flush_stream``), so a console can tail it
 while the run is still going: per-node watermark lag and EPS from each
 checkpoint's ``snapshot.dag`` / ``snapshot.nodes`` blocks, overload
-shed/degrade/breaker state, pipeline collapses, and the SLO-transition /
+shed/degrade/breaker state, and the SLO-transition /
 fault-firing instant events as they land in span batches.
 
 Reading REUSES :func:`tools.sfprof.stream.read_records` on every poll —
@@ -77,9 +77,6 @@ def _checkpoint_lines(rec: Dict[str, Any]) -> List[str]:
         head += (f"  shed {_i(ov.get('shed_total'))}  "
                  f"rung {_i(ov.get('rung'))}/"
                  f"{_i(ov.get('ladder_depth'))}  breaker {br}")
-    pipe = snap.get("pipeline") or {}
-    if pipe.get("collapses"):
-        head += f"  pipeline COLLAPSED x{_i(pipe.get('collapses'))}"
     coll = snap.get("collectives") or {}
     if coll:
         head += f"  collective {_i(coll.get('bytes'))} B"
@@ -120,8 +117,7 @@ def _checkpoint_lines(rec: Dict[str, Any]) -> List[str]:
 #: Instant-event groups worth a live console line (the rest are counted
 #: in the final summary only — compile events alone would flood it).
 _LOUD_GROUPS = frozenset({
-    "slo", "faults", "overload", "circuit", "pipeline", "dag",
-    "self-healing",
+    "slo", "faults", "overload", "circuit", "dag", "self-healing",
 })
 
 
@@ -189,8 +185,6 @@ def _summary(records: List[dict],
             "breaker": ((snap.get("overload") or {})
                         .get("breaker") or {}).get("state"),
         },
-        "pipeline_collapses": _i((snap.get("pipeline") or {})
-                                 .get("collapses")),
         "e2e": snap.get("e2e"),
         "straggler": (
             {"node": strag[0], "e2e_compute_p99_ms": float(strag[1])}
@@ -276,7 +270,7 @@ def add_parser(sub) -> None:
     """Register the ``live`` subcommand on the sfprof CLI."""
     liv = sub.add_parser(
         "live", help="follow an in-flight SFT_LEDGER_STREAM capture: "
-                     "per-node lag/EPS, shed/degrade/breaker/pipeline "
+                     "per-node lag/EPS, shed/degrade/breaker "
                      "state, SLO + fault transitions; exits 0 when the "
                      "stream seals")
     liv.add_argument("stream")

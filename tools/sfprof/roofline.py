@@ -7,8 +7,7 @@ reader had to do the attribution by hand. This module turns one run
 ledger into a verdict with an sfcheck-style evidence chain:
 
 - **link-bound** — device-boundary bytes ÷ the MEASURED LinkProbe p50
-  bandwidth explain the traced wall (post-codec bytes: the wire-codec
-  gauges annotate what the raw wire would have cost);
+  bandwidth explain the traced wall;
 - **host-bound** — inter-window host gaps plus the unattributed residue
   inside window spans dominate (assembly, serde, GC);
 - **dispatch-bound** — kernel steady dispatch time dominates, but the
@@ -21,7 +20,7 @@ ledger into a verdict with an sfcheck-style evidence chain:
 
 Everything here is derived from signals the ledger already carries
 (``telemetry.capture_costs`` flops/bytes, ``instrument_jit`` steady
-wall-ns, LinkProbe gauges, wire-codec byte gauges, span attribution) —
+wall-ns, LinkProbe gauges, span attribution) —
 no new instrumentation, no jax import (the sfprof no-cross-import
 rule). The machine models are order-of-magnitude ridge estimates per
 backend, overridable via ``--peak-flops``/``--peak-bw``; they gate
@@ -177,19 +176,10 @@ def classify(doc: Optional[Dict[str, Any]], events: List[dict],
             f"{float(_pct(link_us, wall_us)):.1f}% of the "
             f"{float(wall_ms):.2f} ms traced span"
         )
-        wc = snap.get("wire_codec") or {}
-        if wc.get("ratio"):
-            evidence.append(
-                f"link: post-codec bytes (wire codec shipped "
-                f"{int(wc.get('coded_bytes') or 0)} B for "
-                f"{int(wc.get('raw_bytes') or 0)} B raw, ratio "
-                f"{float(wc['ratio']):.2f}x) — the raw wire would "
-                "widen the link share by that ratio"
-            )
     else:
         evidence.append(
             "link: no LinkProbe bandwidth gauge in this ledger — link "
-            "share unknown (run without SFT_NO_LINK_PROBE to measure)"
+            "share unknown"
         )
 
     # -- host: inter-window gaps + unattributed residue ---------------------

@@ -24,7 +24,7 @@ bytes) instead of pretending the artifact is complete.
 Tolerance: a half-written line (the only corruption a kill can produce)
 is dropped and counted, and it marks the truncation point — ordinary
 records after it are ignored, never silently re-synchronized. The ONE
-exception is the epilogue: a dial watchdog (bench.py, driver.py) seals
+exception is the epilogue: the dial watchdog (driver.py) seals
 a wedged run's stream by appending an epilogue AFTER the partial tail
 (on its own line), and that termination reason must survive recovery — so past
 the truncation point only ``t == "epilogue"`` records are honored.
