@@ -15,6 +15,15 @@ the result is out under the configuration's guarantee — observed without
 touching the program. At that pull the adapter also reads the driver's own
 checkpoint count: a fire that published nothing is a problem, not a result.
 
+The lines are rendered by worker processes started before the backend is
+(``before_backend``) and collected once it is up (``prepare``): the flood's
+9.5 M lines cost 2.4 us each (``repr`` of two floats and the formatting), 23 s
+in one process, which would double ``setup_s``; split eight ways beside the
+backend's own start they leave about 4 s to wait for (my chip run, PR 31;
+PERF.md section 5). Each worker returns its rows as one newline-joined string
+(one object to pickle, not a million) and the parent splits it; the lines are
+those of the one-process expression, character for character.
+
 The run ends like a consumer that goes away: the source raises past the window,
 and what is committed by then is compared with the plain reference, window by
 window.
@@ -22,13 +31,31 @@ window.
 
 from __future__ import annotations
 
+import concurrent.futures
 import functools
+import multiprocessing
 import os
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Sequence, Tuple
 
 import numpy as np
 
 from benchmark.harness import spec
+
+
+#: worker processes that render the stream's lines (a one-chip machine has 13
+#: cores; the backend's start-up runs beside them)
+RENDERERS = 8
+
+
+def render_lines(names: Sequence[str], ts: np.ndarray, ids: np.ndarray,
+                 xs: np.ndarray, ys: np.ndarray) -> str:
+    """Rows of the stream as the ``objID,timestamp,x,y`` lines the CLI parses
+    (``repr`` of the float64: the shortest text that reads back exactly),
+    joined by newlines."""
+    return "\n".join(
+        f"{names[d]},{t},{x!r},{y!r}"
+        for d, t, x, y in zip(ids.tolist(), ts.tolist(), xs.tolist(),
+                              ys.tolist()))
 
 
 class _EndOfRun(BaseException):
@@ -69,8 +96,37 @@ class Adapter:
         self.ckpt = os.path.join(workdir, "unit.ckpt")
         self.commits: List[Tuple[int, float, int, int, int]] = []
         self.health_report: Dict[str, Any] = {}
+        prefix = stream_cfg["id_prefix"]
+        self.names = [f"{prefix}{i}" for i in range(int(stream_cfg["ids"]))]
+        self.renderers = None
+        self.parts: List[concurrent.futures.Future] = []
 
     # -- set-up ----------------------------------------------------------------
+
+    def before_backend(self, stream, windows) -> None:
+        """Start rendering the lines, an equal run of rows to a worker.
+        ``spawn``: a worker imports this module and numpy, never the backend."""
+        self.renderers = concurrent.futures.ProcessPoolExecutor(
+            RENDERERS, mp_context=multiprocessing.get_context("spawn"))
+        edges = [stream.n_total * i // RENDERERS for i in range(RENDERERS + 1)]
+        self.parts = [
+            self.renderers.submit(render_lines, self.names, stream.ts(lo, hi),
+                                  stream.ids[lo:hi], stream.x[lo:hi],
+                                  stream.y[lo:hi])
+            for lo, hi in zip(edges, edges[1:])]
+
+    def rendered(self) -> List[str]:
+        """The stream's lines, once: waits for each worker's part in turn."""
+        lines: List[str] = []
+        while self.parts:
+            lines += self.parts.pop(0).result().split("\n")
+        return lines
+
+    def close(self) -> None:
+        """The workers are gone when this returns, whatever they were at."""
+        if self.renderers is not None:
+            self.renderers.shutdown(wait=True, cancel_futures=True)
+            self.renderers = None
 
     def prepare(self, stream, windows) -> None:
         from spatialflink_tpu.config import Params
@@ -78,13 +134,7 @@ class Adapter:
         from spatialflink_tpu.streams.serde import parse_csv_point
 
         self.stream, self.windows = stream, windows
-        prefix = self.stream_cfg["id_prefix"]
-        self.names = [f"{prefix}{i}" for i in range(int(self.stream_cfg["ids"]))]
-        ts = stream.ts(0, stream.n_total)
-        self.lines = [
-            f"{self.names[d]},{t},{x!r},{y!r}"
-            for d, t, x, y in zip(stream.ids.tolist(), ts.tolist(),
-                                  stream.x.tolist(), stream.y.tolist())]
+        self.lines = self.rendered()
         self.params = Params.loads(_yml(self.cfg, self.stream_cfg))
         sc = self.params.input_stream1
         self.parse = functools.partial(

@@ -90,8 +90,6 @@ def test_unwrapped_runs():
 
 
 NEW = {
-    "gc_full_ms_per_window": ["sncb.paced"],
-    "gc_full_us_per_event": ["sncb.flood"],
     "walk_link_ms_per_window": ["sncb.paced"],
     "commit_span_ms_p50": ["sncb.paced"],
     "commit_egress_ms_p50": ["sncb.paced"],

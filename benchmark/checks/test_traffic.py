@@ -12,7 +12,8 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-from benchmark.harness import traffic  # noqa: E402
+from benchmark.harness import spec, traffic  # noqa: E402
+from benchmark.harness.main import _stream_room  # noqa: E402
 
 T0 = 1_700_000_000_000
 STREAM = {"event_rate_eps": 1000, "ids": 10, "id_assignment": "round_robin",
@@ -146,11 +147,117 @@ def test_a_stream_that_runs_dry_is_an_error():
 def test_pool_replays_cyclically_and_must_span_whole_slides():
     wn = traffic.Windows(10_000, 5_000, 0, T0)
     tr = {"mode": "flood", "batch_events": 100, "warmup_results": 2,
-          "stream_eps": 50_000, "pool_events": 20_000}
+          "pool_events": 20_000}
     stream, w = traffic.build_stream(STREAM, tr, wn, 3, 10.0, False)
-    assert stream.pool == 20_000 and stream.n_total > 500_000
+    assert stream.pool == 20_000 and not stream.bounded
     assert w == 10_100  # the whole batch that holds the trigger
     assert stream.ts(20_000, 20_001)[0] == T0 + 20_000  # time goes on
+    # the pool does not depend on how long the run is
+    longer, _w = traffic.build_stream(STREAM, tr, wn, 3, 600.0, False)
+    assert np.array_equal(stream.x, longer.x) \
+        and np.array_equal(stream.y, longer.y)
     with pytest.raises(ValueError):
         traffic.build_stream(STREAM, {**tr, "pool_events": 20_500}, wn, 3,
                              10.0, False)
+    # a paced pool keeps its length: the schedule ends it
+    paced, pw = traffic.build_stream(
+        STREAM, {**tr, "mode": "paced", "rate_eps": 1000}, wn, 3, 30.0, False)
+    assert paced.bounded and paced.n_total == pw + 30_000 + 10_000
+    assert paced.pool == 20_000
+
+
+#: what the two pooled files gave a second of run until PR 31 (rehearsal
+#: sizes): the feed below is pulled past a stream of that length
+OLD_REHEARSAL_STREAM_EPS = {"knn.flood": 6_000_000, "join.flood": 600_000}
+
+
+@pytest.mark.parametrize("cell_name", sorted(OLD_REHEARSAL_STREAM_EPS))
+def test_a_pooled_flood_never_runs_dry(cell_name):
+    """The cell's own files and its adapter's own ``_chunk``, at rehearsal
+    size: a system that takes no time at all pulls far past where the stream
+    used to end, and every chunk is the pool's rows with time gone on."""
+    cell = spec.load_cell(cell_name)
+    cfg = cell.config
+    stream_cfg = traffic.effective(cfg["stream"], True)
+    tr = traffic.effective(cell.traffic, True)
+    assert "stream_eps" not in tr and "stream_eps" not in cell.traffic
+    wn = traffic.Windows(int(cfg["window_s"] * 1000),
+                         int(cfg["slide_s"] * 1000),
+                         int(cfg["fire_delay_ms"]), int(stream_cfg["t0_ms"]))
+    seconds = 6.0
+    stream, w = traffic.build_stream(stream_cfg, tr, wn, 2**31 + 17, seconds,
+                                     False)
+    assert not stream.bounded and stream.pool == tr["pool_events"]
+    clock = FakeClock()
+    feed = traffic.Feed(stream, wn, tr, w, seconds, clock=clock,
+                        sleep=clock.sleep)
+    ad = spec.plugin("adapters", cfg["adapter"]).Adapter(
+        cfg, stream_cfg, "/nonexistent", True)
+    ad.stream, ad.windows = stream, wn
+    ad.ts_pool = stream.ts(0, stream.pool)
+    ad.cycle_ms = stream.pool * 1000 // stream.rate_eps
+    old_end = w + int(seconds * OLD_REHEARSAL_STREAM_EPS[cell_name]) \
+        + 2 * wn.slide_ms * stream.rate_eps // 1000
+    last_ts, n, expect_lo = T0 - 1, 0, 0
+    for lo, hi in feed.segments():
+        assert lo == expect_lo and hi - lo == tr["batch_events"]
+        expect_lo = hi
+        c = ad._chunk(lo, hi)
+        assert c["ts"][0] >= last_ts and c["ts"][-1] > c["ts"][0]
+        last_ts = c["ts"][-1]
+        if n % 997 == 0:  # in full now and then: the whole feed is long
+            assert np.array_equal(c["ts"], stream.ts(lo, hi))
+            a = lo % stream.pool
+            assert np.shares_memory(c["x"], stream.x)
+            assert np.array_equal(c["x"], stream.x[a:a + hi - lo])
+            assert np.array_equal(c["oid"], stream.ids[a:a + hi - lo])
+        n += 1
+        if lo > 3 * old_end:
+            clock.now += seconds + 1.0  # only now does the window close
+    assert feed.idx_closed > 3 * old_end > 5 * stream.pool
+    room = _stream_room(stream, feed)
+    assert room == {"stream_events": None}  # no share: there is no end
+
+
+def test_a_bounded_flood_says_how_much_of_its_stream_the_window_took():
+    feed, stream, _wn, w, clock, _m = make("flood", seconds=20.0)
+    for lo, hi in feed.segments():
+        clock.now += 0.0005 * (hi - lo)  # 2,000 events/s of a 3,000/s stream
+    room = _stream_room(stream, feed)
+    assert room["stream_events"] == stream.n_total == w + 60_000 + 10_000
+    assert room["stream_used_share"] == \
+        (feed.idx_closed - w) / (stream.n_total - w)
+    assert 0.55 < room["stream_used_share"] < 0.60
+    # a paced stream is as long as its schedule: no share to give
+    feed, stream, *_ = make("paced", seconds=12.0)
+    for _ in feed.segments():
+        pass
+    assert set(_stream_room(stream, feed)) == {"stream_events"}
+
+
+@pytest.mark.parametrize("block", ["file", "rehearsal"])
+def test_a_pooled_flood_file_with_stream_eps_is_refused(block, monkeypatch):
+    good = spec.load_cell("knn.flood").traffic
+    bad = {**good, "rehearsal": dict(good["rehearsal"])}
+    (bad if block == "file" else bad["rehearsal"])["stream_eps"] = 36_000_000
+    with pytest.raises(ValueError, match="stream_eps"):
+        traffic.check(bad)
+    # at load, with the file's name; and by the generator itself
+    load_json = spec._load_json
+    monkeypatch.setattr(
+        spec, "_load_json", lambda path:
+        bad if path.endswith("knn_backlog.json") else load_json(path))
+    with pytest.raises(spec.SpecError, match="knn_backlog.json.*stream_eps"):
+        spec.load_cell("knn.flood")
+    wn = traffic.Windows(10_000, 5_000, 0, T0)
+    with pytest.raises(ValueError, match="stream_eps"):
+        traffic.build_stream(STREAM, traffic.effective(bad, block != "file"),
+                             wn, 3, 10.0, False)
+
+
+def test_a_flood_without_a_pool_needs_its_length():
+    with pytest.raises(ValueError, match="needs stream_eps"):
+        traffic.check({"mode": "flood", "batch_events": 100,
+                       "warmup_results": 2})
+    for name in spec.cell_names():  # every file of the benchmark passes
+        traffic.check(spec.load_cell(name).traffic)

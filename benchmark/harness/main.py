@@ -3,9 +3,11 @@ print the line.
 
 Phases (everything before the window opens is ``setup_s``):
 
-1. read the cell from data (``spec``), refuse the wrong platform;
-2. build the seeded stream in bulk and let the adapter encode it and build the
-   system (``adapter.prepare``);
+1. read the cell from data (``spec``), build the seeded stream in bulk and
+   let the adapter start what needs no backend (``adapter.before_backend``:
+   worker processes that encode the stream while the backend comes up);
+2. start the backend, refuse the wrong platform, and let the adapter collect
+   the encoded stream and build the system (``adapter.prepare``);
 3. ``adapter.run(feed)``: the feed hands the warm-up over at flood speed until
    the warm-up's last result is out, opens the window, keeps to its schedule
    for ``--seconds`` and ends; nothing may compile inside the window;
@@ -164,26 +166,6 @@ def main(argv: Sequence[str], t_start: float) -> int:
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                           os.path.join(spec.ROOT, ".jax_cache"))
     cell = spec.load_cell(args.workload)
-
-    import jax
-
-    devices = jax.devices()
-    stages = {"backend_s": time.perf_counter() - t_start}
-    platform, kind = devices[0].platform, devices[0].device_kind
-    if platform != "tpu" and not args.rehearsal:
-        return _fail(f"JAX found platform {platform!r}, not 'tpu': a cell "
-                     "counts only on the chip (--rehearsal is the CPU mode)")
-    if len(devices) < cell.chips:
-        return _fail(f"cell {cell.name} needs {cell.chips} chips, JAX found "
-                     f"{len(devices)}")
-    with open(os.path.join(spec.BENCH_DIR, "harness", "peaks.json")) as f:
-        peaks = json.load(f).get(kind)
-    if peaks is None and not args.rehearsal:
-        return _fail(f"device kind {kind!r} is not in harness/peaks.json")
-
-    from benchmark.harness.compile_clock import CompileClock
-
-    compile_clock = CompileClock()
     cfg = cell.config
     stream_cfg = traffic.effective(cfg["stream"], args.rehearsal)
     tr = traffic.effective(cell.traffic, args.rehearsal)
@@ -191,20 +173,49 @@ def main(argv: Sequence[str], t_start: float) -> int:
         size_ms=int(cfg["window_s"] * 1000), slide_ms=int(cfg["slide_s"] * 1000),
         fire_delay_ms=int(cfg["fire_delay_ms"]), t0_ms=int(stream_cfg["t0_ms"]))
     workdir = os.path.join(spec.ROOT, ".bench_work", cell.name)
-    shutil.rmtree(workdir, ignore_errors=True)
-    os.makedirs(workdir)
-
     adapter = spec.plugin("adapters", cfg["adapter"]).Adapter(
         cfg, stream_cfg, workdir, args.rehearsal)
     stream, w = traffic.build_stream(stream_cfg, tr, windows, args.seed,
                                      args.seconds, adapter.split_at_triggers)
-    feed = traffic.Feed(
-        stream, windows, tr, w, args.seconds,
-        split_at_triggers=adapter.split_at_triggers,
-        on_mark=getattr(adapter, "on_mark", None))
-    adapter.prepare(stream, windows)
-    stages["stream_and_system_s"] = (time.perf_counter() - t_start
-                                     - stages["backend_s"])
+    # An adapter may have host work that needs no backend (encoding the
+    # stream in worker processes): it starts here, goes on while the backend
+    # comes up and is collected in ``prepare``; ``close`` ends what a run
+    # that stops before then has left of it.
+    if hasattr(adapter, "before_backend"):
+        adapter.before_backend(stream, windows)
+    stages = {"stream_s": time.perf_counter() - t_start}
+    try:
+        import jax
+
+        devices = jax.devices()
+        stages["backend_s"] = time.perf_counter() - t_start - stages["stream_s"]
+        platform, kind = devices[0].platform, devices[0].device_kind
+        if platform != "tpu" and not args.rehearsal:
+            return _fail(f"JAX found platform {platform!r}, not 'tpu': a cell "
+                         "counts only on the chip (--rehearsal is the CPU "
+                         "mode)")
+        if len(devices) < cell.chips:
+            return _fail(f"cell {cell.name} needs {cell.chips} chips, JAX "
+                         f"found {len(devices)}")
+        with open(os.path.join(spec.BENCH_DIR, "harness", "peaks.json")) as f:
+            peaks = json.load(f).get(kind)
+        if peaks is None and not args.rehearsal:
+            return _fail(f"device kind {kind!r} is not in harness/peaks.json")
+
+        from benchmark.harness.compile_clock import CompileClock
+
+        compile_clock = CompileClock()
+        shutil.rmtree(workdir, ignore_errors=True)
+        os.makedirs(workdir)
+        feed = traffic.Feed(
+            stream, windows, tr, w, args.seconds,
+            split_at_triggers=adapter.split_at_triggers,
+            on_mark=getattr(adapter, "on_mark", None))
+        adapter.prepare(stream, windows)
+    finally:
+        if hasattr(adapter, "close"):
+            adapter.close()
+    stages["system_s"] = time.perf_counter() - t_start - sum(stages.values())
 
     telemetry = None
     snaps: Dict[str, Any] = {}
@@ -266,8 +277,8 @@ def main(argv: Sequence[str], t_start: float) -> int:
     waited = [p[3] for p in feed.pulls]
     _say(cell=cell.name, seed=args.seed, seconds=args.seconds,
          rehearsal=args.rehearsal, platform=platform, device_kind=kind,
-         allocator=allocator, stream_events=stream.n_total, warmup_events=w,
-         handed_in_window=feed.idx_closed - w,
+         allocator=allocator, warmup_events=w,
+         handed_in_window=feed.idx_closed - w, **_stream_room(stream, feed),
          fire_delay_ms=windows.fire_delay_ms, window_ms=windows.size_ms,
          slide_ms=windows.slide_ms, results_total=len(feed.results),
          results_in_window=e2e["results"], late=e2e["late"],
@@ -341,6 +352,20 @@ def main(argv: Sequence[str], t_start: float) -> int:
     shutil.rmtree(workdir, ignore_errors=True)
     print(json.dumps(line), flush=True)
     return 0
+
+
+def _stream_room(stream: traffic.Stream, feed: traffic.Feed) -> Dict[str, Any]:
+    """How long the stream was and, for a bounded flood, the share of what it
+    held past the warm-up that the window took: at 1 the run ends in
+    ``SourceDry``. A pooled flood has no end and a paced stream is as long as
+    its schedule, so neither has a share to give."""
+    if not stream.bounded:
+        return {"stream_events": None}
+    room = {"stream_events": stream.n_total}
+    if not feed.paced:
+        room["stream_used_share"] = \
+            (feed.idx_closed - feed.w) / (stream.n_total - feed.w)
+    return room
 
 
 def _lag_after(feed: traffic.Feed, which: int) -> Optional[float]:

@@ -23,6 +23,8 @@ import json
 import os
 from typing import Any, Dict, List
 
+from benchmark.harness import traffic
+
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 
@@ -62,6 +64,16 @@ def _in_cell(metric: Dict[str, Any], cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def _load_traffic(name: str) -> Dict[str, Any]:
+    path = os.path.join(BENCH_DIR, "traffic", name + ".json")
+    params = _load_json(path)
+    try:
+        traffic.check(params)
+    except ValueError as e:
+        raise SpecError(f"{os.path.relpath(path, ROOT)}: {e}") from e
+    return params
+
+
 def load_cell(name: str) -> Cell:
     bench = benchmark()
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -79,8 +91,7 @@ def load_cell(name: str) -> Cell:
     return Cell(
         name=name, chips=int(w["chips"]),
         config=_load_json(os.path.join(ROOT, configs[w["config"]]["file"])),
-        traffic=_load_json(os.path.join(BENCH_DIR, "traffic",
-                                        w["traffic"] + ".json")),
+        traffic=_load_traffic(w["traffic"]),
         end_to_end=end_to_end, per_layer=per_layer,
     )
 

@@ -20,22 +20,27 @@ Traffic parameters (``benchmark/traffic/<name>.json``):
                     event-time second); flood streams keep the configuration's
 ``batch_events``    a segment's largest size: events released together
 ``warmup_results``  results produced at flood speed before the window opens
-``stream_eps``      flood: the stream holds this many events per second of
-                    ``--seconds`` (size it to >= 3x what the system drains)
-``pool_events``     replay a pool of this many events cyclically (optional)
+``pool_events``     replay a pool of this many events cyclically (optional);
+                    a pooled flood has no end: the pool goes round for as
+                    long as the system pulls
+``stream_eps``      flood without a pool: the stream holds this many events per
+                    second of ``--seconds`` (size it to >= 3x what the system
+                    drains); refused beside ``pool_events`` in a flood
 ``rehearsal``       overrides for the toy-size rehearsal
 
 Schedule. Event ``i`` (``i >= W``, the first event after the warm-up) is due at
 ``t_open + (i - W) / rate``; a segment is released when its LAST event is due,
 so nothing is handed over early. A flood feed releases at once. The window
 opens at the first pull after the warm-up's last result is out and closes
-``--seconds`` later; the feed ends at the first pull after that. A stream that
-runs dry before then is an error (:class:`SourceDry`), never a shorter window.
+``--seconds`` later; the feed ends at the first pull after that. A bounded
+stream that runs dry before then is an error (:class:`SourceDry`), never a
+shorter window; the result's ``stream_used_share`` says how near a run came.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
@@ -75,7 +80,7 @@ class Windows:
 class Stream:
     rate_eps: int        # event-time events per second
     t0_ms: int
-    n_total: int         # events the stream holds
+    n_total: float       # events the stream holds; math.inf: a pooled flood
     x: np.ndarray        # float64, one cycle of the pool
     y: np.ndarray
     ids: np.ndarray      # int64 index into the configuration's id space
@@ -83,6 +88,10 @@ class Stream:
     @property
     def pool(self) -> int:
         return len(self.x)
+
+    @property
+    def bounded(self) -> bool:
+        return self.n_total != math.inf
 
     def ts(self, lo: int, hi: int) -> np.ndarray:
         return self.t0_ms + (np.arange(lo, hi, dtype=np.int64) * 1000) \
@@ -108,6 +117,21 @@ def effective(params: Dict[str, Any], rehearsal: bool) -> Dict[str, Any]:
     return out
 
 
+def check(params: Dict[str, Any]) -> None:
+    """Refuse a traffic file whose keys contradict each other, as it stands
+    and with its ``rehearsal`` block applied: a pooled flood has no length to
+    give, any other flood has to give one."""
+    for tr in (effective(params, False), effective(params, True)):
+        if tr["mode"] != "flood":
+            continue
+        if tr.get("pool_events") and "stream_eps" in tr:
+            raise ValueError(
+                "a flood with pool_events replays its pool without an end: "
+                "stream_eps means nothing there, take it out")
+        if not tr.get("pool_events") and "stream_eps" not in tr:
+            raise ValueError("a flood without pool_events needs stream_eps")
+
+
 def warmup_end(stream_rate: int, windows: Windows,
                traffic: Dict[str, Any], split_at_triggers: bool) -> int:
     """W: the first event of the measured window. The warm-up's last trigger
@@ -125,16 +149,21 @@ def build_stream(stream_cfg: Dict[str, Any], traffic: Dict[str, Any],
                  windows: Windows, seed: int, seconds: float,
                  split_at_triggers: bool) -> Tuple[Stream, int]:
     """The seeded stream for one run and W. Same seed, same stream."""
+    check(traffic)
     paced = traffic["mode"] == "paced"
     rate = int(traffic["rate_eps"] if paced else stream_cfg["event_rate_eps"])
     w = warmup_end(rate, windows, traffic, split_at_triggers)
-    per_s = rate if paced else int(traffic["stream_eps"])
-    # Two slides of slack: the window closes at a pull, a little after t_close.
-    n_total = w + int(seconds * per_s) + 2 * windows.slide_ms * rate // 1000
-    pool = int(traffic.get("pool_events") or n_total)
+    pool = int(traffic.get("pool_events") or 0)
+    if pool and not paced:
+        n_total = math.inf
+    else:
+        per_s = rate if paced else int(traffic["stream_eps"])
+        # Two slides of slack: the window closes at a pull, a little after
+        # t_close.
+        n_total = w + int(seconds * per_s) + 2 * windows.slide_ms * rate // 1000
+        pool = min(pool or n_total, n_total)
     if pool < n_total and (pool * 1000) % (rate * windows.slide_ms):
         raise ValueError("pool_events must span a whole number of slides")
-    pool = min(pool, n_total)
     rng = np.random.default_rng(seed)
     min_x, min_y, max_x, max_y = stream_cfg["bbox"]
     x = rng.uniform(min_x, max_x, pool)
