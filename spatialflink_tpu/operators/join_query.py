@@ -268,7 +268,9 @@ class PointPointJoinQuery(SpatialOperator):
         result that still reports overflow (the safety net under that
         pick) is run again one rung up, and one with more pairs than the
         budget under a grown budget. Returns (result, count, cap re-runs,
-        budget re-runs); the result's overflow is 0."""
+        budget re-runs, peel passes); the result's overflow is 0, and the
+        passes are those of the result held (0 from a program that does not
+        count any), fetched with its count."""
         num_cells = self.grid.num_cells
         self._climb_cap(max(
             max_cell_count(lcell, lvalid, num_cells),
@@ -277,8 +279,11 @@ class PointPointJoinQuery(SpatialOperator):
         cap_retries = budget_retries = 0
         while True:
             res = call(self.join_cap, self.join_budget)
-            count, overflow = (
-                int(v) for v in telemetry.fetch((res.count, res.overflow))
+            scalars = (res.count, res.overflow)
+            if res.peel_passes is not None:
+                scalars += (res.peel_passes,)
+            count, overflow, *passes = (
+                int(v) for v in telemetry.fetch(scalars)
             )
             if overflow > 0:
                 self._climb_cap(2 * self.join_cap)
@@ -287,7 +292,7 @@ class PointPointJoinQuery(SpatialOperator):
                 self._grow_budget(count)
                 budget_retries += 1
             else:
-                return res, count, cap_retries, budget_retries
+                return res, count, cap_retries, budget_retries, sum(passes)
 
     def _filter_radius(self, radius):
         """Distance-predicate radius: in approximate mode every grid
@@ -458,7 +463,7 @@ class PointPointJoinQuery(SpatialOperator):
         self.join_budget = max(
             self.join_budget, 1024, min(4 * lb.capacity, 262_144)
         )
-        res, count, _, _ = self._join_until_held(
+        res, count, _, _, _ = self._join_until_held(
             lb.cell, lb.valid, rb.cell, rb.valid,
             lambda cap, budget: grid_hash_join_batches(
                 self.grid, lb, rb, radius, cap, offsets,
@@ -642,13 +647,15 @@ class PointPointJoinQuery(SpatialOperator):
             lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d = ship(
                 lxy, lvalid, lcell, rxy, rvalid, rcell
             )
-            res, count, cap_retries, budget_retries = self._join_until_held(
-                lcell, lvalid, rcell, rvalid,
-                lambda cap, budget: fn(
-                    lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d,
-                    grid_n=self.grid.n, layers=layers, radius=fr,
-                    cap_left=cap, cap_right=cap, max_pairs=budget,
-                ),
+            res, count, cap_retries, budget_retries, passes = (
+                self._join_until_held(
+                    lcell, lvalid, rcell, rvalid,
+                    lambda cap, budget: fn(
+                        lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d,
+                        grid_n=self.grid.n, layers=layers, radius=fr,
+                        cap_left=cap, cap_right=cap, max_pairs=budget,
+                    ),
+                )
             )
             pairs = (res.left_index, res.right_index, res.dist)
             if self.join_budget != warmed:
@@ -662,7 +669,7 @@ class PointPointJoinQuery(SpatialOperator):
             telemetry.record_join(
                 pairs=count, cap_retries=cap_retries,
                 budget_retries=budget_retries, cap=self.join_cap,
-                budget=self.join_budget,
+                budget=self.join_budget, peel_passes=passes,
             )
             self._grow_budget(count)  # headroom for the next window
             yield (win.start, win.end, li, ri, dd, count, 0)

@@ -17,7 +17,7 @@ join/PointPointJoinQuery.java:186-243).
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -128,7 +128,10 @@ class CompactJoinResult(NamedTuple):
 
     ``left_index``/``right_index``: (max_pairs,) original-batch indices,
     -1 padding; ``dist``: (max_pairs,); ``count``: () true number of pairs
-    (> max_pairs means truncation); ``overflow``: () cell-capacity drops.
+    (> max_pairs means truncation); ``overflow``: () cell-capacity drops;
+    ``peel_passes``: () vector passes the Pallas extraction took to lift
+    the ``count`` hits out of their blocks (ops/pallas_join.py) — None
+    from every program that has no such loop.
     """
 
     left_index: jnp.ndarray
@@ -136,6 +139,7 @@ class CompactJoinResult(NamedTuple):
     dist: jnp.ndarray
     count: jnp.ndarray
     overflow: jnp.ndarray
+    peel_passes: Optional[jnp.ndarray] = None
 
 
 def join_kernel_compact(

@@ -1,4 +1,4 @@
-"""Pallas TPU grid-hash join — hit extraction in time ∝ matches.
+"""Pallas TPU grid-hash join — hit extraction in vector passes.
 
 The XLA dense-bucket join (ops.join.join_window_bucketed) evaluates the
 pair predicate over span²·cells·capL·capR lanes essentially for free, but
@@ -6,19 +6,58 @@ compacting the hits with ``jnp.nonzero`` costs ~9 ns/lane on the TPU scalar
 core (~2 s for a 131k×131k window at cap 48) because the cumsum+scatter
 touches every lane. Real joins are sparse — ~1 M hits out of 1.5 G lanes at
 two 500k-point sides — so this kernel walks the bucket planes once and
-extracts each hit with an argmin-over-mask loop whose cost is proportional
-to the HIT count:
+takes the hits out of each block that has any:
 
   grid step = one cell row; per column and per neighbour bucket of the
-  right side, one (capL, capR) pair mask is evaluated on the VPU and a
-  while-loop peels off its set lanes one at a time (vector min-reduce over
-  that one block, scalar store via an SMEM cursor).
+  right side, one (capL, capR) pair mask is evaluated on the VPU; a block
+  with hits is peeled in PASSES, each of which takes the next hit of every
+  left row at once.
 
-The three output arrays live in HBM, not in VMEM: hits collect in a
-128-lane register row, full rows in a ``STAGE_ROWS``-row VMEM stage, and a
-full stage is copied out (one DMA per array) at the running offset. What
-the outputs may hold is bounded by HBM; VMEM holds 3 × STAGE_ROWS × 512 B
-whatever the budget.
+How a hit leaves the kernel:
+
+1. *The pass.* ``nxt`` = the smallest right slot each left row has not
+   given yet (one lane reduction → a (capL, 1) column, a vector, never a
+   scalar); the row's right index and d² follow by a one-hot select and a
+   lane reduction each, the left index is the row's own. A block needs as
+   many passes as its fullest row has hits; the loop carries the column
+   of slots last taken (int32 — Mosaic cannot carry the i1 mask) and the
+   hits still in the block, one scalar a pass.
+2. *The placement.* The column's valid rows take consecutive slots of the
+   output stream: slot = lane cursor + exclusive prefix count down the
+   column (a strictly-lower-triangular 0/1 matmul, exact on the MXU),
+   ``hot[i, k] = valid[i] & (slot[i] mod 128 == k)``, and each of the
+   three output rows is a select and a sublane reduction over ``hot`` —
+   transpose, compact and place in one step, exactly (the MXU never sees
+   an index or a distance). A pass yields at most 128 slots (columns of
+   more than 128 left rows are placed 128 at a time), distinct mod 128:
+   lanes from the cursor up finish the current 128-lane register row,
+   lanes below the wrapped end start the next one after it is staged.
+3. *The way out.* A full register row goes to a ``STAGE_ROWS``-row VMEM
+   stage — d² becomes d there, 128 square roots at a time — and a full
+   stage is copied out (one DMA per array) at the running offset. The
+   three output arrays live in HBM: what they may hold is bounded by HBM;
+   VMEM holds 3 × STAGE_ROWS × 512 B whatever the budget.
+
+SMEM holds five scalars: the hit count (it runs on past the budget; nothing
+is written there), the lane cursor, the stage row, the stages copied out
+and the passes made. Count and passes leave together ((1, 2) int32);
+``pairs ÷ peel_passes`` is what a pass carries — 1 by construction for a
+loop that takes one hit a trip (this kernel until PR 32: four block → scalar
+reductions, a scalar ``sqrt`` and four SMEM updates a hit, 0.46 µs a hit).
+Measured on a v5e at two 500,000-point sides, r = 0.002° on the 100-cell
+Beijing grid, cap 128 (PERF.md §6, PR 32): 78,100 passes for 997,000 pairs
+= 12.7 hits a pass, 10.9 passes an occupied cell, 0.41 µs a pass; the
+kernel 459 → 38 ms a window.
+
+Pairs leave a block in pass order (row-major inside a pass), blocks in the
+grid's order: deterministic, and no consumer reads an order. The pair set
+and every distance are those of the one-hit loop, bit for bit.
+
+Mosaic limits that shaped it (jax 0.9.0, libtpu 0.0.34): no i1 block through
+a ``while_loop``; no ``dynamic_slice`` or gather on a vector (hence one-hot
+selects); no scalar stores to VMEM (hence the register row); ``tpu.iota``
+makes no float32. Cap 1,024 on a 100-column grid asks for 20 MB of VMEM for
+the planes' blocks alone and does not compile (nor did it before).
 
 Replaces the reference's replicate+shuffle+filter join
 (join/JoinQuery.java:73-137, join/PointPointJoinQuery.java:124-183) as the
@@ -58,6 +97,7 @@ def _extract_kernel(
     sm, accl, accr, accd, stl, str_, std, sem = rest[n_right + 4:]
     lane_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
     outs = ((stl, outl_ref), (str_, outr_ref), (std, outd_ref))
+    accs = (accl, accr, accd)
 
     i = pl.program_id(0)
 
@@ -67,6 +107,7 @@ def _extract_kernel(
         sm[1] = 0  # lane of the register row the next hit takes (0..127)
         sm[2] = 0  # row of the stage the register row goes to
         sm[3] = 0  # stages copied out to HBM so far
+        sm[4] = 0  # peel passes so far
 
     def copy_out():
         """The stage → rows [chunk·stage_rows, +stage_rows) of the outputs.
@@ -90,11 +131,12 @@ def _extract_kernel(
         sm[3] = chunk + 1
 
     def stage_row():
-        """The register row → the stage; a full stage → HBM."""
+        """The register row → the stage (d² → d here, 128 at a time); a
+        full stage → HBM."""
         srow = sm[2]
         stl[pl.ds(srow, 1), :] = accl[:]
         str_[pl.ds(srow, 1), :] = accr[:]
-        std[pl.ds(srow, 1), :] = accd[:]
+        std[pl.ds(srow, 1), :] = jnp.sqrt(accd[:])
         sm[2] = srow + 1
 
         @pl.when(srow + 1 == stage_rows)
@@ -104,52 +146,96 @@ def _extract_kernel(
 
     r2 = radius_ref[0, 0] * radius_ref[0, 0]
     row_any = jnp.sum((lidx_ref[0, :, :] >= 0).astype(jnp.int32)) > 0
-    # Codes of one (capL, capR) block, row-major: the peel's order.
-    code_iota = (
-        jax.lax.broadcasted_iota(jnp.int32, (cap_left, cap_right), 0)
-        * cap_right
-        + jax.lax.broadcasted_iota(jnp.int32, (cap_left, cap_right), 1)
-    )
-    big = cap_left * cap_right
+    # Lane (right slot) of every position of one (capL, capR) block.
+    col_iota = jax.lax.broadcasted_iota(jnp.int32, (cap_left, cap_right), 1)
+    col_f32 = col_iota.astype(jnp.float32)
+    # A pass's column is placed at most 128 left rows at a time, so that it
+    # wraps the 128-lane register row at most once.
+    chunks = [(r0, min(r0 + 128, cap_left)) for r0 in range(0, cap_left, 128)]
+
+    def lower_triangle(n):
+        """tri[i, j] = 1 where j < i: tri @ v is v's exclusive prefix sum
+        down the column (0/1 operands, counts ≤ 128: exact on the MXU)."""
+        return (
+            jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+            < jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+        ).astype(jnp.float32)
+
+    tri = {n: lower_triangle(n) for n in {r1 - r0 for r0, r1 in chunks}}
+
+    def place(valid, lcol, scol, dcol):
+        """One pass's (n ≤ 128, 1) column → the output stream: the valid
+        rows, in row order, take the next lanes of the register row (a
+        one-hot (n, 128) placement: transpose, compact and place in one
+        step); a row that fills is staged and the rest start the next one.
+        Returns the number of valid rows."""
+        n = valid.shape[0]
+        ones = jnp.where(valid, 1.0, 0.0).astype(jnp.float32)
+        before = jnp.dot(
+            tri[n], jnp.broadcast_to(ones, (n, 128)),
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)
+        nv = jnp.sum(valid.astype(jnp.int32))
+        lane = sm[1]
+        # Slots are consecutive from the cursor and at most 128: distinct
+        # mod 128, so one ring row holds the tail of this register row
+        # (lanes ≥ cursor) and the head of the next (lanes < end − 128).
+        hot = valid & (((lane + before) & 127) == lane_iota)
+        rings = [
+            jnp.sum(jnp.where(hot, col, 0), axis=0, keepdims=True)
+            for col in (lcol, scol, dcol)
+        ]
+        held = lane_iota < lane
+        for acc, ring in zip(accs, rings):
+            acc[:] = jnp.where(held, acc[:], ring)
+        end = lane + nv
+        sm[0] = sm[0] + nv
+        sm[1] = end & 127
+
+        @pl.when(end >= 128)
+        def _row_full():
+            stage_row()
+            for acc, ring in zip(accs, rings):
+                acc[:] = ring
+
+        return nv
 
     def peel(mask, nhit, lidxv, sidx, d2):
-        """Extract the ``nhit`` set lanes of one block, ascending code."""
+        """Extract the ``nhit`` set lanes of one block: every pass takes
+        the next hit (ascending right slot) of every left row at once."""
 
         def cond(st):
             return st[1] > 0
 
         def body(st):
-            # Scalar-only carry (last extracted code): Mosaic cannot
-            # carry the (capL, capR) i1 mask through a while loop.
+            # Carried: the lane each row took last, as an int32 column
+            # (Mosaic cannot carry the (capL, capR) i1 mask through a
+            # while loop), and the hits still in the block.
             last, remaining = st
-            code = jnp.min(
-                jnp.where(mask & (code_iota > last), code_iota, big)
-            )
-            # One-hot reduces instead of dynamic_slice (which Mosaic
-            # does not lower): exactly one lane has code_iota == code.
-            hot = code_iota == code
-            lval = jnp.sum(jnp.where(hot, lidxv, 0))
-            rval = jnp.sum(jnp.where(hot, sidx, 0))
-            dval = jnp.sqrt(jnp.sum(jnp.where(hot, d2, 0.0)))
-            # Scalar stores to VMEM are impossible on TPU; instead
-            # accumulate into a 128-lane register row (one-hot
-            # select) and hand full rows on with a vector store.
-            lane = sm[1]
-            lane_hot = lane_iota == lane
-            accl[:] = jnp.where(lane_hot, lval, accl[:])
-            accr[:] = jnp.where(lane_hot, rval, accr[:])
-            accd[:] = jnp.where(lane_hot, dval.astype(jnp.float32), accd[:])
-            sm[0] = sm[0] + 1
-            sm[1] = lane + 1
+            # The minimum runs on float32 (slots are small whole numbers,
+            # exact there): an int32 lane minimum is dearer on the v5e.
+            nxt = jnp.min(
+                jnp.where(mask & (col_iota > last), col_f32, cap_right),
+                axis=1, keepdims=True,
+            ).astype(jnp.int32)
+            # One-hot lane reduces instead of a gather: in a row that has
+            # a hit left exactly one lane has col_iota == nxt, in a row
+            # that has none (nxt == cap_right) no lane has.
+            hot = col_iota == nxt
+            scol = jnp.sum(jnp.where(hot, sidx, 0), axis=1, keepdims=True)
+            dcol = jnp.sum(jnp.where(hot, d2, 0.0), axis=1, keepdims=True)
+            valid = nxt < cap_right
+            taken = jnp.int32(0)
+            for r0, r1 in chunks:
+                cut = slice(r0, r1)
+                taken = taken + place(
+                    valid[cut], lidxv[cut], scol[cut], dcol[cut]
+                )
+            sm[4] = sm[4] + 1
+            return (nxt, remaining - taken)
 
-            @pl.when(lane == 127)
-            def _row_full():
-                stage_row()
-                sm[1] = 0
-
-            return (code, remaining - 1)
-
-        jax.lax.while_loop(cond, body, (jnp.int32(-1), nhit))
+        last0 = jnp.full((cap_left, 1), -1, jnp.int32)
+        jax.lax.while_loop(cond, body, (last0, nhit))
 
     @pl.when(row_any)
     def _row():
@@ -161,7 +247,7 @@ def _extract_kernel(
 
             @pl.when(jnp.sum(lvalid.astype(jnp.int32)) > 0)
             def _cell():
-                # One neighbour bucket at a time: a hit's cost is a pass
+                # One neighbour bucket at a time: a pass's cost is a sweep
                 # over its (capL, capR) block, not over all span² of them.
                 for di in range(span):
                     rx_ref = right_refs[3 * di]
@@ -199,6 +285,7 @@ def _extract_kernel(
             copy_out()
 
         cnt_ref[0, 0] = sm[0]
+        cnt_ref[0, 1] = sm[4]
 
 
 @functools.partial(
@@ -287,10 +374,10 @@ def join_window_pallas(
             jax.ShapeDtypeStruct((max_rows, 128), jnp.int32),
             jax.ShapeDtypeStruct((max_rows, 128), jnp.int32),
             jax.ShapeDtypeStruct((max_rows, 128), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, 2), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.SMEM((4,), jnp.int32),
+            pltpu.SMEM((5,), jnp.int32),
             pltpu.VMEM((1, 128), jnp.int32),
             pltpu.VMEM((1, 128), jnp.int32),
             pltpu.VMEM((1, 128), jnp.float32),
@@ -311,5 +398,5 @@ def join_window_pallas(
         jnp.where(found, outl.reshape(-1), -1),
         jnp.where(found, outr.reshape(-1), -1),
         jnp.where(found, outd.reshape(-1), jnp.inf),
-        count, l_over + r_over,
+        count, l_over + r_over, cnt[0, 1],
     )

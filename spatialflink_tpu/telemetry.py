@@ -318,8 +318,8 @@ class Telemetry:
         # kernel_table()/capture_costs() (fed by instrument_jit).
         self._kernel_stats: Dict[Tuple[str, Tuple], Dict[str, Any]] = {}
         # Window point join (operators/join_query.py:run_soa via
-        # record_join): counters pairs / windows / cap_retries /
-        # budget_retries and the gauges cap / budget (the rung and the
+        # record_join): counters pairs / peel_passes / windows / cap_retries
+        # / budget_retries and the gauges cap / budget (the rung and the
         # pair budget in use) — snapshot()["join"], empty until the first
         # joined window.
         self._join: Dict[str, int] = {}
@@ -1107,19 +1107,22 @@ class Telemetry:
             }
 
     def record_join(self, pairs: int, cap_retries: int, budget_retries: int,
-                    cap: int, budget: int):
+                    cap: int, budget: int, peel_passes: int = 0):
         """One window of the SoA point join, fetched: ``pairs`` found,
-        the re-runs it took (a bucket capacity or a pair budget the window
-        did not fit), and the capacity rung and budget it ended on. Lands
-        in ``snapshot()["join"]`` as the counters ``pairs``, ``windows``,
-        ``cap_retries``, ``budget_retries`` and the gauges ``cap``,
-        ``budget``. Per window, never per event."""
+        the vector passes the Pallas extraction took them out in
+        (``pairs ÷ peel_passes`` = hits a pass carries; 0 from the XLA
+        program, which has no such loop), the re-runs it took (a bucket
+        capacity or a pair budget the window did not fit), and the
+        capacity rung and budget it ended on. Lands in
+        ``snapshot()["join"]`` as the counters ``pairs``, ``peel_passes``,
+        ``windows``, ``cap_retries``, ``budget_retries`` and the gauges
+        ``cap``, ``budget``. Per window, never per event."""
         if not self.enabled:
             return
         with self._lock:
             j = self._join
-            for key, n in (("pairs", pairs), ("windows", 1),
-                           ("cap_retries", cap_retries),
+            for key, n in (("pairs", pairs), ("peel_passes", peel_passes),
+                           ("windows", 1), ("cap_retries", cap_retries),
                            ("budget_retries", budget_retries)):
                 j[key] = j.get(key, 0) + int(n)
             j["cap"], j["budget"] = int(cap), int(budget)
