@@ -901,19 +901,31 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
                     "run_wire_panes expects (3, n) uint16 plane-major "
                     f"panes, got {wire_p.dtype} {wire_p.shape}"
                 )
-            check_oid_range(wire_p[2].view(np.int16), num_segments)
+            # The uint16 bits, as the device upcasts them
+            # (ops/wire_knn.py:wire_plane_coords): an id with its top bit
+            # set — a negative int16 at the producer — reads 32768-65535
+            # there and the segment reductions would drop the point, so
+            # the unsigned view refuses it in the same pass as an id
+            # >= num_segments.
+            check_oid_range(wire_p[2], num_segments)
 
         i = pane0 - 1
         last_carry = self._wire_pane_carry
         for i, wire_p in enumerate(slides, start=pane0):
-            wire_p = np.asarray(wire_p)
-            check_pane(wire_p)
-            n = wire_p.shape[1]
-            nb = wire_pane_bucket(n)
-            if nb != n:
-                wire_p = np.concatenate(
-                    [wire_p, np.zeros((3, nb - n), np.uint16)], axis=1
-                )
+            # The host's share of a pane before it crosses: everything
+            # between the hand-over and ``ship``.
+            with telemetry.span("wire.prepare") as sp:
+                wire_p = np.asarray(wire_p)
+                check_pane(wire_p)
+                n = wire_p.shape[1]
+                nb = wire_pane_bucket(n)
+                if nb != n:
+                    wire_p = np.concatenate(
+                        [wire_p, np.zeros((3, nb - n), np.uint16)], axis=1
+                    )
+                # (the disabled-telemetry null span has no args)
+                getattr(sp, "args", {}).update(n=n, bucket=nb)
+            telemetry.record_wire_pane(n, nb)
             (wire_d,) = ship(wire_p)
             if jstep is None:
                 kind, step = select_wire_digest_step(

@@ -55,7 +55,8 @@ def wire_scale(span: float) -> float:
 class WireFormat:
     """Quantizer/dequantizer for one grid extent.
 
-    ``quantize`` runs host-side at the producer (serde/source layer);
+    ``quantize`` and ``pack_pane`` run host-side at the producer (the
+    serde/source layer, or a windowing tier outside this process);
     ``dequantize`` is jit-safe and fuses into the consuming kernel;
     ``dequantize_np`` is the host reference the parity tests compare
     against (bit-identical by the exactness contract above).
@@ -91,6 +92,43 @@ class WireFormat:
         """Host reference dequant (bit-identical to ``dequantize``)."""
         return (np.asarray(q, np.float32) * self.scale + self.origin)
 
+    def pack_pane(self, x, y, oid) -> np.ndarray:
+        """The producer half: one slide's points → one ``(3, n)`` uint16
+        PLANE-MAJOR pane (rows ``x_q``, ``y_q``, the interned int16 id's
+        bits), contiguous — what ``run_wire_panes`` takes.
+
+        ``x``, ``y``: float coordinates, quantised as ``quantize`` does
+        (clipped to the bbox); ``oid``: ids already interned. An id that
+        does not fit the int16 the format interns into is refused, never
+        wrapped: a wrapped id lands on another object's segment."""
+        x = np.asarray(x, np.float64)
+        y = np.asarray(y, np.float64)
+        oid = np.asarray(oid)
+        if not (x.ndim == y.ndim == oid.ndim == 1
+                and len(x) == len(y) == len(oid)):
+            raise ValueError(
+                "pack_pane expects three 1-D arrays of one length, got "
+                f"x {x.shape}, y {y.shape}, oid {oid.shape}"
+            )
+        n = len(x)
+        if n:
+            lo, hi = oid.min(), oid.max()
+            if lo < -0x8000 or hi > 0x7FFF:
+                raise ValueError(
+                    f"oid {lo if lo < -0x8000 else hi} does not fit the "
+                    "int16 the wire format interns ids into"
+                )
+        origin = self.origin.astype(np.float64)
+        scale = self.scale.astype(np.float64)
+        pane = np.empty((3, n), np.uint16)
+        for row, v in enumerate((x, y)):
+            # as ``quantize``; the store into the uint16 row is the cast
+            pane[row] = np.clip(
+                np.floor((v - origin[row]) / scale[row]), 0, U16_MAX
+            )
+        pane[2] = oid.astype(np.int16).view(np.uint16)
+        return pane
+
     @property
     def bytes_per_point(self) -> int:
         """uint16 x + uint16 y + int16 interned oid."""
@@ -110,7 +148,8 @@ class WirePaneAssembler:
     In-order streams only (the pane-path contract): a pane is emitted
     once an event at/after its end arrives, so an event earlier than
     the current pane raises rather than being silently mis-binned.
-    ``oid`` must already be interned into int16 range. ``flush()``
+    ``oid`` must already be interned into int16 range (``pack_pane``,
+    which packs every pane here, refuses one that is not). ``flush()``
     emits the final, possibly partial, pane at end of stream.
 
     ``state()``/``restore()`` snapshot the OPEN pane's buffered events
@@ -136,11 +175,7 @@ class WirePaneAssembler:
         self._pend_oid = np.zeros(0, np.int64)
 
     def _pack(self, xy, oid):
-        q = self._wf.quantize(xy)
-        o = np.asarray(oid, np.int16).view(np.uint16)
-        return np.ascontiguousarray(
-            np.concatenate([q, o[:, None]], axis=1).T
-        )
+        return self._wf.pack_pane(xy[:, 0], xy[:, 1], oid)
 
     def feed(self, ch) -> list:
         """One SoA chunk in → the panes it completed (possibly [])."""

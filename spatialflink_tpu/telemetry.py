@@ -323,6 +323,11 @@ class Telemetry:
         # pair budget in use) — snapshot()["join"], empty until the first
         # joined window.
         self._join: Dict[str, int] = {}
+        # Wire-pane kNN (operators/knn_query.py:run_wire_panes via
+        # record_wire_pane): panes taken, their points, the lanes shipped
+        # for them (each pane padded up to its bucket) and the padding's
+        # share of those — snapshot()["wire"], empty until the first pane.
+        self._wire: Dict[str, int] = {}
         # tids already named via a ph:"M" thread_name metadata event.
         self._named_tids: set = set()
         # Per-node attribution buckets: node name (or None = unscoped) →
@@ -1127,6 +1132,19 @@ class Telemetry:
                 j[key] = j.get(key, 0) + int(n)
             j["cap"], j["budget"] = int(cap), int(budget)
 
+    def record_wire_pane(self, n: int, bucket: int):
+        """One pane taken by ``run_wire_panes``: ``n`` points padded up to
+        ``bucket`` lanes before the ship. Lands in ``snapshot()["wire"]``
+        as the counters ``panes``, ``points`` (Σ n), ``lanes`` (Σ bucket)
+        and ``pad_lanes`` (Σ bucket − n). Per pane, never per event."""
+        if not self.enabled:
+            return
+        with self._lock:
+            w = self._wire
+            for key, v in (("panes", 1), ("points", n), ("lanes", bucket),
+                           ("pad_lanes", bucket - n)):
+                w[key] = w.get(key, 0) + int(v)
+
     # -- mesh-collective accounting (parallel/) --------------------------------
 
     def account_collective(self, kind: str, nbytes: int,
@@ -1601,6 +1619,8 @@ class Telemetry:
                                "bytes": self.shed_bytes}
             if self._join:
                 out["join"] = dict(self._join)
+            if self._wire:
+                out["wire"] = dict(self._wire)
         if self.overload_provider is not None:
             try:
                 out["overload"] = json_safe(self.overload_provider())  # sfcheck: ok=lock-discipline -- stream-flush checkpoints call this under Telemetry._lock by design; the provider contract (documented at overload.OverloadController._lock) forbids providers from taking telemetry's lock — overload queues transition emits for after release
