@@ -32,6 +32,8 @@ import math
 
 import numpy as np
 
+from spatialflink_tpu.telemetry import telemetry
+
 U16_MAX = 65535
 
 
@@ -92,6 +94,38 @@ class WireFormat:
         """Host reference dequant (bit-identical to ``dequantize``)."""
         return (np.asarray(q, np.float32) * self.scale + self.origin)
 
+    def check_oid(self, oid: np.ndarray) -> None:
+        """An id that does not fit the int16 the format interns into is
+        refused, never wrapped: a wrapped id lands on another object's
+        segment."""
+        if len(oid):
+            lo, hi = oid.min(), oid.max()
+            if lo < -0x8000 or hi > 0x7FFF:
+                raise ValueError(
+                    f"oid {lo if lo < -0x8000 else hi} does not fit the "
+                    "int16 the wire format interns ids into"
+                )
+
+    def quantize_into(self, out: np.ndarray, x: np.ndarray, y: np.ndarray,
+                      oid: np.ndarray) -> None:
+        """The one per-row quantiser: float64 ``x``, ``y`` and checked
+        ``oid`` (``check_oid``) of one length n → the ``(3, n)`` uint16
+        ``out`` (rows ``x_q``, ``y_q``, the int16 id's bits). ``out`` may
+        be a column slice of a larger plane-major buffer. ``pack_pane``
+        and ``WirePaneAssembler`` both write through here, so a pane is
+        the same bytes whichever chunks it arrived in."""
+        for row, v in enumerate((x, y)):
+            # as ``quantize``, in float64: clip(floor((v − origin) /
+            # scale), 0, 65535), the clip as its two ufuncs; the store
+            # into the uint16 row is the cast
+            q = v - float(self.origin[row])
+            q /= float(self.scale[row])
+            np.floor(q, out=q)
+            np.maximum(q, 0.0, out=q)
+            np.minimum(q, float(U16_MAX), out=q)
+            out[row] = q
+        np.copyto(out[2].view(np.int16), oid, casting="unsafe")
+
     def pack_pane(self, x, y, oid) -> np.ndarray:
         """The producer half: one slide's points → one ``(3, n)`` uint16
         PLANE-MAJOR pane (rows ``x_q``, ``y_q``, the interned int16 id's
@@ -110,23 +144,9 @@ class WireFormat:
                 "pack_pane expects three 1-D arrays of one length, got "
                 f"x {x.shape}, y {y.shape}, oid {oid.shape}"
             )
-        n = len(x)
-        if n:
-            lo, hi = oid.min(), oid.max()
-            if lo < -0x8000 or hi > 0x7FFF:
-                raise ValueError(
-                    f"oid {lo if lo < -0x8000 else hi} does not fit the "
-                    "int16 the wire format interns ids into"
-                )
-        origin = self.origin.astype(np.float64)
-        scale = self.scale.astype(np.float64)
-        pane = np.empty((3, n), np.uint16)
-        for row, v in enumerate((x, y)):
-            # as ``quantize``; the store into the uint16 row is the cast
-            pane[row] = np.clip(
-                np.floor((v - origin[row]) / scale[row]), 0, U16_MAX
-            )
-        pane[2] = oid.astype(np.int16).view(np.uint16)
+        self.check_oid(oid)
+        pane = np.empty((3, len(x)), np.uint16)
+        self.quantize_into(pane, x, y, oid)
         return pane
 
     @property
@@ -145,14 +165,36 @@ class WirePaneAssembler:
     EVERY pane in order is emitted, including empty (3, 0) panes in
     event-time gaps, so downstream window indexing stays aligned.
 
+    WRITE-ONCE: the open pane is held as what it will be — a ``(3, cap)``
+    uint16 plane-major buffer and a fill count — and each chunk is
+    quantised once, straight into ``buf[:, fill:fill+n]``
+    (``WireFormat.quantize_into``, the function ``pack_pane`` packs
+    with, so a pane is byte-identical to ``pack_pane`` of its rows). No
+    float64 row and no timestamp outlives ``feed``; only the last
+    timestamp is kept, for the in-order check. ``cap`` starts small,
+    doubles when a chunk does not fit and stays at what the stream's
+    panes reached. A closed pane is emitted as ONE contiguous copy of
+    ``buf[:, :fill]``: the caller owns it and the assembler never writes
+    it again (``buf`` is wider than the pane, so a column slice of it is
+    not contiguous, and a pane that aliased it would change under the
+    windows that still hold it).
+
     In-order streams only (the pane-path contract): a pane is emitted
     once an event at/after its end arrives, so an event earlier than
     the current pane raises rather than being silently mis-binned.
-    ``oid`` must already be interned into int16 range (``pack_pane``,
-    which packs every pane here, refuses one that is not). ``flush()``
-    emits the final, possibly partial, pane at end of stream.
+    ``oid`` must already be interned into int16 range: one that is not
+    is refused at the ``feed`` that brings it. A refused chunk (order,
+    id, shape) leaves the assembler as it was. ``flush()`` emits the
+    final, possibly partial, pane at end of stream.
 
-    ``state()``/``restore()`` snapshot the OPEN pane's buffered events
+    With telemetry on, every closed pane records once
+    (``telemetry.record_wire_assembler`` → ``snapshot()["wire"]``) the
+    chunks and rows taken in since the last record, the rows copied
+    after their first write (regrowth + the emitted copy) and the
+    regrowths: ``assembler_rows_moved ÷ assembler_rows`` is the copy
+    amplification, 1.0 in a steady stream.
+
+    ``state()``/``restore()`` snapshot the OPEN pane's quantised rows
     + position (checkpoint.py:wire_pane_assembler_state): together
     with the consumer offsets and the operator digest ring, the whole
     wire pipeline resumes
@@ -165,68 +207,102 @@ class WirePaneAssembler:
     barrier any checkpointing runtime imposes.
     """
 
+    #: columns the open pane's buffer starts with; it doubles from here
+    _MIN_CAP = 1024
+
     def __init__(self, wire_format: WireFormat, slide_ms: int,
                  start_ms: int):
         self._wf = wire_format
         self._slide = int(slide_ms)
         self._cur = int(start_ms)
-        self._pend_ts = np.zeros(0, np.int64)
-        self._pend_xy = np.zeros((0, 2), np.float64)
-        self._pend_oid = np.zeros(0, np.int64)
+        self._last = self._cur  # newest timestamp taken in
+        self._buf = np.empty((3, self._MIN_CAP), np.uint16)
+        self._fill = 0
+        # what the next telemetry record carries (one a closed pane)
+        self._chunks = self._rows = self._moved = self._grows = 0
 
-    def _pack(self, xy, oid):
-        return self._wf.pack_pane(xy[:, 0], xy[:, 1], oid)
+    def _reserve(self, need: int) -> None:
+        """Room for ``need`` columns: double until they fit, keep the
+        open pane's rows."""
+        cap = self._buf.shape[1]
+        if need <= cap:
+            return
+        while cap < need:
+            cap *= 2
+        grown = np.empty((3, cap), np.uint16)
+        grown[:, :self._fill] = self._buf[:, :self._fill]
+        self._buf = grown
+        self._moved += self._fill
+        self._grows += 1
+
+    def _take(self, x, y, oid) -> None:
+        """Quantise checked rows into the open pane, once."""
+        end = self._fill + len(x)
+        self._reserve(end)
+        self._wf.quantize_into(self._buf[:, self._fill:end], x, y, oid)
+        self._rows += end - self._fill
+        self._fill = end
+
+    def _close(self) -> np.ndarray:
+        """The open pane as the caller's own contiguous array; the next
+        pane opens on the same buffer."""
+        pane = self._buf[:, :self._fill].copy()
+        self._moved += self._fill
+        self._fill = 0
+        self._cur += self._slide
+        telemetry.record_wire_assembler(self._chunks, self._rows,
+                                        self._moved, self._grows)
+        self._chunks = self._rows = self._moved = self._grows = 0
+        return pane
 
     def feed(self, ch) -> list:
         """One SoA chunk in → the panes it completed (possibly [])."""
         ts = np.asarray(ch["ts"], np.int64)
         if len(ts) == 0:
             return []
-        xy = np.stack(
-            [np.asarray(ch["x"], np.float64),
-             np.asarray(ch["y"], np.float64)], axis=1
-        )
+        x = np.asarray(ch["x"], np.float64)
+        y = np.asarray(ch["y"], np.float64)
         oid = np.asarray(ch["oid"])
+        # Everything that can refuse the chunk comes before the first
+        # write: a refused chunk leaves the assembler as it was.
+        if not (ts.ndim == x.ndim == y.ndim == oid.ndim == 1
+                and len(ts) == len(x) == len(y) == len(oid)):
+            raise ValueError(
+                "a chunk is four 1-D columns of one length, got "
+                f"ts {ts.shape}, x {x.shape}, y {y.shape}, oid {oid.shape}"
+            )
         # Full in-order check: against the open pane, against the
-        # pending tail, AND within the chunk (searchsorted below is a
-        # binary search — unsorted input would silently mis-bin).
-        prev_last = (int(self._pend_ts[-1]) if len(self._pend_ts)
-                     else self._cur)
-        if int(ts[0]) < max(self._cur, prev_last) or (
-                len(ts) > 1 and bool(np.any(np.diff(ts) < 0))):
+        # previous chunk's last timestamp, AND within the chunk
+        # (searchsorted below is a binary search — unsorted input would
+        # silently mis-bin).
+        if (int(ts[0]) < max(self._cur, self._last)
+                or bool((ts[1:] < ts[:-1]).any())):
             raise ValueError(
                 "out-of-order event stream: wire panes require "
                 "non-decreasing timestamps (the pane-path contract); "
                 f"open pane starts at {self._cur} ms"
             )
-        self._pend_ts = np.concatenate([self._pend_ts, ts])
-        self._pend_xy = np.concatenate([self._pend_xy, xy])
-        self._pend_oid = np.concatenate([self._pend_oid, oid])
+        self._wf.check_oid(oid)
+        self._chunks += 1
         # Emit every pane strictly BEFORE the newest event's pane (the
         # in-order watermark: a later event closes all earlier panes).
         out = []
-        newest = int(self._pend_ts[-1])
+        newest = int(ts[-1])
+        lo = 0
         while self._cur + self._slide <= newest:
-            hi = int(np.searchsorted(
-                self._pend_ts, self._cur + self._slide, "left"
-            ))
-            out.append(self._pack(self._pend_xy[:hi], self._pend_oid[:hi]))
-            self._pend_ts = self._pend_ts[hi:]
-            self._pend_xy = self._pend_xy[hi:]
-            self._pend_oid = self._pend_oid[hi:]
-            self._cur += self._slide
+            hi = int(np.searchsorted(ts, self._cur + self._slide, "left"))
+            self._take(x[lo:hi], y[lo:hi], oid[lo:hi])
+            out.append(self._close())
+            lo = hi
+        self._take(x[lo:], y[lo:], oid[lo:])
+        self._last = newest
         return out
 
     def flush(self) -> list:
         """End of stream: the open pane's events as one final pane."""
-        if not len(self._pend_ts):
+        if not self._fill:
             return []
-        out = [self._pack(self._pend_xy, self._pend_oid)]
-        self._pend_ts = np.zeros(0, np.int64)
-        self._pend_xy = np.zeros((0, 2), np.float64)
-        self._pend_oid = np.zeros(0, np.int64)
-        self._cur += self._slide
-        return out
+        return [self._close()]
 
     def state(self) -> dict:
         return {
@@ -236,9 +312,9 @@ class WirePaneAssembler:
             # grid extent must not restore into another
             "wire_origin": [float(v) for v in self._wf.origin],
             "wire_scale": [float(v) for v in self._wf.scale],
-            "pend_ts": np.asarray(self._pend_ts),
-            "pend_xy": np.asarray(self._pend_xy),
-            "pend_oid": np.asarray(self._pend_oid),
+            # the open pane as it will be emitted: 6 B a buffered point
+            "pane": self._buf[:, :self._fill].copy(),
+            "last_ts": int(self._last),
         }
 
     def restore(self, state: dict) -> None:
@@ -257,10 +333,31 @@ class WirePaneAssembler:
                 "checkpoint wire format (origin/scale) does not match "
                 "this assembler's grid extent"
             )
-        self._cur = int(state["cur"])
-        self._pend_ts = np.asarray(state["pend_ts"], np.int64)
-        self._pend_xy = np.asarray(state["pend_xy"], np.float64)
-        self._pend_oid = np.asarray(state["pend_oid"])
+        cur = int(state["cur"])
+        pane = state.get("pane")
+        if pane is None:
+            # The form every checkpoint had before the open pane was held
+            # quantised: float64 rows and their timestamps. Quantising is
+            # pointwise, so the resumed pane is the same bytes.
+            ts = np.asarray(state["pend_ts"], np.int64)
+            xy = np.asarray(state["pend_xy"], np.float64).reshape(-1, 2)
+            pane = self._wf.pack_pane(xy[:, 0], xy[:, 1], state["pend_oid"])
+            last = int(ts[-1]) if len(ts) else cur
+        else:
+            pane = np.asarray(pane, np.uint16)
+            if pane.ndim != 2 or pane.shape[0] != 3:
+                raise ValueError(
+                    "checkpoint pane must be plane-major (3, n) uint16, "
+                    f"got {pane.shape}"
+                )
+            last = int(state.get("last_ts", cur))
+        n = pane.shape[1]
+        self._fill = 0
+        self._reserve(n)
+        self._buf[:, :n] = pane
+        self._fill = n
+        self._cur = cur
+        self._last = last
 
 
 def wire_panes(chunks, wire_format: WireFormat, slide_ms: int,

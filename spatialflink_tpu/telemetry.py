@@ -326,7 +326,9 @@ class Telemetry:
         # Wire-pane kNN (operators/knn_query.py:run_wire_panes via
         # record_wire_pane): panes taken, their points, the lanes shipped
         # for them (each pane padded up to its bucket) and the padding's
-        # share of those — snapshot()["wire"], empty until the first pane.
+        # share of those; the assembler_* counters of the pane assembler
+        # that feeds it (streams/wire.py via record_wire_assembler) —
+        # snapshot()["wire"], empty until the first pane.
         self._wire: Dict[str, int] = {}
         # tids already named via a ph:"M" thread_name metadata event.
         self._named_tids: set = set()
@@ -1143,6 +1145,26 @@ class Telemetry:
             w = self._wire
             for key, v in (("panes", 1), ("points", n), ("lanes", bucket),
                            ("pad_lanes", bucket - n)):
+                w[key] = w.get(key, 0) + int(v)
+
+    def record_wire_assembler(self, chunks: int, rows: int,
+                              rows_moved: int, grows: int):
+        """One pane closed by ``streams/wire.py:WirePaneAssembler``: the
+        chunks and rows it took in since its last record, the rows it
+        copied after their first write (buffer regrowth + the emitted
+        copy) and the regrowths. Lands in ``snapshot()["wire"]`` as the
+        counters ``assembler_chunks``, ``assembler_rows``,
+        ``assembler_rows_moved``, ``assembler_grows``;
+        ``assembler_rows_moved ÷ assembler_rows`` is the copy
+        amplification. Per closed pane, never per chunk or event."""
+        if not self.enabled:
+            return
+        with self._lock:
+            w = self._wire
+            for key, v in (("assembler_chunks", chunks),
+                           ("assembler_rows", rows),
+                           ("assembler_rows_moved", rows_moved),
+                           ("assembler_grows", grows)):
                 w[key] = w.get(key, 0) + int(v)
 
     # -- mesh-collective accounting (parallel/) --------------------------------

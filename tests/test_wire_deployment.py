@@ -11,7 +11,13 @@ public producer half and hands it straight to ``run_wire_panes``.
   ladder, sizes that change from pane to pane, empty panes in a gap;
 - the direct route and the SoA -> assembler route give the same windows;
 - bad producer input is refused, never wrapped or dropped;
-- ``wire.prepare`` once a pane, ``snapshot()["wire"]`` counting.
+- ``wire.prepare`` once a pane, ``snapshot()["wire"]`` counting;
+- the write-once ``WirePaneAssembler``: its panes equal ``pack_pane`` of the
+  same rows whatever the chunking (gaps, boundaries, lists, float32, a
+  buffer that grows), an emitted pane is the caller's own, a refused chunk
+  leaves the assembler as it was, a snapshot (today's form and the
+  ``pend_*`` form every older checkpoint has) resumes to the same panes,
+  and the ``assembler_*`` counters record once a closed pane.
 """
 
 import numpy as np
@@ -183,13 +189,14 @@ def test_pack_pane_refuses_an_id_outside_int16():
             WF.pack_pane(x, y, oid2)
     with pytest.raises(ValueError, match="one length"):
         WF.pack_pane(x, y[:-1], oid)
-    # the assembler packs through the same half: it refuses too, where it
-    # used to wrap the id onto another object's segment
+    # the assembler quantises through the same half: it refuses too, at the
+    # feed that brings the id, where it once wrapped the id onto another
+    # object's segment
     asm = WirePaneAssembler(WF, SLIDE_MS, T0)
-    asm.feed({"ts": np.asarray([T0]), "x": x[:1], "y": y[:1],
-              "oid": np.asarray([40_000])})
     with pytest.raises(ValueError, match="int16"):
-        asm.flush()
+        asm.feed({"ts": np.asarray([T0]), "x": x[:1], "y": y[:1],
+                  "oid": np.asarray([40_000])})
+    assert asm.flush() == []
 
 
 def test_wire_prepare_span_and_wire_counters_once_a_pane():
@@ -217,3 +224,342 @@ def test_wire_prepare_span_and_wire_counters_once_a_pane():
     assert wire == {"panes": len(sizes), "points": sum(sizes),
                     "lanes": sum(buckets),
                     "pad_lanes": sum(buckets) - sum(sizes)}
+
+
+# -- the write-once assembler ------------------------------------------------
+
+def _stream(sizes, seed=0):
+    """Rows of consecutive panes of the given sizes, in time order: the four
+    columns and ``pack_pane`` of each pane's rows."""
+    rng = np.random.default_rng(seed)
+    x, y, oid = _events(rng, sum(sizes), ids=16_384)
+    ts = np.concatenate([
+        T0 + i * SLIDE_MS + np.sort(rng.integers(0, SLIDE_MS, n))
+        for i, n in enumerate(sizes)]).astype(np.int64)
+    edges = np.concatenate([[0], np.cumsum(sizes)])
+    packed = [WF.pack_pane(x[a:b], y[a:b], oid[a:b])
+              for a, b in zip(edges[:-1], edges[1:])]
+    return {"ts": ts, "x": x, "y": y, "oid": oid}, packed
+
+
+def _cut(cols, a, b):
+    return {key: v[a:b] for key, v in cols.items()}
+
+
+def _feed_all(asm, cols, chunk, lo=0):
+    n = len(cols["ts"])
+    return [p for a in range(lo, n, chunk)
+            for p in asm.feed(_cut(cols, a, min(a + chunk, n)))]
+
+
+def _same_panes(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == np.uint16 and g.shape == w.shape
+        assert g.flags.c_contiguous
+        assert g.tobytes() == w.tobytes()
+
+
+def _state_bytes(asm):
+    st = asm.state()
+    return {key: (v.tobytes(), v.shape) if isinstance(v, np.ndarray) else v
+            for key, v in st.items()}
+
+
+CHUNKINGS = {"1": 1, "999": 999, "10000": 10_000, "one_chunk": 10 ** 9}
+
+
+@pytest.mark.parametrize("chunking", sorted(CHUNKINGS))
+def test_assembler_panes_equal_pack_pane_whatever_the_chunking(chunking):
+    # 10,000 rows span panes; "one_chunk" is larger than every pane
+    cols, packed = _stream([6_000, 4_500, 0, 5_500, 14_000], seed=21)
+    asm = WirePaneAssembler(WF, SLIDE_MS, T0)
+    got = _feed_all(asm, cols, CHUNKINGS[chunking]) + asm.flush()
+    _same_panes(got, packed)
+    assert asm.flush() == [] and asm.state()["pane"].shape == (3, 0)
+
+
+def test_assembler_chunk_across_a_gap_closes_three_panes_in_order():
+    cols, packed = _stream([700, 0, 0, 650], seed=22)
+    asm = WirePaneAssembler(WF, SLIDE_MS, T0)
+    assert asm.feed(_cut(cols, 0, 300)) == []
+    got = asm.feed(_cut(cols, 300, 1_000))  # rows of panes 0 and 3
+    assert [p.shape for p in got] == [(3, 700), (3, 0), (3, 0)]
+    _same_panes(got + asm.feed(_cut(cols, 1_000, 1_350)) + asm.flush(),
+                packed)
+
+
+@pytest.mark.parametrize("last", ["just_before", "on_the_boundary"])
+def test_assembler_chunk_ending_at_a_pane_boundary(last):
+    cols, packed = _stream([400, 300], seed=23)
+    cols["ts"][399] = T0 + SLIDE_MS - 1
+    cols["ts"][400] = T0 + SLIDE_MS  # the first instant of pane 1
+    asm = WirePaneAssembler(WF, SLIDE_MS, T0)
+    if last == "just_before":
+        assert asm.feed(_cut(cols, 0, 400)) == []  # nothing closes it yet
+        got = asm.feed(_cut(cols, 400, 700))
+    else:
+        got = asm.feed(_cut(cols, 0, 401))  # the boundary row joins pane 1
+        assert asm.state()["pane"].shape == (3, 1)
+        got = got + asm.feed(_cut(cols, 401, 700))
+    _same_panes(got + asm.flush(), packed)
+
+
+def _as_lists(cols):
+    return {key: v.tolist() for key, v in cols.items()}, T0
+
+
+def _as_float32(cols):
+    return dict(cols, x=cols["x"].astype(np.float32),
+                y=cols["y"].astype(np.float32)), T0
+
+
+def _as_int32(cols):
+    # times since the stream's start, so that they fit
+    return dict(cols, ts=(cols["ts"] - T0).astype(np.int32),
+                oid=cols["oid"].astype(np.int32)), 0
+
+
+INPUT_FORMS = {"lists": _as_lists, "float32_coordinates": _as_float32,
+               "int32_ids_and_times": _as_int32}
+
+
+@pytest.mark.parametrize("form", sorted(INPUT_FORMS))
+def test_assembler_takes_lists_and_any_numeric_dtype(form):
+    sizes = [350, 0, 420]
+    cols, _ = _stream(sizes, seed=24)
+    conv, start = INPUT_FORMS[form](cols)
+    asm = WirePaneAssembler(WF, SLIDE_MS, start)
+    got = _feed_all(asm, conv, 100) + asm.flush()
+    # pack_pane of what was handed in (float32 upcasts exactly to float64)
+    edges = np.concatenate([[0], np.cumsum(sizes)])
+    _same_panes(got, [WF.pack_pane(conv["x"][a:b], conv["y"][a:b],
+                                   conv["oid"][a:b])
+                      for a, b in zip(edges[:-1], edges[1:])])
+
+
+def _assembler_counters():
+    return {key[len("assembler_"):]: v
+            for key, v in telemetry.snapshot().get("wire", {}).items()
+            if key.startswith("assembler_")}
+
+
+def test_assembler_buffer_grows_for_a_pane_four_times_the_last():
+    sizes = [2_000, 8_000, 8_000, 1_000]
+    cols, packed = _stream(sizes, seed=25)
+    bounds = np.cumsum(sizes)
+    asm = WirePaneAssembler(WF, SLIDE_MS, T0)
+    telemetry.enable()
+    try:
+        got, grows = [], []
+        for a, b in zip(np.concatenate([[0], bounds[:-1]]), bounds):
+            # a pane's rows in 500-row chunks; the next pane's first closes it
+            got += _feed_all(asm, _cut(cols, a, b), 500)
+            grows.append(_assembler_counters().get("grows", 0))
+        got += asm.flush()
+        final = _assembler_counters()
+    finally:
+        telemetry.disable()
+    _same_panes(got, packed)
+    # recorded when pane i closes, i.e. while pane i+1 arrives: 1,024 -> 2,048
+    # for the first pane, -> 4,096 -> 8,192 for the second, then never again
+    assert grows == [0, 1, 3, 3] and final["grows"] == 3
+    assert final["rows"] == sum(sizes)
+
+
+def test_an_emitted_pane_is_the_callers_own():
+    cols, packed = _stream([900, 1_100, 1_000, 950], seed=26)
+    asm = WirePaneAssembler(WF, SLIDE_MS, T0)
+    (first,) = _feed_all(asm, _cut(cols, 0, 1_000), 100)
+    assert first.flags.c_contiguous and first.flags.owndata
+    held = first.tobytes()
+    assert held == packed[0].tobytes()
+    later = _feed_all(asm, cols, 100, lo=1_000) + asm.flush()
+    assert len(later) == 3  # feed went on for more than two panes
+    assert first.tobytes() == held
+    first[:] = 0  # and the caller may write it: the assembler never reads it
+    _same_panes(later, packed[1:])
+
+
+def _bad_id(cols, prev_last):
+    cols["oid"][450] = 40_000  # past the pane boundary at the chunk's row 300
+
+
+def _before_the_open_pane(cols, prev_last):
+    cols["ts"][:] -= 2 * SLIDE_MS
+
+
+def _before_the_last_chunk(cols, prev_last):
+    cols["ts"][0] = prev_last - 1  # still inside the open pane
+
+
+def _inside_the_chunk(cols, prev_last):
+    cols["ts"][450] = cols["ts"][449] - 1
+
+
+def _two_lengths(cols, prev_last):
+    cols["x"] = cols["x"][:-1]
+
+
+REFUSED = {
+    "id_outside_int16": (_bad_id, "int16"),
+    "before_the_open_pane": (_before_the_open_pane, "out-of-order"),
+    "before_the_previous_chunks_last": (_before_the_last_chunk,
+                                        "out-of-order"),
+    "inside_the_chunk": (_inside_the_chunk, "out-of-order"),
+    "columns_of_two_lengths": (_two_lengths, "one length"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFUSED))
+def test_a_refused_chunk_leaves_the_assembler_as_it_was(case):
+    """The offending chunk spans a pane boundary: an assembler that wrote
+    before it checked would have closed pane 1 or kept half the chunk."""
+    spoil, match = REFUSED[case]
+    cols, packed = _stream([500, 400, 300], seed=27)
+    asm = WirePaneAssembler(WF, SLIDE_MS, T0)
+    got = _feed_all(asm, _cut(cols, 0, 600), 200)  # 100 rows into pane 1
+    prev_last = int(cols["ts"][599])
+    assert prev_last - 1 > T0 + SLIDE_MS
+    before = _state_bytes(asm)
+    bad = {key: v.copy() for key, v in _cut(cols, 600, 1_100).items()}
+    spoil(bad, prev_last)
+    with pytest.raises(ValueError, match=match):
+        asm.feed(bad)
+    assert _state_bytes(asm) == before
+    got += asm.feed(_cut(cols, 600, 1_100)) + _feed_all(asm, cols, 50, 1_100)
+    _same_panes(got + asm.flush(), packed)
+
+
+CUTS = {"before_anything": 0, "inside_a_pane": 1_234,
+        "first_row_of_a_pane": 2_001, "after_a_gap": 3_501,
+        "everything_fed": 5_000}
+
+
+@pytest.mark.parametrize("cut", sorted(CUTS))
+def test_assembler_snapshot_resumes_to_the_uninterrupted_panes(cut):
+    cols, packed = _stream([2_000, 1_500, 0, 0, 1_500], seed=28)
+    at = CUTS[cut]
+    asm = WirePaneAssembler(WF, SLIDE_MS, T0)
+    got = _feed_all(asm, _cut(cols, 0, at), 333)
+    snap = asm.state()
+    assert sorted(snap) == ["cur", "last_ts", "pane", "slide_ms",
+                            "wire_origin", "wire_scale"]
+    assert snap["pane"].dtype == np.uint16  # 6 B a buffered point
+    asm.feed(_cut(cols, at, at + 100))  # the snapshot is a copy, not a view
+    resumed = WirePaneAssembler(WF, SLIDE_MS, start_ms=0)
+    resumed.restore(snap)
+    got += _feed_all(resumed, cols, 333, lo=at) + resumed.flush()
+    _same_panes(got, packed)
+    # the in-order refusal survives the restore
+    again = WirePaneAssembler(WF, SLIDE_MS, start_ms=0)
+    again.restore(snap)
+    if at:
+        with pytest.raises(ValueError, match="out-of-order"):
+            again.feed({"ts": [int(cols["ts"][at - 1]) - 1], "x": [116.0],
+                        "y": [40.0], "oid": [1]})
+
+
+def _legacy_snapshot(cols, at, identity=True):
+    """What ``state()`` wrote before the open pane was held quantised: the
+    pending float64 rows and their timestamps."""
+    cur = T0 + (int(cols["ts"][at - 1]) - T0) // SLIDE_MS * SLIDE_MS \
+        if at else T0
+    lo = int(np.searchsorted(cols["ts"], cur, "left"))
+    snap = {"cur": cur,
+            "pend_ts": cols["ts"][lo:at].copy(),
+            "pend_xy": np.stack([cols["x"][lo:at], cols["y"][lo:at]], axis=1),
+            "pend_oid": cols["oid"][lo:at].copy()}
+    if identity:
+        snap.update(slide_ms=SLIDE_MS,
+                    wire_origin=[float(v) for v in WF.origin],
+                    wire_scale=[float(v) for v in WF.scale])
+    return snap, lo
+
+
+@pytest.mark.parametrize("identity", [True, False],
+                         ids=["with_identity_keys", "oldest_form"])
+@pytest.mark.parametrize("cut", ["before_anything", "inside_a_pane",
+                                 "after_a_gap"])
+def test_a_pend_form_checkpoint_restores_to_the_same_panes(cut, identity):
+    cols, packed = _stream([2_000, 1_500, 0, 0, 1_500], seed=29)
+    at = CUTS[cut]
+    snap, lo = _legacy_snapshot(cols, at, identity)
+    asm = WirePaneAssembler(WF, SLIDE_MS, start_ms=0)
+    asm.restore(snap)
+    now = asm.state()
+    want = WF.pack_pane(cols["x"][lo:at], cols["y"][lo:at],
+                        cols["oid"][lo:at])
+    assert now["pane"].tobytes() == want.tobytes()
+    assert now["cur"] == snap["cur"]
+    assert now["last_ts"] == (int(cols["ts"][at - 1]) if at > lo
+                              else snap["cur"])
+    got = _feed_all(asm, cols, 333, lo=at) + asm.flush()
+    done = (snap["cur"] - T0) // SLIDE_MS  # panes closed before the snapshot
+    _same_panes(got, packed[done:])
+
+
+@pytest.mark.parametrize("form", ["pane", "pend"])
+@pytest.mark.parametrize("what", ["slide_ms", "wire_format", "pane_shape"])
+def test_restore_refuses_another_slide_or_wire_format(form, what):
+    cols, _ = _stream([800], seed=30)
+    if form == "pane":
+        asm = WirePaneAssembler(WF, SLIDE_MS, T0)
+        asm.feed(_cut(cols, 0, 500))
+        snap = asm.state()
+    else:
+        snap, _ = _legacy_snapshot(cols, 500)
+    if what == "slide_ms":
+        other, match = WirePaneAssembler(WF, SLIDE_MS // 5, T0), "slide_ms"
+    elif what == "wire_format":
+        other = WirePaneAssembler(WireFormat(0.0, 20.0, 0.0, 20.0),
+                                  SLIDE_MS, T0)
+        match = "wire format"
+    elif form == "pane":
+        other, match = WirePaneAssembler(WF, SLIDE_MS, T0), "plane-major"
+        snap["pane"] = np.ascontiguousarray(snap["pane"].T)  # (n, 3)
+    else:
+        other, match = WirePaneAssembler(WF, SLIDE_MS, T0), "one length"
+        snap["pend_xy"] = snap["pend_xy"][:-1]  # a row short
+    before = _state_bytes(other)
+    with pytest.raises(ValueError, match=match):
+        other.restore(snap)
+    assert _state_bytes(other) == before
+
+
+def test_assembler_counters_once_a_closed_pane(monkeypatch):
+    sizes = [50_000] * 4 + [20_000]  # the last pane stays open
+    cols, packed = _stream(sizes, seed=31)
+    records = []
+    record = telemetry.record_wire_assembler
+    monkeypatch.setattr(
+        telemetry, "record_wire_assembler",
+        lambda *a: (records.append(a), record(*a))[1])
+    # telemetry off: the hook is called once a pane and records nothing
+    idle = telemetry.snapshot()
+    asm = WirePaneAssembler(WF, SLIDE_MS, T0)
+    _same_panes(_feed_all(asm, cols, 10_000), packed[:4])
+    assert len(records) == 4 and telemetry.snapshot() == idle
+    del records[:]
+    asm = WirePaneAssembler(WF, SLIDE_MS, T0)
+    telemetry.enable()
+    try:
+        first = _feed_all(asm, _cut(cols, 0, 60_000), 10_000)
+        warm = _assembler_counters()
+        rest = _feed_all(asm, cols, 10_000, lo=60_000)
+        total = _assembler_counters()
+    finally:
+        telemetry.disable()
+    _same_panes(first + rest, packed[:4])
+    assert len(records) == 4  # one a closed pane, none a chunk
+    assert sorted(total) == ["chunks", "grows", "rows", "rows_moved"]
+    # rows and chunks of closed panes only: the chunk that closes a pane is
+    # counted with it, its rows past the boundary with the next
+    assert total["rows"] == 200_000 and total["chunks"] == 21
+    assert warm["rows"] == 50_000 and warm["grows"] >= 1
+    # a steady stream: every row written once and copied once, at the
+    # hand-over; the buffer keeps the capacity the first pane reached
+    steady = {key: total[key] - warm[key] for key in total}
+    assert steady["grows"] == 0
+    assert steady["rows_moved"] == steady["rows"] == 150_000
+    assert total["rows_moved"] / total["rows"] < 1.5  # the first pane's growth
