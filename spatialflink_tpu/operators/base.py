@@ -15,6 +15,7 @@ CountBased uses count windows.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Callable, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -341,10 +342,15 @@ def device_point_args(grid: UniformGrid, xy64: np.ndarray, oid, dtype):
 
 
 def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
-                      dtype=np.float64):
+                      dtype=np.float64, span: Optional[str] = None):
     """SoA windows → (window, padded arrays) for the run_soa fast paths.
 
     Yields (win, xy, valid, cell, oid) per the device_point_args contract.
+    ``span`` names a telemetry span a window around its materialisation:
+    from the chunk that lets the window fire, before the assembler
+    consolidates (a further window of the same firing: from its slice), to
+    the padded arrays (args ``n``, ``bucket``); the chunks appended before
+    are outside it, and a firing with no window has none. It holds no leaf.
     """
     from spatialflink_tpu.streams.soa import SoaWindowAssembler
 
@@ -354,7 +360,8 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
         conf.window_size_ms, conf.slide_step_ms,
         ooo_ms=conf.allowed_lateness_ms,
     )
-    for win in asm.stream(chunks):
+
+    def batch(win):
         if counters.enabled:
             # Throughput meter for the SoA path (Point.java:237-253 analog);
             # candidate tallies come from the operator (it owns the flags).
@@ -364,7 +371,27 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
              np.asarray(win.arrays["y"], np.float64)],
             axis=1,
         )
-        yield (win, *device_point_args(grid, xy64, win.arrays.get("oid"), dtype))
+        return (win, *device_point_args(grid, xy64, win.arrays.get("oid"), dtype))
+
+    def fired(fire):
+        """One firing's windows, padded. Under ``span`` each is timed from
+        the firing's start (or the hand-back of the window before) to its
+        padded arrays; a firing that gives no window emits nothing."""
+        if span is None or not telemetry.enabled:
+            yield from map(batch, fire())
+            return
+        t0 = time.perf_counter_ns()
+        for win in fire():
+            item = batch(win)
+            telemetry.emit_span(span, t0, time.perf_counter_ns() - t0,
+                                n=win.count, bucket=len(item[2]))
+            yield item
+            t0 = time.perf_counter_ns()
+
+    for c in chunks:
+        if asm.take(c):
+            yield from fired(asm.fire)
+    yield from fired(asm.flush)
 
 
 @functools.lru_cache(maxsize=None)

@@ -61,11 +61,29 @@ class _PointStreamRangeQuery(SpatialOperator):
 
     query_kind = "point"
 
+    def __init__(self, conf, grid, mesh=None):
+        super().__init__(conf, grid, mesh)
+        # The pruned polygon kernels' knobs — candidates a point, and the
+        # compact kernel's lane budget. They persist across windows and
+        # runs: crowded data pays its re-run once.
+        self._ncand = 8
+        self._cand_budget = 4096
+        #: the kernel the newest evaluator built: "points", "polylines",
+        #: or for a polygon set "dense", "pruned", "pruned_compact"
+        self.last_range_kernel = None
+
     def _window_evaluator(self, query_set, flags, radius, dtype, mesh):
-        """Build ``eval(common) -> (keep, dist)`` for this family's query
-        kind — ONE place for kernel selection, query packing, and the
-        polygon pruned/compact overflow-retry machinery (budgets persist
-        on the operator). Shared by run() and run_soa().
+        """Build ``(launch, settle)`` for this family's query kind — ONE
+        place for kernel selection, query packing, and the polygon
+        pruned/compact overflow-retry machinery (the knobs persist on the
+        operator). Shared by run() and run_soa().
+
+        ``launch(common)`` dispatches the window's program and returns its
+        device outputs; ``settle(out, common)`` fetches them and returns
+        host ``(keep, dist, cand_retries, budget_retries)``. A pruned
+        kernel's overflow scalars cross WITH keep and dist, in the one
+        ``telemetry.fetch``; while a knob did not hold, settle grows it and
+        launches again (a re-run that grew both counts under both).
 
         Polygon selection: large exact-mode query sets use bbox-candidate
         pruning (the dense P·E sweep loses ~10× there); sparse candidate
@@ -75,28 +93,36 @@ class _PointStreamRangeQuery(SpatialOperator):
         the dense min-over-all on kept lanes.
         """
         approx = self.conf.approximate_query
+
+        def settle_plain(out, common):
+            keep, dist = telemetry.fetch(out)
+            return keep, dist, 0, 0
+
         if self.query_kind == "point":
+            self.last_range_kernel = "points"
             pk = window_program(
                 mesh, range_points_fused, (0, 1, 2), 6, approximate=approx
             )
             q = self.device_q(pack_query_points(query_set, np.float64), dtype)
-            return lambda common: pk(*common, q, radius)
+            return (lambda common: pk(*common, q, radius)), settle_plain
 
         verts, ev = pack_query_geometries(query_set, np.float64)
         qv, qe = self.device_q(verts, dtype), jnp.asarray(ev)
         if self.query_kind == "linestring":
+            self.last_range_kernel = "polylines"
             lk = window_program(
                 mesh, range_polylines_fused, (0, 1, 2), 7, approximate=approx
             )
-            return lambda common: lk(*common, qv, qe, radius)
+            return (lambda common: lk(*common, qv, qe, radius)), settle_plain
 
         nq = len(query_set)
         use_pruned = nq >= 64 and mesh is None and not approx
         if not use_pruned:
+            self.last_range_kernel = "dense"
             polyk = window_program(
                 mesh, range_polygons_fused, (0, 1, 2), 7, approximate=approx
             )
-            return lambda common: polyk(*common, qv, qe, radius)
+            return (lambda common: polyk(*common, qv, qe, radius)), settle_plain
 
         from spatialflink_tpu.ops.range import (
             range_polygons_pruned_compact_fused,
@@ -105,44 +131,44 @@ class _PointStreamRangeQuery(SpatialOperator):
 
         use_compact = float((flags > 0).mean()) < 0.25
         if use_compact:
+            self.last_range_kernel = "pruned_compact"
             prunedk = jitted(
                 range_polygons_pruned_compact_fused,
                 "budget", "cand", "point_chunk",
             )
-            if not hasattr(self, "_cand_budget"):
-                self._cand_budget = 4096  # persists across windows
         else:
+            self.last_range_kernel = "pruned"
             prunedk = jitted(
                 range_polygons_pruned_fused, "cand", "point_chunk",
                 "approximate",
             )
-        if not hasattr(self, "_ncand"):
-            self._ncand = 8  # persists: dense data pays the retry once
 
-        def ev_pruned(common):
+        def launch(common):
+            if use_compact:
+                return prunedk(
+                    *common, qv, qe, radius,
+                    budget=self._cand_budget, cand=self._ncand,
+                )
+            return prunedk(*common, qv, qe, radius, cand=self._ncand)
+
+        def settle(out, common):
+            cand_retries = budget_retries = 0
             while True:
-                if use_compact:
-                    keep, dist, c_over, b_over = prunedk(
-                        *common, qv, qe, radius,
-                        budget=self._cand_budget, cand=self._ncand,
-                    )
-                else:
-                    keep, dist, c_over = prunedk(
-                        *common, qv, qe, radius, cand=self._ncand,
-                    )
-                    b_over = 0
-                grew = False
-                if int(b_over) > 0:
-                    need = self._cand_budget + int(b_over)
+                keep, dist, c_over, *b_over = telemetry.fetch(out)
+                grew_budget = bool(b_over) and int(b_over[0]) > 0
+                if grew_budget:
+                    need = self._cand_budget + int(b_over[0])
                     self._cand_budget = int(2 ** np.ceil(np.log2(need)))
-                    grew = True
-                if int(c_over) > 0 and self._ncand < nq:
+                grew_cand = int(c_over) > 0 and self._ncand < nq
+                if grew_cand:
                     self._ncand = min(self._ncand * 2, nq)
-                    grew = True
-                if not grew:
-                    return keep, dist
+                if not (grew_budget or grew_cand):
+                    return keep, dist, cand_retries, budget_retries
+                cand_retries += grew_cand
+                budget_retries += grew_budget
+                out = launch(common)
 
-        return ev_pruned
+        return launch, settle
 
     def run(
         self,
@@ -173,11 +199,11 @@ class _PointStreamRangeQuery(SpatialOperator):
         # setup transfers below would hang the resume at a device_put.
         drv = driver if driver is not None else strict_driver()
         drv.attach(self)
-        evaluate = flags_d = None
+        launch = settle = flags_d = None
         if drv.backend == "device":
             flags_d = jnp.asarray(flags)
-            evaluate = self._window_evaluator(query_set, flags, radius,
-                                              dtype, mesh)
+            launch, settle = self._window_evaluator(query_set, flags, radius,
+                                                    dtype, mesh)
 
         def process(win) -> RangeResult:
             # assemble → ship → compute → fetch phase spans (see
@@ -205,9 +231,9 @@ class _PointStreamRangeQuery(SpatialOperator):
                         flags_d,
                     )
                 with telemetry.span("compute"):
-                    keep, dist = evaluate(common)
+                    out = launch(common)
                 with telemetry.span("fetch"):
-                    keep, dist = telemetry.fetch((keep, dist))
+                    keep, dist, _, _ = settle(out, common)
                 idx = np.nonzero(keep)[0]
                 objs = [win.events[i] for i in idx]
                 return RangeResult(
@@ -349,27 +375,40 @@ class _PointStreamRangeQuery(SpatialOperator):
             query_set = [query_set]
         flags = flags_for_queries(self.grid, radius, query_set)
         flags_d = jnp.asarray(flags)
-        evaluate = self._window_evaluator(query_set, flags, radius, dtype,
-                                          mesh=None)
+        launch, settle = self._window_evaluator(query_set, flags, radius,
+                                                dtype, mesh=None)
+        kernel = self.last_range_kernel
         from spatialflink_tpu.ops.counters import count_candidates, counters
 
         for win, xy, valid, cell, _ in soa_point_batches(
-            self.grid, chunks, self.conf, dtype
+            self.grid, chunks, self.conf, dtype, span="range.assemble"
         ):
             if counters.enabled:
                 cand = count_candidates(flags, cell, win.count)
                 counters.record_candidates(cand, cand * len(query_set))
             # ship/fetch through telemetry: the oid lane is NOT shipped on
             # this path, so accounting at the ship site keeps bytes_h2d
-            # honest; the fetch is the same device_get np.asarray would do.
-            xy_d, valid_d, cell_d = ship(xy, valid, cell)
-            keep, dist = evaluate((xy_d, valid_d, cell_d, flags_d))
-            keep, dist = telemetry.fetch((keep, dist))
+            # honest; settle's fetch is the path's one crossing back a
+            # window (one more a re-run).
+            common = (*ship(xy, valid, cell), flags_d)
+            keep, dist, cand_retries, budget_retries = settle(
+                launch(common), common
+            )
             n = win.count
-            keep = np.asarray(keep)[:n]
-            idx = np.nonzero(keep)[0]
-            matched = {k: np.asarray(v)[idx] for k, v in win.arrays.items()}
-            yield win.start, win.end, matched, np.asarray(dist)[:n][idx]
+            with telemetry.span("range.select") as sp:
+                idx = np.nonzero(np.asarray(keep)[:n])[0]
+                matched = {k: np.asarray(v)[idx]
+                           for k, v in win.arrays.items()}
+                dists = np.asarray(dist)[:n][idx]
+                if telemetry.enabled:
+                    sp.args["matches"] = len(idx)
+            telemetry.record_range(
+                points=n, lanes=len(valid), matches=len(idx),
+                cand_retries=cand_retries, budget_retries=budget_retries,
+                cand=self._ncand if kernel.startswith("pruned") else 0,
+                budget=self._cand_budget if kernel == "pruned_compact" else 0,
+            )
+            yield win.start, win.end, matched, dists
 
 
 class PointPointRangeQuery(_PointStreamRangeQuery):

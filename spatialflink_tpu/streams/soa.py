@@ -71,11 +71,18 @@ class _SlidingAssemblerBase:
 
     def feed(self, chunk):
         """Add one chunk; return the windows that fire."""
+        return self.fire() if self.take(chunk) else []
+
+    def take(self, chunk) -> bool:
+        """Add one chunk and fire nothing: True when a window is due, which
+        ``fire`` then gives. ``feed`` in two steps, for a caller that times
+        the firing (consolidation, the windows' slices) apart from the
+        intake (an append)."""
         if faults.armed:  # chaos injection point (faults.py)
             faults.hit("soa.feed")
         ts = self._ingest(chunk)
         if ts is None or len(ts) == 0:
-            return []
+            return False
         mx = int(ts.max())
         if self._max_ts is None or mx > self._max_ts:
             self._max_ts = mx
@@ -85,6 +92,16 @@ class _SlidingAssemblerBase:
             # (later within-bound arrivals may precede the first event).
             horizon = min(int(ts.min()), self._max_ts - self.ooo)
             self._next_start = earliest_window_of(horizon, self.size, self.slide)
+        return self._due(self._max_ts - self.ooo)
+
+    def _due(self, wm: int) -> bool:
+        """Has watermark ``wm`` passed the end of the earliest unfired
+        window? The one test ``take`` and ``_fire`` both go by."""
+        return (self._next_start is not None
+                and self._next_start + self.size <= wm)
+
+    def fire(self):
+        """The windows the watermark has passed (``take`` said True)."""
         return self._fire(self._max_ts - self.ooo)
 
     def flush(self):
@@ -102,7 +119,7 @@ class _SlidingAssemblerBase:
 
     def _fire(self, wm: int, record_lag: bool = True):
         out = []
-        if self._next_start is None or self._next_start + self.size > wm:
+        if not self._due(wm):
             return out
         ts = self._consolidate()
         # Events older than the earliest live window start are late beyond
@@ -111,7 +128,7 @@ class _SlidingAssemblerBase:
         if late:
             self.dropped_late += late
             telemetry.record_late_drop(late)
-        while self._next_start + self.size <= wm:
+        while self._due(wm):
             s, e = self._next_start, self._next_start + self.size
             lo = int(np.searchsorted(ts, s, side="left"))
             hi = int(np.searchsorted(ts, e, side="left"))

@@ -330,6 +330,12 @@ class Telemetry:
         # that feeds it (streams/wire.py via record_wire_assembler) —
         # snapshot()["wire"], empty until the first pane.
         self._wire: Dict[str, int] = {}
+        # Point–polygon range query (operators/range_query.py:run_soa via
+        # record_range): counters windows / points / lanes / matches /
+        # cand_retries / budget_retries and the gauges cand / budget (the
+        # candidates a point and the compact kernel's lane budget in use)
+        # — snapshot()["range"], empty until the first window.
+        self._range: Dict[str, int] = {}
         # tids already named via a ph:"M" thread_name metadata event.
         self._named_tids: set = set()
         # Per-node attribution buckets: node name (or None = unscoped) →
@@ -698,6 +704,13 @@ class Telemetry:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, args)
+
+    def emit_span(self, name: str, t0_ns: int, dur_ns: int, **args):
+        """A span the caller timed itself on ``time.perf_counter_ns``: for
+        a phase no ``with`` block can bracket (a generator's step, emitted
+        only once it is known to have produced something). No profiler
+        annotation goes with it."""
+        self._emit_span(name, t0_ns, dur_ns, args)
 
     def _emit_span(self, name, t0_ns, dur_ns, args):
         if not self.enabled:  # disabled mid-span
@@ -1133,6 +1146,30 @@ class Telemetry:
                            ("budget_retries", budget_retries)):
                 j[key] = j.get(key, 0) + int(n)
             j["cap"], j["budget"] = int(cap), int(budget)
+
+    def record_range(self, points: int, lanes: int, matches: int,
+                     cand_retries: int, budget_retries: int, cand: int,
+                     budget: int):
+        """One window of the SoA point range query, fetched: its
+        ``points``, the ``lanes`` shipped for them (the padding bucket),
+        the ``matches`` the host selected, the re-runs the pruned polygon
+        kernels took (more than ``cand`` polygon boxes within r of a point;
+        more candidate lanes than the compact kernel's ``budget``), and
+        what both ended on (0 where the kernel that ran has no such knob).
+        Lands in ``snapshot()["range"]`` as the counters ``windows``,
+        ``points``, ``lanes``, ``matches``, ``cand_retries``,
+        ``budget_retries`` and the gauges ``cand``, ``budget``. Per
+        window, never per event."""
+        if not self.enabled:
+            return
+        with self._lock:
+            r = self._range
+            for key, n in (("windows", 1), ("points", points),
+                           ("lanes", lanes), ("matches", matches),
+                           ("cand_retries", cand_retries),
+                           ("budget_retries", budget_retries)):
+                r[key] = r.get(key, 0) + int(n)
+            r["cand"], r["budget"] = int(cand), int(budget)
 
     def record_wire_pane(self, n: int, bucket: int):
         """One pane taken by ``run_wire_panes``: ``n`` points padded up to
@@ -1643,6 +1680,8 @@ class Telemetry:
                 out["join"] = dict(self._join)
             if self._wire:
                 out["wire"] = dict(self._wire)
+            if self._range:
+                out["range"] = dict(self._range)
         if self.overload_provider is not None:
             try:
                 out["overload"] = json_safe(self.overload_provider())  # sfcheck: ok=lock-discipline -- stream-flush checkpoints call this under Telemetry._lock by design; the provider contract (documented at overload.OverloadController._lock) forbids providers from taking telemetry's lock — overload queues transition emits for after release

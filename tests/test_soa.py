@@ -60,6 +60,47 @@ def test_soa_assembler_gap_skip():
     assert total == 4 * 1000
 
 
+@pytest.mark.parametrize("ooo_ms", [0, 3_000])
+def test_take_then_fire_is_feed(rng, ooo_ms):
+    """``take`` says a window is due exactly when ``feed`` would fire one
+    (both go by ``_due``), gaps and late events included."""
+    ts = np.sort(rng.integers(0, 90_000, 4000)).astype(np.int64)
+    ts[ts > 40_000] += 35_000  # a gap of several windows
+    ts += rng.integers(-2_000, 1, len(ts))  # out of order within the bound
+    fed, stepped = (SoaWindowAssembler(10_000, 5_000, ooo_ms)
+                    for _ in range(2))
+    for a in range(0, len(ts), 97):
+        chunk = {"ts": ts[a:a + 97]}
+        want = fed.feed(chunk)
+        got = stepped.fire() if stepped.take(chunk) else []
+        assert [(w.start, w.end, w.count) for w in got] == [
+            (w.start, w.end, w.count) for w in want]
+        # no window is left due, whether take said yes or no
+        assert not stepped._due(stepped._max_ts - ooo_ms)
+    assert fed.dropped_late == stepped.dropped_late
+
+
+def test_soa_point_batches_span_only_where_a_window_fired():
+    from spatialflink_tpu.operators.base import soa_point_batches
+    from spatialflink_tpu.telemetry import telemetry
+
+    conf = QueryConfiguration(QueryType.WindowBased, window_size=10,
+                              slide_step=10)
+    ts = np.array([1_000, 2_000, 61_000, 62_000], np.int64)  # 5 empty between
+    xy = np.full(len(ts), 5.0)
+    chunks = [{"ts": ts[i:i + 1], "x": xy[i:i + 1], "y": xy[i:i + 1]}
+              for i in range(len(ts))]
+    telemetry.enable()
+    try:
+        none = list(soa_point_batches(GRID, [], conf, span="t.assemble"))
+        wins = list(soa_point_batches(GRID, chunks, conf, span="t.assemble"))
+        spans = [e for e in telemetry.events if e["name"] == "t.assemble"]
+    finally:
+        telemetry.disable()
+    assert none == [] and [w[0].count for w in wins] == [2, 2]
+    assert [e["args"]["n"] for e in spans] == [2, 2]
+
+
 def test_soa_assembler_out_of_order_within_bound(rng):
     base = np.sort(rng.integers(0, 30_000, 500)).astype(np.int64)
     jitter = rng.integers(-1500, 1500, 500)

@@ -62,6 +62,7 @@ CASES = {
     ),
     "knn": ["wire_panes.xla", "wire_panes.pallas_interpret"],
     "join": ["run_soa.xla", "run_soa.pallas_interpret"],
+    "range": ["run_soa.dense", "run_soa.pruned", "run_soa.pruned_compact"],
     "numerics": (
         [f"center_coords.{name}" for name, _ in GRIDS]
         + [f"contains_any_zone.{z}" for z in ZONE_SETS]
@@ -367,6 +368,63 @@ def child_join():
     return out
 
 
+def child_range():
+    """``PointPolygonRangeQuery.run_soa`` through each of its polygon
+    kernels: the matched set equals the float64 reference's outside the band
+    of 4 · eps32 · span (1.0e-6 deg on the Beijing grid) around the radius,
+    every matched row is the window's own, every distance within the band of
+    the reference's (float32 ray casting and distances on centred
+    coordinates against float64 on raw degrees)."""
+    from __graft_entry__ import BEIJING_GRID_ARGS
+    from benchmark.references.range_polygons import Reference
+    from spatialflink_tpu.grid import UniformGrid
+    from spatialflink_tpu.operators import (
+        PointPolygonRangeQuery,
+        QueryConfiguration,
+        QueryType,
+    )
+    from spatialflink_tpu.utils.helper import generate_query_polygons
+
+    grid = UniformGrid(**BEIJING_GRID_ARGS)
+    bbox = (grid.min_x, grid.min_y, grid.max_x, grid.max_y)
+    radius, n = 0.002, 20_000
+    tol = 4 * float(np.finfo(np.float32).eps) * (grid.max_x - grid.min_x)
+    rng = np.random.default_rng(23)
+    window = {"ts": T0_MS + np.sort(rng.integers(0, 10_000, n)),
+              "x": rng.uniform(grid.min_x, grid.max_x, n),
+              "y": rng.uniform(grid.min_y, grid.max_y, n),
+              "oid": np.arange(n, dtype=np.int64)}
+    conf = QueryConfiguration(QueryType.WindowBased, window_size=10,
+                              slide_step=10)
+    out = {}
+    for kernel, count in (("dense", 40), ("pruned", 400),
+                          ("pruned_compact", 90)):
+        polygons = generate_query_polygons(count, *bbox, grid_size=100,
+                                           seed=count)
+        ref = Reference(bbox=bbox, grid_cells=100, radius=radius, tol=tol,
+                        polygons=[[np.asarray(r) for r in p.rings]
+                                  for p in polygons])
+        op = PointPolygonRangeQuery(conf, grid)
+        got = list(op.run_soa(iter([window]), polygons, radius))
+        bad = []
+        if op.last_range_kernel != kernel:
+            bad.append(f"the operator picked {op.last_range_kernel!r}")
+        if len(got) != 1:
+            bad.append(f"{len(got)} windows fired, expected 1")
+        else:
+            start, end, matched, dist = got[0]
+            if (start, end) != (T0_MS, T0_MS + 10_000):
+                bad.append(f"window span {(start, end)}")
+            if dist.dtype != np.float32:
+                bad.append(f"distances came back {dist.dtype}: x64 is on?")
+            bad += ref.compare(ref.matches(window["x"], window["y"]),
+                               window, matched, dist)
+            if not len(dist):
+                bad.append("no point matched: the comparison is empty")
+        out[f"run_soa.{kernel}"] = _verdict(bad)
+    return out
+
+
 def _metric_margin(zones, pts):
     """Signed slack (metres, float64) of "inside any zone OR within its
     buffer of its boundary": an even-odd ray cast and point-to-segment
@@ -480,7 +538,7 @@ def child_numerics():
 
 
 CHILDREN = {"sncb": child_sncb, "knn": child_knn, "join": child_join,
-            "numerics": child_numerics}
+            "range": child_range, "numerics": child_numerics}
 
 
 def main(argv):
@@ -535,6 +593,11 @@ def join_child():
 
 
 @pytest.fixture(scope="module")
+def range_child():
+    return _run_child("range")
+
+
+@pytest.fixture(scope="module")
 def numerics_child():
     return _run_child("numerics")
 
@@ -552,6 +615,11 @@ def test_wire_knn_matches_reference(knn_child, case):
 @pytest.mark.parametrize("case", CASES["join"])
 def test_join_pairs_match_float64_reference(join_child, case):
     assert join_child[case]["ok"], join_child[case]["problems"]
+
+
+@pytest.mark.parametrize("case", CASES["range"])
+def test_range_polygons_match_float64_reference(range_child, case):
+    assert range_child[case]["ok"], range_child[case]["problems"]
 
 
 @pytest.mark.parametrize("case", CASES["numerics"])
