@@ -351,12 +351,16 @@ def bench_knn_k(jax, jnp, grid, k, quick):
 def bench_polygon_range(jax, jnp, grid, quick):
     """Config 3: Point-Polygon range with a 1k-polygon query set.
 
-    Uses the bbox-candidate-pruned kernel (exact when overflow == 0 —
-    asserted) with device-side cell assignment, double-buffered streamed
-    ingest and pipelined egress (per-window hit counts fetched once at the
-    end).
+    Uses the grid-indexed pruned kernel (a point evaluates its cell's
+    candidate polygons; the table is built once, below) with device-side
+    cell assignment, double-buffered streamed ingest and pipelined egress
+    (per-window hit counts fetched once at the end).
     """
-    from spatialflink_tpu.operators.base import pack_query_geometries
+    from spatialflink_tpu.operators.base import (
+        pack_cell_candidates,
+        pack_cell_edges,
+        pack_query_geometries,
+    )
     from spatialflink_tpu.ops.cells import assign_cells, gather_cell_flags
     from spatialflink_tpu.ops.range import range_query_polygons_pruned_kernel
     from spatialflink_tpu.utils.helper import generate_query_polygons
@@ -367,10 +371,11 @@ def bench_polygon_range(jax, jnp, grid, quick):
     polys = generate_query_polygons(
         n_polys, 115.5, 39.6, 117.6, 41.1, grid_size=100, seed=3
     )
-    verts, ev = pack_query_geometries(polys, np.float32)
+    verts, ev = pack_query_geometries(polys, np.float64)
+    index = pack_cell_candidates(grid, verts, ev, 0.002)
     dev = jax.devices()[0]
-    qv = jax.device_put(jnp.asarray(verts), dev)
-    qe = jax.device_put(jnp.asarray(ev), dev)
+    edges_d = jax.device_put(jnp.asarray(pack_cell_edges(
+        index.table, verts.astype(np.float32), ev)), dev)
     cells = []
     for p in polys:
         cells.extend(p.grid_cells(grid))
@@ -379,34 +384,33 @@ def bench_polygon_range(jax, jnp, grid, quick):
     valid_d = jax.device_put(jnp.asarray(np.ones(win_pts, bool)), dev)
     xy, oid, ts = _stream(win_pts * n_win, seed=7)
 
-    def step(xy_w, valid, flags_table, pverts, pev):
+    def step(xy_w, valid, flags_table, cell_edges):
         cell = assign_cells(
             xy_w, grid.min_x, grid.min_y, grid.cell_length, grid.n
         )
-        keep, _, over = range_query_polygons_pruned_kernel(
-            xy_w, valid, gather_cell_flags(cell, flags_table), pverts, pev,
-            np.float32(0.002), cand=8,
+        keep, _ = range_query_polygons_pruned_kernel(
+            xy_w, valid, cell, gather_cell_flags(cell, flags_table),
+            cell_edges, np.float32(0.002),
         )
-        return jnp.sum(keep), over
+        return jnp.sum(keep)
 
     jstep = _instr(jax.jit(step), "polygon_range_step")
 
     def win_xy(i):
         return jax.device_put(xy[i * win_pts:(i + 1) * win_pts], dev)
 
-    jax.device_get(jstep(win_xy(0), valid_d, flags_d, qv, qe))  # compile
+    jax.device_get(jstep(win_xy(0), valid_d, flags_d, edges_d))  # compile
 
     out, dt, t_min, t_max = _pipelined(
         jax, n_win, win_xy,
-        lambda xy_w: jstep(xy_w, valid_d, flags_d, qv, qe),
+        lambda xy_w: jstep(xy_w, valid_d, flags_d, edges_d),
     )
-    hits = sum(int(h) for h, _ in out)
-    assert sum(int(o) for _, o in out) == 0, "candidate overflow: raise cand"
+    hits = sum(int(h) for h in out)
 
     xs = jax.device_put(jnp.asarray(xy.reshape(n_win, win_pts, 2)), dev)
     pps_r, r_min, r_max, _ = _resident_rate(
         jax,
-        lambda c, xy_w: (c, step(xy_w, valid_d, flags_d, qv, qe)),
+        lambda c, xy_w: (c, step(xy_w, valid_d, flags_d, edges_d)),
         jnp.int32(0), xs, n_win * win_pts,
     )
     return _result(f"range_point_{n_polys}polygons", n_win * win_pts, dt,

@@ -16,7 +16,16 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Callable, Iterable, Iterator, List, Optional, Sequence, Tuple
+from typing import (
+    Callable,
+    Iterable,
+    Iterator,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import jax
 import numpy as np
@@ -171,6 +180,12 @@ class SpatialOperator:
         host = center_coords(self.grid, np.asarray(coords, np.float64), dtype)
         return _h2d((host,))[0]
 
+    def device_table(self, table: np.ndarray):
+        """A query set's index table, device-ready as built (any
+        coordinates in it centred and cast already): crosses the link
+        through ``_h2d`` like ``device_q`` — counted, one leaf span."""
+        return _h2d((table,))[0]
+
     def device_xy(self, batch: PointBatch, dtype):
         """Device-ready point-batch coordinates."""
         return self.device_q(batch.xy, dtype)
@@ -234,6 +249,120 @@ def pack_query_geometries(
         verts[i] = pv
         ev[i] = pe
     return verts, ev
+
+
+class CellCandidates(NamedTuple):
+    """The grid index of a polygon query set (``pack_cell_candidates``)."""
+
+    table: np.ndarray  # (num_cells + 1, K) int32; P marks an empty slot
+    entries: int  # Σ list lengths: the (cell, polygon) pairs
+    cells: int  # cells with a non-empty list
+
+    @property
+    def slots(self) -> int:
+        """K: the fullest cell's list, up the bucket ladder (least 8)."""
+        return self.table.shape[1]
+
+
+def _rank_in_group(counts: np.ndarray) -> np.ndarray:
+    """0, 1, … within each run of a sequence grouped into runs of
+    ``counts`` items: [2, 0, 3] → [0, 1, 0, 1, 2]."""
+    return np.arange(counts.sum()) - np.repeat(
+        np.cumsum(counts) - counts, counts)
+
+
+def pack_cell_candidates(
+    grid: UniformGrid, verts: np.ndarray, edge_valid: np.ndarray,
+    radius: float,
+) -> CellCandidates:
+    """Cell → candidate-polygons table for a packed polygon set, built once
+    per query set on the host in float64.
+
+    Polygon ``i`` of ``verts`` (P, V, 2) / ``edge_valid`` (P, V-1) (uncentred
+    coordinates, as ``pack_query_geometries`` gives them) is entered in the
+    list of every grid cell its bounding box touches once grown by
+    ``radius`` plus a margin of 32 float32 eps of the largest centred
+    coordinate (the band in which a float32 distance on centred coordinates
+    can fall on the other side of r; 4.0e-6° on the Beijing bbox). Cell
+    ranges use ``UniformGrid.assign_cells_np``'s own arithmetic —
+    ``floor((x - min_x) / cell_length)``, closed on both sides of the grown
+    box, clipped to the grid — and that expression is monotone in x, so a
+    point ``assign_cells_np`` puts in cell g can be within r (and the band)
+    only of polygons in g's list. Row ``num_cells`` (out of grid) is empty;
+    an empty slot holds P, one past the last polygon (``pack_cell_edges``
+    keeps its far edges there). A polygon with no valid edge is in no list.
+    """
+    from spatialflink_tpu.utils.padding import next_bucket
+
+    verts = np.asarray(verts, np.float64)
+    ev = np.asarray(edge_valid, bool)
+    p, n = len(verts), grid.n
+    real = np.zeros(verts.shape[:2], bool)  # a vertex bounds a real edge
+    real[:, :-1] |= ev
+    real[:, 1:] |= ev
+    live = np.nonzero(real.any(axis=1))[0]
+    lo = np.where(real[..., None], verts, np.inf).min(axis=1)[live]
+    hi = np.where(real[..., None], verts, -np.inf).max(axis=1)[live]
+    origin = np.array([grid.min_x, grid.min_y])
+    centre = np.array([(grid.min_x + grid.max_x) / 2.0,
+                       (grid.min_y + grid.max_y) / 2.0])
+    scale = max(float(np.abs(np.array([lo, hi]) - centre).max(initial=0.0)),
+                float(np.abs(origin - centre).max()))
+    grow = radius + 32.0 * float(np.finfo(np.float32).eps) * scale
+    # floor indices as assign_cells_np takes them; clipped one past each
+    # side first, so that a far-away box cannot overflow the int cast
+    i0 = np.clip(np.floor((lo - grow - origin) / grid.cell_length), -1, n)
+    i1 = np.clip(np.floor((hi + grow - origin) / grid.cell_length), -1, n)
+    inside = ((i1 >= 0) & (i0 <= n - 1)).all(axis=1)
+    live = live[inside]
+    i0 = np.maximum(i0[inside], 0).astype(np.int64)
+    i1 = np.minimum(i1[inside], n - 1).astype(np.int64)
+    nx, ny = (i1 - i0 + 1).T
+    per_poly = nx * ny
+    which = np.repeat(np.arange(len(live)), per_poly)
+    k = _rank_in_group(per_poly)
+    cell = ((i0[which, 0] + k // ny[which]) * n
+            + i0[which, 1] + k % ny[which])
+    order = np.lexsort((live[which], cell))
+    cell, poly = cell[order], live[which][order]
+    counts = np.bincount(cell, minlength=grid.num_cells + 1)
+    slots = next_bucket(int(counts.max(initial=0)), minimum=8)
+    table = np.full((grid.num_cells + 1, slots), p, np.int32)
+    table[cell, _rank_in_group(counts)] = poly
+    return CellCandidates(table, len(cell), int(np.count_nonzero(counts)))
+
+
+#: where ``pack_cell_edges`` puts both ends of an empty edge slot: a
+#: degenerate segment no ray crosses, farther than any real edge, whose
+#: squared distance still fits float32
+FAR_EDGE = 1e18
+
+
+def pack_cell_edges(table: np.ndarray, verts: np.ndarray,
+                    edge_valid: np.ndarray) -> np.ndarray:
+    """The cell table with the candidates' edges in place of their
+    indices: (num_cells + 1, 4, E, K) endpoint planes x1, y1, x2, y2 — what
+    ops/range.py's pruned kernels gather one row of per point.
+
+    ``table`` is ``pack_cell_candidates``' (num_cells + 1, K); ``verts``
+    (P, V, 2) are the packed rings as the device will see them (centred
+    and cast: ``center_coords``), ``edge_valid`` (P, V-1) their edge mask.
+    Each polygon's valid edges are listed first (a ring seam and padding
+    are no edges), E is the longest such list, and every slot left over —
+    the tail of a shorter polygon, the whole of an empty candidate slot —
+    holds ``FAR_EDGE`` at both ends. The minimum and the crossing count
+    over a polygon's slots are those over its valid edges, in any order.
+    """
+    ev = np.asarray(edge_valid, bool)
+    per_poly = ev.sum(axis=1)
+    which, j = np.nonzero(ev)  # row-major: a polygon's edges in ring order
+    slot = _rank_in_group(per_poly)
+    edges = np.full((len(verts) + 1, 4, max(int(per_poly.max(initial=0)), 1)),
+                    FAR_EDGE, verts.dtype)
+    edges[which, 0, slot], edges[which, 1, slot] = verts[which, j].T
+    edges[which, 2, slot], edges[which, 3, slot] = verts[which, j + 1].T
+    # (cells, K, 4, E) -> (cells, 4, E, K): the K slots on the minor axis
+    return np.ascontiguousarray(edges[table].transpose(0, 2, 3, 1))
 
 
 def center_coords(grid: UniformGrid, xy: np.ndarray, dtype) -> np.ndarray:

@@ -28,9 +28,12 @@ from spatialflink_tpu.models.batch import GeometryBatch, PointBatch
 from spatialflink_tpu.models.objects import LineString, Point, Polygon, SpatialObject
 from spatialflink_tpu.operators.base import (
     SpatialOperator,
+    center_coords,
     count_window_batches,
     flags_for_queries,
     jitted,
+    pack_cell_candidates,
+    pack_cell_edges,
     pack_query_geometries,
     pack_query_points,
     ship,
@@ -63,9 +66,10 @@ class _PointStreamRangeQuery(SpatialOperator):
 
     def __init__(self, conf, grid, mesh=None):
         super().__init__(conf, grid, mesh)
-        # The pruned polygon kernels' knobs — candidates a point, and the
-        # compact kernel's lane budget. They persist across windows and
-        # runs: crowded data pays its re-run once.
+        # The pruned polygon kernels' sizes: the width K of the newest
+        # query set's cell table (read off the set when its evaluator is
+        # built), and the compact kernel's lane budget, which persists
+        # across windows and runs: crowded data pays its re-run once.
         self._ncand = 8
         self._cand_budget = 4096
         #: the kernel the newest evaluator built: "points", "polylines",
@@ -74,29 +78,36 @@ class _PointStreamRangeQuery(SpatialOperator):
 
     def _window_evaluator(self, query_set, flags, radius, dtype, mesh):
         """Build ``(launch, settle)`` for this family's query kind — ONE
-        place for kernel selection, query packing, and the polygon
-        pruned/compact overflow-retry machinery (the knobs persist on the
+        place for kernel selection, query packing, the polygon grid index
+        and the compact kernel's budget re-run (the budget persists on the
         operator). Shared by run() and run_soa().
 
         ``launch(common)`` dispatches the window's program and returns its
         device outputs; ``settle(out, common)`` fetches them and returns
-        host ``(keep, dist, cand_retries, budget_retries)``. A pruned
-        kernel's overflow scalars cross WITH keep and dist, in the one
-        ``telemetry.fetch``; while a knob did not hold, settle grows it and
-        launches again (a re-run that grew both counts under both).
+        host ``(keep, dist, budget_retries)``. The compact kernel's
+        overflow scalar crosses WITH keep and dist, in the one
+        ``telemetry.fetch``; while the budget did not hold, settle grows it
+        and launches again — the one re-run left: nothing else can overflow.
 
-        Polygon selection: large exact-mode query sets use bbox-candidate
-        pruning (the dense P·E sweep loses ~10× there); sparse candidate
-        unions (<25% flag occupancy) additionally compact candidate lanes
-        first. Approximate mode stays dense — its keep-set ignores
-        distances, so pruned min-over-candidates dists would diverge from
-        the dense min-over-all on kept lanes.
+        Polygon selection: large exact-mode query sets go through a cell →
+        candidate-polygons table, built here, once per query set, in float64
+        on the host (``pack_cell_candidates``), laid out as the edge planes
+        the kernel gathers one row of per point (``pack_cell_edges``, on the
+        centred and cast rings) and shipped in one counted crossing before
+        the first window. Its width K — the fullest cell's list on the
+        bucket ladder — is a property of the query set, so a crowded set
+        costs a wider K from the start and never a re-run; ``self._ncand``
+        and the gauge ``cand`` report it. Sparse candidate unions (<25%
+        flag occupancy) additionally compact candidate lanes first.
+        Approximate mode stays dense — its keep-set ignores distances, so
+        min-over-candidates dists would diverge from the dense min-over-all
+        on kept lanes.
         """
         approx = self.conf.approximate_query
 
         def settle_plain(out, common):
             keep, dist = telemetry.fetch(out)
-            return keep, dist, 0, 0
+            return keep, dist, 0
 
         if self.query_kind == "point":
             self.last_range_kernel = "points"
@@ -107,21 +118,21 @@ class _PointStreamRangeQuery(SpatialOperator):
             return (lambda common: pk(*common, q, radius)), settle_plain
 
         verts, ev = pack_query_geometries(query_set, np.float64)
-        qv, qe = self.device_q(verts, dtype), jnp.asarray(ev)
         if self.query_kind == "linestring":
             self.last_range_kernel = "polylines"
             lk = window_program(
                 mesh, range_polylines_fused, (0, 1, 2), 7, approximate=approx
             )
+            qv, qe = self.device_q(verts, dtype), jnp.asarray(ev)
             return (lambda common: lk(*common, qv, qe, radius)), settle_plain
 
-        nq = len(query_set)
-        use_pruned = nq >= 64 and mesh is None and not approx
+        use_pruned = len(query_set) >= 64 and mesh is None and not approx
         if not use_pruned:
             self.last_range_kernel = "dense"
             polyk = window_program(
                 mesh, range_polygons_fused, (0, 1, 2), 7, approximate=approx
             )
+            qv, qe = self.device_q(verts, dtype), jnp.asarray(ev)
             return (lambda common: polyk(*common, qv, qe, radius)), settle_plain
 
         from spatialflink_tpu.ops.range import (
@@ -129,43 +140,43 @@ class _PointStreamRangeQuery(SpatialOperator):
             range_polygons_pruned_fused,
         )
 
-        use_compact = float((flags > 0).mean()) < 0.25
-        if use_compact:
-            self.last_range_kernel = "pruned_compact"
-            prunedk = jitted(
-                range_polygons_pruned_compact_fused,
-                "budget", "cand", "point_chunk",
-            )
-        else:
+        # The grid index, once per query set: K is read off it here, before
+        # the first window, so the program's shapes are settled in the
+        # warm-up. One counted crossing, as the rings take on the other paths.
+        index = pack_cell_candidates(self.grid, verts, ev, radius)
+        self._ncand = index.slots
+        telemetry.record_range_index(index.slots, index.entries, index.cells)
+        cell_edges = self.device_table(pack_cell_edges(
+            index.table, center_coords(self.grid, verts, dtype), ev))
+
+        if float((flags > 0).mean()) >= 0.25:
             self.last_range_kernel = "pruned"
             prunedk = jitted(
-                range_polygons_pruned_fused, "cand", "point_chunk",
-                "approximate",
+                range_polygons_pruned_fused, "point_chunk", "approximate"
             )
+            return (
+                lambda common: prunedk(*common, cell_edges, radius)
+            ), settle_plain
+
+        self.last_range_kernel = "pruned_compact"
+        compactk = jitted(
+            range_polygons_pruned_compact_fused, "budget", "point_chunk"
+        )
 
         def launch(common):
-            if use_compact:
-                return prunedk(
-                    *common, qv, qe, radius,
-                    budget=self._cand_budget, cand=self._ncand,
-                )
-            return prunedk(*common, qv, qe, radius, cand=self._ncand)
+            return compactk(
+                *common, cell_edges, radius, budget=self._cand_budget
+            )
 
         def settle(out, common):
-            cand_retries = budget_retries = 0
+            budget_retries = 0
             while True:
-                keep, dist, c_over, *b_over = telemetry.fetch(out)
-                grew_budget = bool(b_over) and int(b_over[0]) > 0
-                if grew_budget:
-                    need = self._cand_budget + int(b_over[0])
-                    self._cand_budget = int(2 ** np.ceil(np.log2(need)))
-                grew_cand = int(c_over) > 0 and self._ncand < nq
-                if grew_cand:
-                    self._ncand = min(self._ncand * 2, nq)
-                if not (grew_budget or grew_cand):
-                    return keep, dist, cand_retries, budget_retries
-                cand_retries += grew_cand
-                budget_retries += grew_budget
+                keep, dist, b_over = telemetry.fetch(out)
+                if int(b_over) == 0:
+                    return keep, dist, budget_retries
+                need = self._cand_budget + int(b_over)
+                self._cand_budget = int(2 ** np.ceil(np.log2(need)))
+                budget_retries += 1
                 out = launch(common)
 
         return launch, settle
@@ -233,7 +244,7 @@ class _PointStreamRangeQuery(SpatialOperator):
                 with telemetry.span("compute"):
                     out = launch(common)
                 with telemetry.span("fetch"):
-                    keep, dist, _, _ = settle(out, common)
+                    keep, dist, _ = settle(out, common)
                 idx = np.nonzero(keep)[0]
                 objs = [win.events[i] for i in idx]
                 return RangeResult(
@@ -389,11 +400,9 @@ class _PointStreamRangeQuery(SpatialOperator):
             # ship/fetch through telemetry: the oid lane is NOT shipped on
             # this path, so accounting at the ship site keeps bytes_h2d
             # honest; settle's fetch is the path's one crossing back a
-            # window (one more a re-run).
+            # window (one more a budget re-run).
             common = (*ship(xy, valid, cell), flags_d)
-            keep, dist, cand_retries, budget_retries = settle(
-                launch(common), common
-            )
+            keep, dist, budget_retries = settle(launch(common), common)
             n = win.count
             with telemetry.span("range.select") as sp:
                 idx = np.nonzero(np.asarray(keep)[:n])[0]
@@ -404,7 +413,7 @@ class _PointStreamRangeQuery(SpatialOperator):
                     sp.args["matches"] = len(idx)
             telemetry.record_range(
                 points=n, lanes=len(valid), matches=len(idx),
-                cand_retries=cand_retries, budget_retries=budget_retries,
+                cand_retries=0, budget_retries=budget_retries,
                 cand=self._ncand if kernel.startswith("pruned") else 0,
                 budget=self._cand_budget if kernel == "pruned_compact" else 0,
             )

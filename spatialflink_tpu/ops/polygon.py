@@ -81,6 +81,18 @@ def pack_polyline(
     return verts, edge_valid
 
 
+def ray_crosses(x, y, x1, y1, x2, y2) -> jnp.ndarray:
+    """Does the +x ray from (x, y) cross the edge (x1, y1)–(x2, y2)?
+    Coordinate planes of any broadcastable shapes. The half-open vertical
+    span test avoids double-counting shared vertices; a horizontal edge
+    (and a degenerate one) is never crossed."""
+    spans = (y1 > y) != (y2 > y)
+    dy = y2 - y1
+    t = jnp.where(dy != 0, (y - y1) / jnp.where(dy != 0, dy, 1), 0.0)
+    x_int = x1 + t * (x2 - x1)
+    return spans & (x < x_int)
+
+
 def points_in_polygon(
     p: jnp.ndarray, verts: jnp.ndarray, edge_valid: jnp.ndarray
 ) -> jnp.ndarray:
@@ -94,12 +106,7 @@ def points_in_polygon(
     x, y = p[:, 0:1], p[:, 1:2]  # (N, 1)
     x1, y1 = verts[:-1, 0][None, :], verts[:-1, 1][None, :]  # (1, E)
     x2, y2 = verts[1:, 0][None, :], verts[1:, 1][None, :]
-    # Half-open vertical span test avoids double-counting shared vertices.
-    spans = (y1 > y) != (y2 > y)
-    dy = y2 - y1
-    t = jnp.where(dy != 0, (y - y1) / jnp.where(dy != 0, dy, 1), 0.0)
-    x_int = x1 + t * (x2 - x1)
-    crossings = spans & (x < x_int) & edge_valid[None, :]
+    crossings = ray_crosses(x, y, x1, y1, x2, y2) & edge_valid[None, :]
     return jnp.sum(crossings.astype(jnp.int32), axis=1) % 2 == 1
 
 

@@ -23,8 +23,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from spatialflink_tpu.ops.distances import pairwise_distance, point_polyline_distance
-from spatialflink_tpu.ops.polygon import points_in_polygon
+from spatialflink_tpu.ops.distances import (
+    pairwise_distance,
+    point_polyline_distance,
+    point_segment_distance,
+)
+from spatialflink_tpu.ops.polygon import points_in_polygon, ray_crosses
 
 __all__ = [
     "range_query_kernel",
@@ -106,138 +110,121 @@ def range_query_polygons_kernel(
 def range_query_polygons_pruned_kernel(
     xy: jnp.ndarray,
     valid: jnp.ndarray,
+    cell: jnp.ndarray,
     flags: jnp.ndarray,
-    poly_verts: jnp.ndarray,
-    poly_edge_valid: jnp.ndarray,
+    cell_edges: jnp.ndarray,
     radius,
-    cand: int = 8,
     point_chunk: int = 8192,
     approximate: bool = False,
 ):
-    """Large-query-set point–polygon range via bbox-candidate pruning.
+    """Large-query-set point–polygon range through a grid index.
 
     The dense kernel evaluates every (point, polygon, edge) triple — P·E
     edge distances per point. For big query sets (the 1000-polygon config)
-    almost all pairs are far apart, so this kernel does a cheap
-    (N, P) bbox-distance pass, takes each point's ``cand`` nearest polygons
-    by bbox distance (lax.top_k), and computes exact edge distances ONLY
-    for those candidates — O(P + cand·E) per point instead of O(P·E).
+    almost all pairs are far apart, and which polygons can be within
+    ``radius`` of a point is a property of the point's grid cell. Row
+    ``cell`` of ``cell_edges`` (num_cells + 1, 4, E, K)
+    (operators/base.py:pack_cell_candidates + pack_cell_edges, built once
+    per query set) holds the cell's K candidate polygons as the ray cast
+    and the point–segment distance consume them — the endpoint planes x1,
+    y1, x2, y2 of up to E edges each — so a point gathers ONE row and
+    evaluates K·E edges: no per-point ranking, no ring gather, no
+    re-layout. An empty edge slot (a shorter polygon, an empty candidate
+    slot) is a degenerate segment at ``pack_cell_edges``' far point:
+    never crossed, and farther than any real edge.
 
-    Exactness contract (mirrors the bucketed join's overflow/retry):
-    bbox distance lower-bounds exact distance, so every polygon within
-    ``radius`` of a point is among its bbox-candidates UNLESS more than
-    ``cand`` polygon bboxes fall within radius — counted per point into
-    ``overflow``. With overflow == 0, keep/min_dist are bit-exact for all
-    kept lanes (dropped lanes report the min over their candidates only);
-    otherwise retry with a larger ``cand``.
+    Exactness contract: a cell's list holds every polygon within
+    ``radius`` (and the float32 band) of any point assigned to the cell, so
+    on every kept lane whose nearest polygon is within ``radius`` keep and
+    min_dist are bit-exact with the dense kernel — the same expressions,
+    and the minimum over the list is the minimum over all polygons. K is
+    read off the query set before the first window, so nothing can
+    overflow and nothing is re-run. Dropped lanes report the minimum over
+    their cell's list only — the distance to the far point (≈ 1.4e18)
+    where the row is empty, as the out-of-grid row always is — and so does
+    a guaranteed (flag 2) lane whose nearest polygon lies beyond
+    ``radius``.
 
     Points stream through ``point_chunk``-sized lax.map blocks so the
-    (chunk, P) bbox matrix stays bounded. Returns (keep, min_dist, overflow).
+    gathered (chunk, 4·E·K) rows stay bounded; a block transposes them so
+    that points lie on the minor axis and the K slots beside it. Returns
+    (keep, min_dist).
     """
     n = xy.shape[0]
-    p = poly_verts.shape[0]
-    cand = min(cand, p)
-    vmask = _vert_valid(poly_edge_valid)  # (P, V)
-    vx, vy = poly_verts[..., 0], poly_verts[..., 1]
-    big = jnp.asarray(jnp.finfo(xy.dtype).max, xy.dtype)
-    minx = jnp.min(jnp.where(vmask, vx, big), axis=1)
-    maxx = jnp.max(jnp.where(vmask, vx, -big), axis=1)
-    miny = jnp.min(jnp.where(vmask, vy, big), axis=1)
-    maxy = jnp.max(jnp.where(vmask, vy, -big), axis=1)
-    # All-invalid (padding) polygons: minx > maxx → clamped dx below stays
-    # positive-huge, so they are never candidates within radius.
-    dead = ~jnp.any(vmask, axis=1)
+    n_rows, _, n_edges, slots = cell_edges.shape
+    rows = cell_edges.reshape(n_rows, -1)
 
     def chunk_fn(args):
-        xy_c, valid_c, flags_c = args
-        x, y = xy_c[:, 0:1], xy_c[:, 1:2]  # (C, 1)
-        dx = jnp.maximum(jnp.maximum(minx[None, :] - x, x - maxx[None, :]), 0.0)
-        dy = jnp.maximum(jnp.maximum(miny[None, :] - y, y - maxy[None, :]), 0.0)
-        bbox_d = jnp.where(dead[None, :], big, jnp.hypot(dx, dy))  # (C, P)
-        neg_top, idx = jax.lax.top_k(-bbox_d, cand)  # nearest by bbox
-        within = jnp.sum((bbox_d <= radius).astype(jnp.int32), axis=1)
-        lanes = valid_c & (flags_c > 0)
-        over = jnp.sum(
-            jnp.where(lanes, jnp.maximum(within - cand, 0), 0)
-        )
-        cverts = poly_verts[idx]  # (C, cand, V, 2)
-        cev = poly_edge_valid[idx]  # (C, cand, V-1)
-
-        def one(p_xy, cv, ce):
-            def per_cand(verts, ev):
-                ed = point_polyline_distance(p_xy[None, :], verts, ev)[0]
-                ins = points_in_polygon(p_xy[None, :], verts, ev)[0]
-                return jnp.where(ins, jnp.zeros((), ed.dtype), ed)
-
-            return jnp.min(jax.vmap(per_cand)(cv, ce))
-
-        min_d = jax.vmap(one)(xy_c, cverts, cev)  # (C,)
+        xy_c, valid_c, cell_c, flags_c = args
+        x1, y1, x2, y2 = rows[cell_c].T.reshape(4, n_edges, slots, -1)
+        edge_d = point_segment_distance(
+            xy_c, jnp.stack([x1, y1], axis=-1), jnp.stack([x2, y2], axis=-1)
+        )  # (E, K, C)
+        crossings = ray_crosses(xy_c[:, 0], xy_c[:, 1], x1, y1, x2, y2)
+        inside = jnp.sum(crossings.astype(jnp.int32), axis=0) % 2 == 1
+        poly_d = jnp.where(
+            inside, jnp.zeros((), edge_d.dtype), jnp.min(edge_d, axis=0)
+        )  # (K, C)
+        min_d = jnp.min(poly_d, axis=0)
         keep = _emit_mask(valid_c, flags_c, min_d, radius, approximate)
-        return keep, min_d, over
+        return keep, min_d
 
     pad = (-n) % point_chunk
     if pad:
         xy = jnp.concatenate([xy, jnp.zeros((pad, 2), xy.dtype)])
         valid = jnp.concatenate([valid, jnp.zeros((pad,), bool)])
+        cell = jnp.concatenate([cell, jnp.full((pad,), n_rows - 1, cell.dtype)])
         flags = jnp.concatenate([flags, jnp.zeros((pad,), flags.dtype)])
     n_blocks = (n + pad) // point_chunk
-    keep_b, dist_b, over_b = jax.lax.map(
+    keep_b, dist_b = jax.lax.map(
         chunk_fn,
         (
             xy.reshape(n_blocks, point_chunk, 2),
             valid.reshape(n_blocks, point_chunk),
+            cell.reshape(n_blocks, point_chunk),
             flags.reshape(n_blocks, point_chunk),
         ),
     )
-    return (
-        keep_b.reshape(-1)[:n],
-        dist_b.reshape(-1)[:n],
-        jnp.sum(over_b),
-    )
+    return keep_b.reshape(-1)[:n], dist_b.reshape(-1)[:n]
 
 
-def range_polygons_pruned_fused(xy, valid, cell, flags_table, poly_verts,
-                                poly_edge_valid, radius, cand: int = 8,
-                                point_chunk: int = 8192,
+def range_polygons_pruned_fused(xy, valid, cell, flags_table, cell_edges,
+                                radius, point_chunk: int = 8192,
                                 approximate: bool = False):
     from spatialflink_tpu.ops.cells import gather_cell_flags
 
     return range_query_polygons_pruned_kernel(
-        xy, valid, gather_cell_flags(cell, flags_table), poly_verts,
-        poly_edge_valid, radius, cand=cand, point_chunk=point_chunk,
-        approximate=approximate,
+        xy, valid, cell, gather_cell_flags(cell, flags_table), cell_edges,
+        radius, point_chunk=point_chunk, approximate=approximate,
     )
 
 
 def range_query_polygons_pruned_compact_kernel(
     xy: jnp.ndarray,
     valid: jnp.ndarray,
+    cell: jnp.ndarray,
     flags: jnp.ndarray,
-    poly_verts: jnp.ndarray,
-    poly_edge_valid: jnp.ndarray,
+    cell_edges: jnp.ndarray,
     radius,
     budget: int,
-    cand: int = 8,
     point_chunk: int = 8192,
 ):
     """Candidate-compacted form of the pruned kernel.
 
     Grid flags already exclude most of a window (typically >90% of lanes
     have flags == 0 and can never be emitted); this kernel gathers the
-    ≤ ``budget`` candidate lanes on device and runs the bbox-pruned
-    evaluation only on them — the one place compaction beats the
-    mask-don't-compact default, because the per-lane work here
-    (P bbox distances + top-cand + cand·E exact edges) is ~1000×
+    ≤ ``budget`` candidate lanes on device — their coordinates, flags and
+    cells — and runs the grid-indexed evaluation only on them: the one
+    place compaction beats the mask-don't-compact default, because the
+    per-lane work here (a row of the cell table, K·E exact edges) is ~100×
     an elementwise op.
 
     Returns (keep (N,), min_dist (N,) — +big on lanes that were not
-    evaluated — cand_overflow, budget_overflow). Exactness contract:
-    both overflows 0 ⇒ keep/min_dist(kept) are bit-exact; a nonzero
-    ``budget_overflow`` means more than ``budget`` candidate lanes
-    existed (retry with a bigger budget), a nonzero ``cand_overflow``
-    means retry with bigger ``cand``. Exact mode only (the approximate
-    keep-set is flag-driven and needs no distances — use the dense
-    kernel's approximate path).
+    evaluated — budget_overflow). Exactness contract: ``budget_overflow``
+    0 ⇒ keep/min_dist(kept) are bit-exact; nonzero means more than
+    ``budget`` candidate lanes existed (retry with a bigger budget).
+    Exact mode only (the approximate keep-set is flag-driven and needs no
+    distances — use the dense kernel's approximate path).
     """
     n = xy.shape[0]
     lanes = valid & (flags > 0)
@@ -248,9 +235,9 @@ def range_query_polygons_pruned_compact_kernel(
     xy_c = jnp.where(in_range[:, None], xy[safe], 0.0)
     flags_c = jnp.where(in_range, flags[safe], 0)
 
-    keep_c, dist_c, cand_over = range_query_polygons_pruned_kernel(
-        xy_c, in_range, flags_c, poly_verts, poly_edge_valid, radius,
-        cand=cand, point_chunk=min(point_chunk, budget),
+    keep_c, dist_c = range_query_polygons_pruned_kernel(
+        xy_c, in_range, cell[safe], flags_c, cell_edges, radius,
+        point_chunk=min(point_chunk, budget),
     )
 
     big = jnp.asarray(jnp.finfo(dist_c.dtype).max, dist_c.dtype)
@@ -258,20 +245,18 @@ def range_query_polygons_pruned_compact_kernel(
     # mode="drop" discards (clipped indices would overwrite lane n-1).
     keep = jnp.zeros(n, bool).at[idx].set(keep_c, mode="drop")
     dist = jnp.full(n, big, dist_c.dtype).at[idx].set(dist_c, mode="drop")
-    budget_overflow = jnp.maximum(n_cand - budget, 0)
-    return keep, dist, cand_over, budget_overflow
+    return keep, dist, jnp.maximum(n_cand - budget, 0)
 
 
 def range_polygons_pruned_compact_fused(
-    xy, valid, cell, flags_table, poly_verts, poly_edge_valid, radius,
-    budget: int, cand: int = 8, point_chunk: int = 8192,
+    xy, valid, cell, flags_table, cell_edges, radius, budget: int,
+    point_chunk: int = 8192,
 ):
     from spatialflink_tpu.ops.cells import gather_cell_flags
 
     return range_query_polygons_pruned_compact_kernel(
-        xy, valid, gather_cell_flags(cell, flags_table), poly_verts,
-        poly_edge_valid, radius, budget=budget, cand=cand,
-        point_chunk=point_chunk,
+        xy, valid, cell, gather_cell_flags(cell, flags_table), cell_edges,
+        radius, budget=budget, point_chunk=point_chunk,
     )
 
 

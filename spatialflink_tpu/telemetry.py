@@ -332,9 +332,12 @@ class Telemetry:
         self._wire: Dict[str, int] = {}
         # Point–polygon range query (operators/range_query.py:run_soa via
         # record_range): counters windows / points / lanes / matches /
-        # cand_retries / budget_retries and the gauges cand / budget (the
-        # candidates a point and the compact kernel's lane budget in use)
-        # — snapshot()["range"], empty until the first window.
+        # cand_retries / budget_retries / index_windows and the gauges
+        # cand / budget (the cell table's slots a cell and the compact
+        # kernel's lane budget in use); the gauges index_slots /
+        # index_entries / index_cells of a polygon set's cell table
+        # (record_range_index, once an evaluator) — snapshot()["range"],
+        # empty until the first evaluator or window.
         self._range: Dict[str, int] = {}
         # tids already named via a ph:"M" thread_name metadata event.
         self._named_tids: set = set()
@@ -1152,14 +1155,17 @@ class Telemetry:
                      budget: int):
         """One window of the SoA point range query, fetched: its
         ``points``, the ``lanes`` shipped for them (the padding bucket),
-        the ``matches`` the host selected, the re-runs the pruned polygon
-        kernels took (more than ``cand`` polygon boxes within r of a point;
-        more candidate lanes than the compact kernel's ``budget``), and
-        what both ended on (0 where the kernel that ran has no such knob).
-        Lands in ``snapshot()["range"]`` as the counters ``windows``,
+        the ``matches`` the host selected, the re-runs the compact polygon
+        kernel took (more candidate lanes than its ``budget``;
+        ``cand_retries`` stays 0 since the pruned kernels read a point's
+        candidates off the cell table: nothing there can overflow), and the
+        sizes the window ran with: ``cand`` = K, the cell table's slots a
+        cell, and ``budget`` (0 where the kernel that ran has no such
+        size). Lands in ``snapshot()["range"]`` as the counters ``windows``,
         ``points``, ``lanes``, ``matches``, ``cand_retries``,
-        ``budget_retries`` and the gauges ``cand``, ``budget``. Per
-        window, never per event."""
+        ``budget_retries``, ``index_windows`` (windows answered through the
+        cell table: those with ``cand`` > 0) and the gauges ``cand``,
+        ``budget``. Per window, never per event."""
         if not self.enabled:
             return
         with self._lock:
@@ -1167,9 +1173,24 @@ class Telemetry:
             for key, n in (("windows", 1), ("points", points),
                            ("lanes", lanes), ("matches", matches),
                            ("cand_retries", cand_retries),
-                           ("budget_retries", budget_retries)):
+                           ("budget_retries", budget_retries),
+                           ("index_windows", cand > 0)):
                 r[key] = r.get(key, 0) + int(n)
             r["cand"], r["budget"] = int(cand), int(budget)
+
+    def record_range_index(self, slots: int, entries: int, cells: int):
+        """The cell → candidate-polygons table of a polygon range query,
+        once when its evaluator is built: gauges ``index_slots`` (K),
+        ``index_entries`` (Σ list lengths) and ``index_cells`` (cells with
+        a non-empty list) of ``snapshot()["range"]``. ``index_entries ÷
+        (index_cells × index_slots)`` is the share of the slots gathered in
+        non-empty rows that hold a real polygon."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._range.update(index_slots=int(slots),
+                               index_entries=int(entries),
+                               index_cells=int(cells))
 
     def record_wire_pane(self, n: int, bucket: int):
         """One pane taken by ``run_wire_panes``: ``n`` points padded up to

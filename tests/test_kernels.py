@@ -377,91 +377,167 @@ def test_join_out_of_grid_points_never_match(rng):
         assert got == {("in", "r")}, (cap, got)
 
 
-def test_pruned_polygon_range_matches_dense(rng):
-    """range_query_polygons_pruned_kernel must keep exactly the dense
-    kernel's lanes (and equal min_dist on kept lanes) when overflow == 0."""
+def _indexed_polygon_set(polys, grid, radius):
+    """The pruned kernels' view of a polygon set, as the operator builds
+    it: (the cell table's edge planes (num_cells + 1, 4, E, K), K)."""
+    from spatialflink_tpu.operators.base import (
+        pack_cell_candidates,
+        pack_cell_edges,
+        pack_query_geometries,
+    )
+
+    verts, ev = pack_query_geometries(polys, np.float64)
+    index = pack_cell_candidates(grid, verts, ev, radius)
+    return pack_cell_edges(index.table, verts, ev), index.slots
+
+
+def _dense_polygon_range(xy, valid, flags, polys, radius):
     import jax
     import jax.numpy as jnp
 
     from spatialflink_tpu.operators.base import pack_query_geometries
+    from spatialflink_tpu.ops.range import range_query_polygons_kernel
+
+    verts, ev = pack_query_geometries(polys, np.float64)
+    keep, dist = jax.jit(range_query_polygons_kernel,
+                         static_argnames="approximate")(
+        jnp.asarray(xy), jnp.asarray(valid), jnp.asarray(flags),
+        jnp.asarray(verts), jnp.asarray(ev), radius)
+    return np.asarray(keep), np.asarray(dist)
+
+
+def _pruned_polygon_range(xy, valid, flags, polys, grid, radius,
+                          point_chunk, budget=None):
+    """(keep, dist, K[, budget overflow]) of the pruned kernel — the compact
+    one with a ``budget`` — on points whose cells the host assigned."""
+    import jax
+    import jax.numpy as jnp
+
     from spatialflink_tpu.ops.range import (
-        range_query_polygons_kernel,
+        range_query_polygons_pruned_compact_kernel,
         range_query_polygons_pruned_kernel,
     )
+
+    cell_edges, slots = _indexed_polygon_set(polys, grid, radius)
+    args = (jnp.asarray(xy), jnp.asarray(valid),
+            jnp.asarray(grid.assign_cells_np(xy)), jnp.asarray(flags),
+            jnp.asarray(cell_edges), radius)
+    if budget is None:
+        out = jax.jit(range_query_polygons_pruned_kernel,
+                      static_argnames=("point_chunk", "approximate"))(
+            *args, point_chunk=point_chunk)
+    else:
+        out = jax.jit(range_query_polygons_pruned_compact_kernel,
+                      static_argnames=("budget", "point_chunk"))(
+            *args, budget=budget, point_chunk=point_chunk)
+    keep, dist, *over = (np.asarray(o) for o in out)
+    return (keep, dist, slots, *over)
+
+
+def test_pruned_polygon_range_matches_dense(rng):
+    """range_query_polygons_pruned_kernel must keep exactly the dense
+    kernel's lanes, with equal min_dist on kept lanes: a cell's list holds
+    every polygon within r of its points."""
+    from spatialflink_tpu.grid import UniformGrid
     from spatialflink_tpu.utils.helper import generate_query_polygons
 
+    grid = UniformGrid(20, 0.0, 10.0, 0.0, 10.0)
     polys = generate_query_polygons(60, 0.0, 0.0, 10.0, 10.0, grid_size=20,
                                     seed=5)
-    verts, ev = pack_query_geometries(polys, np.float64)
     n = 3000
     xy = rng.uniform(0, 10, (n, 2))
     valid = np.ones(n, bool)
     flags = np.ones(n, np.uint8)  # all candidate lanes: distances decide
     r = 0.4
 
-    keep_d, dist_d = jax.jit(range_query_polygons_kernel,
-                             static_argnames="approximate")(
-        jnp.asarray(xy), jnp.asarray(valid), jnp.asarray(flags),
-        jnp.asarray(verts), jnp.asarray(ev), r)
-    keep_p, dist_p, over = jax.jit(
-        range_query_polygons_pruned_kernel,
-        static_argnames=("cand", "point_chunk", "approximate"))(
-        jnp.asarray(xy), jnp.asarray(valid), jnp.asarray(flags),
-        jnp.asarray(verts), jnp.asarray(ev), r,
-        cand=8, point_chunk=512)
-    assert int(over) == 0
-    np.testing.assert_array_equal(np.asarray(keep_p), np.asarray(keep_d))
-    kept = np.asarray(keep_d)
-    np.testing.assert_allclose(np.asarray(dist_p)[kept],
-                               np.asarray(dist_d)[kept], rtol=0, atol=0)
+    keep_d, dist_d = _dense_polygon_range(xy, valid, flags, polys, r)
+    keep_p, dist_p, slots = _pruned_polygon_range(
+        xy, valid, flags, polys, grid, r, point_chunk=512)
+    assert slots == 8
+    np.testing.assert_array_equal(keep_p, keep_d)
+    assert 0 < keep_d.sum() < n
+    np.testing.assert_allclose(dist_p[keep_d], dist_d[keep_d],
+                               rtol=0, atol=0)
 
 
-def test_pruned_polygon_range_overflow_detects_undercount(rng):
-    """With cand smaller than the number of in-radius polygon bboxes at
-    some point, overflow must be nonzero (the retry signal)."""
-    import jax
-    import jax.numpy as jnp
-
-    from spatialflink_tpu.operators.base import pack_query_geometries
+def test_pruned_polygon_range_crowded_spot_widens_the_table(rng):
+    """Six concentric squares: every nearby point has six polygons within
+    r. What used to arm the ``cand`` re-run (cand 4 < 6) is read off the
+    query set at set-up — K >= 6 from the start — and the result equals the
+    dense kernel's."""
+    from spatialflink_tpu.grid import UniformGrid
     from spatialflink_tpu.models.objects import Polygon
-    from spatialflink_tpu.ops.range import range_query_polygons_pruned_kernel
 
-    # 6 concentric small squares around (5,5): any nearby point has 6
-    # bbox-candidates within r.
     polys = []
     for i in range(6):
         s = 0.1 + 0.05 * i
         polys.append(Polygon(rings=[np.array(
             [[5 - s, 5 - s], [5 + s, 5 - s], [5 + s, 5 + s], [5 - s, 5 + s],
              [5 - s, 5 - s]])]))
-    verts, ev = pack_query_geometries(polys, np.float64)
-    xy = np.array([[5.05, 5.0], [9.0, 9.0]])
-    keep, dist, over = jax.jit(
-        range_query_polygons_pruned_kernel,
-        static_argnames=("cand", "point_chunk", "approximate"))(
-        jnp.asarray(xy), jnp.asarray(np.ones(2, bool)),
-        jnp.asarray(np.ones(2, np.uint8)), jnp.asarray(verts),
-        jnp.asarray(ev), 1.0, cand=4, point_chunk=2)
-    assert int(over) > 0
+    grid = UniformGrid(20, 0.0, 10.0, 0.0, 10.0)
+    xy = np.concatenate([np.array([[5.05, 5.0], [9.0, 9.0]]),
+                         rng.uniform(3.0, 7.0, (254, 2))])
+    valid, flags = np.ones(256, bool), np.ones(256, np.uint8)
+    keep_d, dist_d = _dense_polygon_range(xy, valid, flags, polys, 1.0)
+    keep_p, dist_p, slots = _pruned_polygon_range(
+        xy, valid, flags, polys, grid, 1.0, point_chunk=2)
+    assert slots >= 6
+    assert keep_d[0] and not keep_d[1]
+    np.testing.assert_array_equal(keep_p, keep_d)
+    np.testing.assert_allclose(dist_p[keep_d], dist_d[keep_d],
+                               rtol=0, atol=0)
+
+
+def test_pruned_polygon_range_lowers_with_no_ranking_and_no_box_matrix():
+    """Structural guard, at the benchmark rehearsal's shapes (131,072 lanes,
+    250 generator rectangles, the 100 x 100 Beijing grid, blocks of 8,192):
+    the lowered ``range_polygons_pruned_fused`` ranks nothing (no top-k, no
+    sort) and holds nothing of shape (point_chunk, P) — a point's candidates
+    come from one gathered row of the cell table."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from spatialflink_tpu.grid import UniformGrid
+    from spatialflink_tpu.ops.range import range_polygons_pruned_fused
+    from spatialflink_tpu.utils.helper import generate_query_polygons
+
+    bbox = (115.5, 39.6, 117.6, 41.1)
+    grid = UniformGrid(100, bbox[0], bbox[2], bbox[1], bbox[3])
+    n_polys, lanes, chunk = 250, 131_072, 8192
+    polys = generate_query_polygons(n_polys, *bbox, grid_size=100, seed=1)
+    cell_edges, slots = _indexed_polygon_set(polys, grid, 0.002)
+    assert cell_edges.shape == (grid.num_cells + 1, 4, 4, 8) and slots == 8
+
+    def shape(a, dtype):
+        return jax.ShapeDtypeStruct(a, dtype)
+
+    text = jax.jit(
+        range_polygons_pruned_fused,
+        static_argnames=("point_chunk", "approximate"),
+    ).lower(
+        shape((lanes, 2), jnp.float32), shape((lanes,), jnp.bool_),
+        shape((lanes,), jnp.int32), shape((grid.num_cells + 1,), jnp.uint8),
+        shape(cell_edges.shape, jnp.float32), 0.002, point_chunk=chunk,
+    ).as_text()
+    assert not re.search(r"top_?k|stablehlo\.sort|chlo\.", text, re.IGNORECASE)
+    assert not re.search(rf"[<x]({chunk}x{n_polys}|{n_polys}x{chunk})x", text)
+    # what took their place: a block gathers one row of 4·E·K a point
+    row = cell_edges[0].size
+    assert re.search(rf"gather.*tensor<{chunk}x{row}xf32>", text)
 
 
 def test_pruned_compact_polygon_range_matches_dense(rng):
     """The candidate-compacted pruned kernel must keep exactly the dense
-    kernel's lanes (equal dists on kept lanes) when both overflows are 0,
-    with realistic mostly-non-candidate flags."""
-    import jax
-    import jax.numpy as jnp
-
-    from spatialflink_tpu.operators.base import pack_query_geometries
-    from spatialflink_tpu.ops.range import (
-        range_query_polygons_kernel,
-        range_query_polygons_pruned_compact_kernel,
-    )
+    kernel's lanes (equal dists on kept lanes) when the budget holds, with
+    realistic mostly-non-candidate flags."""
+    from spatialflink_tpu.grid import UniformGrid
     from spatialflink_tpu.utils.helper import generate_query_polygons
 
+    grid = UniformGrid(20, 0.0, 10.0, 0.0, 10.0)
     polys = generate_query_polygons(50, 0.0, 0.0, 10.0, 10.0, grid_size=20,
                                     seed=6)
-    verts, ev = pack_query_geometries(polys, np.float64)
     n = 4000
     xy = rng.uniform(0, 10, (n, 2))
     valid = np.ones(n, bool)
@@ -469,43 +545,27 @@ def test_pruned_compact_polygon_range_matches_dense(rng):
     flags = np.where(rng.uniform(size=n) < 0.1, 1, 0).astype(np.uint8)
     r = 0.35
 
-    keep_d, dist_d = jax.jit(range_query_polygons_kernel,
-                             static_argnames="approximate")(
-        jnp.asarray(xy), jnp.asarray(valid), jnp.asarray(flags),
-        jnp.asarray(verts), jnp.asarray(ev), r)
-    keep_c, dist_c, cand_over, budget_over = jax.jit(
-        range_query_polygons_pruned_compact_kernel,
-        static_argnames=("budget", "cand", "point_chunk"))(
-        jnp.asarray(xy), jnp.asarray(valid), jnp.asarray(flags),
-        jnp.asarray(verts), jnp.asarray(ev), r,
-        budget=1024, cand=8, point_chunk=256)
-    assert int(cand_over) == 0 and int(budget_over) == 0
-    np.testing.assert_array_equal(np.asarray(keep_c), np.asarray(keep_d))
-    kept = np.asarray(keep_d)
-    np.testing.assert_allclose(np.asarray(dist_c)[kept],
-                               np.asarray(dist_d)[kept], rtol=0, atol=0)
+    keep_d, dist_d = _dense_polygon_range(xy, valid, flags, polys, r)
+    keep_c, dist_c, _slots, budget_over = _pruned_polygon_range(
+        xy, valid, flags, polys, grid, r, point_chunk=256, budget=1024)
+    assert int(budget_over) == 0
+    np.testing.assert_array_equal(keep_c, keep_d)
+    assert keep_d.any()
+    np.testing.assert_allclose(dist_c[keep_d], dist_d[keep_d],
+                               rtol=0, atol=0)
 
 
 def test_pruned_compact_budget_overflow(rng):
-    import jax
-    import jax.numpy as jnp
-
-    from spatialflink_tpu.operators.base import pack_query_geometries
-    from spatialflink_tpu.ops.range import (
-        range_query_polygons_pruned_compact_kernel,
-    )
+    from spatialflink_tpu.grid import UniformGrid
     from spatialflink_tpu.utils.helper import generate_query_polygons
 
+    grid = UniformGrid(20, 0.0, 10.0, 0.0, 10.0)
     polys = generate_query_polygons(10, 0.0, 0.0, 10.0, 10.0, grid_size=20,
                                     seed=8)
-    verts, ev = pack_query_geometries(polys, np.float64)
     n = 512
     xy = rng.uniform(0, 10, (n, 2))
     flags = np.ones(n, np.uint8)  # every lane is a candidate
-    _, _, _, budget_over = jax.jit(
-        range_query_polygons_pruned_compact_kernel,
-        static_argnames=("budget", "cand", "point_chunk"))(
-        jnp.asarray(xy), jnp.asarray(np.ones(n, bool)), jnp.asarray(flags),
-        jnp.asarray(verts), jnp.asarray(ev), 0.3,
-        budget=128, cand=8, point_chunk=128)
+    _, _, _, budget_over = _pruned_polygon_range(
+        xy, np.ones(n, bool), flags, polys, grid, 0.3, point_chunk=128,
+        budget=128)
     assert int(budget_over) == n - 128

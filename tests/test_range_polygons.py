@@ -168,7 +168,8 @@ def _grid():
 
 def _stacked(n_spread, n_stacked, seed):
     """``n_spread`` generator rectangles and ``n_stacked`` more, all at one
-    spot: a point there has more than 8 polygon boxes within r."""
+    spot: a point there has more than 8 polygons within r, so the cells
+    there list more than 8."""
     polys = generate_query_polygons(n_spread, *BBOX, grid_size=GRID_N,
                                     seed=seed)
     at = polys[0].rings[0]
@@ -177,21 +178,23 @@ def _stacked(n_spread, n_stacked, seed):
 
 
 #: name -> (polygons, points a window, the kernel the operator must pick,
-#:          cand re-runs, budget re-runs over the two windows)
+#:          the cell table's slots K (0: no table), budget re-runs over the
+#:          two windows). The two stacked cases armed the ``cand`` re-run
+#:          until K was read off the query set: now a wider K from set-up.
 CASES = {
     "dense": (lambda: generate_query_polygons(40, *BBOX, grid_size=GRID_N,
                                               seed=3), 6_000, "dense", 0, 0),
     "pruned": (lambda: generate_query_polygons(400, *BBOX, grid_size=GRID_N,
                                                seed=4), 12_000, "pruned",
-               0, 0),
+               8, 0),
     "pruned_compact": (lambda: generate_query_polygons(
-        90, *BBOX, grid_size=GRID_N, seed=5), 12_000, "pruned_compact", 0, 0),
+        90, *BBOX, grid_size=GRID_N, seed=5), 12_000, "pruned_compact", 8, 0),
     "pruned_cand_retry": (lambda: _stacked(400, 12, seed=6), 12_000,
-                          "pruned", 1, 0),
+                          "pruned", 16, 0),
     "compact_budget_retry": (lambda: generate_query_polygons(
-        90, *BBOX, grid_size=GRID_N, seed=7), 60_000, "pruned_compact", 0, 1),
+        90, *BBOX, grid_size=GRID_N, seed=7), 60_000, "pruned_compact", 8, 1),
     "compact_both_retries": (lambda: _stacked(90, 12, seed=8), 60_000,
-                             "pruned_compact", 1, 1),
+                             "pruned_compact", 16, 1),
 }
 
 
@@ -226,7 +229,7 @@ def _run(case):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_run_soa_equals_the_reference(case):
-    _make, n, kernel, cand_retries, budget_retries = CASES[case]
+    _make, n, kernel, slots, budget_retries = CASES[case]
     telemetry.enable()
     op, got, per_window, ref = _run(case)
     snap = telemetry.snapshot()["range"]
@@ -242,24 +245,38 @@ def test_run_soa_equals_the_reference(case):
         assert 0 < len(dist) < n  # some match, most do not
         assert (dist <= RADIUS).all()
         matches += len(dist)
-    # the re-runs: paid in the first window, the knobs persist into the next
+    # the one re-run left: paid in the first window, the budget persists
+    # into the next; a crowded spot is a wider K from set-up, never a re-run
     assert (snap["cand_retries"], snap["budget_retries"]) == (
-        cand_retries, budget_retries)
+        0, budget_retries)
     assert snap["windows"] == 2 and snap["points"] == 2 * n
     assert snap["matches"] == matches and snap["lanes"] >= snap["points"]
-    assert snap["cand"] == (0 if kernel == "dense" else op._ncand)
+    assert snap["cand"] == slots
     assert snap["budget"] == (
         op._cand_budget if kernel == "pruned_compact" else 0)
-    assert op._ncand == (16 if cand_retries else 8)
+    assert op._ncand == (slots or 8)
     if budget_retries:  # the next power of two over what the window held
         assert op._cand_budget in (8192, 16384)
     else:
         assert op._cand_budget == 4096
+    # the cell table's gauges, recorded once when the evaluator was built,
+    # and the windows answered through it
+    assert snap["index_windows"] == (2 if slots else 0)
+    if slots:
+        assert snap["index_slots"] == slots
+        assert 0 < snap["index_cells"] <= GRID_N * GRID_N
+        assert snap["index_cells"] <= snap["index_entries"] \
+            <= snap["index_cells"] * slots
+        if slots == 16:  # the twelve stacked and the one under them
+            assert snap["index_entries"] >= snap["index_cells"] + 12
+    else:
+        assert not any(k in snap for k in (
+            "index_slots", "index_entries", "index_cells"))
 
 
 @pytest.mark.parametrize("case", ["pruned", "compact_both_retries"])
 def test_every_crossing_is_a_leaf_and_the_spans_hold_none(case):
-    _make, n, _kernel, cand_retries, budget_retries = CASES[case]
+    _make, n, _kernel, _slots, budget_retries = CASES[case]
     telemetry.enable()
     _op, got, _per_window, _ref = _run(case)
     events = [e for e in telemetry.events if e.get("ph") == "X"]
@@ -285,11 +302,10 @@ def test_every_crossing_is_a_leaf_and_the_spans_hold_none(case):
               or e["name"].startswith("dispatch:")]
     assert leaves and not any(
         inside(leaf, sp) for leaf in leaves for sp in assemble + select)
-    # one ship a window whatever the re-runs; one fetch a run of the program
-    # (both knobs grew in the same re-run here), and nothing crosses unseen
-    reruns = max(cand_retries, budget_retries)
-    assert len(by("h2d")) == 2 + 1  # + the polygon table, shipped once
-    assert len(by("d2h")) == d2h_transfers == 2 + reruns
+    # one ship a window whatever the re-runs; one fetch a run of the program,
+    # and nothing crosses unseen
+    assert len(by("h2d")) == 2 + 1  # + the query set's tables, shipped once
+    assert len(by("d2h")) == d2h_transfers == 2 + budget_retries
     assert snap["range"]["windows"] == 2
 
 
@@ -307,3 +323,142 @@ def test_knobs_are_set_where_the_operator_is_built():
                            slide_step=10), _grid())
     assert (op._ncand, op._cand_budget, op.last_range_kernel) == (
         8, 4096, None)
+
+
+# -- the grid index: a cell's list holds every polygon within r of its points --
+
+
+def _triangles(rng, count, lo, hi, size):
+    """``count`` triangles with corners within ``size`` of a point uniform
+    over [lo, hi]²: boxes the polygon does not fill."""
+    at = rng.uniform(lo, hi, (count, 1, 2))
+    return [Polygon(obj_id=f"t{i}", rings=[ring]) for i, ring in
+            enumerate(at + rng.uniform(-size, size, (count, 3, 2)))]
+
+
+#: name -> (grid side n, grid bbox (min_x, max_x, min_y, max_y), radius,
+#:          polygons from (rng), slots K the set must give)
+INDEX_CASES = {
+    # the generator's rectangles on the cell's own grid, its r
+    "beijing_rectangles": (GRID_N, (BBOX[0], BBOX[2], BBOX[1], BBOX[3]), RADIUS,
+                           lambda rng: generate_query_polygons(
+                               300, *BBOX, grid_size=GRID_N, seed=11), 8),
+    # r of several cells: a polygon enters a 7 x 7 block of lists and more
+    "radius_of_three_cells": (20, (0.0, 10.0, 0.0, 10.0), 1.5,
+                              lambda rng: _triangles(rng, 25, 0.0, 10.0, 0.4),
+                              None),
+    # polygons across the grid's edge and wholly outside it
+    "straddling_and_outside": (16, (0.0, 8.0, 0.0, 8.0), 0.3,
+                               lambda rng: _triangles(rng, 60, -3.0, 11.0, 0.9),
+                               None),
+    # r = 0: only the boxes' own cells and the margin
+    "zero_radius": (10, (-5.0, 5.0, -5.0, 5.0), 0.0,
+                    lambda rng: _triangles(rng, 40, -5.0, 5.0, 0.7), None),
+    # twelve on one spot: K is read off the set, 16 from the start
+    "twelve_stacked": (GRID_N, (BBOX[0], BBOX[2], BBOX[1], BBOX[3]), RADIUS,
+                       lambda rng: _stacked(100, 12, seed=6), 16),
+    # a grid of one cell: everything in one list
+    "one_cell": (1, (0.0, 4.0, 0.0, 4.0), 0.5,
+                 lambda rng: _triangles(rng, 20, 0.0, 4.0, 0.5), 32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INDEX_CASES))
+def test_a_cells_list_holds_every_polygon_within_r_of_its_points(case, rng):
+    from spatialflink_tpu.operators.base import (
+        pack_cell_candidates,
+        pack_query_geometries,
+    )
+
+    n, (min_x, max_x, min_y, max_y), radius, make, slots = INDEX_CASES[case]
+    grid = UniformGrid(n, min_x, max_x, min_y, max_y)
+    polygons = make(rng)
+    verts, ev = pack_query_geometries(polygons, np.float64)
+    index = pack_cell_candidates(grid, verts, ev, radius)
+    p, table = len(polygons), index.table
+
+    # shape and bookkeeping
+    assert table.shape == (grid.num_cells + 1, index.slots)
+    assert table.dtype == np.int32 and table.min() >= 0 and table.max() <= p
+    assert index.slots >= 8 and index.slots & (index.slots - 1) == 0
+    assert slots is None or index.slots == slots
+    live = table < p
+    assert not live[grid.num_cells].any()  # the out-of-grid row is empty
+    assert index.entries == live.sum()
+    assert index.cells == live.any(axis=1).sum() > 0
+    assert index.slots // 2 < max(live.sum(axis=1).max(), 5)  # no rung too many
+    for row in table[live.any(axis=1)][:50]:  # a polygon once a list
+        assert len(set(row[row < p])) == (row < p).sum()
+
+    # points: uniform over the grid and a margin around it, on the cells'
+    # boundaries exactly, and on the boxes' grown edges
+    span = max_x - min_x
+    uniform = rng.uniform([min_x - 0.2 * span, min_y - 0.2 * span],
+                          [max_x + 0.2 * span, max_y + 0.2 * span], (3000, 2))
+    lines = min_x + grid.cell_length * rng.integers(0, n + 1, 600)
+    on_x = np.stack([lines, rng.uniform(min_y, max_y, 600)], axis=1)
+    on_y = np.stack([rng.uniform(min_x, max_x, 600),
+                     min_y + grid.cell_length * rng.integers(0, n + 1, 600)],
+                    axis=1)
+    rings = [np.asarray(q.rings[0], np.float64) for q in polygons]
+    corners = np.array([[f(r[:, 0]) + sx * radius, g(r[:, 1]) + sy * radius]
+                        for r in rings[:150] for f, sx in ((min, -1), (max, 1))
+                        for g, sy in ((min, -1), (max, 1))])
+    pts = np.concatenate([uniform, on_x, on_y, corners])
+    cell = grid.assign_cells_np(pts)
+    tol = 1e-6 * span  # the float32 band and more
+    near = held = 0
+    for i, q in enumerate(polygons):
+        d = polygon_distance(pts[:, 0], pts[:, 1], q.rings)
+        close = (d <= radius + tol) & (cell < grid.num_cells)
+        listed = (table[cell] == i).any(axis=1)
+        assert listed[close].all(), (case, i, pts[close & ~listed][:3])
+        near += close.sum()
+        held += listed.sum()
+    # it finds, and (where there is more than one cell) it prunes
+    assert near > 0 and (n == 1 or held < len(pts) * p / 2)
+    assert (cell == grid.num_cells).any()  # out-of-grid points were among them
+
+
+def test_cell_edges_hold_each_candidates_valid_edges_and_far_slots():
+    """``pack_cell_edges``: row g, slot k of the (cells + 1, 4, E, K) planes
+    holds the edges of polygon ``table[g, k]`` — its valid edges first, a
+    ring seam and the padding gone — and ``FAR_EDGE`` wherever no edge is:
+    the tail of a shorter polygon, an empty slot, the out-of-grid row."""
+    from spatialflink_tpu.operators.base import (
+        FAR_EDGE,
+        pack_cell_candidates,
+        pack_cell_edges,
+        pack_query_geometries,
+    )
+
+    grid = UniformGrid(4, 0.0, 16.0, 0.0, 16.0)
+    polygons = [Polygon(obj_id="holed", rings=HOLED),  # 4 + 4 edges, a seam
+                Polygon(obj_id="rect", rings=[RECT[0] + 10.0])]  # 4 edges
+    verts, ev = pack_query_geometries(polygons, np.float64)
+    assert ev.sum(axis=1).tolist() == [8, 4] and not ev[0].all()
+    index = pack_cell_candidates(grid, verts, ev, 0.5)
+    edges = pack_cell_edges(index.table, verts, ev)
+    assert edges.shape == (grid.num_cells + 1, 4, 8, index.slots)
+    assert edges.dtype == verts.dtype and edges.flags["C_CONTIGUOUS"]
+
+    def segments(ring):
+        ring = np.asarray(ring, np.float64)
+        if not np.array_equal(ring[0], ring[-1]):
+            ring = np.concatenate([ring, ring[:1]])
+        return {tuple(np.concatenate([a, b])) for a, b in zip(ring, ring[1:])}
+
+    want = [set().union(*map(segments, p.rings)) for p in polygons]
+    seen = set()
+    for g, row in enumerate(index.table):
+        for k, i in enumerate(row):
+            got = {tuple(e) for e in edges[g, :, :, k].T}
+            if i == len(polygons):
+                assert got == {(FAR_EDGE,) * 4}
+                continue
+            seen.add(int(i))
+            n_real = len(want[i])
+            assert {tuple(e) for e in edges[g, :, :n_real, k].T} == want[i]
+            assert (edges[g, :, n_real:, k] == FAR_EDGE).all()
+    assert seen == {0, 1}
+    assert (edges[grid.num_cells] == FAR_EDGE).all()
