@@ -452,22 +452,37 @@ def device_point_args(grid: UniformGrid, xy64: np.ndarray, oid, dtype):
     origin-centering before sub-f64 casts, invalid lanes carrying
     cell=grid.num_cells (the out-of-grid slot whose flag is always 0) —
     identical to PointBatch.from_arrays(...).with_cells(grid).
+
+    Three phase spans, inside whatever span the caller has open:
+    ``soa.center``, ``soa.cells``, ``soa.pad`` (args ``n``).
     """
+    with telemetry.span("soa.center", n=len(xy64)):
+        xy = center_coords(grid, xy64, dtype)
+    return _padded_point_args(grid, xy64, xy, oid)
+
+
+def _padded_point_args(grid: UniformGrid, xy64: np.ndarray, xy: np.ndarray,
+                       oid):
+    """``device_point_args`` past the centring: the cells of ``xy64``
+    (span ``soa.cells``), then the four lanes padded to the bucket (span
+    ``soa.pad``) around the centred ``xy``."""
     from spatialflink_tpu.utils.padding import next_bucket, pad_to_bucket
 
     n = len(xy64)
     b = next_bucket(n)
-    cell = grid.assign_cells_np(xy64)
+    with telemetry.span("soa.cells", n=n):
+        cell = grid.assign_cells_np(xy64)
     # Host-side padding only — no byte accounting here: callers ship
     # different subsets of these lanes (run_soa drops oid, the pane digest
     # path replaces valid/cell), so h2d tallies live at the actual
     # jnp.asarray ship sites (base.ship) to stay truthful.
-    return (
-        pad_to_bucket(center_coords(grid, xy64, dtype), b),
-        pad_to_bucket(np.ones(n, bool), b, fill=False),
-        pad_to_bucket(cell, b, fill=grid.num_cells),
-        None if oid is None else pad_to_bucket(np.asarray(oid, np.int32), b, fill=0),
-    )
+    with telemetry.span("soa.pad", n=n, bucket=b):
+        return (
+            pad_to_bucket(xy, b),
+            pad_to_bucket(np.ones(n, bool), b, fill=False),
+            pad_to_bucket(cell, b, fill=grid.num_cells),
+            None if oid is None else pad_to_bucket(np.asarray(oid, np.int32), b, fill=0),
+        )
 
 
 def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
@@ -479,7 +494,12 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
     from the chunk that lets the window fire, before the assembler
     consolidates (a further window of the same firing: from its slice), to
     the padded arrays (args ``n``, ``bucket``); the chunks appended before
-    are outside it, and a firing with no window has none. It holds no leaf.
+    are outside it, and a firing with no window has none. It holds no leaf;
+    inside it lie the passes' own phase spans ``soa.consolidate`` (the
+    assembler's firing), ``soa.center`` (``np.stack`` to float64 ``(n, 2)``,
+    ``center_coords``, the cast), ``soa.cells``, ``soa.pad``. The clock
+    reading it opens at goes on with the window (``win.t0_ns``), for the
+    operator's parent span to open at the same instant.
     """
     from spatialflink_tpu.streams.soa import SoaWindowAssembler
 
@@ -495,12 +515,15 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
             # Throughput meter for the SoA path (Point.java:237-253 analog);
             # candidate tallies come from the operator (it owns the flags).
             counters.record_window(win.count, 0, 0)
-        xy64 = np.stack(
-            [np.asarray(win.arrays["x"], np.float64),
-             np.asarray(win.arrays["y"], np.float64)],
-            axis=1,
-        )
-        return (win, *device_point_args(grid, xy64, win.arrays.get("oid"), dtype))
+        with telemetry.span("soa.center", n=win.count):
+            xy64 = np.stack(
+                [np.asarray(win.arrays["x"], np.float64),
+                 np.asarray(win.arrays["y"], np.float64)],
+                axis=1,
+            )
+            xy = center_coords(grid, xy64, dtype)
+        return (win, *_padded_point_args(grid, xy64, xy,
+                                         win.arrays.get("oid")))
 
     def fired(fire):
         """One firing's windows, padded. Under ``span`` each is timed from
@@ -511,6 +534,7 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
             return
         t0 = time.perf_counter_ns()
         for win in fire():
+            win.t0_ns = t0
             item = batch(win)
             telemetry.emit_span(span, t0, time.perf_counter_ns() - t0,
                                 n=win.count, bucket=len(item[2]))

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 import heapq
+import time
 from dataclasses import dataclass
 from typing import Iterable, Iterator, List, Sequence, Tuple
 
@@ -272,10 +273,13 @@ class PointPointJoinQuery(SpatialOperator):
         passes are those of the result held (0 from a program that does not
         count any), fetched with its count."""
         num_cells = self.grid.num_cells
-        self._climb_cap(max(
-            max_cell_count(lcell, lvalid, num_cells),
-            max_cell_count(rcell, rvalid, num_cells),
-        ))
+        # The host side of the pick (phase span ``join.capacity``; the
+        # re-runs' arithmetic below is a few integer operations).
+        with telemetry.span("join.capacity"):
+            self._climb_cap(max(
+                max_cell_count(lcell, lvalid, num_cells),
+                max_cell_count(rcell, rvalid, num_cells),
+            ))
         cap_retries = budget_retries = 0
         while True:
             res = call(self.join_cap, self.join_budget)
@@ -608,7 +612,17 @@ class PointPointJoinQuery(SpatialOperator):
         the pair budget keeps a quarter of headroom over the last count
         (``_grow_budget``, ``max_pairs`` its first value); a window either
         one fails to hold is run again, never yielded short. Two fetches a
-        window: count and overflow, then the pairs found."""
+        window: count and overflow, then the pairs found.
+
+        With telemetry on, one parent span ``join.window`` a two-sided
+        window (args ``n``: events of both sides), emitted by hand at the
+        hand-back: from the chunk that lets the left side's window fire
+        (``join.assemble_left``'s start, ``win.t0_ns``) to just before the
+        yield, so it holds ``join.assemble_left``, ``join.assemble`` (the
+        right side), ``h2d``, ``join.capacity``, ``dispatch:*`` and both
+        ``d2h`` and none of the consumer's time. A one-sided window emits
+        none, and neither does a window whose left side was in hand while
+        a right-only window went to the consumer."""
         from spatialflink_tpu.operators.base import soa_point_batches
         from spatialflink_tpu.ops.counters import (
             count_join_candidates,
@@ -619,17 +633,20 @@ class PointPointJoinQuery(SpatialOperator):
         head = jitted(head_pairs, "bucket")
         layers = self.grid.candidate_layers(radius)
         fr = self._filter_radius(radius)
-        gen_l = soa_point_batches(self.grid, left_chunks, self.conf, dtype)
+        gen_l = soa_point_batches(self.grid, left_chunks, self.conf, dtype,
+                                  span="join.assemble_left")
         gen_r = _spanned(
             soa_point_batches(self.grid, right_chunks, self.conf, dtype),
             "join.assemble",
         )
         self.join_budget = max(self.join_budget, max_pairs)
         warmed = 0  # the budget whose head programs are compiled
+        left_waited = False  # wl sat through a right-only window's hand-back
         for kind, wl, wr in _aligned_soa_windows(
             gen_l, gen_r, lambda w: w[0].start, lambda w: w[0].start
         ):
             if kind != "both":
+                left_waited = kind == "right"
                 w = wl[0] if kind == "left" else wr[0]
                 yield (w.start, w.end, np.empty(0, np.int32),
                        np.empty(0, np.int32), np.empty(0), 0, 0)
@@ -672,6 +689,13 @@ class PointPointJoinQuery(SpatialOperator):
                 budget=self.join_budget, peel_passes=passes,
             )
             self._grow_budget(count)  # headroom for the next window
+            if win.t0_ns is not None and not left_waited:
+                telemetry.emit_span(
+                    "join.window", win.t0_ns,
+                    time.perf_counter_ns() - win.t0_ns,
+                    n=win.count + wr[0].count,
+                )
+            left_waited = False
             yield (win.start, win.end, li, ri, dd, count, 0)
 
 

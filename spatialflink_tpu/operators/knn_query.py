@@ -11,6 +11,7 @@ the ordered (objID, dist, representative object) list.
 from __future__ import annotations
 
 import functools
+import time
 from dataclasses import dataclass
 from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
 
@@ -745,6 +746,18 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
         beyond the candidate budget falls back IN-PROGRAM, so results
         are exact either way); 'xla'/'pallas' force. The chosen kind is
         recorded on ``self.last_wire_digest_kind``.
+
+        With telemetry on, one parent span ``wire.pane`` a received pane
+        (args ``n``), emitted by hand: from the pane's receipt from
+        ``slides`` to just before its result is yielded (a pane that
+        yields nothing: to the end of its body), so it holds none of the
+        consumer's time. Its children tile it: ``wire.prepare``, ``h2d``,
+        ``wire.step_args`` (what is built on the device a pane before the
+        digest step), ``dispatch:*``, ``wire.merge_args`` (the ring, the
+        carry, the tuples ``merge`` takes), ``d2h`` twice and, between the
+        two, ``wire.slice`` (the eager ``[:nv]`` slices). Under a
+        ``batch_slides`` rung the parent closes before the batch's first
+        yield; the synthetic panes of ``flush_at_end`` have none.
         """
         from spatialflink_tpu.operators.query_config import QueryType
         from spatialflink_tpu.ops.compaction import wire_pane_bucket
@@ -817,24 +830,46 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
             "counts": list(counts),
         }
 
-        def merge_window(pane_i):
-            # Gap-window suppression: a window none of whose panes held
-            # an event does not exist on the SoA path (the assembler
-            # only builds windows containing events) — skip it here
-            # too. Event count, NOT digest liveness, decides: a window
-            # of events all out of radius still fires (nv = 0).
+        def merge_args():
+            """The ring as the two tuples ``merge`` takes; None for a gap
+            window. Gap-window suppression: a window none of whose panes
+            held an event does not exist on the SoA path (the assembler
+            only builds windows containing events) — skip it here too.
+            Event count, NOT digest liveness, decides: a window of events
+            all out of radius still fires (nv = 0)."""
             if not any(counts):
                 return None
-            res = merge(
-                tuple(s for s, _ in digests),
-                tuple(r for _, r in digests), no_bases, k=k,
-            )
+            return (tuple(s for s, _ in digests),
+                    tuple(r for _, r in digests))
+
+        def merge_window(pane_i, ring):
+            if ring is None:
+                return None
+            res = merge(*ring, no_bases, k=k)
             return (start_ms + (pane_i - ppw + 1) * slide_ms, res)
 
         def fetch_one(w_start, res):
             nv = int(telemetry.fetch(res.num_valid))
-            segs, dists = telemetry.fetch((res.segment[:nv], res.dist[:nv]))
+            with telemetry.span("wire.slice"):
+                rows = (res.segment[:nv], res.dist[:nv])
+            segs, dists = telemetry.fetch(rows)
             return (w_start, w_start + size, segs, dists, nv)
+
+        #: perf_counter_ns at the open pane's receipt; None between panes,
+        #: past the pane's hand-back, and with telemetry off
+        pane_t0 = None
+
+        def close_pane():
+            """Emit the open pane's parent span, once: called right before
+            every yield (the pane's events already head ``counts``), so no
+            parent holds a consumer's time."""
+            nonlocal pane_t0
+            if pane_t0 is not None:
+                t0, pane_t0 = pane_t0, None
+                telemetry.emit_span(
+                    "wire.pane", t0, time.perf_counter_ns() - t0,
+                    n=counts[-1],
+                )
 
         pending: list = []
 
@@ -863,11 +898,12 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
                 # resume — lost egress).
                 self._wire_pane_carry = carry
                 nv = int(nv_a)
+                close_pane()
                 yield (w_start, w_start + size, np.asarray(seg_a)[:nv],
                        np.asarray(dist_a)[:nv], nv)
             del pending[:]
 
-        def emit(pane_i, carry):
+        def emit(pane_i, carry, ring):
             """Yield-ready results for this pane's window (if any).
 
             Under an active overload ``batch_slides`` degradation rung
@@ -880,7 +916,7 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
             is open the carry stays at the last YIELDED window's pane
             (flush_pending advances it per yield).
             """
-            out = merge_window(pane_i)
+            out = merge_window(pane_i, ring)
             if out is None:
                 if not pending:
                     self._wire_pane_carry = carry
@@ -888,7 +924,9 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
             width = overload.batch_slides()
             if width <= 1 and not pending:
                 self._wire_pane_carry = carry
-                yield fetch_one(*out)
+                result = fetch_one(*out)
+                close_pane()
+                yield result
                 return
             pending.append((out, carry))
             if len(pending) >= max(width, 1):
@@ -912,6 +950,8 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
         i = pane0 - 1
         last_carry = self._wire_pane_carry
         for i, wire_p in enumerate(slides, start=pane0):
+            if telemetry.enabled:
+                pane_t0 = time.perf_counter_ns()
             # The host's share of a pane before it crosses: everything
             # between the hand-over and ``ship``.
             with telemetry.span("wire.prepare") as sp:
@@ -927,21 +967,26 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
                 getattr(sp, "args", {}).update(n=n, bucket=nb)
             telemetry.record_wire_pane(n, nb)
             (wire_d,) = ship(wire_p)
+            with telemetry.span("wire.step_args"):
+                n_d = jnp.int32(n)
             if jstep is None:
                 kind, step = select_wire_digest_step(
-                    wire_d, jnp.int32(n), q, scale, origin, r32,
+                    wire_d, n_d, q, scale, origin, r32,
                     num_segments=num_segments, cand=cand,
                     interpret=interpret, strategy=strategy,
                 )
                 self.last_wire_digest_kind = kind
                 jstep = _wire_digest_program(kind, step)
-            d = jstep(wire_d, jnp.int32(n), q, scale, origin, r32)
-            digests.append((d.seg_min, d.rep))
-            del digests[:-ppw]
-            counts.append(n)
-            del counts[:-ppw]
-            last_carry = carry_now(i + 1)
-            yield from emit(i, last_carry)
+            d = jstep(wire_d, n_d, q, scale, origin, r32)
+            with telemetry.span("wire.merge_args"):
+                digests.append((d.seg_min, d.rep))
+                del digests[:-ppw]
+                counts.append(n)
+                del counts[:-ppw]
+                last_carry = carry_now(i + 1)
+                ring = merge_args()
+            yield from emit(i, last_carry, ring)
+            close_pane()  # a pane that yielded nothing
         # Flush iff ≥1 REAL pane exists in the logical stream: consumed
         # this call (i advanced past pane0-1) or before the checkpoint
         # (pane0 > 0). A restore taken before any pane must NOT flush —
@@ -955,7 +1000,7 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
                 del digests[:-ppw]
                 counts.append(0)
                 del counts[:-ppw]
-                yield from emit(i + j, last_carry)
+                yield from emit(i + j, last_carry, merge_args())
         yield from flush_pending()
         # End-of-call invariant (what the call-boundary checkpoint
         # callers pair with source offsets): every consumed REAL pane is

@@ -16,6 +16,7 @@ The GeoFlink pruning semantics are preserved per class:
 from __future__ import annotations
 
 import functools
+import time
 from dataclasses import dataclass
 from typing import Iterable, Iterator, List, Sequence
 
@@ -379,7 +380,13 @@ class _PointStreamRangeQuery(SpatialOperator):
         events (so callers get the actual matches, not just a count).
         Works for every query kind of the family (point / polygon /
         linestring query sets), with run()'s exact kernel selection —
-        including the pruned/compact large-polygon-set paths."""
+        including the pruned/compact large-polygon-set paths.
+
+        With telemetry on, one parent span ``range.window`` a window
+        (args ``n``), emitted by hand at the hand-back: from
+        ``range.assemble``'s start (``win.t0_ns``) to just before the
+        yield, so it holds ``range.assemble``, ``h2d``, ``dispatch:*``,
+        ``d2h`` and ``range.select`` and none of the consumer's time."""
         from spatialflink_tpu.operators.base import soa_point_batches
 
         if not isinstance(query_set, (list, tuple)):
@@ -417,6 +424,11 @@ class _PointStreamRangeQuery(SpatialOperator):
                 cand=self._ncand if kernel.startswith("pruned") else 0,
                 budget=self._cand_budget if kernel == "pruned_compact" else 0,
             )
+            if win.t0_ns is not None:
+                telemetry.emit_span(
+                    "range.window", win.t0_ns,
+                    time.perf_counter_ns() - win.t0_ns, n=n,
+                )
             yield win.start, win.end, matched, dists
 
 

@@ -39,6 +39,11 @@ class SoaWindow:
     start: int
     end: int
     arrays: Dict[str, np.ndarray]  # each (n,), same order, incl. "ts"
+    #: ``perf_counter_ns`` at which ``soa_point_batches`` began to
+    #: materialise this window under a ``span``: where the operator's
+    #: parent span (``range.window``, ``join.window``) opens. None
+    #: wherever that span was not timed (telemetry off, no ``span``).
+    t0_ns: Optional[int] = None
 
     @property
     def count(self) -> int:
@@ -118,9 +123,20 @@ class _SlidingAssemblerBase:
         yield from self.flush()
 
     def _fire(self, wm: int, record_lag: bool = True):
-        out = []
         if not self._due(wm):
-            return out
+            return []
+        # One phase span a firing (inside whatever span the caller has
+        # open): the consolidation, the late trim, the windows' slices,
+        # the eviction.
+        with telemetry.span("soa.consolidate") as sp:
+            out = self._fire_due(wm, record_lag)
+            if telemetry.enabled:
+                sp.args.update(n=sum(w.count for w in out),
+                               windows=len(out))
+        return out
+
+    def _fire_due(self, wm: int, record_lag: bool):
+        out = []
         ts = self._consolidate()
         # Events older than the earliest live window start are late beyond
         # every remaining window: count and trim.

@@ -855,13 +855,21 @@ class Telemetry:
 
     def fetch(self, x):
         """True-sync device→host fetch with timing + byte accounting
-        (one ``d2h`` span carrying ``bytes``).
+        (one ``d2h`` span carrying ``bytes``, and inside it one
+        ``d2h.wait``).
 
         The tree's ONE synchronization point (sfcheck sync-discipline):
         a real ``jax.device_get``, so every wait on the device is timed
         and its bytes counted. Accepts any pytree; returns host numpy. Use this IN PLACE OF the
         operator's ``np.asarray``/``device_get`` so accounting rides the
         fetch the operator was doing anyway (zero extra round trips).
+
+        ``d2h.wait`` is the leaf's own child (no profiler annotation goes
+        with it): the time until every leaf of ``x`` is computed
+        (``block_until_ready`` launches nothing: in a synchronous loop it
+        is the inputs landing, the program, and whatever was queued before
+        it). The rest of ``d2h`` is the copy. A loop that blocks nowhere
+        else keeps the device busy only while it is inside ``d2h.wait``.
         """
         import jax
 
@@ -873,11 +881,18 @@ class Telemetry:
         # spans own that name, and a sum by name must not count the wait
         # twice.
         with _Span(self, "d2h", {}) as sp:
+            t0 = time.perf_counter_ns()
+            jax.block_until_ready(x)
+            waited_ns = time.perf_counter_ns() - t0
             out = jax.device_get(x)
             nbytes = 0
             for leaf in jax.tree_util.tree_leaves(out):
                 nbytes += getattr(leaf, "nbytes", 0)
             sp.args["bytes"] = int(nbytes)
+        # Timed by hand and emitted once the leaf has closed: the child's
+        # own emit (tens of microseconds on a cold cache) is then no part
+        # of ``d2h``, which reads what it read without the split.
+        self._emit_span("d2h.wait", t0, waited_ns, None)
         self.account_d2h(nbytes)
         return out
 

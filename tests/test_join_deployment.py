@@ -19,6 +19,7 @@ from spatialflink_tpu.telemetry import _NULL_SPAN, telemetry
 from spatialflink_tpu.utils.padding import next_bucket
 
 from join_reference import Reference, brute_force
+from span_tiling import assert_parents_tile, inside, slow_consumer, x_spans
 
 GRID_N, SPAN = 8, 8.0
 BBOX = (0.0, 0.0, SPAN, SPAN)
@@ -246,6 +247,62 @@ def test_one_dispatch_span_a_call_and_bytes_of_what_was_found(traced):
         table[r["kernel"]] = table.get(r["kernel"], 0) + r["calls"]
     assert table["join_window_pallas"] == calls
     assert table["head_pairs"] >= j["windows"]
+
+
+def test_join_window_parent_tiles_the_window_and_no_consumer_time(traced):
+    """One ``join.window`` a two-sided window, from the left side's firing
+    chunk to the hand-back: both sides' assembly, the capacity pick, the
+    ship, every run of the program and both fetches lie inside it, the
+    consumer's time does not, and the pairs are the telemetry-off run's."""
+    left, right = _streams(seed=14)
+    telemetry.disable()
+    plain = list(_run(_operator("pallas_interpret"), left, right, np.float32))
+    telemetry.enable()
+    naps = []
+    got = slow_consumer(
+        _run(_operator("pallas_interpret"), left, right, np.float32), naps)
+    events = x_spans(telemetry.events)
+    assert len(got) == len(plain) == 2
+    for a, b in zip(plain, got):
+        assert a[:2] == b[:2] and a[5:] == b[5:]
+        assert all(np.array_equal(u, v) for u, v in zip(a[2:5], b[2:5]))
+    parents, inner = assert_parents_tile(events, "join.window", naps)
+    assert [p["args"]["n"] for p in parents] == [
+        len(lw["ts"]) + len(rw["ts"]) for lw, rw in zip(left, right)]
+    # outside every parent: the right side's last step, which finds its
+    # stream at an end (``_spanned`` times every step)
+    assert [e["name"] for e in events if e["name"] != "join.window"
+            and not any(inside(e, p) for p in parents)] == ["join.assemble"]
+    lefts = [e for e in events if e["name"] == "join.assemble_left"]
+    j = telemetry.snapshot()["join"]
+    retries = [j["cap_retries"] + j["budget_retries"], 0]  # paid once
+    for p, names, asm, again in zip(parents, inner, lefts, retries):
+        assert p["ts"] == asm["ts"]  # one clock reading opens both
+        for once in ("join.assemble_left", "join.assemble", "join.capacity",
+                     "h2d"):
+            assert names.count(once) == 1, (once, names)
+        # each side's passes, inside its own assembly span
+        for soa in ("soa.consolidate", "soa.center", "soa.cells", "soa.pad"):
+            assert names.count(soa) == 2
+        assert names.count("dispatch:join_window_pallas") == 1 + again
+        assert names.count("d2h") == names.count("d2h.wait") == 2 + again
+        assert names.index("join.assemble_left") \
+            < names.index("join.assemble") < names.index("h2d") \
+            < names.index("join.capacity")
+
+
+def test_one_sided_windows_emit_no_parent_and_stale_lefts_neither(traced):
+    """A right-only window goes to the consumer while the left side's next
+    window is in hand: the two-sided window after it emits no parent, since
+    one from the left's firing would hold that consumer's time."""
+    left, right = _streams(seed=16, windows=3)
+    del left[0]  # window 0 is the right side's alone
+    naps = []
+    got = slow_consumer(_run(_operator("xla"), left, right, np.float64), naps)
+    assert [o[5] > 0 for o in got] == [False, True, True]
+    parents, _inner = assert_parents_tile(telemetry.events, "join.window",
+                                          naps)
+    assert len(parents) == 1  # window 2's; window 1's left sat through a nap
 
 
 def test_null_span_when_telemetry_is_off():

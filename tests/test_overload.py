@@ -780,6 +780,30 @@ class TestBatchSlides:
         _batching_controller()
         assert run() == base
 
+    def test_wire_pane_parents_close_before_a_batch_is_yielded(self):
+        """Telemetry on under the rung: still one ``wire.pane`` a received
+        pane, closed before the batch's first yield, so the consumer's time
+        between the batch's results is in none."""
+        from span_tiling import assert_parents_tile, slow_consumer
+        from spatialflink_tpu.telemetry import telemetry
+
+        make_op, collect, panes, qp, wf = _wire_pane_setup()
+        base = collect(make_op().run_wire_panes(panes, qp, 2.0, 5, 16, wf))
+        _batching_controller()
+        naps = []
+        telemetry.enable()
+        try:
+            got = collect(slow_consumer(make_op().run_wire_panes(
+                panes, qp, 2.0, 5, 16, wf), naps, nap_s=0.005))
+            events = list(telemetry.events)
+        finally:
+            telemetry.disable()
+        assert got == base and len(naps) == len(base) == len(panes) + 2
+        parents, inner = assert_parents_tile(events, "wire.pane", naps)
+        assert len(parents) == len(panes)
+        # one fetch a batch of three windows, inside the third pane's parent
+        assert [names.count("d2h") for names in inner] == [0, 0, 1] * 3
+
     def test_mid_batch_checkpoint_never_loses_pending_windows(
             self, tmp_path):
         """A checkpoint taken at a yield while a batch_slides batch is

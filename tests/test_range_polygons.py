@@ -27,6 +27,7 @@ from spatialflink_tpu.operators import (
 )
 from spatialflink_tpu.telemetry import telemetry
 from spatialflink_tpu.utils.helper import generate_query_polygons
+from span_tiling import assert_parents_tile, inside, slow_consumer, x_spans
 
 BBOX = (115.5, 39.6, 117.6, 41.1)  # conf/geoflink-conf.yml's, as the cell's
 GRID_N = 100
@@ -212,14 +213,14 @@ def _window_chunks(n, seed, windows=2, chunk=5_000):
     return chunks, per_window
 
 
-def _run(case):
+def _run(case, consume=list):
     make, n, _kernel, _c, _b = CASES[case]
     polygons = make()
     op = PointPolygonRangeQuery(
         QueryConfiguration(QueryType.WindowBased, window_size=10,
                            slide_step=10, approximate_query=False), _grid())
     chunks, per_window = _window_chunks(n, seed=len(case))
-    got = list(op.run_soa(iter(chunks), polygons, RADIUS))
+    got = consume(op.run_soa(iter(chunks), polygons, RADIUS))
     ref = Reference(bbox=BBOX, grid_cells=GRID_N,
                     polygons=[[np.asarray(r) for r in p.rings]
                               for p in polygons],
@@ -307,6 +308,53 @@ def test_every_crossing_is_a_leaf_and_the_spans_hold_none(case):
     assert len(by("h2d")) == 2 + 1  # + the query set's tables, shipped once
     assert len(by("d2h")) == d2h_transfers == 2 + budget_retries
     assert snap["range"]["windows"] == 2
+
+
+@pytest.mark.parametrize("case", ["pruned", "compact_budget_retry"])
+def test_range_window_parent_tiles_the_window_and_no_consumer_time(case):
+    """One ``range.window`` a window, from ``range.assemble``'s own start to
+    the hand-back: every span of the loop lies inside one, the four ``soa.*``
+    passes once a window inside ``range.assemble``, each ``d2h`` holds its one
+    ``d2h.wait``, and the answers are the telemetry-off run's."""
+    _make, n, _kernel, _slots, budget_retries = CASES[case]
+    _op, plain, _pw, _ref = _run(case)
+    naps = []
+    telemetry.enable()
+    try:
+        _op, got, _pw, _ref = _run(
+            case, consume=lambda results: slow_consumer(results, naps))
+        events = x_spans(telemetry.events)
+    finally:
+        telemetry.disable()
+    assert len(got) == len(plain) == len(naps) == 2
+    for (s1, e1, m1, d1), (s2, e2, m2, d2) in zip(plain, got):
+        assert (s1, e1) == (s2, e2) and np.array_equal(d1, d2)
+        assert m1.keys() == m2.keys()
+        assert all(np.array_equal(m1[k], m2[k]) for k in m1)
+    parents, inner = assert_parents_tile(events, "range.window", naps)
+    assert [p["args"]["n"] for p in parents] == [n, n]
+    # nothing of the loop is outside a parent but the query set's one ship
+    loose = [e["name"] for e in events if e["name"] != "range.window"
+             and not any(inside(e, p) for p in parents)]
+    assert loose == ["h2d"]
+    passes = ["soa.consolidate", "soa.center", "soa.cells", "soa.pad"]
+    assemble = [e for e in events if e["name"] == "range.assemble"]
+    for p, names, asm in zip(parents, inner, assemble):
+        assert p["ts"] == asm["ts"]  # one clock reading opens both
+        assert names.count("range.assemble") == names.count("h2d") == 1
+        assert names.count("range.select") == 1
+        mine = [e for e in events if e["name"] in passes and inside(e, asm)]
+        assert sorted(e["name"] for e in mine) == sorted(passes)
+        assert all(e["args"]["n"] == n for e in mine)
+        assert sum(e["dur"] for e in mine) <= asm["dur"]
+    # the first window pays the budget re-run: one more dispatch and fetch
+    assert inner[0].count("d2h") == 1 + budget_retries
+    assert inner[1].count("d2h") == 1
+    d2h = [e for e in events if e["name"] == "d2h"]
+    waits = [e for e in events if e["name"] == "d2h.wait"]
+    assert len(waits) == len(d2h) == 2 + budget_retries
+    for wait, leaf in zip(waits, d2h):
+        assert inside(wait, leaf) and wait["dur"] <= leaf["dur"]
 
 
 def test_nothing_recorded_and_no_span_when_telemetry_is_off():

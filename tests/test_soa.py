@@ -101,6 +101,62 @@ def test_soa_point_batches_span_only_where_a_window_fired():
     assert [e["args"]["n"] for e in spans] == [2, 2]
 
 
+@pytest.mark.parametrize("slide", [10, 5], ids=["tumbling", "sliding"])
+def test_soa_point_batches_hands_its_clock_on_and_names_its_passes(slide):
+    """Under a span the window carries the clock reading the span opened at
+    (``t0_ns``: the operator's parent span opens there too), and the four
+    passes lie inside the span, once a window — ``soa.consolidate`` once a
+    firing, so a window after the first of one firing has three. Telemetry
+    off, or no span asked for: no reading, no event, the same arrays."""
+    from spatialflink_tpu.operators.base import soa_point_batches
+    from spatialflink_tpu.telemetry import telemetry
+
+    conf = QueryConfiguration(QueryType.WindowBased, window_size=10,
+                              slide_step=slide)
+    ts = np.arange(0, 40_000, 250, dtype=np.int64)
+    rng = np.random.default_rng(3)
+    cols = {"ts": ts, "x": rng.uniform(0, 10, len(ts)),
+            "y": rng.uniform(0, 10, len(ts))}
+    chunks = [{k: v[i:i + 16] for k, v in cols.items()}
+              for i in range(0, len(ts), 16)]
+    kept = len(telemetry.events)  # an earlier test's, until the next enable
+    plain = list(soa_point_batches(GRID, chunks, conf, np.float32,
+                                   span="t.assemble"))
+    assert len(telemetry.events) == kept
+    assert all(w[0].t0_ns is None for w in plain)
+    telemetry.enable()
+    try:
+        bare = list(soa_point_batches(GRID, chunks, conf, np.float32))
+        n_bare = len(telemetry.events)
+        wins = list(soa_point_batches(GRID, chunks, conf, np.float32,
+                                      span="t.assemble"))
+        events = telemetry.events[n_bare:]
+    finally:
+        telemetry.disable()
+    assert all(w[0].t0_ns is None for w in bare)
+    assert len(plain) == len(wins) == len(bare) >= 4
+    for a, b in zip(plain, wins):
+        assert (a[0].start, a[0].end) == (b[0].start, b[0].end)
+        assert all(np.array_equal(u, v) for u, v in zip(a[1:4], b[1:4]))
+    spans = [e for e in events if e["name"] == "t.assemble"]
+    assert [e["ts"] for e in spans] == [w[0].t0_ns // 1000 for w in wins]
+    firings = 0
+    for sp, w in zip(spans, wins):
+        mine = [e for e in events if e["name"].startswith("soa.")
+                and sp["ts"] <= e["ts"]
+                and e["ts"] + e["dur"] <= sp["ts"] + sp["dur"] + 2]
+        names = sorted(e["name"] for e in mine)
+        firings += "soa.consolidate" in names
+        assert [n for n in names if n != "soa.consolidate"] == [
+            "soa.cells", "soa.center", "soa.pad"]
+        assert all(e["args"]["n"] == w[0].count for e in mine
+                   if e["name"] != "soa.consolidate")
+        assert sum(e["dur"] for e in mine) <= sp["dur"]
+    # every firing's consolidation is inside its first window's span
+    assert firings == len([e for e in events
+                           if e["name"] == "soa.consolidate"]) >= 3
+
+
 def test_soa_assembler_out_of_order_within_bound(rng):
     base = np.sort(rng.integers(0, 30_000, 500)).astype(np.int64)
     jitter = rng.integers(-1500, 1500, 500)

@@ -353,6 +353,49 @@ def test_fetch_accounts_bytes_and_emits_event():
     assert not [e for e in telemetry.events if e["name"] == "fetch"]
 
 
+@pytest.mark.parametrize("tree", [
+    lambda x: x,
+    lambda x: (x, x[:5]),
+    lambda x: {"rows": [x, x * 2], "n": 7, "host": np.arange(3)},
+], ids=["array", "tuple", "mixed_tree"])
+@pytest.mark.parametrize("node", [None, "q9"])
+def test_fetch_splits_its_leaf_into_the_wait_and_the_copy(tree, node):
+    """Exactly one ``d2h.wait`` inside each ``d2h``: the time until every
+    leaf is computed, before the copy. ``d2h`` keeps its name, its bytes and
+    its count; disabled, nothing is emitted and the result is the same."""
+    x = jnp.arange(1024, dtype=jnp.float32)
+    plain = telemetry.fetch(tree(x))
+    assert not telemetry.events and telemetry.d2h_transfers == 0
+    telemetry.enable()
+    with telemetry.scope(node):
+        out = [telemetry.fetch(tree(x)) for _ in range(3)]
+    leaves = jax.tree_util.tree_leaves
+    for o in out:
+        assert jax.tree_util.tree_structure(o) \
+            == jax.tree_util.tree_structure(plain)
+        assert all(np.array_equal(a, b)
+                   for a, b in zip(leaves(o), leaves(plain)))
+    spans = [e for e in telemetry.events if e["ph"] == "X"]
+    d2h = [e for e in spans if e["name"] == "d2h"]
+    waits = [e for e in spans if e["name"] == "d2h.wait"]
+    assert len(spans) == 6 and len(d2h) == len(waits) == 3
+    nbytes = sum(getattr(a, "nbytes", 0) for a in leaves(plain))
+    assert telemetry.d2h_transfers == 3 and telemetry.d2h_bytes == 3 * nbytes
+    for wait, leaf in zip(waits, d2h):
+        assert leaf["args"]["bytes"] == nbytes
+        assert wait["ts"] <= leaf["ts"] + leaf["dur"]  # before the copy
+        assert "bytes" not in wait.get("args", {})
+        assert wait["tid"] == leaf["tid"]
+        assert leaf["ts"] <= wait["ts"]
+        assert wait["ts"] + wait["dur"] <= leaf["ts"] + leaf["dur"] + 2
+        assert wait["dur"] <= leaf["dur"]
+        for e in (wait, leaf):
+            assert e.get("args", {}).get("node") == node
+    # the child is emitted once the leaf has closed, so that its own emit
+    # is no part of ``d2h``
+    assert [e["name"] for e in spans] == ["d2h", "d2h.wait"] * 3
+
+
 def test_operator_ship_path_accounts_h2d():
     telemetry.enable()
     conf = QueryConfiguration(QueryType.WindowBased, window_size=10,

@@ -31,6 +31,7 @@ from spatialflink_tpu.operators.knn_query import PointPointKNNQuery
 from spatialflink_tpu.ops.compaction import wire_pane_bucket
 from spatialflink_tpu.streams.wire import WireFormat, WirePaneAssembler
 from spatialflink_tpu.telemetry import telemetry
+from span_tiling import assert_parents_tile, inside, slow_consumer, x_spans
 
 BBOX = (115.5, 39.6, 117.6, 41.1)  # min_x, min_y, max_x, max_y
 GRID = UniformGrid(100, BBOX[0], BBOX[2], BBOX[1], BBOX[3])
@@ -52,12 +53,12 @@ def _reference(ids=IDS):
     return Reference(bbox=BBOX, query=QUERY, radius=RADIUS, k=K, ids=ids)
 
 
-def _run(panes, strategy="xla"):
+def _run(panes, strategy="xla", consume=list):
     op = PointPointKNNQuery(CONF, GRID)
     out = [(s, e, np.asarray(oo), np.asarray(dd), nv)
-           for s, e, oo, dd, nv in op.run_wire_panes(
+           for s, e, oo, dd, nv in consume(op.run_wire_panes(
                panes, Point(x=QUERY[0], y=QUERY[1]), RADIUS, K, IDS, WF,
-               start_ms=T0, strategy=strategy, interpret=True)]
+               start_ms=T0, strategy=strategy, interpret=True))]
     assert op.last_wire_digest_kind in (strategy, None)
     return out
 
@@ -224,6 +225,48 @@ def test_wire_prepare_span_and_wire_counters_once_a_pane():
     assert wire == {"panes": len(sizes), "points": sum(sizes),
                     "lanes": sum(buckets),
                     "pad_lanes": sum(buckets) - sum(sizes)}
+
+
+def test_wire_pane_parent_tiles_the_pane_and_no_consumer_time():
+    """One ``wire.pane`` a received pane, from its receipt to just before its
+    result is yielded (to the end of its body where it yields none): every
+    span of the loop lies inside one, a result's two ``d2h`` with their
+    ``d2h.wait`` and the ``wire.slice`` between them, and nothing of the
+    consumer; the windows are the telemetry-off run's, bit for bit."""
+    rng = np.random.default_rng(12)
+    # pane 0 and pane 3 close windows that hold no event: they yield none
+    sizes = [0, 300, 0, 0, 517, 128]
+    panes = [WF.pack_pane(*_events(rng, n)) for n in sizes]
+    plain = _run(panes)
+    naps = []
+    telemetry.enable()
+    try:
+        traced = _run(panes, consume=lambda r: slow_consumer(r, naps))
+        events = x_spans(telemetry.events)
+    finally:
+        telemetry.disable()
+    assert len(plain) == len(traced) == len(naps) == 5  # 4 + the last flush
+    for (s1, e1, o1, d1, n1), (s2, e2, o2, d2, n2) in zip(plain, traced):
+        assert (s1, e1, n1) == (s2, e2, n2)
+        assert np.array_equal(o1, o2) and np.array_equal(d1, d2)
+    parents, inner = assert_parents_tile(events, "wire.pane", naps)
+    assert [p["args"]["n"] for p in parents] == sizes
+    # outside every parent: only the trailing partial window's merge and
+    # fetch, which no received pane stands behind
+    loose = [e["name"] for e in events if e["name"] != "wire.pane"
+             and not any(inside(e, p) for p in parents)]
+    assert sorted(set(loose)) == [
+        "d2h", "d2h.wait", "dispatch:knn_merge_digest_list", "wire.slice"]
+    every = ["wire.prepare", "h2d", "wire.step_args", "wire.merge_args"]
+    result = ["d2h", "d2h.wait", "wire.slice", "d2h", "d2h.wait"]
+    for names, yields in zip(inner, [False, True, True, False, True, True]):
+        kernels = [n for n in names if n.startswith("dispatch:")]
+        rest = [n for n in names if not n.startswith("dispatch:")]
+        assert sorted(rest) == sorted(every + (result if yields else []))
+        assert len(kernels) == 1 + yields  # the digest step, then the merge
+        if yields:
+            i = names.index("wire.slice")
+            assert names[:i].count("d2h") == names[i:].count("d2h") == 1
 
 
 # -- the write-once assembler ------------------------------------------------
