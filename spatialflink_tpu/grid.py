@@ -111,6 +111,43 @@ class UniformGrid:
         inside = (xi >= 0) & (xi < self.n) & (yi >= 0) & (yi < self.n)
         return np.where(inside, xi * self.n + yi, self.num_cells).astype(np.int32)
 
+    def assign_cells_into(self, x: np.ndarray, y: np.ndarray, out: np.ndarray,
+                          work: np.ndarray, mask: np.ndarray) -> None:
+        """``out[:] = assign_cells_np`` of the points ``(x, y)``, on
+        scratch the caller keeps: the same float64 floor arithmetic, so the
+        same cells bit for bit, with no whole-array temporary.
+
+        ``x`` and ``y`` are one block of two columns (any real dtype, any
+        stride; upcast to float64 before anything else), ``out`` the int32
+        lane that takes their cells, ``work`` float64 and ``mask`` bool,
+        both ``(2, m)`` with ``m >= len(x)``, overwritten. The floor indices
+        stay float64 (whole numbers, exact far beyond any grid), so the
+        inside test is ``(fx >= 0) & (fx < n) & (fy >= 0) & (fy < n)`` in
+        this form: a NaN fails every comparison and falls outside, as it
+        does through ``assign_cells_np``'s int64 cast.
+        """
+        m = len(x)
+        fx, fy = work[0, :m], work[1, :m]
+        inside, t = mask[0, :m], mask[1, :m]
+        # dtype= makes the ufunc compute in float64 whatever the column
+        # holds (NumPy 2 would subtract a float32 column in float32).
+        np.subtract(x, self.min_x, out=fx, dtype=np.float64)
+        np.divide(fx, self.cell_length, out=fx)
+        np.floor(fx, out=fx)
+        np.subtract(y, self.min_y, out=fy, dtype=np.float64)
+        np.divide(fy, self.cell_length, out=fy)
+        np.floor(fy, out=fy)
+        np.greater_equal(fx, 0, out=inside)
+        inside &= np.less(fx, self.n, out=t)
+        inside &= np.greater_equal(fy, 0, out=t)
+        inside &= np.less(fy, self.n, out=t)
+        with np.errstate(invalid="ignore"):  # inf − inf, of a point outside
+            np.multiply(fx, self.n, out=fx)
+            np.add(fx, fy, out=fx)
+        np.logical_not(inside, out=inside)
+        np.putmask(fx, inside, self.num_cells)
+        out[...] = fx  # whole numbers below 2**31: the cast is exact
+
     def cell_name(self, flat: int) -> str:
         """String key parity with the reference ("xxxxxyyyyy", 5+5 digits)."""
         xi, yi = divmod(int(flat), self.n)

@@ -365,6 +365,18 @@ def pack_cell_edges(table: np.ndarray, verts: np.ndarray,
     return np.ascontiguousarray(edges[table].transpose(0, 2, 3, 1))
 
 
+def _centring(grid: UniformGrid, dtype):
+    """``center_coords``' rule: the (cx, cy) to subtract in float64 and the
+    dtype to cast to, or None where nothing is centred — the EFFECTIVE
+    device dtype is float64 (asked for, and jax x64 enabled)."""
+    if np.dtype(dtype) == np.float64:
+        if jax.config.jax_enable_x64:
+            return None
+        dtype = np.float32
+    return ((grid.min_x + grid.max_x) / 2.0,
+            (grid.min_y + grid.max_y) / 2.0), dtype
+
+
 def center_coords(grid: UniformGrid, xy: np.ndarray, dtype) -> np.ndarray:
     """Origin-center coordinates before a float32 cast.
 
@@ -380,17 +392,11 @@ def center_coords(grid: UniformGrid, xy: np.ndarray, dtype) -> np.ndarray:
     (the TPU default), a float64 request still lands as f32 on device
     (jnp.asarray silently downcasts), so centering must happen then too.
     """
-    import jax
-
-    effective_f64 = (
-        np.dtype(dtype) == np.float64 and jax.config.jax_enable_x64
-    )
-    if effective_f64:
+    centring = _centring(grid, dtype)
+    if centring is None:
         return np.asarray(xy, np.float64)
-    cx = (grid.min_x + grid.max_x) / 2.0
-    cy = (grid.min_y + grid.max_y) / 2.0
-    out_dtype = np.float32 if np.dtype(dtype) == np.float64 else dtype
-    return (np.asarray(xy, np.float64) - np.array([cx, cy])).astype(out_dtype)
+    centre, out_dtype = centring
+    return (np.asarray(xy, np.float64) - np.array(centre)).astype(out_dtype)
 
 
 def check_oid_range(oid, num_segments: int) -> None:
@@ -445,61 +451,119 @@ def ship(*arrays):
     return _h2d(arrays)
 
 
-def device_point_args(grid: UniformGrid, xy64: np.ndarray, oid, dtype):
+#: Points a block of ``point_lanes``' walk: its scratch (two float64 and
+#: two bool rows a block long, 0.6 MB) stays in L2 while a million-point
+#: window streams through. Flat from 8,192 to 131,072 (PERF.md §6, PR 38).
+_LANE_BLOCK = 32768
+
+
+def lane_scratch():
+    """The block-length work rows ``point_lanes`` walks a slice over
+    (float64 ``(2, block)``, bool ``(2, block)``): a stream makes them once
+    and hands them to every window's call. Pages a short block never
+    reaches are never touched."""
+    return (np.empty((2, _LANE_BLOCK), np.float64),
+            np.empty((2, _LANE_BLOCK), bool))
+
+
+def point_lanes(grid: UniformGrid, x, y, oid, dtype, scratch=None):
     """One SoA point-slice → device-ready padded (xy, valid, cell, oid).
 
     The shared batch contract of every SoA fast path: bucket padding,
-    origin-centering before sub-f64 casts, invalid lanes carrying
-    cell=grid.num_cells (the out-of-grid slot whose flag is always 0) —
-    identical to PointBatch.from_arrays(...).with_cells(grid).
+    origin-centering before sub-f64 casts (``center_coords``' rule and
+    bits), invalid lanes carrying cell=grid.num_cells (the out-of-grid slot
+    whose flag is always 0) — identical to
+    PointBatch.from_arrays(...).with_cells(grid).
 
-    Three phase spans, inside whatever span the caller has open:
-    ``soa.center``, ``soa.cells``, ``soa.pad`` (args ``n``).
+    ``x`` and ``y`` are the slice's columns (any real dtype, any stride).
+    The four lanes are allocated at bucket length, fresh every call (the
+    consumer and ``jnp.asarray`` may hold them), and each is written once:
+    the n points go through in blocks of ``_LANE_BLOCK`` over ``scratch``
+    (``lane_scratch()``: the caller's, kept from window to window; made
+    here when not given), so no whole-window temporary is made. Three phase
+    spans, one each a call, inside whatever span the caller has open (args
+    ``n``; ``bucket`` on ``soa.pad``):
+
+    - ``soa.center``: a block of ``x``, then of ``y``, upcast to float64,
+      less the grid's centre, assigned into its column of ``xy`` — the
+      assignment is the cast. With an effective float64 the columns are
+      written as they are.
+    - ``soa.cells``: ``UniformGrid.assign_cells_into`` a block, into
+      ``cell`` (the arithmetic of ``assign_cells_np``, on the original
+      coordinates).
+    - ``soa.pad``: the tails past n (``xy`` 0, ``cell`` num_cells), the
+      ``valid`` lane, ``oid`` cast to int32; nothing is concatenated.
+
+    Enabled telemetry counts the call once (``record_soa_lanes``); there is
+    no span, clock read or counter per block.
     """
-    with telemetry.span("soa.center", n=len(xy64)):
-        xy = center_coords(grid, xy64, dtype)
-    return _padded_point_args(grid, xy64, xy, oid)
+    from spatialflink_tpu.utils.padding import next_bucket
 
-
-def _padded_point_args(grid: UniformGrid, xy64: np.ndarray, xy: np.ndarray,
-                       oid):
-    """``device_point_args`` past the centring: the cells of ``xy64``
-    (span ``soa.cells``), then the four lanes padded to the bucket (span
-    ``soa.pad``) around the centred ``xy``."""
-    from spatialflink_tpu.utils.padding import next_bucket, pad_to_bucket
-
-    n = len(xy64)
+    x, y = np.asarray(x), np.asarray(y)
+    n = len(x)
     b = next_bucket(n)
+    blocks = range(0, n, _LANE_BLOCK)
+    work, mask = scratch or lane_scratch()
+    with telemetry.span("soa.center", n=n):
+        centring = _centring(grid, dtype)
+        if centring is None:
+            xy = np.empty((b, 2), np.float64)
+            xy[:n, 0], xy[:n, 1] = x, y
+        else:
+            centre, out_dtype = centring
+            xy = np.empty((b, 2), out_dtype)
+            for lo in blocks:
+                hi = min(lo + _LANE_BLOCK, n)
+                for k, col in enumerate((x, y)):
+                    # dtype=: float64 arithmetic whatever the column holds
+                    xy[lo:hi, k] = np.subtract(
+                        col[lo:hi], centre[k], out=work[0, :hi - lo],
+                        dtype=np.float64)
     with telemetry.span("soa.cells", n=n):
-        cell = grid.assign_cells_np(xy64)
-    # Host-side padding only — no byte accounting here: callers ship
+        cell = np.empty(b, np.int32)
+        for lo in blocks:
+            hi = min(lo + _LANE_BLOCK, n)
+            grid.assign_cells_into(x[lo:hi], y[lo:hi], cell[lo:hi], work, mask)
+    # Host-side lanes only — no byte accounting here: callers ship
     # different subsets of these lanes (run_soa drops oid, the pane digest
     # path replaces valid/cell), so h2d tallies live at the actual
     # jnp.asarray ship sites (base.ship) to stay truthful.
     with telemetry.span("soa.pad", n=n, bucket=b):
-        return (
-            pad_to_bucket(xy, b),
-            pad_to_bucket(np.ones(n, bool), b, fill=False),
-            pad_to_bucket(cell, b, fill=grid.num_cells),
-            None if oid is None else pad_to_bucket(np.asarray(oid, np.int32), b, fill=0),
-        )
+        xy[n:] = 0
+        cell[n:] = grid.num_cells
+        valid = np.empty(b, bool)
+        valid[:n], valid[n:] = True, False
+        if oid is not None:
+            lane = np.empty(b, np.int32)
+            lane[:n], lane[n:] = oid, 0
+            oid = lane
+    telemetry.record_soa_lanes(n, b, len(blocks))
+    return xy, valid, cell, oid
+
+
+def device_point_args(grid: UniformGrid, xy64: np.ndarray, oid, dtype):
+    """``point_lanes`` of the two columns of an ``(n, 2)`` point slice."""
+    xy64 = np.asarray(xy64)
+    return point_lanes(grid, xy64[:, 0], xy64[:, 1], oid, dtype)
 
 
 def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
                       dtype=np.float64, span: Optional[str] = None):
     """SoA windows → (window, padded arrays) for the run_soa fast paths.
 
-    Yields (win, xy, valid, cell, oid) per the device_point_args contract.
+    Yields (win, xy, valid, cell, oid) per the point_lanes contract.
     ``span`` names a telemetry span a window around its materialisation:
     from the chunk that lets the window fire, before the assembler
     consolidates (a further window of the same firing: from its slice), to
     the padded arrays (args ``n``, ``bucket``); the chunks appended before
     are outside it, and a firing with no window has none. It holds no leaf;
     inside it lie the passes' own phase spans ``soa.consolidate`` (the
-    assembler's firing), ``soa.center`` (``np.stack`` to float64 ``(n, 2)``,
-    ``center_coords``, the cast), ``soa.cells``, ``soa.pad``. The clock
-    reading it opens at goes on with the window (``win.t0_ns``), for the
-    operator's parent span to open at the same instant.
+    assembler's firing) and ``point_lanes``' three: ``soa.center`` (the
+    columns centred in float64 blocks, cast on assignment into ``xy``),
+    ``soa.cells`` (the cells, a block at a time, into ``cell``), ``soa.pad``
+    (the tails, ``valid``, ``oid``). The clock reading it opens at goes on
+    with the window (``win.t0_ns``), for the operator's parent span to open
+    at the same instant.
     """
     from spatialflink_tpu.streams.soa import SoaWindowAssembler
 
@@ -509,21 +573,15 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
         conf.window_size_ms, conf.slide_step_ms,
         ooo_ms=conf.allowed_lateness_ms,
     )
+    scratch = lane_scratch()
 
     def batch(win):
         if counters.enabled:
             # Throughput meter for the SoA path (Point.java:237-253 analog);
             # candidate tallies come from the operator (it owns the flags).
             counters.record_window(win.count, 0, 0)
-        with telemetry.span("soa.center", n=win.count):
-            xy64 = np.stack(
-                [np.asarray(win.arrays["x"], np.float64),
-                 np.asarray(win.arrays["y"], np.float64)],
-                axis=1,
-            )
-            xy = center_coords(grid, xy64, dtype)
-        return (win, *_padded_point_args(grid, xy64, xy,
-                                         win.arrays.get("oid")))
+        return (win, *point_lanes(grid, win.arrays["x"], win.arrays["y"],
+                                  win.arrays.get("oid"), dtype, scratch))
 
     def fired(fire):
         """One firing's windows, padded. Under ``span`` each is timed from

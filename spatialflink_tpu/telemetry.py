@@ -339,6 +339,11 @@ class Telemetry:
         # (record_range_index, once an evaluator) — snapshot()["range"],
         # empty until the first evaluator or window.
         self._range: Dict[str, int] = {}
+        # A fired SoA window's device lanes (operators/base.py:point_lanes
+        # via record_soa_lanes): counters windows / points / lanes (Σ
+        # bucket) / blocks (Σ blocks its points were walked in) —
+        # snapshot()["soa"], empty until the first window.
+        self._soa: Dict[str, int] = {}
         # tids already named via a ph:"M" thread_name metadata event.
         self._named_tids: set = set()
         # Per-node attribution buckets: node name (or None = unscoped) →
@@ -1207,6 +1212,23 @@ class Telemetry:
                                index_entries=int(entries),
                                index_cells=int(cells))
 
+    def record_soa_lanes(self, points: int, lanes: int, blocks: int):
+        """One point slice made into device lanes by ``operators/base.py:
+        point_lanes`` (a fired SoA window; a pane of ``run_soa_panes``): its
+        ``points``, the bucket-length ``lanes`` written once for them and
+        the ``blocks`` the points were walked in. Lands in
+        ``snapshot()["soa"]`` as the counters ``windows``, ``points``,
+        ``lanes``, ``blocks``; ``points ÷ blocks`` is the mean block — that
+        the blocked passes ran, and at what grain. Per window, never per
+        block or event."""
+        if not self.enabled:
+            return
+        with self._lock:
+            s = self._soa
+            for key, v in (("windows", 1), ("points", points),
+                           ("lanes", lanes), ("blocks", blocks)):
+                s[key] = s.get(key, 0) + int(v)
+
     def record_wire_pane(self, n: int, bucket: int):
         """One pane taken by ``run_wire_panes``: ``n`` points padded up to
         ``bucket`` lanes before the ship. Lands in ``snapshot()["wire"]``
@@ -1718,6 +1740,8 @@ class Telemetry:
                 out["wire"] = dict(self._wire)
             if self._range:
                 out["range"] = dict(self._range)
+            if self._soa:
+                out["soa"] = dict(self._soa)
         if self.overload_provider is not None:
             try:
                 out["overload"] = json_safe(self.overload_provider())  # sfcheck: ok=lock-discipline -- stream-flush checkpoints call this under Telemetry._lock by design; the provider contract (documented at overload.OverloadController._lock) forbids providers from taking telemetry's lock — overload queues transition emits for after release
