@@ -21,7 +21,7 @@ import functools
 import heapq
 import time
 from dataclasses import dataclass
-from typing import Iterable, Iterator, List, Sequence, Tuple
+from typing import Iterable, Iterator, List, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -223,27 +223,50 @@ def grid_hash_join_batches(grid, left_batch, right_batch, radius, cap, offsets,
     return jk(*args, grid_n=grid.n, radius=fr, cap=cap)
 
 
-class PointPointJoinQuery(SpatialOperator):
-    """join/PointPointJoinQuery.java (windowBased :124-183, naive :186-243).
+def headroom_bucket(count: int) -> int:
+    """The headroom policy of a device output budget (the join's pair
+    budget, the trajectory join's pair budget): at least the next power of
+    two of 1.25 × ``count``."""
+    return next_bucket(-(-5 * count // 4), minimum=1024)
 
-    ``cap`` is the per-cell point capacity. The dense-bucket fast path caps
-    BOTH sides per cell; results are exact iff every window's
-    ``overflow == 0`` — a nonzero overflow means some cell exceeded ``cap``
-    and the join dropped candidates (raise ``cap`` for dense data; the
-    gather fallback engages automatically when cap²·cells grows too large).
-    Out-of-grid points never join, matching the reference's key semantics.
-    """
 
-    def __init__(self, conf, grid, cap: int = 64, join_backend: str | None = None,
-                 mesh=None):
-        super().__init__(conf, grid, mesh=mesh)
+class HeldJoin(NamedTuple):
+    """What ``JoinCapacity._join_until_held`` hands back: the join result
+    that holds its window (overflow 0, ``count`` ≤ the budget), the re-runs
+    it took, the peel passes of the result held (0 from a program that
+    counts none), and — where a ``follow`` program was given — what it
+    returned for that result and its scalars, fetched."""
+
+    res: object
+    count: int
+    cap_retries: int
+    budget_retries: int
+    peel_passes: int
+    followed: object = None
+    followed_scalars: Tuple[int, ...] = ()
+
+
+class JoinCapacity:
+    """The capacity and pair-budget contract of a bucketed window join,
+    shared by ``PointPointJoinQuery`` and ``TJoinQuery`` (the one home of
+    it): ``join_cap`` is the per-cell bucket capacity in use — the
+    constructor's ``cap`` its first rung, a window whose fullest cell
+    holds more climbs it on the ``ops/compaction.py`` ladder — and
+    ``join_budget`` the pair budget; both only grow and persist across
+    windows. A window either one fails to hold is run again, never handed
+    back short. Needs ``self.grid``."""
+
+    def _init_join_capacity(self, cap: int) -> None:
         self.cap = cap
-        self.join_backend = join_backend  # None=auto, 'xla', 'pallas[_interpret]'
         #: The bucket capacity in use: ``cap`` is its first rung, a window
         #: whose fullest cell holds more climbs it (``_climb_cap``); like
         #: the pair budget it only grows and persists across windows.
         self.join_cap = cap
         self.join_budget = 0  # grown pair budget, persists across windows
+        #: A window has been held at the sizes in use: from then on what
+        #: follows the join is dispatched behind it without waiting for
+        #: its scalars (``_join_until_held``).
+        self._join_settled = False
         #: 'pallas' | 'xla': the extraction ``run_soa`` last ran
         #: (``last_wire_digest_kind``'s twin); None before the first window.
         self.last_join_backend = None
@@ -258,20 +281,26 @@ class PointPointJoinQuery(SpatialOperator):
     def _grow_budget(self, count: int) -> None:
         """Headroom policy of the pair budget: at least the next power of
         two of 1.25 × ``count``."""
-        self.join_budget = max(
-            self.join_budget, next_bucket(-(-5 * count // 4), minimum=1024)
-        )
+        self.join_budget = max(self.join_budget, headroom_bucket(count))
 
-    def _join_until_held(self, lcell, lvalid, rcell, rvalid, call):
+    def _join_until_held(self, lcell, lvalid, rcell, rvalid, call,
+                         follow=None) -> HeldJoin:
         """``call(cap, budget)`` — one bucketed join of the two batches
         whose cells these are — until its result holds them: the capacity
         first climbs to the fullest cell (one bincount a side), then a
         result that still reports overflow (the safety net under that
         pick) is run again one rung up, and one with more pairs than the
-        budget under a grown budget. Returns (result, count, cap re-runs,
-        budget re-runs, peel passes); the result's overflow is 0, and the
-        passes are those of the result held (0 from a program that does not
-        count any), fetched with its count."""
+        budget under a grown budget. The held result's overflow is 0, and
+        its peel passes are fetched with its count.
+
+        ``follow(res)`` (optional) dispatches what consumes the pairs on
+        the device and returns ``(value, scalars)``; those of the held
+        result come back as ``followed`` and ``followed_scalars``. Once a
+        window has been held it is dispatched right behind every run of
+        the join, its scalars crossing with the join's in the one fetch;
+        before that (the first window, whose budget is about to grow) only
+        behind the run that holds, so that no program of it is compiled
+        for a pair list of a length that will not be seen again."""
         num_cells = self.grid.num_cells
         # The host side of the pick (phase span ``join.capacity``; the
         # re-runs' arithmetic below is a few integer operations).
@@ -286,9 +315,12 @@ class PointPointJoinQuery(SpatialOperator):
             scalars = (res.count, res.overflow)
             if res.peel_passes is not None:
                 scalars += (res.peel_passes,)
-            count, overflow, *passes = (
-                int(v) for v in telemetry.fetch(scalars)
-            )
+            speculate = follow is not None and self._join_settled
+            followed, extra = follow(res) if speculate else (None, ())
+            fetched = [
+                int(v) for v in telemetry.fetch(scalars + tuple(extra))
+            ]
+            count, overflow, *passes = fetched[:len(scalars)]
             if overflow > 0:
                 self._climb_cap(2 * self.join_cap)
                 cap_retries += 1
@@ -296,7 +328,33 @@ class PointPointJoinQuery(SpatialOperator):
                 self._grow_budget(count)
                 budget_retries += 1
             else:
-                return res, count, cap_retries, budget_retries, sum(passes)
+                tail = fetched[len(scalars):]
+                if follow is not None and not speculate:
+                    followed, extra = follow(res)
+                    tail = [int(v) for v in telemetry.fetch(tuple(extra))]
+                self._join_settled = True
+                return HeldJoin(
+                    res, count, cap_retries, budget_retries, sum(passes),
+                    followed, tuple(tail),
+                )
+
+
+class PointPointJoinQuery(JoinCapacity, SpatialOperator):
+    """join/PointPointJoinQuery.java (windowBased :124-183, naive :186-243).
+
+    ``cap`` is the per-cell point capacity. The dense-bucket fast path caps
+    BOTH sides per cell; results are exact iff every window's
+    ``overflow == 0`` — a nonzero overflow means some cell exceeded ``cap``
+    and the join dropped candidates (raise ``cap`` for dense data; the
+    gather fallback engages automatically when cap²·cells grows too large).
+    Out-of-grid points never join, matching the reference's key semantics.
+    """
+
+    def __init__(self, conf, grid, cap: int = 64, join_backend: str | None = None,
+                 mesh=None):
+        super().__init__(conf, grid, mesh=mesh)
+        self._init_join_capacity(cap)
+        self.join_backend = join_backend  # None=auto, 'xla', 'pallas[_interpret]'
 
     def _filter_radius(self, radius):
         """Distance-predicate radius: in approximate mode every grid
@@ -467,7 +525,7 @@ class PointPointJoinQuery(SpatialOperator):
         self.join_budget = max(
             self.join_budget, 1024, min(4 * lb.capacity, 262_144)
         )
-        res, count, _, _, _ = self._join_until_held(
+        res, count, *_ = self._join_until_held(
             lb.cell, lb.valid, rb.cell, rb.valid,
             lambda cap, budget: grid_hash_join_batches(
                 self.grid, lb, rb, radius, cap, offsets,
@@ -664,7 +722,7 @@ class PointPointJoinQuery(SpatialOperator):
             lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d = ship(
                 lxy, lvalid, lcell, rxy, rvalid, rcell
             )
-            res, count, cap_retries, budget_retries, passes = (
+            res, count, cap_retries, budget_retries, passes, *_ = (
                 self._join_until_held(
                     lcell, lvalid, rcell, rvalid,
                     lambda cap, budget: fn(

@@ -10,6 +10,7 @@ sub-trajectory LineStrings, per-cell aggregates, per-trajectory stats).
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
@@ -27,10 +28,18 @@ from spatialflink_tpu.operators.base import (
     ship,
     window_program,
 )
-from spatialflink_tpu.operators.join_query import _TaggedEvent, merge_by_timestamp
+from spatialflink_tpu.operators.join_query import (
+    HeldJoin,
+    JoinCapacity,
+    _TaggedEvent,
+    headroom_bucket,
+    merge_by_timestamp,
+)
+from spatialflink_tpu.ops.join import head_pairs
 from spatialflink_tpu.telemetry import telemetry
 from spatialflink_tpu.ops.knn import knn_points_fused
 from spatialflink_tpu.ops.trajectory import (
+    MAX_TRAJ_IDS,
     traj_cell_spans_kernel,
     traj_pair_dedup_kernel,
     traj_range_hits_fused,
@@ -244,7 +253,7 @@ class TJoinResult:
     window_count: int
 
 
-class TJoinQuery(SpatialOperator):
+class TJoinQuery(JoinCapacity, SpatialOperator):
     """Trajectory join: trajectory pairs whose points come within r inside
     the window, each pair emitted once as paired windowed sub-trajectories
     (tJoin/TJoinQuery.java:60-154, PointPointTJoinQuery.java:183+).
@@ -255,17 +264,75 @@ class TJoinQuery(SpatialOperator):
     a strictly more informative representative (documented deviation).
     ``run_single`` self-joins a stream (PointPointTJoinQuery.runSingle:57).
 
+    **Exact on every window handed back** (``run`` and ``run_soa``): the
+    point join runs under ``JoinCapacity``'s contract, shared with
+    ``PointPointJoinQuery`` — the bucket capacity from the window's
+    fullest cell (``cap`` is only its first rung), the pair budget with a
+    quarter of headroom, a window either one fails to hold run again and
+    never handed back short. The dedup is sparse (``ops/trajectory.py:
+    traj_pair_dedup_kernel``: the pair list sorted by a packed (left id,
+    right id) key and the distance, the run starts sorted to the front;
+    nothing sized by the number of ids, at most 46,340 a side) and as
+    long as the pair list, so it has nothing to overflow. The point pairs
+    never leave the device: per window the scalars cross (pair count,
+    overflow, peel passes, trajectory-pair count, one fetch), then the
+    trajectory pairs in the padding bucket of their count (12 B each).
+
     ``mesh=`` executes the point-pair join shard_mapped (the dedup stage
-    runs on the compacted pairs). Like PointPointJoinQuery, results are
-    exact iff no cell exceeds ``cap`` — under a mesh the cap applies per
-    shard, so overcapacity windows can differ from single-device.
+    runs on the compacted pairs); under a mesh the capacity applies per
+    shard.
     """
 
     def __init__(self, conf, grid, cap: int = 64, mesh=None):
         super().__init__(conf, grid, mesh=mesh)
-        self.cap = cap
-        self._max_pairs = 0
-        self._max_tpairs = 256
+        self._init_join_capacity(cap)
+        #: Trajectory-pair budget: the largest count whose fetch programs
+        #: (the padding buckets' slices) are compiled; grown with the pair
+        #: budget's headroom policy, persists across windows.
+        self.tpair_budget = 0
+
+    def _tpairs_until_held(self, lcell, lvalid, rcell, rvalid, loid, roid,
+                           num_ids: int, call) -> HeldJoin:
+        """The window's point join under the capacity and budget contract
+        (``_join_until_held``) with the dedup program dispatched behind it
+        (``loid`` / ``roid``: the two sides' device id lanes), so that the
+        join's scalars and the trajectory-pair count cross in one fetch.
+        The held join's ``followed`` is the ``TrajPairs`` (on the device),
+        ``followed_scalars`` its count."""
+        if num_ids > MAX_TRAJ_IDS:
+            raise ValueError(
+                f"{num_ids} trajectory ids a side: the dedup's int32 pair "
+                f"key left · ids + right holds at most {MAX_TRAJ_IDS}"
+            )
+        dedup = jitted(traj_pair_dedup_kernel)
+        ids = np.int32(num_ids)
+
+        def follow(res):
+            tp = dedup(res.left_index, res.right_index, res.dist, loid,
+                       roid, ids)
+            return tp, (tp.count,)
+
+        return self._join_until_held(lcell, lvalid, rcell, rvalid, call,
+                                     follow)
+
+    def _fetch_tpairs(self, tp, tcount: int):
+        """The ``tcount`` trajectory pairs of ``tp`` on the host — (left
+        ids, right ids, minimum distances), fetched in the padding bucket
+        of their count and cut to it. A count past ``tpair_budget`` grows
+        it (the pair budget's headroom policy) and compiles the slicing
+        programs a count under the new budget asks for at once, not inside
+        a later window."""
+        head = jitted(head_pairs, "bucket")
+        arrays = (tp.left_oid, tp.right_oid, tp.dist)
+        lanes = len(tp.dist)
+        if tcount > self.tpair_budget:
+            self.tpair_budget = headroom_bucket(tcount)
+            for b in (self.tpair_budget // 2, self.tpair_budget):
+                head(*arrays, bucket=min(b, lanes))
+        lo, ro, dd = telemetry.fetch(
+            head(*arrays, bucket=min(next_bucket(tcount), lanes))
+        )
+        return lo[:tcount], ro[:tcount], dd[:tcount]
 
     def run(
         self,
@@ -283,9 +350,6 @@ class TJoinQuery(SpatialOperator):
             for tag, ev in merge_by_timestamp(stream, query_stream)
         )
         offsets = jnp.asarray(self.grid.neighbor_offsets(radius))
-        dedup = jitted(
-            traj_pair_dedup_kernel, "num_left", "num_right", "max_tpairs"
-        )
 
         for win in self.windows(merged):
             left_ev = [t.event for t in win.events if t.tag == 0]
@@ -295,55 +359,30 @@ class TJoinQuery(SpatialOperator):
                 continue
             lb = self.point_batch(left_ev)
             rb = self.point_batch(right_ev)
-            # Device-compacted point-pair join (Pallas extraction on TPU),
-            # with the same grown-budget retry as PointPointJoinQuery.
-            self._max_pairs = max(
-                self._max_pairs, 1024, min(4 * lb.capacity, 262_144)
+            # Device-compacted point-pair join (Pallas extraction on TPU)
+            # under PointPointJoinQuery's capacity and budget contract,
+            # then per-(traj, traj) min distance + compaction on device —
+            # the reference's dedup map (TJoinQuery.java:60-154) without
+            # the per-matching-point host loop. The interner's ids are the
+            # trajectory ids: no window-local relabel.
+            self.join_budget = max(
+                self.join_budget, 1024, min(4 * lb.capacity, 262_144)
             )
-            while True:
-                res = grid_hash_join_batches(
-                    self.grid, lb, rb, radius, self.cap, offsets,
-                    max_pairs=self._max_pairs, dtype=dtype, mesh=mesh,
-                )
-                if int(res.count) <= self._max_pairs:
-                    break
-                self._max_pairs = int(2 ** np.ceil(np.log2(int(res.count))))
-            # Window-local dense trajectory ranks (vectorized host relabel).
-            l_uniq, l_local = np.unique(
-                lb.oid[: len(left_ev)], return_inverse=True
+            loid, roid = ship(lb.oid, rb.oid)
+            held = self._tpairs_until_held(
+                lb.cell, lb.valid, rb.cell, rb.valid, loid, roid,
+                max(self.interner.num_segments, 1),
+                lambda cap, budget: grid_hash_join_batches(
+                    self.grid, lb, rb, radius, cap, offsets,
+                    max_pairs=budget, dtype=dtype, mesh=mesh,
+                ),
             )
-            r_uniq, r_local = np.unique(
-                rb.oid[: len(right_ev)], return_inverse=True
-            )
-            l_loc = np.zeros(lb.capacity, np.int32)
-            l_loc[: len(left_ev)] = l_local
-            r_loc = np.zeros(rb.capacity, np.int32)
-            r_loc[: len(right_ev)] = r_local
-            num_l = int(next_bucket(len(l_uniq), minimum=16))
-            num_r = int(next_bucket(len(r_uniq), minimum=16))
-            # Per-(traj, traj) min distance + compaction on device — the
-            # reference's dedup map (TJoinQuery.java:60-154) without the
-            # per-matching-point host loop.
-            while True:
-                tp = dedup(
-                    res.left_index, res.right_index, res.dist,
-                    jnp.asarray(l_loc), jnp.asarray(r_loc),
-                    num_left=num_l, num_right=num_r,
-                    max_tpairs=self._max_tpairs,
-                )
-                if int(tp.count) <= self._max_tpairs:
-                    break
-                self._max_tpairs = int(2 ** np.ceil(np.log2(int(tp.count))))
             lgroups = group_by_oid(left_ev)
             rgroups = group_by_oid(right_ev)
             # Vectorized pair decode — the dedup'd pair list is the only
             # thing that crosses into Python (no per-point-pair loop).
-            keys = np.asarray(tp.pair_key)
-            hit = keys >= 0
-            kk = keys[hit]
-            l_ids = l_uniq[kk // num_r]
-            r_ids = r_uniq[kk % num_r]
-            dists = np.asarray(tp.dist)[hit]
+            l_ids, r_ids, dists = self._fetch_tpairs(
+                held.followed, *held.followed_scalars)
             found: List[Tuple[str, str, float]] = sorted(
                 (self.interner.lookup(int(a)), self.interner.lookup(int(b)),
                  float(d))
@@ -377,92 +416,109 @@ class TJoinQuery(SpatialOperator):
         """SoA fast path for tJoin: two point chunk streams
         {"ts","x","y","oid"} (dense int32 oids in [0, num_segments)) →
         per-window RAW trajectory-pair arrays
-        (start, end, left_oids, right_oids, min_dists, count, overflow) —
-        the reference's windowBased tJoin
-        (tJoin/PointPointTJoinQuery.java:183+) with zero per-point-pair
-        Python: grid-hash point join and per-trajectory-pair min-distance
-        dedup both run on device (ops/trajectory.py:
-        traj_pair_dedup_kernel); the host only relabels window-local
-        trajectory ranks (one vectorized np.unique per side) and decodes
-        the dedup'd pair list. Exact iff ``overflow == 0`` (per-cell cap,
-        same contract as run()). Windows align on the shared slide grid;
-        one-sided windows yield zero pairs."""
+        (start, end, left_oids, right_oids, min_dists, count, overflow),
+        the pairs ascending by (left id, right id) — the reference's
+        windowBased tJoin (tJoin/PointPointTJoinQuery.java:183+) with zero
+        per-point-pair Python: the grid-hash point join and the sparse
+        per-trajectory-pair min-distance dedup (ops/trajectory.py:
+        traj_pair_dedup_kernel) both run on device, the second fed the
+        first's pairs where they lie. Windows align on the shared slide
+        grid; one-sided windows yield zero pairs.
+
+        Exact on every yielded window (``overflow == 0``), as
+        ``PointPointJoinQuery.run_soa`` and by the same code
+        (``JoinCapacity``): capacity from the window's fullest cell, the
+        pair budget (``max_pairs`` its first value) with a quarter of
+        headroom over the last count, a window either fails to hold run
+        again, never yielded short. The dedup's output is as long as the
+        pair list and cannot overflow; ``tpair_budget`` (same headroom
+        policy) is the count up to which the slicing programs of the fetch
+        are compiled, a new one's at once and not inside a later window.
+        What crosses to the host a window: one fetch of four scalars (pair
+        count, overflow, peel passes, trajectory-pair count), then the
+        trajectory pairs in the padding bucket of their count — left id,
+        right id (int32), minimum distance: 12 B each. The point pairs
+        never cross. ``num_segments`` at most 46,340 (the dedup's int32
+        pair key).
+
+        With telemetry on: one parent span ``tjoin.window`` a two-sided
+        window (args ``n``: events of both sides), emitted by hand at the
+        hand-back, from the chunk that lets the left side's window fire
+        (``join.assemble_left``'s start) to just before the yield; inside
+        it ``join.assemble_left``, ``join.assemble`` (the right side),
+        ``tjoin.ids`` (the id-range check of both sides), ``h2d``,
+        ``join.capacity``, ``dispatch:*`` (the extraction,
+        ``traj_pair_dedup_kernel``, ``head_pairs``) and both ``d2h``; one
+        ``record_tjoin`` a window (``snapshot()["tjoin"]``). A one-sided
+        window emits none, and neither does a window whose left side was
+        in hand while a right-only window went to the consumer."""
         from spatialflink_tpu.operators.base import (
             check_oid_range,
             soa_point_batches,
         )
         from spatialflink_tpu.operators.join_query import (
             _aligned_soa_windows,
+            _spanned,
             window_join_program,
         )
-        from spatialflink_tpu.utils.padding import next_bucket as _nb
 
-        fn, _ = window_join_program()
-        dedup = jitted(
-            traj_pair_dedup_kernel, "num_left", "num_right", "max_tpairs"
-        )
+        fn, self.last_join_backend = window_join_program()
         layers = self.grid.candidate_layers(radius)
-        gen_l = soa_point_batches(self.grid, left_chunks, self.conf, dtype)
-        gen_r = soa_point_batches(self.grid, right_chunks, self.conf, dtype)
-        budget = max_pairs
+        gen_l = soa_point_batches(self.grid, left_chunks, self.conf, dtype,
+                                  span="join.assemble_left")
+        gen_r = _spanned(
+            soa_point_batches(self.grid, right_chunks, self.conf, dtype),
+            "join.assemble",
+        )
+        self.join_budget = max(self.join_budget, max_pairs)
+        left_waited = False  # wl sat through a right-only window's hand-back
         empty = (np.empty(0, np.int32), np.empty(0, np.int32), np.empty(0))
         for kind, wl, wr in _aligned_soa_windows(
             gen_l, gen_r, lambda w: w[0].start, lambda w: w[0].start
         ):
             if kind != "both":
+                left_waited = kind == "right"
                 w = wl[0] if kind == "left" else wr[0]
                 yield (w.start, w.end, *empty, 0, 0)
                 continue
             win, lxy, lvalid, lcell, loid = wl
             rwin, rxy, rvalid, rcell, roid = wr
-            check_oid_range(loid[:win.count], num_segments)
-            check_oid_range(roid[:rwin.count], num_segments)
-            # Window-local dense trajectory ranks (vectorized host).
-            l_uniq, l_inv = np.unique(loid[:win.count], return_inverse=True)
-            r_uniq, r_inv = np.unique(roid[:rwin.count], return_inverse=True)
-            l_loc = np.zeros(len(loid), np.int32)
-            l_loc[:win.count] = l_inv
-            r_loc = np.zeros(len(roid), np.int32)
-            r_loc[:rwin.count] = r_inv
-            num_l = int(_nb(max(len(l_uniq), 1), minimum=16))
-            num_r = int(_nb(max(len(r_uniq), 1), minimum=16))
-            # Ship once, outside the budget-retry loops: retries reuse the
-            # same (immutable) device buffers instead of re-crossing the
-            # link, and bytes_h2d counts each lane exactly once.
-            lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d = ship(
-                lxy, lvalid, lcell, rxy, rvalid, rcell
+            with telemetry.span("tjoin.ids"):
+                check_oid_range(loid[:win.count], num_segments)
+                check_oid_range(roid[:rwin.count], num_segments)
+            # Ship once, outside the retry loops: re-runs reuse the same
+            # (immutable) device buffers instead of re-crossing the link,
+            # and bytes_h2d counts each lane exactly once.
+            (lxy_d, lvalid_d, lcell_d, loid_d,
+             rxy_d, rvalid_d, rcell_d, roid_d) = ship(
+                lxy, lvalid, lcell, loid, rxy, rvalid, rcell, roid
             )
-            l_loc_d, r_loc_d = ship(l_loc, r_loc)
-            while True:
-                res = fn(
+            held = self._tpairs_until_held(
+                lcell, lvalid, rcell, rvalid, loid_d, roid_d, num_segments,
+                lambda cap, budget: fn(
                     lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d,
                     grid_n=self.grid.n, layers=layers, radius=radius,
-                    cap_left=self.cap, cap_right=self.cap, max_pairs=budget,
-                )
-                if int(res.count) <= budget:
-                    break
-                budget = int(2 ** np.ceil(np.log2(int(res.count))))
-            while True:
-                tp = dedup(
-                    res.left_index, res.right_index, res.dist,
-                    l_loc_d, r_loc_d,
-                    num_left=num_l, num_right=num_r,
-                    max_tpairs=self._max_tpairs,
-                )
-                if int(tp.count) <= self._max_tpairs:
-                    break
-                self._max_tpairs = int(2 ** np.ceil(np.log2(int(tp.count))))
-            keys = np.asarray(tp.pair_key)
-            hit = keys >= 0
-            kk = keys[hit]
-            yield (
-                win.start, win.end,
-                l_uniq[kk // num_r].astype(np.int32),
-                r_uniq[kk % num_r].astype(np.int32),
-                np.asarray(tp.dist)[hit],
-                int(hit.sum()), int(res.overflow),
+                    cap_left=cap, cap_right=cap, max_pairs=budget,
+                ),
             )
-
+            (tcount,) = held.followed_scalars
+            lo, ro, dd = self._fetch_tpairs(held.followed, tcount)
+            telemetry.record_tjoin(
+                pairs=held.count, tpairs=tcount,
+                cap_retries=held.cap_retries,
+                budget_retries=held.budget_retries, cap=self.join_cap,
+                budget=self.join_budget, tpair_budget=self.tpair_budget,
+                peel_passes=held.peel_passes,
+            )
+            self._grow_budget(held.count)  # headroom for the next window
+            if win.t0_ns is not None and not left_waited:
+                telemetry.emit_span(
+                    "tjoin.window", win.t0_ns,
+                    time.perf_counter_ns() - win.t0_ns,
+                    n=win.count + rwin.count,
+                )
+            left_waited = False
+            yield (win.start, win.end, lo, ro, dd, tcount, 0)
 
     def run_soa_panes(
         self,

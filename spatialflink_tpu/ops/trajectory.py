@@ -234,59 +234,78 @@ def stay_time_cells_kernel(
 
 
 class TrajPairs(NamedTuple):
-    """Deduped trajectory-pair join output (device-compacted).
+    """Deduped trajectory-pair join output (device-compacted), as long as
+    the pair list it was made of (a window has no more trajectory pairs
+    than point pairs, so nothing here can overflow).
 
-    ``pair_key``: (max_tpairs,) int32 — left_local * num_right + right_local,
-    -1 padding; ``dist``: (max_tpairs,) min point distance of the pair;
-    ``count``: () number of distinct qualifying pairs (> max_tpairs means
-    the budget must grow).
+    ``left_oid`` / ``right_oid``: int32 object ids of each distinct pair,
+    ascending by (left, right), -1 padding; ``dist``: the pair's minimum
+    point distance (dtype max on padding); ``count``: () number of distinct
+    pairs, the leading lanes.
     """
 
-    pair_key: jnp.ndarray
+    left_oid: jnp.ndarray
+    right_oid: jnp.ndarray
     dist: jnp.ndarray
     count: jnp.ndarray
+
+
+#: ids a side the packed int32 pair key ``left · S + right`` can hold
+MAX_TRAJ_IDS = 46_340
 
 
 def traj_pair_dedup_kernel(
     left_index: jnp.ndarray,
     right_index: jnp.ndarray,
     dist: jnp.ndarray,
-    left_local: jnp.ndarray,
-    right_local: jnp.ndarray,
-    num_left: int,
-    num_right: int,
-    max_tpairs: int,
+    left_oid: jnp.ndarray,
+    right_oid: jnp.ndarray,
+    num_ids,
 ) -> TrajPairs:
     """Compact join pairs → distinct (trajectory, trajectory) pairs with
-    min distance, entirely on device.
+    their minimum distance, entirely on device and **sparse**: time
+    O(P log P) and memory O(P) in the P lanes of the pair list (the pair
+    budget); no array, table or loop is sized by the number of ids.
 
     Replaces the reference's per-record dedup map (latest pair per
-    (traj, queryTraj), tJoin/TJoinQuery.java:60-154) — and round 1's host
-    Python dict loop over every matching point pair — with a segment-min
-    over window-local trajectory-pair keys + one small compaction.
+    (traj, queryTraj), tJoin/TJoinQuery.java:60-154): the pairs are keyed
+    ``left id · num_ids + right id`` and sorted by (key, distance), so
+    every trajectory pair's matches lie in one run led by its closest
+    match; a second sort, by "the key on a run's first lane, the padding
+    key elsewhere", moves the run starts to the front in key order (a
+    compaction without a scatter or a ``nonzero``: on a v5e two sorts of
+    2²¹ lanes cost 8 ms, the same compaction through two scatters 20 and
+    through ``nonzero`` 90–130; PERF.md §6, PR 39).
 
     ``left_index``/``right_index``/``dist``: a CompactJoinResult's arrays
-    (-1 padding); ``left_local``/``right_local``: (N,)/(M,) window-local
-    dense trajectory ranks of each batch lane.
+    (-1 padding); ``left_oid``/``right_oid``: (N,)/(M,) int32 object id of
+    each batch lane, in ``[0, num_ids)``; ``num_ids`` (a traced scalar: no
+    program per id count) at most ``MAX_TRAJ_IDS``, so that the key fits
+    int32 — the callers refuse more.
     """
     ok = left_index >= 0
-    key = (
-        left_local[jnp.maximum(left_index, 0)] * num_right
-        + right_local[jnp.maximum(right_index, 0)]
-    )
-    n_keys = num_left * num_right
-    key = jnp.where(ok, key, n_keys)
+    pad = jnp.iinfo(jnp.int32).max
     big = jnp.asarray(jnp.finfo(dist.dtype).max, dist.dtype)
-    best = jax.ops.segment_min(
-        jnp.where(ok, dist, big), key, num_segments=n_keys + 1
-    )[:n_keys]
-    hit_mask = best < big
-    (hit,) = jnp.nonzero(hit_mask, size=max_tpairs, fill_value=-1)
-    found = hit >= 0
-    pair_key = jnp.where(found, hit.astype(jnp.int32), -1)
-    pair_dist = jnp.where(found, best[jnp.maximum(hit, 0)], big)
-    count = jnp.sum(hit_mask.astype(jnp.int32))
-    return TrajPairs(pair_key, pair_dist, count)
+    num_ids = jnp.asarray(num_ids, jnp.int32)
+    key = jnp.where(
+        ok,
+        left_oid[jnp.maximum(left_index, 0)] * num_ids
+        + right_oid[jnp.maximum(right_index, 0)],
+        pad,
+    )
+    key, dmin = jax.lax.sort((key, jnp.where(ok, dist, big)), num_keys=2)
+    first = (key != pad) & jnp.concatenate(
+        [jnp.ones((1,), bool), key[1:] != key[:-1]]
+    )
+    count = jnp.sum(first.astype(jnp.int32))
+    key, dmin = jax.lax.sort((jnp.where(first, key, pad), dmin), num_keys=1)
+    found = key != pad
+    return TrajPairs(
+        jnp.where(found, key // num_ids, -1),
+        jnp.where(found, key % num_ids, -1),
+        jnp.where(found, dmin, big),
+        count,
+    )
 
 
 class TrajAggregate(NamedTuple):

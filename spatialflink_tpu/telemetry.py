@@ -323,6 +323,12 @@ class Telemetry:
         # pair budget in use) — snapshot()["join"], empty until the first
         # joined window.
         self._join: Dict[str, int] = {}
+        # Window trajectory join (operators/trajectory.py:TJoinQuery.run_soa
+        # via record_tjoin): counters windows / pairs (point pairs) /
+        # tpairs / peel_passes / cap_retries / budget_retries and the
+        # gauges cap / budget / tpair_budget —
+        # snapshot()["tjoin"], empty until the first joined window.
+        self._tjoin: Dict[str, int] = {}
         # Wire-pane kNN (operators/knn_query.py:run_wire_panes via
         # record_wire_pane): panes taken, their points, the lanes shipped
         # for them (each pane padded up to its bucket) and the padding's
@@ -1170,6 +1176,33 @@ class Telemetry:
                 j[key] = j.get(key, 0) + int(n)
             j["cap"], j["budget"] = int(cap), int(budget)
 
+    def record_tjoin(self, pairs: int, tpairs: int, cap_retries: int,
+                     budget_retries: int, cap: int, budget: int,
+                     tpair_budget: int, peel_passes: int = 0):
+        """One window of the SoA trajectory join, fetched: the point
+        ``pairs`` the extraction found (they stay on the device), the
+        distinct trajectory pairs ``tpairs`` the dedup made of them
+        (``tpairs ÷ pairs`` = what the dedup collapses to), the re-runs it
+        took (a bucket capacity or a pair budget the window did not fit;
+        the dedup's output is as long as the pair list, so it has nothing
+        to overflow) and the sizes it ended on (``tpair_budget``: the
+        largest trajectory-pair count whose fetch programs are compiled).
+        Lands in ``snapshot()["tjoin"]`` as the counters ``windows``,
+        ``pairs``, ``tpairs``, ``peel_passes``, ``cap_retries``,
+        ``budget_retries`` and the gauges ``cap``, ``budget``,
+        ``tpair_budget``. Per window, never per event."""
+        if not self.enabled:
+            return
+        with self._lock:
+            j = self._tjoin
+            for key, n in (("windows", 1), ("pairs", pairs),
+                           ("tpairs", tpairs), ("peel_passes", peel_passes),
+                           ("cap_retries", cap_retries),
+                           ("budget_retries", budget_retries)):
+                j[key] = j.get(key, 0) + int(n)
+            j["cap"], j["budget"] = int(cap), int(budget)
+            j["tpair_budget"] = int(tpair_budget)
+
     def record_range(self, points: int, lanes: int, matches: int,
                      cand_retries: int, budget_retries: int, cand: int,
                      budget: int):
@@ -1736,6 +1769,8 @@ class Telemetry:
                                "bytes": self.shed_bytes}
             if self._join:
                 out["join"] = dict(self._join)
+            if self._tjoin:
+                out["tjoin"] = dict(self._tjoin)
             if self._wire:
                 out["wire"] = dict(self._wire)
             if self._range:

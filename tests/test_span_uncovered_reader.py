@@ -80,6 +80,9 @@ def test_none_where_there_is_nothing_to_read(spans, events):
 
 WIRE, JOIN, RANGE = ["knn_wire.flood", "knn.flood"], ["join.flood"], \
     ["range_poly.flood"]
+#: the trajectory join's cell (PR 39) runs the join's assembly, capacity pick
+#: and SoA passes under the same span names, so it joined those lists
+TJOIN = ["tjoin.flood"]
 #: metric -> (what it reads, its cells, its layer)
 METRICS = {
     "wire_pane_us_per_event": ("wire.pane", WIRE, "ship_fetch"),
@@ -90,18 +93,21 @@ METRICS = {
     "wire_d2h_wait_us_per_event": ("d2h.wait", WIRE, "ship_fetch"),
     "join_window_us_per_event": ("join.window", JOIN, "ship_fetch"),
     "join_unspanned_us_per_event": (("join.window",), JOIN, "operators"),
-    "join_assemble_left_us_per_event": ("join.assemble_left", JOIN,
+    "join_assemble_left_us_per_event": ("join.assemble_left", JOIN + TJOIN,
                                         "operators"),
-    "join_capacity_us_per_event": ("join.capacity", JOIN, "operators"),
+    "join_capacity_us_per_event": ("join.capacity", JOIN + TJOIN,
+                                   "operators"),
     "join_d2h_wait_us_per_event": ("d2h.wait", JOIN, "ship_fetch"),
     "range_window_us_per_event": ("range.window", RANGE, "ship_fetch"),
     "range_unspanned_us_per_event": (("range.window",), RANGE, "operators"),
     "range_d2h_wait_us_per_event": ("d2h.wait", RANGE, "ship_fetch"),
-    "soa_consolidate_us_per_event": ("soa.consolidate", RANGE + JOIN,
+    "soa_consolidate_us_per_event": ("soa.consolidate", RANGE + JOIN + TJOIN,
                                      "host_ingest"),
-    "soa_center_us_per_event": ("soa.center", RANGE + JOIN, "host_ingest"),
-    "soa_cells_us_per_event": ("soa.cells", RANGE + JOIN, "host_ingest"),
-    "soa_pad_us_per_event": ("soa.pad", RANGE + JOIN, "host_ingest"),
+    "soa_center_us_per_event": ("soa.center", RANGE + JOIN + TJOIN,
+                                "host_ingest"),
+    "soa_cells_us_per_event": ("soa.cells", RANGE + JOIN + TJOIN,
+                               "host_ingest"),
+    "soa_pad_us_per_event": ("soa.pad", RANGE + JOIN + TJOIN, "host_ingest"),
 }
 
 
@@ -140,11 +146,58 @@ def test_metric_file_reads_the_span_the_program_emits(name):
     assert reader.read(trace([("x", 10, 100)]), **mf["args"]) is None
 
 
+#: PR 39's per-layer metrics, appended behind the 18: (reader, what it reads)
+TJOIN_METRICS = {
+    "tjoin_window_us_per_event": ("program_span_us_per_event",
+                                  {"names": ["tjoin.window"]}),
+    "tjoin_dedup_dispatch_us_per_event": (
+        "program_span_us_per_event",
+        {"names": ["dispatch:traj_pair_dedup_kernel"]}),
+    "tjoin_retries_per_window": (
+        "counter_ratio", {"num": ["tjoin.cap_retries",
+                                  "tjoin.budget_retries"],
+                          "den": ["results"]}),
+    "tjoin_collapse_share": ("counter_ratio", {"num": ["tjoin.tpairs"],
+                                               "den": ["tjoin.pairs"]}),
+    "tjoin_unspanned_us_per_event": ("span_uncovered_us_per_event",
+                                     {"parent": "tjoin.window"}),
+    "tjoin_d2h_wait_us_per_event": ("program_span_us_per_event",
+                                    {"names": ["d2h.wait"]}),
+    "tjoin_d2h_bytes_per_tpair": ("counter_ratio", {"num": ["d2h_bytes"],
+                                                    "den": ["tjoin.tpairs"]}),
+    "tjoin_dedup_roofline": ("tjoin_dedup_roofline",
+                             {"programs": ["jit_traj_pair_dedup_kernel"]}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TJOIN_METRICS))
+def test_tjoin_metric_file_reads_what_the_program_emits(name):
+    reader, args = TJOIN_METRICS[name]
+    (entry,) = [m for m in spec.benchmark()["per_layer"] if m["name"] == name]
+    assert entry["workloads"] == TJOIN and entry["moves"] == "events_per_s"
+    mf = spec.metric_file(name)
+    assert mf["reader"] == reader and mf["args"] == args
+    assert callable(spec.plugin("readers", reader).read)
+    # every span, counter or program the metric reads is one the package
+    # emits: its name stands in the package's source
+    src = _package_source()
+    for said in [a for v in args.values()
+                 for a in (v if isinstance(v, list) else [v])]:
+        if said in ("results", "d2h_bytes", "d2h.wait"):
+            continue  # the harness's own counters; ``fetch``'s child span
+        word = said.split(":")[-1].split(".")[-1]
+        word = word[4:] if word.startswith("jit_") else word
+        assert word in src, said
+    assert '"tjoin.window"' in src and "def record_tjoin" in src
+
+
 def test_benchmark_json_only_grew():
-    """The 18 entries stand at the end of ``per_layer``, in the issue's
-    order, and the file stays well inside its size limit."""
+    """The 18 entries stand where they stood, in the issue's order, PR 39's
+    eight behind them at the end of ``per_layer``, and the file stays well
+    inside its size limit."""
     names = [m["name"] for m in spec.benchmark()["per_layer"]]
     assert len(names) == len(set(names))
-    assert set(names[-18:]) == set(METRICS)
+    assert set(names[-26:-8]) == set(METRICS)
+    assert set(names[-8:]) == set(TJOIN_METRICS)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         assert len(f.read()) < 64 * 1024

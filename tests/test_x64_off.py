@@ -62,6 +62,7 @@ CASES = {
     ),
     "knn": ["wire_panes.xla", "wire_panes.pallas_interpret"],
     "join": ["run_soa.xla", "run_soa.pallas_interpret"],
+    "tjoin": ["run_soa.uniform", "run_soa.trips"],
     "range": ["run_soa.dense", "run_soa.pruned", "run_soa.pruned_compact"],
     "numerics": (
         [f"center_coords.{name}" for name, _ in GRIDS]
@@ -368,6 +369,76 @@ def child_join():
     return out
 
 
+def child_tjoin():
+    """``PointPointTJoinQuery.run_soa`` (the capacity contract, the sparse
+    dedup): the trajectory-pair set and its minimum distances equal the
+    float64 reference's outside the band of 4 · eps32 · span around the
+    radius — on uniform points with 16,384 ids (ids² = 2²⁸ possible keys)
+    and on coherent trips, where many point pairs collapse to one pair."""
+    from __graft_entry__ import BEIJING_GRID_ARGS
+    from benchmark.references.tjoin_tdrive import Reference
+    from spatialflink_tpu.grid import UniformGrid
+    from spatialflink_tpu.operators import QueryConfiguration, QueryType
+    from spatialflink_tpu.operators.trajectory import PointPointTJoinQuery
+
+    grid = UniformGrid(**BEIJING_GRID_ARGS)
+    radius, n, ids = 0.002, 8_000, 16_384
+    rng = np.random.default_rng(12)
+    bbox = (grid.min_x, grid.min_y, grid.max_x, grid.max_y)
+
+    def uniform():
+        return {"ts": T0_MS + np.sort(rng.integers(0, 10_000, n)),
+                "x": rng.uniform(grid.min_x, grid.max_x, n),
+                "y": rng.uniform(grid.min_y, grid.max_y, n),
+                "oid": rng.integers(0, ids, n).astype(np.int64)}
+
+    def trips(shift):
+        """40 taxis of 200 fixes each, every taxi a straight run of 0.0004°
+        steps; the other side's taxi of the same number runs ``shift``
+        beside it, so 200+ point pairs make one trajectory pair."""
+        taxi = np.repeat(np.arange(40), 200)
+        step = np.tile(np.arange(200), 40)
+        x0 = grid.min_x + 0.05 + 0.05 * taxi
+        y0 = grid.min_y + 0.05 + 0.03 * taxi
+        return {"ts": T0_MS + (step * 50).astype(np.int64),
+                "x": x0 + 0.0004 * step + shift, "y": y0 + shift,
+                "oid": (taxi * 400 + 7).astype(np.int64)}
+
+    def in_time(c):
+        order = np.argsort(c["ts"], kind="stable")
+        return {k: v[order] for k, v in c.items()}
+
+    tol = 4 * float(np.finfo(np.float32).eps) * (grid.max_x - grid.min_x)
+    ref = Reference(bbox=bbox, grid_cells=100, radius=radius, tol=tol,
+                    num_ids=ids)
+    conf = QueryConfiguration(QueryType.WindowBased, window_size=10,
+                              slide_step=10)
+    out = {}
+    for name, left, right in (
+            ("uniform", uniform(), uniform()),
+            ("trips", in_time(trips(0.0)), in_time(trips(0.0007)))):
+        want = ref.tpairs(left["x"], left["y"], left["oid"],
+                          right["x"], right["y"], right["oid"])
+        op = PointPointTJoinQuery(conf, grid)
+        got = list(op.run_soa(iter([left]), iter([right]), radius,
+                              num_segments=ids))
+        bad = []
+        if len(got) != 1:
+            bad.append(f"{len(got)} windows fired, expected 1")
+        else:
+            start, end, lo, ro, dd, count, overflow = got[0]
+            if (start, end) != (T0_MS, T0_MS + 10_000):
+                bad.append(f"window span {(start, end)}")
+            bad += ref.compare(want, lo, ro, dd, int(count), int(overflow))
+            if not int(count):
+                bad.append("no pair found: the comparison is empty")
+            if name == "trips" and int(count) != 40:
+                bad.append(f"{int(count)} trajectory pairs, expected the 40 "
+                           "side-by-side taxis")
+        out[f"run_soa.{name}"] = _verdict(bad)
+    return out
+
+
 def child_range():
     """``PointPolygonRangeQuery.run_soa`` through each of its polygon
     kernels: the matched set equals the float64 reference's outside the band
@@ -538,7 +609,8 @@ def child_numerics():
 
 
 CHILDREN = {"sncb": child_sncb, "knn": child_knn, "join": child_join,
-            "range": child_range, "numerics": child_numerics}
+            "tjoin": child_tjoin, "range": child_range,
+            "numerics": child_numerics}
 
 
 def main(argv):
@@ -593,6 +665,11 @@ def join_child():
 
 
 @pytest.fixture(scope="module")
+def tjoin_child():
+    return _run_child("tjoin")
+
+
+@pytest.fixture(scope="module")
 def range_child():
     return _run_child("range")
 
@@ -615,6 +692,11 @@ def test_wire_knn_matches_reference(knn_child, case):
 @pytest.mark.parametrize("case", CASES["join"])
 def test_join_pairs_match_float64_reference(join_child, case):
     assert join_child[case]["ok"], join_child[case]["problems"]
+
+
+@pytest.mark.parametrize("case", CASES["tjoin"])
+def test_tjoin_pairs_match_float64_reference(tjoin_child, case):
+    assert tjoin_child[case]["ok"], tjoin_child[case]["problems"]
 
 
 @pytest.mark.parametrize("case", CASES["range"])
