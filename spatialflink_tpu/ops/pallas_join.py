@@ -314,6 +314,11 @@ def join_window_pallas(
     Drop-in for ops.join.join_window_bucketed (same argument and result
     contract); float32 compute. ``interpret=True`` runs the Pallas
     interpreter for CPU testing.
+
+    Both sides' planes come from ``bucketize_planes`` — a sort and a
+    window a cell, no per-point index: 1.4 ms a side at 2¹⁹ lanes beside a
+    38 ms extraction, where its gathers and scatters took 24.5 (my chip
+    run, PR 40).
     """
     f32 = jnp.float32
     max_pairs = int(max_pairs)  # sfcheck: ok=trace-hygiene -- static shape budget, a Python int at trace time (never traced)

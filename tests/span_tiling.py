@@ -12,7 +12,12 @@ ROUNDING_US = 2
 
 
 def x_spans(events):
-    return [e for e in events if e.get("ph") == "X"]
+    """The complete spans, less the collector's own: a generation-2 pass
+    (``gc.full``) falls wherever the worker's earlier tests left the
+    allocation counts, inside a parent or in the consumer's nap, and is no
+    span of the loop."""
+    return [e for e in events
+            if e.get("ph") == "X" and e["name"] != "gc.full"]
 
 
 def end(e):
