@@ -40,6 +40,7 @@ from spatialflink_tpu.ops.join import (
     join_kernel,
     join_kernel_compact,
     join_window_bucketed,
+    join_window_cells,
     join_window_compact,
     pallas_join_supported,
     point_geometry_join_kernel,
@@ -235,7 +236,10 @@ class HeldJoin(NamedTuple):
     that holds its window (overflow 0, ``count`` ≤ the budget), the re-runs
     it took, the peel passes of the result held (0 from a program that
     counts none), and — where a ``follow`` program was given — what it
-    returned for that result and its scalars, fetched."""
+    returned for that result and its scalars, fetched. ``fullest_cell``:
+    the most points of one side in one cell of the key grid;
+    ``bucket_lanes``: the pair lanes (buckets × span² × cap²) every run of
+    the window's extraction evaluated, re-runs included."""
 
     res: object
     count: int
@@ -244,24 +248,72 @@ class HeldJoin(NamedTuple):
     peel_passes: int
     followed: object = None
     followed_scalars: Tuple[int, ...] = ()
+    fullest_cell: int = 0
+    bucket_lanes: int = 0
+
+
+class JoinCapacityError(ValueError):
+    """No bucket layout the join's program exists for holds the window:
+    the bucket grid is as fine as the radius allows and its fullest bucket
+    still asks for a capacity rung past the largest that compiles."""
+
+
+#: Refinements of the bucket grid the contract picks from: the key grid's
+#: cell side ÷ f, while that stays at least the radius.
+REFINEMENTS = (1, 2, 4, 8)
+
+#: The rung past which a crowded cell is held by a finer bucket grid before
+#: a larger capacity: the lanes of one vector register. A (cap, cap) block
+#: of the extraction costs cap², a refinement divides what a bucket holds by
+#: four at the same lanes a point, and a rung under 128 leaves a register's
+#: lanes idle.
+REFINE_RUNG = 128
+
+#: Margin a refined bucket's side keeps over the radius, so that the
+#: float32 bucket index of two points within the radius of each other
+#: differs by at most one (``ops/join.py:join_window_cells``).
+_SIDE_MARGIN = 1.0 + 2.0 ** -10
+
+#: The largest capacity rung the Pallas extraction exists for: its (cap, cap)
+#: blocks live in VMEM, and at 1,024 they no longer fit (a v5e's compiler
+#: refuses 100 × 1,024 with VMEM exhausted; 100 × 512, 200 × 512 and
+#: 400 × 256 compile: PERF.md §6, PR 41).
+PALLAS_TOP_RUNG = 512
+
+#: Most lanes one row of buckets (``grid_n · refine · cap``) may hold for
+#: the Pallas extraction, whose grid step keeps twelve such rows in VMEM,
+#: double-buffered: 400 × 256, 800 × 128 and 200 × 512 (102,400 lanes,
+#: 9.8 MB) compile for a v5e (PERF.md §6, PR 41).
+PALLAS_ROW_LANES = 102_400
 
 
 class JoinCapacity:
     """The capacity and pair-budget contract of a bucketed window join,
     shared by ``PointPointJoinQuery`` and ``TJoinQuery`` (the one home of
-    it): ``join_cap`` is the per-cell bucket capacity in use — the
-    constructor's ``cap`` its first rung, a window whose fullest cell
-    holds more climbs it on the ``ops/compaction.py`` ladder — and
-    ``join_budget`` the pair budget; both only grow and persist across
-    windows. A window either one fails to hold is run again, never handed
-    back short. Needs ``self.grid``."""
+    it). It picks how the window's fullest cell is held: ``join_cap`` is
+    the per-bucket capacity in use — the constructor's ``cap`` its first
+    rung on the ``ops/compaction.py`` ladder — and ``join_refine`` the
+    refinement f of the bucket grid (``grid.n · f`` buckets a side). Up to
+    ``REFINE_RUNG`` a fuller cell climbs the ladder on the key grid, as it
+    always has; past it the buckets are refined first (where the caller's
+    program takes its bucket cells from the contract and the refined side
+    stays at least the radius: ``_open_join``), and only at the finest
+    grid does the capacity climb on. A layout the Pallas extraction does
+    not exist for (a rung past ``PALLAS_TOP_RUNG``, a row of buckets past
+    ``PALLAS_ROW_LANES``) is never picked, and a window no other holds is
+    refused by name (``JoinCapacityError``) before a dispatch, not by the
+    compiler inside the window. ``join_budget`` is the pair budget. All
+    three only grow and persist across windows; a window one fails to hold
+    is run again, never handed back short. Needs ``self.grid``."""
 
     def _init_join_capacity(self, cap: int) -> None:
         self.cap = cap
         #: The bucket capacity in use: ``cap`` is its first rung, a window
-        #: whose fullest cell holds more climbs it (``_climb_cap``); like
+        #: whose fullest cell holds more climbs it (``_climb``); like
         #: the pair budget it only grows and persists across windows.
         self.join_cap = cap
+        #: The refinement of the bucket grid in use (1: the key grid).
+        self.join_refine = 1
         self.join_budget = 0  # grown pair budget, persists across windows
         #: A window has been held at the sizes in use: from then on what
         #: follows the join is dispatched behind it without waiting for
@@ -270,26 +322,110 @@ class JoinCapacity:
         #: 'pallas' | 'xla': the extraction ``run_soa`` last ran
         #: (``last_wire_digest_kind``'s twin); None before the first window.
         self.last_join_backend = None
+        self._open_join(0.0)
 
-    def _climb_cap(self, live: int) -> None:
-        """Climb to the capacity rung that holds ``live`` points in a
-        cell: the smallest power of two from the rung in use upward."""
-        self.join_cap = pick_capacity(
-            live, self.join_cap, minimum=self.join_cap, open_top=True
+    def _open_join(self, radius: float, refinable: bool = False,
+                   pallas: bool = False, dtype=np.float64) -> None:
+        """What the pick may use from here on: ``refinable`` — the caller
+        lays its buckets on the cells the contract hands it (``run_soa``
+        through ``_window_call``; never in approximate mode, whose
+        candidates are the key grid's) — and ``pallas``: the program is the
+        Pallas extraction, which has a largest rung and a widest row (the
+        XLA programs have neither). ``dtype`` is the one the window's
+        coordinates were centred for."""
+        from spatialflink_tpu.operators.base import _centring
+
+        side = self.grid.cell_length
+        self._join_finest = max(
+            f for f in REFINEMENTS
+            if f == 1 or (refinable and side / f >= radius * _SIDE_MARGIN)
         )
+        # a refinement picked for a smaller radius holds nothing here
+        self.join_refine = min(self.join_refine, self._join_finest)
+        self._join_pallas = pallas
+        self._join_span = 2 * self.grid.candidate_layers(radius) + 1
+        centring = _centring(self.grid, dtype)
+        cx, cy = (0.0, 0.0) if centring is None else centring[0]
+        #: the key grid's lower corner in the coordinates the kernels get
+        self._join_origin = np.array(
+            [self.grid.min_x - cx, self.grid.min_y - cy], np.float64)
+
+    def _climb(self, live: int) -> None:
+        """Climb ``(join_refine, join_cap)`` to what holds ``live`` points
+        in one cell of the key grid. A bucket of the grid refined f times
+        holds at least ``live / f²`` of them: the pick goes by that bound
+        (the overflow count of the run is the net under it). From the
+        refinement in use upward it takes the first whose rung stays within
+        ``REFINE_RUNG``, else the finest whose rung the program exists
+        for, and where there is none it refuses (``JoinCapacityError``)."""
+        fits = []
+        f = self.join_refine
+        while f <= self._join_finest:
+            rung = pick_capacity(
+                -(-live // (f * f)), self.join_cap, minimum=self.join_cap,
+                open_top=True,
+            )
+            top = min(PALLAS_TOP_RUNG, PALLAS_ROW_LANES // (self.grid.n * f))
+            if not self._join_pallas or rung <= top:
+                fits.append((f, rung))
+                if rung <= max(REFINE_RUNG, self.join_cap):
+                    break
+            f *= 2
+        if not fits:
+            f = self._join_finest
+            raise JoinCapacityError(
+                f"the fullest cell of the {self.grid.n} x {self.grid.n} key "
+                f"grid holds {live} points: on the finest bucket grid the "
+                f"radius allows (refinement {f}, bucket side "
+                f"{self.grid.cell_length / f:.6g}) a bucket needs capacity "
+                f"rung {rung}, and the Pallas extraction exists up to rung "
+                f"{top} there (rung {PALLAS_TOP_RUNG}, {PALLAS_ROW_LANES} "
+                f"lanes a row of buckets)"
+            )
+        self.join_refine, self.join_cap = fits[-1]
 
     def _grow_budget(self, count: int) -> None:
         """Headroom policy of the pair budget: at least the next power of
         two of 1.25 × ``count``."""
         self.join_budget = max(self.join_budget, headroom_bucket(count))
 
+    def _window_call(self, fn, left, right, radius, filter_radius=None):
+        """``call(refine, cap, budget)`` for ``_join_until_held``: one run
+        of the window program ``fn`` over the two shipped sides (each
+        ``(xy, valid, key cells)`` on the device) with its buckets on the
+        grid the contract picked — the key cells themselves at refinement
+        1, today's program and arguments to the letter; at a finer one the
+        cells ``join_window_cells`` makes of the coordinates (one small
+        program for both sides, once a window and refinement)."""
+        (lxy, lvalid, lcell), (rxy, rvalid, rcell) = left, right
+        layers = self.grid.candidate_layers(radius)
+        fr = radius if filter_radius is None else filter_radius
+        cells = {1: (lcell, rcell)}
+
+        def call(refine, cap, budget):
+            if refine not in cells:
+                cells[refine] = jitted(join_window_cells, "grid_n", "refine")(
+                    lxy, lcell, rxy, rcell, self._join_origin,
+                    refine / self.grid.cell_length,
+                    grid_n=self.grid.n, refine=refine,
+                )
+            lc, rc = cells[refine]
+            return fn(
+                lxy, lvalid, lc, rxy, rvalid, rc,
+                grid_n=self.grid.n * refine, layers=layers, radius=fr,
+                cap_left=cap, cap_right=cap, max_pairs=budget,
+            )
+
+        return call
+
     def _join_until_held(self, lcell, lvalid, rcell, rvalid, call,
                          follow=None) -> HeldJoin:
-        """``call(cap, budget)`` — one bucketed join of the two batches
-        whose cells these are — until its result holds them: the capacity
-        first climbs to the fullest cell (one bincount a side), then a
-        result that still reports overflow (the safety net under that
-        pick) is run again one rung up, and one with more pairs than the
+        """``call(refine, cap, budget)`` — one bucketed join of the two
+        batches whose key cells these are — until its result holds them:
+        the pick first climbs to the fullest cell (one bincount a side),
+        then a result that still reports overflow (the safety net under
+        that pick) is run again one step up — a rung, or past
+        ``REFINE_RUNG`` a refinement — and one with more pairs than the
         budget under a grown budget. The held result's overflow is 0, and
         its peel passes are fetched with its count.
 
@@ -305,13 +441,16 @@ class JoinCapacity:
         # The host side of the pick (phase span ``join.capacity``; the
         # re-runs' arithmetic below is a few integer operations).
         with telemetry.span("join.capacity"):
-            self._climb_cap(max(
+            fullest = max(
                 max_cell_count(lcell, lvalid, num_cells),
                 max_cell_count(rcell, rvalid, num_cells),
-            ))
-        cap_retries = budget_retries = 0
+            )
+            self._climb(fullest)
+        cap_retries = budget_retries = lanes = 0
         while True:
-            res = call(self.join_cap, self.join_budget)
+            res = call(self.join_refine, self.join_cap, self.join_budget)
+            lanes += (num_cells * self.join_refine ** 2 * self._join_span ** 2
+                      * self.join_cap ** 2)
             scalars = (res.count, res.overflow)
             if res.peel_passes is not None:
                 scalars += (res.peel_passes,)
@@ -322,7 +461,9 @@ class JoinCapacity:
             ]
             count, overflow, *passes = fetched[:len(scalars)]
             if overflow > 0:
-                self._climb_cap(2 * self.join_cap)
+                # a bucket holds more than the pick's bound: as if the
+                # fullest key cell held twice what its buckets hold now
+                self._climb(2 * self.join_cap * self.join_refine ** 2)
                 cap_retries += 1
             elif count > self.join_budget:
                 self._grow_budget(count)
@@ -335,18 +476,23 @@ class JoinCapacity:
                 self._join_settled = True
                 return HeldJoin(
                     res, count, cap_retries, budget_retries, sum(passes),
-                    followed, tuple(tail),
+                    followed, tuple(tail), fullest, lanes,
                 )
 
 
 class PointPointJoinQuery(JoinCapacity, SpatialOperator):
     """join/PointPointJoinQuery.java (windowBased :124-183, naive :186-243).
 
-    ``cap`` is the per-cell point capacity. The dense-bucket fast path caps
-    BOTH sides per cell; results are exact iff every window's
-    ``overflow == 0`` — a nonzero overflow means some cell exceeded ``cap``
-    and the join dropped candidates (raise ``cap`` for dense data; the
-    gather fallback engages automatically when cap²·cells grows too large).
+    ``cap`` is the first rung of the per-cell point capacity; the
+    dense-bucket fast path caps BOTH sides per cell, and every path runs
+    under ``JoinCapacity``'s contract: a window whose fullest cell holds
+    more climbs the ladder, and past rung 128 ``run_soa`` lays its buckets
+    on a finer grid instead (exact mode; side at least the radius), so a
+    crowded cell costs a refinement and not cap² lanes. A yielded window's
+    ``overflow`` is 0: one that is not held is run again, and where no
+    layout the program exists for holds it the contract raises
+    ``JoinCapacityError`` with the numbers (off the TPU ``run`` /
+    ``query_panes`` go on to the gather join past 3·10⁸ lanes instead).
     Out-of-grid points never join, matching the reference's key semantics.
     """
 
@@ -525,9 +671,14 @@ class PointPointJoinQuery(JoinCapacity, SpatialOperator):
         self.join_budget = max(
             self.join_budget, 1024, min(4 * lb.capacity, 262_144)
         )
+        # The batches carry the key grid's cells and nothing finer; only
+        # the Pallas extraction has a largest rung (off the TPU the gather
+        # join takes over past the dense program's lanes).
+        self._open_join(radius, pallas=mesh is None and window_join_program(
+            self.join_backend)[1] == "pallas")
         res, count, *_ = self._join_until_held(
             lb.cell, lb.valid, rb.cell, rb.valid,
-            lambda cap, budget: grid_hash_join_batches(
+            lambda _refine, cap, budget: grid_hash_join_batches(
                 self.grid, lb, rb, radius, cap, offsets,
                 max_pairs=budget, dtype=dtype,
                 backend=self.join_backend, mesh=mesh,
@@ -666,11 +817,14 @@ class PointPointJoinQuery(JoinCapacity, SpatialOperator):
         coordinates directly (Pallas extraction on TPU).
 
         Exact on every yielded window (``overflow == 0``): the bucket
-        capacity comes from the window's fullest cell (``_climb_cap``) and
+        capacity — and past rung 128, in exact mode, the refinement of the
+        bucket grid — comes from the window's fullest cell (``_climb``) and
         the pair budget keeps a quarter of headroom over the last count
         (``_grow_budget``, ``max_pairs`` its first value); a window either
-        one fails to hold is run again, never yielded short. Two fetches a
-        window: count and overflow, then the pairs found.
+        one fails to hold is run again, never yielded short, and one no
+        layout of the program holds raises ``JoinCapacityError`` before a
+        dispatch. Two fetches a window: count and overflow, then the pairs
+        found (in the padding bucket of their count).
 
         With telemetry on, one parent span ``join.window`` a two-sided
         window (args ``n``: events of both sides), emitted by hand at the
@@ -691,6 +845,12 @@ class PointPointJoinQuery(JoinCapacity, SpatialOperator):
         head = jitted(head_pairs, "bucket")
         layers = self.grid.candidate_layers(radius)
         fr = self._filter_radius(radius)
+        # Approximate mode emits every candidate of the KEY grid's
+        # neighbourhood: its buckets stay on the key grid.
+        self._open_join(
+            radius, refinable=not self.conf.approximate_query,
+            pallas=self.last_join_backend == "pallas", dtype=dtype,
+        )
         gen_l = soa_point_batches(self.grid, left_chunks, self.conf, dtype,
                                   span="join.assemble_left")
         gen_r = _spanned(
@@ -722,16 +882,14 @@ class PointPointJoinQuery(JoinCapacity, SpatialOperator):
             lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d = ship(
                 lxy, lvalid, lcell, rxy, rvalid, rcell
             )
-            res, count, cap_retries, budget_retries, passes, *_ = (
-                self._join_until_held(
-                    lcell, lvalid, rcell, rvalid,
-                    lambda cap, budget: fn(
-                        lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d,
-                        grid_n=self.grid.n, layers=layers, radius=fr,
-                        cap_left=cap, cap_right=cap, max_pairs=budget,
-                    ),
-                )
+            held = self._join_until_held(
+                lcell, lvalid, rcell, rvalid,
+                self._window_call(
+                    fn, (lxy_d, lvalid_d, lcell_d),
+                    (rxy_d, rvalid_d, rcell_d), radius, fr,
+                ),
             )
+            res, count = held.res, held.count
             pairs = (res.left_index, res.right_index, res.dist)
             if self.join_budget != warmed:
                 # A new budget: compile the two head programs a count under
@@ -742,9 +900,12 @@ class PointPointJoinQuery(JoinCapacity, SpatialOperator):
             bucket = min(next_bucket(count), len(res.dist))
             li, ri, dd = telemetry.fetch(head(*pairs, bucket=bucket))
             telemetry.record_join(
-                pairs=count, cap_retries=cap_retries,
-                budget_retries=budget_retries, cap=self.join_cap,
-                budget=self.join_budget, peel_passes=passes,
+                pairs=count, cap_retries=held.cap_retries,
+                budget_retries=held.budget_retries, cap=self.join_cap,
+                budget=self.join_budget, peel_passes=held.peel_passes,
+                fullest_cell=held.fullest_cell, refine=self.join_refine,
+                bucket_cells=self.grid.num_cells * self.join_refine ** 2,
+                bucket_lanes=held.bucket_lanes,
             )
             self._grow_budget(count)  # headroom for the next window
             if win.t0_ns is not None and not left_waited:

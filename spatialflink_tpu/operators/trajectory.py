@@ -35,7 +35,7 @@ from spatialflink_tpu.operators.join_query import (
     headroom_bucket,
     merge_by_timestamp,
 )
-from spatialflink_tpu.ops.join import head_pairs
+from spatialflink_tpu.ops.join import head_pairs, pallas_join_supported
 from spatialflink_tpu.telemetry import telemetry
 from spatialflink_tpu.ops.knn import knn_points_fused
 from spatialflink_tpu.ops.trajectory import (
@@ -369,10 +369,13 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
                 self.join_budget, 1024, min(4 * lb.capacity, 262_144)
             )
             loid, roid = ship(lb.oid, rb.oid)
+            # the batches carry the key grid's cells and nothing finer
+            self._open_join(
+                radius, pallas=mesh is None and pallas_join_supported())
             held = self._tpairs_until_held(
                 lb.cell, lb.valid, rb.cell, rb.valid, loid, roid,
                 max(self.interner.num_segments, 1),
-                lambda cap, budget: grid_hash_join_batches(
+                lambda _refine, cap, budget: grid_hash_join_batches(
                     self.grid, lb, rb, radius, cap, offsets,
                     max_pairs=budget, dtype=dtype, mesh=mesh,
                 ),
@@ -463,7 +466,10 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
         )
 
         fn, self.last_join_backend = window_join_program()
-        layers = self.grid.candidate_layers(radius)
+        self._open_join(
+            radius, refinable=True,
+            pallas=self.last_join_backend == "pallas", dtype=dtype,
+        )
         gen_l = soa_point_batches(self.grid, left_chunks, self.conf, dtype,
                                   span="join.assemble_left")
         gen_r = _spanned(
@@ -495,10 +501,9 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
             )
             held = self._tpairs_until_held(
                 lcell, lvalid, rcell, rvalid, loid_d, roid_d, num_segments,
-                lambda cap, budget: fn(
-                    lxy_d, lvalid_d, lcell_d, rxy_d, rvalid_d, rcell_d,
-                    grid_n=self.grid.n, layers=layers, radius=radius,
-                    cap_left=cap, cap_right=cap, max_pairs=budget,
+                self._window_call(
+                    fn, (lxy_d, lvalid_d, lcell_d),
+                    (rxy_d, rvalid_d, rcell_d), radius,
                 ),
             )
             (tcount,) = held.followed_scalars
@@ -508,7 +513,7 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
                 cap_retries=held.cap_retries,
                 budget_retries=held.budget_retries, cap=self.join_cap,
                 budget=self.join_budget, tpair_budget=self.tpair_budget,
-                peel_passes=held.peel_passes,
+                peel_passes=held.peel_passes, refine=self.join_refine,
             )
             self._grow_budget(held.count)  # headroom for the next window
             if win.t0_ns is not None and not left_waited:

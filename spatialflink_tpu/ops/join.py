@@ -312,6 +312,40 @@ def bucketize_planes(xy, valid, cells, grid_n: int, cap: int):
     return plane(sx, 0), plane(sy, 0), plane(sidx, -1), overflow
 
 
+def join_window_cells(left_xy, left_cells, right_xy, right_cells, origin,
+                      inv_side, grid_n: int, refine: int):
+    """Both sides' bucket cells on the key grid refined ``refine`` times a
+    side — ``(grid_n · refine)²`` square buckets over the key grid's own
+    square — for a window whose crowded key cells no capacity rung holds
+    (``operators/join_query.py:JoinCapacity``). The exact join needs no
+    more of its buckets than a side of at least the radius: every partner
+    of a point then lies in the 3 × 3 buckets around its own.
+
+    A bucket index is ``floor((x − origin) · inv_side)`` of the coordinates
+    the extraction itself compares (centred float32 on the chip), clamped
+    to the grid: monotone in the coordinate, so two points within the
+    radius of each other lie at most one bucket apart on each axis as long
+    as the bucket's side keeps a float32 margin over the radius (the
+    contract's to see to). Which points join at all stays the key grid's
+    word: a lane whose key cell is outside (``>= grid_n²``: out-of-grid or
+    padding) gets the refined grid's outside cell, so points outside the
+    deployment's grid never join, whatever grid the buckets are laid on."""
+    nf = grid_n * refine
+    origin = jnp.asarray(origin, left_xy.dtype)
+    inv_side = jnp.asarray(inv_side, left_xy.dtype)
+
+    def axis(column, k):
+        index = jnp.floor((column - origin[k]) * inv_side)
+        return jnp.clip(index, 0, nf - 1).astype(jnp.int32)
+
+    def side(xy, cells):
+        # a column at a time: an (n, 2) operand is tiled to 128 lanes
+        return jnp.where(cells < grid_n * grid_n,
+                         axis(xy[:, 0], 0) * nf + axis(xy[:, 1], 1), nf * nf)
+
+    return side(left_xy, left_cells), side(right_xy, right_cells)
+
+
 #: Pair-mask lanes one band of grid rows may hold in join_window_bucketed
 #: (span² · rows · grid_n · capL · capR booleans, and the prefix sum
 #: ``jnp.nonzero`` runs over them): 2²⁵ keeps a band at 32 MB of flags.

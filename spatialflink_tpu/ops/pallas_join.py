@@ -56,8 +56,23 @@ and every distance are those of the one-hit loop, bit for bit.
 Mosaic limits that shaped it (jax 0.9.0, libtpu 0.0.34): no i1 block through
 a ``while_loop``; no ``dynamic_slice`` or gather on a vector (hence one-hot
 selects); no scalar stores to VMEM (hence the register row); ``tpu.iota``
-makes no float32. Cap 1,024 on a 100-column grid asks for 20 MB of VMEM for
-the planes' blocks alone and does not compile (nor did it before).
+makes no float32.
+
+What a grid step holds, and where the kernel ends: the three left and nine
+right plane blocks of one row of buckets (``grid_n · cap · 4 B`` each, double-
+buffered) and the (cap, cap) blocks of the mask and the peel. Compiled for a
+v5e (PERF.md §6, PR 41: no chip needed): 100 columns × cap 128 | 256 | 512,
+200 × 256 | 512, 400 × 128 | 256 and 800 × 128 compile; 100 × 1,024 and
+100 × 2,048 do not (the cap² blocks), nor 400 × 512, 800 × 256 and 800 × 512
+(204,800 lanes a row: the planes' blocks) — VMEM exhausted each time. A cell
+too crowded for rung 512 is therefore never met with a larger rung: the
+capacity contract (``operators/join_query.py:JoinCapacity``) lays the buckets
+on a finer grid — ``grid_n`` here is the key grid's n times its refinement,
+``left_cells`` / ``right_cells`` the bucket cells ``ops/join.py:
+join_window_cells`` makes — keeps to ``PALLAS_TOP_RUNG`` and
+``PALLAS_ROW_LANES``, and refuses by name what neither holds. The walk visits
+every bucket of every row that has a left point; an empty bucket costs its
+count and a branch (skipping whole empty stretches is open: PERF.md §7).
 
 Replaces the reference's replicate+shuffle+filter join
 (join/JoinQuery.java:73-137, join/PointPointJoinQuery.java:124-183) as the

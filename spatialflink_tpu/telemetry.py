@@ -319,9 +319,10 @@ class Telemetry:
         self._kernel_stats: Dict[Tuple[str, Tuple], Dict[str, Any]] = {}
         # Window point join (operators/join_query.py:run_soa via
         # record_join): counters pairs / peel_passes / windows / cap_retries
-        # / budget_retries and the gauges cap / budget (the rung and the
-        # pair budget in use) — snapshot()["join"], empty until the first
-        # joined window.
+        # / budget_retries / bucket_lanes and the gauges cap / budget (the
+        # rung and the pair budget in use) / refine / bucket_cells (the
+        # bucket grid in use) / fullest_cell (the last window's) —
+        # snapshot()["join"], empty until the first joined window.
         self._join: Dict[str, int] = {}
         # Window trajectory join (operators/trajectory.py:TJoinQuery.run_soa
         # via record_tjoin): counters windows / pairs (point pairs) /
@@ -1156,29 +1157,41 @@ class Telemetry:
             }
 
     def record_join(self, pairs: int, cap_retries: int, budget_retries: int,
-                    cap: int, budget: int, peel_passes: int = 0):
+                    cap: int, budget: int, peel_passes: int = 0,
+                    fullest_cell: int = 0, refine: int = 1,
+                    bucket_cells: int = 0, bucket_lanes: int = 0):
         """One window of the SoA point join, fetched: ``pairs`` found,
         the vector passes the Pallas extraction took them out in
         (``pairs ÷ peel_passes`` = hits a pass carries; 0 from the XLA
         program, which has no such loop), the re-runs it took (a bucket
-        capacity or a pair budget the window did not fit), and the
-        capacity rung and budget it ended on. Lands in
+        layout or a pair budget the window did not fit), the capacity rung
+        and budget it ended on, and what holding the window cost:
+        ``fullest_cell`` — the most points of one side in one cell of the
+        key grid —, the refinement ``refine`` of the bucket grid picked for
+        it, that grid's ``bucket_cells``, and ``bucket_lanes`` — buckets ×
+        span² × cap², the pair lanes every run of the extraction evaluated
+        (``bucket_lanes ÷ pairs`` = lanes a pair found). Lands in
         ``snapshot()["join"]`` as the counters ``pairs``, ``peel_passes``,
-        ``windows``, ``cap_retries``, ``budget_retries`` and the gauges
-        ``cap``, ``budget``. Per window, never per event."""
+        ``windows``, ``cap_retries``, ``budget_retries``, ``bucket_lanes``
+        and the gauges ``cap``, ``budget``, ``fullest_cell``, ``refine``,
+        ``bucket_cells``. Per window, never per event."""
         if not self.enabled:
             return
         with self._lock:
             j = self._join
             for key, n in (("pairs", pairs), ("peel_passes", peel_passes),
                            ("windows", 1), ("cap_retries", cap_retries),
-                           ("budget_retries", budget_retries)):
+                           ("budget_retries", budget_retries),
+                           ("bucket_lanes", bucket_lanes)):
                 j[key] = j.get(key, 0) + int(n)
             j["cap"], j["budget"] = int(cap), int(budget)
+            j["fullest_cell"], j["refine"] = int(fullest_cell), int(refine)
+            j["bucket_cells"] = int(bucket_cells)
 
     def record_tjoin(self, pairs: int, tpairs: int, cap_retries: int,
                      budget_retries: int, cap: int, budget: int,
-                     tpair_budget: int, peel_passes: int = 0):
+                     tpair_budget: int, peel_passes: int = 0,
+                     refine: int = 1):
         """One window of the SoA trajectory join, fetched: the point
         ``pairs`` the extraction found (they stay on the device), the
         distinct trajectory pairs ``tpairs`` the dedup made of them
@@ -1190,7 +1203,8 @@ class Telemetry:
         Lands in ``snapshot()["tjoin"]`` as the counters ``windows``,
         ``pairs``, ``tpairs``, ``peel_passes``, ``cap_retries``,
         ``budget_retries`` and the gauges ``cap``, ``budget``,
-        ``tpair_budget``. Per window, never per event."""
+        ``tpair_budget``, ``refine`` (the bucket grid's refinement, as the
+        point join's). Per window, never per event."""
         if not self.enabled:
             return
         with self._lock:
@@ -1201,7 +1215,7 @@ class Telemetry:
                            ("budget_retries", budget_retries)):
                 j[key] = j.get(key, 0) + int(n)
             j["cap"], j["budget"] = int(cap), int(budget)
-            j["tpair_budget"] = int(tpair_budget)
+            j["tpair_budget"], j["refine"] = int(tpair_budget), int(refine)
 
     def record_range(self, points: int, lanes: int, matches: int,
                      cand_retries: int, budget_retries: int, cand: int,

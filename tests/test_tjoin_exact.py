@@ -199,14 +199,15 @@ def test_dedup_holds_no_array_sized_by_the_ids(lanes):
 # -- the operator -------------------------------------------------------------
 
 def test_both_joins_share_one_capacity_contract():
-    for name in ("_climb_cap", "_grow_budget", "_join_until_held"):
+    for name in ("_open_join", "_climb", "_grow_budget", "_window_call",
+                 "_join_until_held"):
         assert getattr(TJoinQuery, name) is getattr(JoinCapacity, name)
         assert getattr(PointPointJoinQuery, name) is getattr(JoinCapacity,
                                                              name)
     assert headroom_bucket(1000) == 2048 and headroom_bucket(10) == 1024
     op = PointPointTJoinQuery(W10, GRID)
-    assert (op.cap, op.join_cap, op.join_budget, op.tpair_budget) == \
-        (64, 64, 0, 0)
+    assert (op.cap, op.join_cap, op.join_refine, op.join_budget,
+            op.tpair_budget) == (64, 64, 1, 0, 0)
     assert op.last_join_backend is None
 
 
