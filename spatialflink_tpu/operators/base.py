@@ -548,7 +548,8 @@ def device_point_args(grid: UniformGrid, xy64: np.ndarray, oid, dtype):
 
 
 def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
-                      dtype=np.float64, span: Optional[str] = None):
+                      dtype=np.float64, span: Optional[str] = None,
+                      counted: bool = True):
     """SoA windows → (window, padded arrays) for the run_soa fast paths.
 
     Yields (win, xy, valid, cell, oid) per the point_lanes contract.
@@ -564,6 +565,10 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
     (the tails, ``valid``, ``oid``). The clock reading it opens at goes on
     with the window (``win.t0_ns``), for the operator's parent span to open
     at the same instant.
+
+    ``counted=False`` leaves each window's ``counters.record_window`` to the
+    caller: a stream materialised on a producer thread, whose consumer keeps
+    the op counters (which take no lock) on its own thread.
     """
     from spatialflink_tpu.streams.soa import SoaWindowAssembler
 
@@ -576,7 +581,7 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
     scratch = lane_scratch()
 
     def batch(win):
-        if counters.enabled:
+        if counted and counters.enabled:
             # Throughput meter for the SoA path (Point.java:237-253 analog);
             # candidate tallies come from the operator (it owns the flags).
             counters.record_window(win.count, 0, 0)

@@ -17,8 +17,11 @@ PointPointJoinQuery.java:128-146).
 
 from __future__ import annotations
 
+import ctypes
 import functools
 import heapq
+import queue
+import threading
 import time
 from dataclasses import dataclass
 from typing import Iterable, Iterator, List, NamedTuple, Sequence, Tuple
@@ -826,16 +829,22 @@ class PointPointJoinQuery(JoinCapacity, SpatialOperator):
         dispatch. Two fetches a window: count and overflow, then the pairs
         found (in the padding bucket of their count).
 
+        Both sides are assembled one window ahead on a producer thread
+        (``_aligned_soa_windows``): window n + 1's chunk pulls, assembly
+        (``join.assemble_left``, ``join.assemble``, ``soa.*``, emitted on that
+        thread) and alignment run while this loop ships, joins and fetches
+        window n; the loop pulls no chunk, so a window's result goes out with
+        no pull after its trigger. Everything else — the capacity contract's
+        state, every JAX call, ``record_join`` and the op counters — stays on
+        the loop's thread.
+
         With telemetry on, one parent span ``join.window`` a two-sided
         window (args ``n``: events of both sides), emitted by hand at the
-        hand-back: from the chunk that lets the left side's window fire
-        (``join.assemble_left``'s start, ``win.t0_ns``) to just before the
-        yield, so it holds ``join.assemble_left``, ``join.assemble`` (the
-        right side), ``h2d``, ``join.capacity``, ``dispatch:*`` and both
-        ``d2h`` and none of the consumer's time. A one-sided window emits
-        none, and neither does a window whose left side was in hand while
-        a right-only window went to the consumer."""
-        from spatialflink_tpu.operators.base import soa_point_batches
+        hand-back: from the moment the loop asks for the window to just
+        before the yield, so it holds ``join.await`` (the wait for the
+        producer: near nothing when the window was assembled beside the last
+        one), ``h2d``, ``join.capacity``, ``dispatch:*`` and both ``d2h`` and
+        none of the consumer's time. A one-sided window emits none."""
         from spatialflink_tpu.ops.counters import (
             count_join_candidates,
             counters as opcounters,
@@ -851,20 +860,15 @@ class PointPointJoinQuery(JoinCapacity, SpatialOperator):
             radius, refinable=not self.conf.approximate_query,
             pallas=self.last_join_backend == "pallas", dtype=dtype,
         )
-        gen_l = soa_point_batches(self.grid, left_chunks, self.conf, dtype,
-                                  span="join.assemble_left")
-        gen_r = _spanned(
-            soa_point_batches(self.grid, right_chunks, self.conf, dtype),
-            "join.assemble",
-        )
         self.join_budget = max(self.join_budget, max_pairs)
         warmed = 0  # the budget whose head programs are compiled
-        left_waited = False  # wl sat through a right-only window's hand-back
-        for kind, wl, wr in _aligned_soa_windows(
-            gen_l, gen_r, lambda w: w[0].start, lambda w: w[0].start
+        for kind, wl, wr, asked_ns in _aligned_soa_windows(
+            left_chunks, right_chunks,
+            *_point_sides(self.grid, self.conf, dtype),
+            lambda w: w[0].start, lambda w: w[0].start,
         ):
+            _record_windows(wl, wr)
             if kind != "both":
-                left_waited = kind == "right"
                 w = wl[0] if kind == "left" else wr[0]
                 yield (w.start, w.end, np.empty(0, np.int32),
                        np.empty(0, np.int32), np.empty(0), 0, 0)
@@ -908,13 +912,12 @@ class PointPointJoinQuery(JoinCapacity, SpatialOperator):
                 bucket_lanes=held.bucket_lanes,
             )
             self._grow_budget(count)  # headroom for the next window
-            if win.t0_ns is not None and not left_waited:
+            if asked_ns is not None:
                 telemetry.emit_span(
-                    "join.window", win.t0_ns,
-                    time.perf_counter_ns() - win.t0_ns,
+                    "join.window", asked_ns,
+                    time.perf_counter_ns() - asked_ns,
                     n=win.count + wr[0].count,
                 )
-            left_waited = False
             yield (win.start, win.end, li, ri, dd, count, 0)
 
 
@@ -929,25 +932,175 @@ def _spanned(gen, name: str):
         yield item
 
 
-def _aligned_soa_windows(gen_l, gen_r, start_l, start_r):
-    """Align two per-window generator streams on their shared slide grid
-    — the single home of the two-stream run_soa merge loop. Yields
-    ('left', wl, None) / ('right', None, wr) for one-sided windows and
-    ('both', wl, wr) for aligned ones; ``start_l``/``start_r`` extract a
-    window's start from each generator's item shape."""
-    wl = next(gen_l, None)
-    wr = next(gen_r, None)
-    while wl is not None or wr is not None:
-        if wr is None or (wl is not None and start_l(wl) < start_r(wr)):
-            yield "left", wl, None
-            wl = next(gen_l, None)
-        elif wl is None or start_r(wr) < start_l(wl):
-            yield "right", None, wr
-            wr = next(gen_r, None)
-        else:
-            yield "both", wl, wr
-            wl = next(gen_l, None)
-            wr = next(gen_r, None)
+def _point_sides(grid, conf, dtype):
+    """The two point sides of the point joins' ``run_soa``, as
+    ``_aligned_soa_windows`` takes them: each side's chunks →
+    ``soa_point_batches``, the left side under ``join.assemble_left`` (from
+    the chunk that lets its window fire), the right side under
+    ``join.assemble`` (``_spanned``: every step, its chunk pulls included).
+    Their op counters are the loop's (``_record_windows``)."""
+    from spatialflink_tpu.operators.base import soa_point_batches
+
+    def left(chunks):
+        return soa_point_batches(grid, chunks, conf, dtype,
+                                 span="join.assemble_left", counted=False)
+
+    def right(chunks):
+        return _spanned(soa_point_batches(grid, chunks, conf, dtype,
+                                          counted=False), "join.assemble")
+
+    return left, right
+
+
+def _record_windows(*sides) -> None:
+    """``counters.record_window`` of each point side's window handed over
+    (None: no window on that side), on the loop's thread."""
+    from spatialflink_tpu.ops.counters import counters
+
+    if counters.enabled:
+        for w in sides:
+            if w is not None:
+                counters.record_window(w[0].count, 0, 0)
+
+
+class _Closed(Exception):
+    """Raised at the producer's next chunk pull once the loop has gone."""
+
+
+class _Raised(NamedTuple):
+    """What the producer raised, handed over in place of its item."""
+
+    error: BaseException
+
+
+_DONE = object()  # the producer's last hand-over: both streams have ended
+
+_M_TOP_PAD = -2  # <malloc.h>
+_THREAD_HEAP_BYTES = 64 << 20  # glibc's largest heap of a thread's arena
+
+
+@functools.lru_cache(maxsize=None)
+def _keep_thread_heaps() -> None:
+    """Keep the producer's malloc heaps from one window to the next.
+
+    glibc gives a second thread an arena of its own, made of 64 MB heaps,
+    and unmaps a heap as soon as it is wholly free, whatever trim threshold
+    the deployment pinned: the heap a window's arrays emptied is mapped anew
+    by a later window and every page of it touched for the first time
+    (≈ 9,000 page faults every other window at 500,000 points a side; on
+    the chip's host 4–10 µs a page). A top pad of one heap keeps every heap
+    (``heap_trim`` lets a heap go only when more than the pad would be left
+    free). Process-wide, once; like any ``mallopt`` it also fixes an
+    adaptive mmap threshold where it stands. Nothing where libc has none."""
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return
+    mallopt(_M_TOP_PAD, _THREAD_HEAP_BYTES)
+
+
+def _aligned_soa_windows(left_chunks, right_chunks, windows_l, windows_r,
+                         start_l, start_r):
+    """Align two per-window streams on their shared slide grid, assembled one
+    window ahead of the loop — the single home of the two-stream ``run_soa``
+    merge loop.
+
+    ``windows_l`` / ``windows_r`` make a side's per-window generator of its
+    chunks (``soa_point_batches``, a ragged assembler's ``stream``);
+    ``start_l`` / ``start_r`` read a window's start off each one's item.
+    Yields ``(kind, wl, wr, asked_ns)``: ('left', wl, None) / ('right', None,
+    wr) for one-sided windows, ('both', wl, wr) for aligned ones, and the
+    ``perf_counter_ns`` at which the loop asked for the item (None with
+    telemetry off), where the operator's parent span opens.
+
+    Both sides' chunk pulls, their assemblers and the merge run on a producer
+    thread, which hands each item over through a one-slot queue and makes
+    item n + 1 only once the loop has taken item n: it is one window ahead,
+    never more, and assembles window n + 1 while the loop ships, joins and
+    fetches window n (``block_until_ready`` and the queues' waits let go of
+    the GIL). The loop pulls no chunk, so it never waits on the source while
+    it holds a window: window n goes out with no pull after its trigger on
+    the loop's thread, and where the source paces, the producer waits on it
+    beside the loop. The producer runs numpy only — the chunk sources, the
+    assemblers, ``point_lanes`` on each side's own ``lane_scratch()`` (the
+    lanes it returns are fresh every window, so nothing handed over aliases
+    the scratch) — and emits the assembly spans (``join.assemble_left``,
+    ``join.assemble``, ``soa.*``) on its own thread; the operator's state,
+    every JAX call and every counter stay on the loop's.
+
+    With telemetry on, the loop's thread emits ``join.await`` around each
+    wait for the producer's next item, and ``record_join_prefetch`` counts
+    the two-sided windows that were ready when asked for
+    (``snapshot()["join"]["prefetched"]``). What the producer raises is
+    raised on the loop's thread at the item the merge would have raised it
+    at. Closing the generator stops the producer at its next chunk pull
+    (the open windows are not flushed) and joins it before returning. The
+    producer's malloc heaps are kept from window to window
+    (``_keep_thread_heaps``)."""
+    slot: queue.Queue = queue.Queue(maxsize=1)  # producer → loop
+    asks: queue.Queue = queue.Queue()  # loop → producer: True, or False: stop
+    closed = threading.Event()
+
+    def pulled(chunks):
+        it = iter(chunks)
+        while not closed.is_set():
+            try:
+                chunk = next(it)
+            except StopIteration:
+                return
+            yield chunk
+        raise _Closed
+
+    def merged():
+        gen_l = iter(windows_l(pulled(left_chunks)))
+        gen_r = iter(windows_r(pulled(right_chunks)))
+        wl = next(gen_l, None)
+        wr = next(gen_r, None)
+        while wl is not None or wr is not None:
+            if wr is None or (wl is not None and start_l(wl) < start_r(wr)):
+                yield "left", wl, None
+                wl = next(gen_l, None)
+            elif wl is None or start_r(wr) < start_l(wl):
+                yield "right", None, wr
+                wr = next(gen_r, None)
+            else:
+                yield "both", wl, wr
+                wl = next(gen_l, None)
+                wr = next(gen_r, None)
+
+    def produce():
+        items = merged()
+        try:
+            while asks.get():
+                slot.put(next(items, _DONE))
+        except _Closed:
+            pass
+        except BaseException as e:  # noqa: BLE001 — the loop's to raise
+            slot.put(_Raised(e))
+
+    _keep_thread_heaps()
+    producer = threading.Thread(target=produce, name="join-assembly",
+                                daemon=True)
+    asks.put(True)
+    producer.start()
+    try:
+        while True:
+            asked = time.perf_counter_ns() if telemetry.enabled else None
+            ready = not slot.empty()
+            with telemetry.span("join.await"):
+                item = slot.get()
+            if item is _DONE:
+                return
+            if isinstance(item, _Raised):
+                raise item.error
+            asks.put(True)  # the next window, while the loop runs this one
+            if ready and item[0] == "both":
+                telemetry.record_join_prefetch()
+            yield (*item, asked)
+    finally:
+        closed.set()
+        asks.put(False)
+        producer.join()
 
 
 @functools.lru_cache(maxsize=None)
@@ -1209,16 +1362,23 @@ class _PointGeometryJoinQuery(SpatialOperator, _PrunedGeomJoinRetry):
             point_geometry_join_pruned_kernel,
             "polygonal", "block", "cand", "max_pairs", "pair_cap", "approx",
         )
-        gen_l = soa_point_batches(self.grid, point_chunks, self.conf, dtype)
-        asm_r = RaggedSoaWindowAssembler(
-            self.conf.window_size_ms, self.conf.slide_step_ms,
-            ooo_ms=self.conf.allowed_lateness_ms,
-        )
-        gen_r = asm_r.stream(geom_chunks)
+
+        def points(chunks):
+            return soa_point_batches(self.grid, chunks, self.conf, dtype,
+                                     counted=False)
+
+        def geometries(chunks):
+            return RaggedSoaWindowAssembler(
+                self.conf.window_size_ms, self.conf.slide_step_ms,
+                ooo_ms=self.conf.allowed_lateness_ms,
+            ).stream(chunks)
+
         empty = (np.empty(0, np.int32), np.empty(0, np.int32), np.empty(0))
-        for kind, wl, wr in _aligned_soa_windows(
-            gen_l, gen_r, lambda w: w[0].start, lambda w: w.start
+        for kind, wl, wr, _asked in _aligned_soa_windows(
+            point_chunks, geom_chunks, points, geometries,
+            lambda w: w[0].start, lambda w: w.start,
         ):
+            _record_windows(wl)
             if kind == "left":
                 yield (wl[0].start, wl[0].end, *empty, 0)
                 continue
@@ -1414,10 +1574,10 @@ class _GeometryGeometryJoinQuery(SpatialOperator, _PrunedGeomJoinRetry):
                 edge_valid_flat=w.edge_valid, dtype=np.float64,
             )
 
-        gen_l, gen_r = gen(left_chunks), gen(right_chunks)
         empty = (np.empty(0, np.int32), np.empty(0, np.int32), np.empty(0))
-        for kind, wl, wr in _aligned_soa_windows(
-            gen_l, gen_r, lambda w: w.start, lambda w: w.start
+        for kind, wl, wr, _asked in _aligned_soa_windows(
+            left_chunks, right_chunks, gen, gen,
+            lambda w: w.start, lambda w: w.start,
         ):
             if kind != "both":
                 w = wl if kind == "left" else wr

@@ -444,24 +444,30 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
         never cross. ``num_segments`` at most 46,340 (the dedup's int32
         pair key).
 
+        Both sides are assembled one window ahead on a producer thread, as
+        ``PointPointJoinQuery.run_soa`` and by the same code
+        (``join_query._aligned_soa_windows``): window n + 1's chunk pulls and
+        assembly (``join.assemble_left``, ``join.assemble``, ``soa.*``,
+        emitted on that thread) run while this loop ships, joins, dedups and
+        fetches window n; the loop pulls no chunk, so a window's result goes
+        out with no pull after its trigger. The capacity contract's state,
+        every JAX call, ``record_tjoin`` and the op counters stay on the
+        loop's thread.
+
         With telemetry on: one parent span ``tjoin.window`` a two-sided
         window (args ``n``: events of both sides), emitted by hand at the
-        hand-back, from the chunk that lets the left side's window fire
-        (``join.assemble_left``'s start) to just before the yield; inside
-        it ``join.assemble_left``, ``join.assemble`` (the right side),
-        ``tjoin.ids`` (the id-range check of both sides), ``h2d``,
+        hand-back, from the moment the loop asks for the window to just
+        before the yield; inside it ``join.await`` (the wait for the
+        producer), ``tjoin.ids`` (the id-range check of both sides), ``h2d``,
         ``join.capacity``, ``dispatch:*`` (the extraction,
         ``traj_pair_dedup_kernel``, ``head_pairs``) and both ``d2h``; one
         ``record_tjoin`` a window (``snapshot()["tjoin"]``). A one-sided
-        window emits none, and neither does a window whose left side was
-        in hand while a right-only window went to the consumer."""
-        from spatialflink_tpu.operators.base import (
-            check_oid_range,
-            soa_point_batches,
-        )
+        window emits none."""
+        from spatialflink_tpu.operators.base import check_oid_range
         from spatialflink_tpu.operators.join_query import (
             _aligned_soa_windows,
-            _spanned,
+            _point_sides,
+            _record_windows,
             window_join_program,
         )
 
@@ -470,20 +476,15 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
             radius, refinable=True,
             pallas=self.last_join_backend == "pallas", dtype=dtype,
         )
-        gen_l = soa_point_batches(self.grid, left_chunks, self.conf, dtype,
-                                  span="join.assemble_left")
-        gen_r = _spanned(
-            soa_point_batches(self.grid, right_chunks, self.conf, dtype),
-            "join.assemble",
-        )
         self.join_budget = max(self.join_budget, max_pairs)
-        left_waited = False  # wl sat through a right-only window's hand-back
         empty = (np.empty(0, np.int32), np.empty(0, np.int32), np.empty(0))
-        for kind, wl, wr in _aligned_soa_windows(
-            gen_l, gen_r, lambda w: w[0].start, lambda w: w[0].start
+        for kind, wl, wr, asked_ns in _aligned_soa_windows(
+            left_chunks, right_chunks,
+            *_point_sides(self.grid, self.conf, dtype),
+            lambda w: w[0].start, lambda w: w[0].start,
         ):
+            _record_windows(wl, wr)
             if kind != "both":
-                left_waited = kind == "right"
                 w = wl[0] if kind == "left" else wr[0]
                 yield (w.start, w.end, *empty, 0, 0)
                 continue
@@ -516,13 +517,12 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
                 peel_passes=held.peel_passes, refine=self.join_refine,
             )
             self._grow_budget(held.count)  # headroom for the next window
-            if win.t0_ns is not None and not left_waited:
+            if asked_ns is not None:
                 telemetry.emit_span(
-                    "tjoin.window", win.t0_ns,
-                    time.perf_counter_ns() - win.t0_ns,
+                    "tjoin.window", asked_ns,
+                    time.perf_counter_ns() - asked_ns,
                     n=win.count + rwin.count,
                 )
-            left_waited = False
             yield (win.start, win.end, lo, ro, dd, tcount, 0)
 
     def run_soa_panes(

@@ -321,7 +321,9 @@ class Telemetry:
         # record_join): counters pairs / peel_passes / windows / cap_retries
         # / budget_retries / bucket_lanes and the gauges cap / budget (the
         # rung and the pair budget in use) / refine / bucket_cells (the
-        # bucket grid in use) / fullest_cell (the last window's) —
+        # bucket grid in use) / fullest_cell (the last window's), and
+        # prefetched (record_join_prefetch: windows the join's producer
+        # thread had assembled when its loop asked) —
         # snapshot()["join"], empty until the first joined window.
         self._join: Dict[str, int] = {}
         # Window trajectory join (operators/trajectory.py:TJoinQuery.run_soa
@@ -1187,6 +1189,19 @@ class Telemetry:
             j["cap"], j["budget"] = int(cap), int(budget)
             j["fullest_cell"], j["refine"] = int(fullest_cell), int(refine)
             j["bucket_cells"] = int(bucket_cells)
+
+    def record_join_prefetch(self):
+        """One two-sided window of a join's ``run_soa`` that the producer
+        thread had assembled before the loop asked for it
+        (``operators/join_query.py:_aligned_soa_windows``). Lands in
+        ``snapshot()["join"]`` as the counter ``prefetched`` (the trajectory
+        join's too); ``prefetched ÷ windows`` = how often assembly ran beside
+        the loop. Per window, never per event."""
+        if not self.enabled:
+            return
+        with self._lock:
+            j = self._join
+            j["prefetched"] = j.get("prefetched", 0) + 1
 
     def record_tjoin(self, pairs: int, tpairs: int, cap_retries: int,
                      budget_retries: int, cap: int, budget: int,

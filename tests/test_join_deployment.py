@@ -250,10 +250,12 @@ def test_one_dispatch_span_a_call_and_bytes_of_what_was_found(traced):
 
 
 def test_join_window_parent_tiles_the_window_and_no_consumer_time(traced):
-    """One ``join.window`` a two-sided window, from the left side's firing
-    chunk to the hand-back: both sides' assembly, the capacity pick, the
-    ship, every run of the program and both fetches lie inside it, the
-    consumer's time does not, and the pairs are the telemetry-off run's."""
+    """One ``join.window`` a two-sided window, from the loop's ask for it to
+    the hand-back: the wait for the producer, the capacity pick, the ship,
+    every run of the program and both fetches lie inside it, the consumer's
+    time does not, and the pairs are the telemetry-off run's. Both sides'
+    assembly runs on the producer thread: none of it lies in the loop's
+    parent."""
     left, right = _streams(seed=14)
     telemetry.disable()
     plain = list(_run(_operator("pallas_interpret"), left, right, np.float32))
@@ -269,32 +271,38 @@ def test_join_window_parent_tiles_the_window_and_no_consumer_time(traced):
     parents, inner = assert_parents_tile(events, "join.window", naps)
     assert [p["args"]["n"] for p in parents] == [
         len(lw["ts"]) + len(rw["ts"]) for lw, rw in zip(left, right)]
-    # outside every parent: the right side's last step, which finds its
-    # stream at an end (``_spanned`` times every step)
-    assert [e["name"] for e in events if e["name"] != "join.window"
-            and not any(inside(e, p) for p in parents)] == ["join.assemble"]
-    lefts = [e for e in events if e["name"] == "join.assemble_left"]
+    loop = parents[0]["tid"]
+    assembly = ("join.assemble_left", "join.assemble", "soa.consolidate",
+                "soa.center", "soa.cells", "soa.pad")
+    # each side's passes, once a window, inside its own assembly span, all
+    # on one other thread
+    assert {e["tid"] for e in events if e["name"] in assembly} != {loop}
+    assert len({e["tid"] for e in events if e["name"] in assembly}) == 1
+    for soa in assembly[2:]:
+        assert sum(e["name"] == soa for e in events) == 4
     j = telemetry.snapshot()["join"]
     retries = [j["cap_retries"] + j["budget_retries"], 0]  # paid once
-    for p, names, asm, again in zip(parents, inner, lefts, retries):
-        assert p["ts"] == asm["ts"]  # one clock reading opens both
-        for once in ("join.assemble_left", "join.assemble", "join.capacity",
-                     "h2d"):
+    for p, names, again in zip(parents, inner, retries):
+        assert p["tid"] == loop
+        assert not set(names) & set(assembly)
+        for once in ("join.await", "join.capacity", "h2d"):
             assert names.count(once) == 1, (once, names)
-        # each side's passes, inside its own assembly span
-        for soa in ("soa.consolidate", "soa.center", "soa.cells", "soa.pad"):
-            assert names.count(soa) == 2
         assert names.count("dispatch:join_window_pallas") == 1 + again
         assert names.count("d2h") == names.count("d2h.wait") == 2 + again
-        assert names.index("join.assemble_left") \
-            < names.index("join.assemble") < names.index("h2d") \
+        assert names.index("join.await") < names.index("h2d") \
             < names.index("join.capacity")
+    # outside every parent on the loop's thread: nothing but the last ask,
+    # which finds both streams at an end
+    assert [e["name"] for e in events if e["tid"] == loop
+            and e["name"] != "join.window"
+            and not any(inside(e, p) for p in parents)] == ["join.await"]
 
 
 def test_one_sided_windows_emit_no_parent_and_stale_lefts_neither(traced):
     """A right-only window goes to the consumer while the left side's next
-    window is in hand: the two-sided window after it emits no parent, since
-    one from the left's firing would hold that consumer's time."""
+    window is in hand: the one-sided window emits no parent, and the
+    two-sided window after it opens its parent at the loop's ask, after the
+    consumer's nap, so it holds none of the consumer's time."""
     left, right = _streams(seed=16, windows=3)
     del left[0]  # window 0 is the right side's alone
     naps = []
@@ -302,7 +310,7 @@ def test_one_sided_windows_emit_no_parent_and_stale_lefts_neither(traced):
     assert [o[5] > 0 for o in got] == [False, True, True]
     parents, _inner = assert_parents_tile(telemetry.events, "join.window",
                                           naps)
-    assert len(parents) == 1  # window 2's; window 1's left sat through a nap
+    assert len(parents) == 2  # windows 1 and 2; window 0 emits none
 
 
 def test_null_span_when_telemetry_is_off():

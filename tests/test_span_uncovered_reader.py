@@ -198,14 +198,15 @@ def test_tjoin_metric_file_reads_what_the_program_emits(name):
 
 
 def test_benchmark_json_only_grew():
-    """The 18 entries stand where they stood, in the issue's order, PR 39's
-    eight behind them, PR 41's three at the end of ``per_layer``, and the
-    file stays well inside its size limit."""
+    """The 18 entries stand where they stood, in the order they came, the
+    trajectory join's eight behind them, the crowded join's three after
+    those and the join's wait for its producer at the end of ``per_layer``,
+    and the file stays well inside its size limit."""
     names = [m["name"] for m in spec.benchmark()["per_layer"]]
     assert len(names) == len(set(names))
-    assert set(names[-29:-11]) == set(METRICS)
-    assert set(names[-11:-3]) == set(TJOIN_METRICS)
-    assert names[-3:] == ["join_skew_extract_roofline", "join_lanes_per_pair",
-                          "join_pairs_per_window"]
+    assert set(names[-30:-12]) == set(METRICS)
+    assert set(names[-12:-4]) == set(TJOIN_METRICS)
+    assert names[-4:] == ["join_skew_extract_roofline", "join_lanes_per_pair",
+                          "join_pairs_per_window", "join_await_us_per_event"]
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         assert len(f.read()) < 64 * 1024

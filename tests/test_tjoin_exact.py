@@ -370,11 +370,12 @@ def test_object_path_equals_run_soa(rng):
 
 
 def test_tjoin_window_parent_tiles_the_window_and_no_consumer_time(rng):
-    """One ``tjoin.window`` a two-sided window, from the left side's firing
-    chunk to the hand-back: both assemblies, the id check, the ship, the
+    """One ``tjoin.window`` a two-sided window, from the loop's ask for it
+    to the hand-back: the wait for the producer, the id check, the ship, the
     capacity pick, the extraction, the dedup and the fetches lie inside it,
-    each crossing a ``d2h`` leaf, the consumer's time outside; a window at
-    settled sizes crosses twice (four scalars, then the pairs)."""
+    each crossing a ``d2h`` leaf, the consumer's time outside, both sides'
+    assembly on the producer's thread; a window at settled sizes crosses
+    twice (four scalars, then the pairs)."""
     left, right = _side(rng, 2500, 40, t_max=19_000), _side(rng, 2500, 40,
                                                             t_max=19_000)
     op = PointPointTJoinQuery(W10, GRID)
@@ -397,14 +398,15 @@ def test_tjoin_window_parent_tiles_the_window_and_no_consumer_time(rng):
     assert [p["args"]["n"] for p in parents] == \
         [int(((left["ts"] // 10_000) == k).sum()
              + ((right["ts"] // 10_000) == k).sum()) for k in (0, 1)]
-    lefts = [e for e in events if e["name"] == "join.assemble_left"]
-    for p, names, asm in zip(parents, inner, lefts):
-        assert p["ts"] == asm["ts"]  # one clock reading opens both
-        for once in ("join.assemble_left", "join.assemble", "tjoin.ids",
-                     "h2d", "join.capacity",
+    assembly = {e["tid"] for e in events
+                if e["name"] in ("join.assemble_left", "join.assemble")}
+    assert len(assembly) == 1 and parents[0]["tid"] not in assembly
+    for p, names in zip(parents, inner):
+        assert "join.assemble_left" not in names
+        for once in ("join.await", "tjoin.ids", "h2d", "join.capacity",
                      "dispatch:traj_pair_dedup_kernel"):
             assert names.count(once) == 1, (once, names)
-        assert names.index("join.assemble") < names.index("tjoin.ids") \
+        assert names.index("join.await") < names.index("tjoin.ids") \
             < names.index("h2d") < names.index("join.capacity")
         assert names.count("d2h") == names.count("d2h.wait")
     # the first window's dedup waits for the join to hold (three crossings),
