@@ -987,15 +987,16 @@ def bench_tjoin_sliding(jax, jnp, grid, quick):
                              grid.n)
         rcell = assign_cells(rxy, grid.min_x, grid.min_y, grid.cell_length,
                              grid.n)
+        # the pairs come out as ids: the extraction carries the id lanes
         res = _join(
             lxy, ones, lcell, rxy, ones, rcell,
             grid_n=grid.n, layers=grid.candidate_layers(float(radius)),
             radius=radius, cap_left=cap, cap_right=cap, max_pairs=max_pairs,
+            left_payload=lw[:, 2].astype(jnp.int32),
+            right_payload=rw[:, 2].astype(jnp.int32),
         )
         tp = traj_pair_dedup_kernel(
-            res.left_index, res.right_index, res.dist,
-            lw[:, 2].astype(jnp.int32), rw[:, 2].astype(jnp.int32),
-            n_obj,
+            res.left_index, res.right_index, res.dist, n_obj,
         )
         return tp.count, res.count, res.overflow
 

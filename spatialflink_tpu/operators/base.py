@@ -400,13 +400,18 @@ def center_coords(grid: UniformGrid, xy: np.ndarray, dtype) -> np.ndarray:
 
 
 def check_oid_range(oid, num_segments: int) -> None:
-    """Dense-id contract guard for the SoA fast paths: ids >= num_segments
-    would be silently dropped by the segment reductions — fail loudly at
-    the batch boundary instead."""
-    if len(oid) and int(np.max(oid)) >= num_segments:
+    """Dense-id contract guard for the SoA fast paths: ids outside
+    ``[0, num_segments)`` would be silently dropped — by the segment
+    reductions, and a negative one by the trajectory join, whose bucket
+    planes mark an empty slot with -1 — so fail loudly at the batch
+    boundary instead."""
+    if not len(oid):
+        return
+    lo, hi = int(np.min(oid)), int(np.max(oid))
+    if lo < 0 or hi >= num_segments:
         raise ValueError(
-            f"oid {int(np.max(oid))} >= num_segments {num_segments}: "
-            f"out-of-range ids would be silently dropped"
+            f"oid {lo if lo < 0 else hi} outside [0, num_segments = "
+            f"{num_segments}): out-of-range ids would be silently dropped"
         )
 
 

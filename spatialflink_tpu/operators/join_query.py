@@ -392,15 +392,20 @@ class JoinCapacity:
         two of 1.25 × ``count``."""
         self.join_budget = max(self.join_budget, headroom_bucket(count))
 
-    def _window_call(self, fn, left, right, radius, filter_radius=None):
+    def _window_call(self, fn, left, right, radius, filter_radius=None,
+                     payload=None):
         """``call(refine, cap, budget)`` for ``_join_until_held``: one run
         of the window program ``fn`` over the two shipped sides (each
         ``(xy, valid, key cells)`` on the device) with its buckets on the
         grid the contract picked — the key cells themselves at refinement
         1, today's program and arguments to the letter; at a finer one the
         cells ``join_window_cells`` makes of the coordinates (one small
-        program for both sides, once a window and refinement)."""
+        program for both sides, once a window and refinement).
+        ``payload``: ``(left, right)`` device lanes every run's pairs carry
+        in place of the points' indices (``bucketize_planes``); None, the
+        indices."""
         (lxy, lvalid, lcell), (rxy, rvalid, rcell) = left, right
+        lload, rload = (None, None) if payload is None else payload
         layers = self.grid.candidate_layers(radius)
         fr = radius if filter_radius is None else filter_radius
         cells = {1: (lcell, rcell)}
@@ -417,6 +422,7 @@ class JoinCapacity:
                 lxy, lvalid, lc, rxy, rvalid, rc,
                 grid_n=self.grid.n * refine, layers=layers, radius=fr,
                 cap_left=cap, cap_right=cap, max_pairs=budget,
+                left_payload=lload, right_payload=rload,
             )
 
         return call

@@ -42,6 +42,7 @@ from spatialflink_tpu.ops.trajectory import (
     MAX_TRAJ_IDS,
     traj_cell_spans_kernel,
     traj_pair_dedup_kernel,
+    traj_pair_ids,
     traj_range_hits_fused,
     traj_stats_kernel,
     traj_stats_sorted_fused,
@@ -291,14 +292,17 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
         #: budget's headroom policy, persists across windows.
         self.tpair_budget = 0
 
-    def _tpairs_until_held(self, lcell, lvalid, rcell, rvalid, loid, roid,
-                           num_ids: int, call) -> HeldJoin:
+    def _tpairs_until_held(self, lcell, lvalid, rcell, rvalid,
+                           num_ids: int, call, oids=None) -> HeldJoin:
         """The window's point join under the capacity and budget contract
-        (``_join_until_held``) with the dedup program dispatched behind it
-        (``loid`` / ``roid``: the two sides' device id lanes), so that the
-        join's scalars and the trajectory-pair count cross in one fetch.
-        The held join's ``followed`` is the ``TrajPairs`` (on the device),
-        ``followed_scalars`` its count."""
+        (``_join_until_held``) with the dedup program dispatched behind it,
+        so that the join's scalars and the trajectory-pair count cross in
+        one fetch. The dedup takes the pair list as trajectory ids: as the
+        join hands it over where the extraction carried the id lanes as its
+        payload (``run_soa``), else mapped from the points' indices through
+        ``oids`` — the two sides' device id lanes — by ``traj_pair_ids``
+        (``run``). The held join's ``followed`` is the ``TrajPairs`` (on the
+        device), ``followed_scalars`` its count."""
         if num_ids > MAX_TRAJ_IDS:
             raise ValueError(
                 f"{num_ids} trajectory ids a side: the dedup's int32 pair "
@@ -308,8 +312,10 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
         ids = np.int32(num_ids)
 
         def follow(res):
-            tp = dedup(res.left_index, res.right_index, res.dist, loid,
-                       roid, ids)
+            left, right = res.left_index, res.right_index
+            if oids is not None:
+                left, right = jitted(traj_pair_ids)(left, right, *oids)
+            tp = dedup(left, right, res.dist, ids)
             return tp, (tp.count,)
 
         return self._join_until_held(lcell, lvalid, rcell, rvalid, call,
@@ -373,12 +379,13 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
             self._open_join(
                 radius, pallas=mesh is None and pallas_join_supported())
             held = self._tpairs_until_held(
-                lb.cell, lb.valid, rb.cell, rb.valid, loid, roid,
+                lb.cell, lb.valid, rb.cell, rb.valid,
                 max(self.interner.num_segments, 1),
                 lambda _refine, cap, budget: grid_hash_join_batches(
                     self.grid, lb, rb, radius, cap, offsets,
                     max_pairs=budget, dtype=dtype, mesh=mesh,
                 ),
+                oids=(loid, roid),
             )
             lgroups = group_by_oid(left_ev)
             rgroups = group_by_oid(right_ev)
@@ -425,8 +432,11 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
         per-point-pair Python: the grid-hash point join and the sparse
         per-trajectory-pair min-distance dedup (ops/trajectory.py:
         traj_pair_dedup_kernel) both run on device, the second fed the
-        first's pairs where they lie. Windows align on the shared slide
-        grid; one-sided windows yield zero pairs.
+        first's pairs where they lie — as trajectory ids: the extraction's
+        bucket sort carries each point's id where it would carry its index
+        (``_window_call``'s ``payload``), so no pair is mapped to its ids
+        by a gather. Windows align on the shared slide grid; one-sided
+        windows yield zero pairs.
 
         Exact on every yielded window (``overflow == 0``), as
         ``PointPointJoinQuery.run_soa`` and by the same code
@@ -461,7 +471,8 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
         producer), ``tjoin.ids`` (the id-range check of both sides), ``h2d``,
         ``join.capacity``, ``dispatch:*`` (the extraction,
         ``traj_pair_dedup_kernel``, ``head_pairs``) and both ``d2h``; one
-        ``record_tjoin`` a window (``snapshot()["tjoin"]``). A one-sided
+        ``record_tjoin`` a window (``snapshot()["tjoin"]``; its ``id_lanes``
+        counts the windows whose extraction carried the ids). A one-sided
         window emits none."""
         from spatialflink_tpu.operators.base import check_oid_range
         from spatialflink_tpu.operators.join_query import (
@@ -500,11 +511,14 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
              rxy_d, rvalid_d, rcell_d, roid_d) = ship(
                 lxy, lvalid, lcell, loid, rxy, rvalid, rcell, roid
             )
+            # The extraction carries the id lanes where it would carry the
+            # points' indices: its pairs come out as trajectory ids.
             held = self._tpairs_until_held(
-                lcell, lvalid, rcell, rvalid, loid_d, roid_d, num_segments,
+                lcell, lvalid, rcell, rvalid, num_segments,
                 self._window_call(
                     fn, (lxy_d, lvalid_d, lcell_d),
                     (rxy_d, rvalid_d, rcell_d), radius,
+                    payload=(loid_d, roid_d),
                 ),
             )
             (tcount,) = held.followed_scalars
@@ -515,6 +529,7 @@ class TJoinQuery(JoinCapacity, SpatialOperator):
                 budget_retries=held.budget_retries, cap=self.join_cap,
                 budget=self.join_budget, tpair_budget=self.tpair_budget,
                 peel_passes=held.peel_passes, refine=self.join_refine,
+                id_lanes=True,
             )
             self._grow_budget(held.count)  # headroom for the next window
             if asked_ns is not None:

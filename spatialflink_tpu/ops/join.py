@@ -127,7 +127,9 @@ class CompactJoinResult(NamedTuple):
     host boundary (the dense (N, K·cap) mask stays on device).
 
     ``left_index``/``right_index``: (max_pairs,) original-batch indices,
-    -1 padding; ``dist``: (max_pairs,); ``count``: () true number of pairs
+    -1 padding — or, from the bucketed programs given ``left_payload`` /
+    ``right_payload``, the paired points' payload values (the trajectory
+    join's ids); ``dist``: (max_pairs,); ``count``: () true number of pairs
     (> max_pairs means truncation); ``overflow``: () cell-capacity drops;
     ``peel_passes``: () vector passes the Pallas extraction took to lift
     the ``count`` hits out of their blocks (ops/pallas_join.py) — None
@@ -268,13 +270,17 @@ def _cell_windows(lane, start, cap: int):
     return win[:, :cap]
 
 
-def bucketize_planes(xy, valid, cells, grid_n: int, cap: int):
+def bucketize_planes(xy, valid, cells, grid_n: int, cap: int, payload=None):
     """Lay a cell-assigned point batch out as dense (grid_n, grid_n, cap)
-    bucket planes: x, y, original-index (-1 = empty slot), plus the count of
+    bucket planes: x, y, payload (-1 = empty slot), plus the count of
     in-grid points dropped beyond ``cap`` (overflow).
 
-    One stable sort by cell carries x, y and the original index along, so
-    a cell's points are a contiguous run of the sorted lanes in index
+    ``payload``: one int32 a lane, ``>= 0`` on every valid lane — what the
+    join emits for a point it pairs (the trajectory join hands its id lanes
+    in); None is each point's index in the batch (``jnp.arange(n)``).
+
+    One stable sort by cell carries x, y and the payload along, so a
+    cell's points are a contiguous run of the sorted lanes in index
     order — the slot order is deterministic — and a plane's row ``c`` is
     the ``cap``-lane window at the run's start (``_cell_windows``), masked
     past ``min(count[c], cap)``. The runs' starts are looked up a cell,
@@ -291,8 +297,10 @@ def bucketize_planes(xy, valid, cells, grid_n: int, cap: int):
     num_cells = grid_n * grid_n
     n = xy.shape[0]
     cells = jnp.where(valid, cells, num_cells)
-    sorted_cells, sx, sy, sidx = jax.lax.sort(
-        (cells, xy[:, 0], xy[:, 1], jnp.arange(n, dtype=jnp.int32)),
+    sorted_cells, sx, sy, sload = jax.lax.sort(
+        (cells, xy[:, 0], xy[:, 1],
+         jnp.arange(n, dtype=jnp.int32) if payload is None
+         else jnp.asarray(payload, jnp.int32)),
         num_keys=1, is_stable=True,
     )
     starts = _run_starts(sorted_cells, num_cells)
@@ -309,7 +317,7 @@ def bucketize_planes(xy, valid, cells, grid_n: int, cap: int):
             live, _cell_windows(lane, start, cap), empty
         ).reshape(shape)
 
-    return plane(sx, 0), plane(sy, 0), plane(sidx, -1), overflow
+    return plane(sx, 0), plane(sy, 0), plane(sload, -1), overflow
 
 
 def join_window_cells(left_xy, left_cells, right_xy, right_cells, origin,
@@ -366,6 +374,8 @@ def join_window_bucketed(
     cap_right: int,
     max_pairs: int,
     band_rows: int | None = None,
+    left_payload=None,
+    right_payload=None,
 ) -> CompactJoinResult:
     """Dense-bucket grid join — the XLA formulation (off the TPU; on it
     the Pallas extraction of ops/pallas_join.py takes the same planes).
@@ -387,6 +397,8 @@ def join_window_bucketed(
     order it always gave (shift-major).
 
     ``left_cells``/``right_cells``: flat cell ids (num_cells = out-of-grid).
+    ``left_payload``/``right_payload``: what a pair carries of each point
+    (``bucketize_planes``' payload: int32, ``>= 0``); None, its index.
     Overflow counts points beyond a side's bucket capacity (result is exact
     iff overflow == 0, same contract as join_kernel).
     """
@@ -399,10 +411,10 @@ def join_window_bucketed(
     n_bands = -(-grid_n // band_rows)
 
     lx, ly, lidx, l_over = bucketize_planes(
-        left_xy, left_valid, left_cells, grid_n, cap_left
+        left_xy, left_valid, left_cells, grid_n, cap_left, left_payload
     )
     rx, ry, ridx, r_over = bucketize_planes(
-        right_xy, right_valid, right_cells, grid_n, cap_right
+        right_xy, right_valid, right_cells, grid_n, cap_right, right_payload
     )
     # Left rows padded to whole bands, right planes by `layers` all round
     # (and by the same rows): every neighbour access is an in-bounds slice,

@@ -323,12 +323,16 @@ def join_window_pallas(
     cap_right: int,
     max_pairs: int,
     interpret: bool = False,
+    left_payload=None,
+    right_payload=None,
 ) -> CompactJoinResult:
     """Dense-bucket grid join with Pallas hit extraction.
 
     Drop-in for ops.join.join_window_bucketed (same argument and result
-    contract); float32 compute. ``interpret=True`` runs the Pallas
-    interpreter for CPU testing.
+    contract, ``left_payload`` / ``right_payload`` included: a hit emits
+    the two points' third-plane values, their payload or their index);
+    float32 compute. ``interpret=True`` runs the Pallas interpreter for CPU
+    testing.
 
     Both sides' planes come from ``bucketize_planes`` — a sort and a
     window a cell, no per-point index: 1.4 ms a side at 2¹⁹ lanes beside a
@@ -343,10 +347,12 @@ def join_window_pallas(
     max_rows += (-max_rows) % stage_rows
     span = 2 * layers + 1
     lx, ly, lidx, l_over = bucketize_planes(
-        left_xy.astype(f32), left_valid, left_cells, grid_n, cap_left
+        left_xy.astype(f32), left_valid, left_cells, grid_n, cap_left,
+        left_payload,
     )
     rx, ry, ridx, r_over = bucketize_planes(
-        right_xy.astype(f32), right_valid, right_cells, grid_n, cap_right
+        right_xy.astype(f32), right_valid, right_cells, grid_n, cap_right,
+        right_payload,
     )
     # Pad the right planes by `layers` rows/cols so every neighbor access is
     # a static in-bounds slice; padding slots carry idx=-1 (never match).

@@ -255,17 +255,16 @@ MAX_TRAJ_IDS = 46_340
 
 
 def traj_pair_dedup_kernel(
-    left_index: jnp.ndarray,
-    right_index: jnp.ndarray,
+    left_id: jnp.ndarray,
+    right_id: jnp.ndarray,
     dist: jnp.ndarray,
-    left_oid: jnp.ndarray,
-    right_oid: jnp.ndarray,
     num_ids,
 ) -> TrajPairs:
     """Compact join pairs → distinct (trajectory, trajectory) pairs with
     their minimum distance, entirely on device and **sparse**: time
     O(P log P) and memory O(P) in the P lanes of the pair list (the pair
-    budget); no array, table or loop is sized by the number of ids.
+    budget); no array, table or loop is sized by the number of ids, and no
+    lane is gathered.
 
     Replaces the reference's per-record dedup map (latest pair per
     (traj, queryTraj), tJoin/TJoinQuery.java:60-154): the pairs are keyed
@@ -277,22 +276,18 @@ def traj_pair_dedup_kernel(
     2²¹ lanes cost 8 ms, the same compaction through two scatters 20 and
     through ``nonzero`` 90–130; PERF.md §6, PR 39).
 
-    ``left_index``/``right_index``/``dist``: a CompactJoinResult's arrays
-    (-1 padding); ``left_oid``/``right_oid``: (N,)/(M,) int32 object id of
-    each batch lane, in ``[0, num_ids)``; ``num_ids`` (a traced scalar: no
-    program per id count) at most ``MAX_TRAJ_IDS``, so that the key fits
-    int32 — the callers refuse more.
+    ``left_id``/``right_id``/``dist``: the pair list as trajectory ids — a
+    CompactJoinResult whose extraction carried the id lanes as its payload
+    (``ops/join.py:bucketize_planes``), or one mapped from indices by
+    ``traj_pair_ids`` — in ``[0, num_ids)``, -1 padding; ``num_ids`` (a
+    traced scalar: no program per id count) at most ``MAX_TRAJ_IDS``, so
+    that the key fits int32 — the callers refuse more.
     """
-    ok = left_index >= 0
+    ok = left_id >= 0
     pad = jnp.iinfo(jnp.int32).max
     big = jnp.asarray(jnp.finfo(dist.dtype).max, dist.dtype)
     num_ids = jnp.asarray(num_ids, jnp.int32)
-    key = jnp.where(
-        ok,
-        left_oid[jnp.maximum(left_index, 0)] * num_ids
-        + right_oid[jnp.maximum(right_index, 0)],
-        pad,
-    )
+    key = jnp.where(ok, left_id * num_ids + right_id, pad)
     key, dmin = jax.lax.sort((key, jnp.where(ok, dist, big)), num_keys=2)
     first = (key != pad) & jnp.concatenate(
         [jnp.ones((1,), bool), key[1:] != key[:-1]]
@@ -306,6 +301,17 @@ def traj_pair_dedup_kernel(
         jnp.where(found, dmin, big),
         count,
     )
+
+
+def traj_pair_ids(left_index, right_index, left_oid, right_oid):
+    """A pair list of batch indices (-1 padding) → the same list as
+    trajectory ids, for ``traj_pair_dedup_kernel``: ``left_oid`` /
+    ``right_oid`` are the two batches' id lanes. A gather a pair: for a
+    join whose extraction could not carry the ids (``TJoinQuery.run``)."""
+    def ids(index, oid):
+        return jnp.where(index >= 0, oid[jnp.maximum(index, 0)], -1)
+
+    return ids(left_index, left_oid), ids(right_index, right_oid)
 
 
 class TrajAggregate(NamedTuple):

@@ -328,8 +328,8 @@ class Telemetry:
         self._join: Dict[str, int] = {}
         # Window trajectory join (operators/trajectory.py:TJoinQuery.run_soa
         # via record_tjoin): counters windows / pairs (point pairs) /
-        # tpairs / peel_passes / cap_retries / budget_retries and the
-        # gauges cap / budget / tpair_budget —
+        # tpairs / peel_passes / cap_retries / budget_retries / id_lanes
+        # and the gauges cap / budget / tpair_budget —
         # snapshot()["tjoin"], empty until the first joined window.
         self._tjoin: Dict[str, int] = {}
         # Wire-pane kNN (operators/knn_query.py:run_wire_panes via
@@ -1206,7 +1206,7 @@ class Telemetry:
     def record_tjoin(self, pairs: int, tpairs: int, cap_retries: int,
                      budget_retries: int, cap: int, budget: int,
                      tpair_budget: int, peel_passes: int = 0,
-                     refine: int = 1):
+                     refine: int = 1, id_lanes: bool = False):
         """One window of the SoA trajectory join, fetched: the point
         ``pairs`` the extraction found (they stay on the device), the
         distinct trajectory pairs ``tpairs`` the dedup made of them
@@ -1215,9 +1215,11 @@ class Telemetry:
         the dedup's output is as long as the pair list, so it has nothing
         to overflow) and the sizes it ended on (``tpair_budget``: the
         largest trajectory-pair count whose fetch programs are compiled).
+        ``id_lanes``: the extraction carried the two sides' id lanes, so
+        its pairs came out as trajectory ids (the dedup gathered none).
         Lands in ``snapshot()["tjoin"]`` as the counters ``windows``,
         ``pairs``, ``tpairs``, ``peel_passes``, ``cap_retries``,
-        ``budget_retries`` and the gauges ``cap``, ``budget``,
+        ``budget_retries``, ``id_lanes`` and the gauges ``cap``, ``budget``,
         ``tpair_budget``, ``refine`` (the bucket grid's refinement, as the
         point join's). Per window, never per event."""
         if not self.enabled:
@@ -1227,7 +1229,8 @@ class Telemetry:
             for key, n in (("windows", 1), ("pairs", pairs),
                            ("tpairs", tpairs), ("peel_passes", peel_passes),
                            ("cap_retries", cap_retries),
-                           ("budget_retries", budget_retries)):
+                           ("budget_retries", budget_retries),
+                           ("id_lanes", id_lanes)):
                 j[key] = j.get(key, 0) + int(n)
             j["cap"], j["budget"] = int(cap), int(budget)
             j["tpair_budget"], j["refine"] = int(tpair_budget), int(refine)

@@ -1,5 +1,5 @@
-"""ops/join.py:bucketize_planes against a plain loop, and what its lowered
-program may not hold.
+"""ops/join.py:bucketize_planes against a plain loop, what its lowered
+program may not hold, and the payload lane it carries where the index rode.
 
 The reference is the definition: walk the points in index order and give
 each the next free lane of its cell; a point past ``cap`` counts as
@@ -108,6 +108,69 @@ def test_bucketize_planes_equals_the_loop(case, dtype):
         assert want[3] > 0
         kept = np.asarray(got[2]).reshape(-1, cap)[3]
         assert kept.tolist() == np.flatnonzero(cells == 3)[:cap].tolist()
+
+
+@pytest.mark.parametrize("case", ["full_window", "in_grid_overflow"])
+def test_payload_rides_where_the_index_rode(case):
+    """A payload lane (the trajectory join's ids) comes out as the third
+    plane: each slot holds the payload of the point whose index it held
+    without one, -1 where empty; x, y and the overflow do not move."""
+    n, grid_n, cap = 3000, 8, 128
+    rng = np.random.default_rng(44)
+    if case == "full_window":
+        cells, valid = _uniform(rng, n, grid_n * grid_n)
+    else:
+        cells, valid = _overflowing(rng, n, grid_n * grid_n)
+    cells = cells.astype(np.int32)
+    xy = rng.uniform(-1.05, 1.05, (n, 2)).astype(np.float32)
+    payload = rng.integers(0, 16_384, n).astype(np.int32)
+    run = jax.jit(bucketize_planes, static_argnums=(3, 4))
+    plain = [np.asarray(a) for a in run(xy, valid, cells, grid_n, cap)]
+    got = [np.asarray(a) for a in run(xy, valid, cells, grid_n, cap, payload)]
+    index = plain[2]
+    want = np.where(index >= 0, payload[np.maximum(index, 0)], -1)
+    assert got[2].dtype == np.int32 and np.array_equal(got[2], want)
+    for name, g, w in zip(("x", "y"), got[:2], plain[:2]):
+        assert g.tobytes() == w.tobytes(), name
+    assert int(got[3]) == int(plain[3])
+    assert (int(plain[3]) > 0) == (case == "in_grid_overflow")
+    assert (index >= 0).sum() > n // 4  # the planes hold points
+
+
+def _window_args(n):
+    lanes = (jax.ShapeDtypeStruct((n, 2), np.float32),
+             jax.ShapeDtypeStruct((n,), np.bool_),
+             jax.ShapeDtypeStruct((n,), np.int32))
+    return lanes + lanes, dict(grid_n=6, layers=1, radius=np.float32(0.1),
+                               cap_left=16, cap_right=16, max_pairs=4096)
+
+
+@pytest.mark.parametrize("program", ["pallas", "xla"])
+def test_point_join_program_takes_no_payload_operand(program):
+    """With no payload the window program's operands are the parent's: the
+    six lanes and the radius — no seventh or eighth array; with one, the
+    two payload lanes are the only ones added."""
+    from spatialflink_tpu.ops.join import join_window_bucketed
+    from spatialflink_tpu.ops.pallas_join import join_window_pallas
+
+    if program == "pallas":
+        fn, kw_program = join_window_pallas, {"interpret": True}
+    else:
+        fn, kw_program = jax.jit(join_window_bucketed, static_argnames=(
+            "grid_n", "layers", "cap_left", "cap_right", "max_pairs")), {}
+    n = 1024
+    args, kw = _window_args(n)
+    payload = jax.ShapeDtypeStruct((n,), np.int32)
+
+    def operands(**extra):
+        return [a.shape for a in jax.tree_util.tree_leaves(
+            fn.lower(*args, **kw, **kw_program, **extra).args_info)]
+
+    plain = operands()
+    assert sorted(plain) == sorted([(n, 2), (n,), (n,)] * 2 + [()])
+    assert operands(left_payload=None, right_payload=None) == plain
+    loaded = operands(left_payload=payload, right_payload=payload)
+    assert sorted(loaded) == sorted(plain + [(n,), (n,)])
 
 
 def _gather_index_rows(text):

@@ -20,6 +20,8 @@ from spatialflink_tpu.operators import (
     QueryConfiguration,
     QueryType,
 )
+from spatialflink_tpu.operators import join_query
+from spatialflink_tpu.operators.base import check_oid_range
 from spatialflink_tpu.operators.join_query import JoinCapacity, headroom_bucket
 from spatialflink_tpu.operators.trajectory import (
     PointPointTJoinQuery,
@@ -28,6 +30,7 @@ from spatialflink_tpu.operators.trajectory import (
 from spatialflink_tpu.ops.trajectory import (
     MAX_TRAJ_IDS,
     traj_pair_dedup_kernel,
+    traj_pair_ids,
 )
 from spatialflink_tpu.telemetry import telemetry
 
@@ -111,11 +114,17 @@ def test_reference_names_what_is_wrong():
 
 # -- the kernel ---------------------------------------------------------------
 
+def _host_ids(index, oid):
+    """A pair list's indices (-1 padding) as ids, on the host."""
+    index = np.asarray(index)
+    return np.where(index >= 0, np.asarray(oid)[np.maximum(index, 0)], -1)
+
+
 def _dedup(li, ri, dd, loid, roid, ids):
     tp = jax.jit(traj_pair_dedup_kernel)(
-        jnp.asarray(li, jnp.int32), jnp.asarray(ri, jnp.int32),
-        jnp.asarray(dd), jnp.asarray(loid, jnp.int32),
-        jnp.asarray(roid, jnp.int32), np.int32(ids))
+        jnp.asarray(_host_ids(li, loid), jnp.int32),
+        jnp.asarray(_host_ids(ri, roid), jnp.int32),
+        jnp.asarray(dd), np.int32(ids))
     return [np.asarray(a) for a in tp]
 
 
@@ -171,29 +180,50 @@ def test_dedup_kernel_on_an_empty_pair_list():
     assert int(count) == 0 and (lo == -1).all() and (ro == -1).all()
 
 
+def _dedup_jaxpr(lanes):
+    args = (jnp.zeros(lanes, jnp.int32), jnp.zeros(lanes, jnp.int32),
+            jnp.zeros(lanes), np.int32(MAX_TRAJ_IDS))
+    return jax.make_jaxpr(traj_pair_dedup_kernel)(*args)
+
+
+def _eqns(jp):
+    for eqn in jp.eqns:
+        yield eqn
+        for sub in eqn.params.values():
+            if hasattr(sub, "jaxpr"):
+                yield from _eqns(sub.jaxpr)
+
+
 @pytest.mark.parametrize("lanes", [1024, 4096])
 def test_dedup_holds_no_array_sized_by_the_ids(lanes):
     """Every value of the traced program is O(lanes): one jaxpr whatever
     the ids (they are a traced scalar), none of its arrays larger than the
-    pair list or the id lanes it gathers from."""
-    args = (jnp.zeros(lanes, jnp.int32), jnp.zeros(lanes, jnp.int32),
-            jnp.zeros(lanes), jnp.zeros(512, jnp.int32),
-            jnp.zeros(512, jnp.int32), np.int32(MAX_TRAJ_IDS))
-    jaxpr = jax.make_jaxpr(traj_pair_dedup_kernel)(*args)
-
-    def sizes(jp):
-        for eqn in jp.eqns:
-            for v in eqn.outvars:
-                yield int(np.prod(v.aval.shape))
-            for sub in eqn.params.values():
-                if hasattr(sub, "jaxpr"):
-                    yield from sizes(sub.jaxpr)
-
-    assert max(sizes(jaxpr.jaxpr)) <= 2 * lanes
+    pair list."""
+    jaxpr = _dedup_jaxpr(lanes)
+    sizes = [int(np.prod(v.aval.shape))
+             for eqn in _eqns(jaxpr.jaxpr) for v in eqn.outvars]
+    assert max(sizes) <= 2 * lanes
     import inspect
 
     params = inspect.signature(traj_pair_dedup_kernel).parameters
     assert "num_left" not in params and "max_tpairs" not in params
+
+
+def test_dedup_gathers_nothing():
+    """The pair list arrives as ids: the program is two sorts and
+    elementwise work, with no lane gathered (a gather a lane runs element
+    by element on a v5e, 8.2 ns a row: 33 ms of a 2²¹-lane pair list)."""
+    prims = {eqn.primitive.name for eqn in _eqns(_dedup_jaxpr(2048).jaxpr)}
+    assert "sort" in prims  # the jaxpr is the program's, not a stub's
+    assert not {"gather", "scatter", "scatter-add", "dynamic_slice"} & prims
+
+
+def test_pair_ids_maps_indices_and_keeps_the_padding():
+    li, ri = np.array([2, -1, 0, 1]), np.array([0, -1, 2, 2])
+    loid, roid = np.array([7, 3, 5]), np.array([1, 4, 6])
+    lid, rid = jax.jit(traj_pair_ids)(li, ri, loid, roid)
+    assert np.array_equal(lid, [5, -1, 7, 3])
+    assert np.array_equal(rid, [1, -1, 6, 6])
 
 
 # -- the operator -------------------------------------------------------------
@@ -302,13 +332,152 @@ def test_run_soa_with_ids_whose_square_no_table_could_hold(rng):
     assert count > 2000 and max(lo.max(), ro.max()) > 16_000
 
 
-def test_run_soa_refuses_an_id_outside_its_range(rng):
+@pytest.mark.parametrize("bad", [10, -1], ids=["past_the_range", "negative"])
+def test_run_soa_refuses_an_id_outside_its_range(rng, bad):
+    """-1 marks an empty slot of the bucket planes the ids ride through: a
+    negative id would join nothing, silently."""
     left, right = _side(rng, 50, 10, t_max=9_000), _side(rng, 50, 10,
                                                          t_max=9_000)
-    left["oid"][3] = 10
+    left["oid"][3] = bad
     with pytest.raises(ValueError, match="num_segments"):
         list(PointPointTJoinQuery(W10, GRID).run_soa(
             _chunks(left), _chunks(right), 0.3, num_segments=10))
+
+
+def test_check_oid_range_refuses_minus_one():
+    check_oid_range(np.array([0, 9, 3]), 10)
+    check_oid_range(np.array([], np.int32), 10)
+    with pytest.raises(ValueError, match=r"oid -1 outside \[0, num_segments"):
+        check_oid_range(np.array([4, -1, 2], np.int32), 10)
+    with pytest.raises(ValueError, match="oid 10 outside"):
+        check_oid_range(np.array([4, 10], np.int32), 10)
+
+
+# -- the ids ride the extraction ----------------------------------------------
+
+SKEW_BBOX = (115.5, 39.6, 117.6, 41.1)
+SKEW_GRID = UniformGrid(30, 115.5, 117.6, 39.6, 41.1)  # key cells of 0.07
+
+
+def _uniform_window(rng):
+    left, right = _side(rng, 1500, 40, t_max=9_000), _side(rng, 1400, 30,
+                                                           t_max=9_000)
+    return PointPointTJoinQuery(W10, GRID, cap=16), left, right, 0.3, 40
+
+
+def _crowded_window(rng, monkeypatch):
+    """``tests/test_join_skew.py``'s crowded shapes: Spider-Gaussian points
+    whose fullest key cell is past four times a refinement rung of 16, so
+    the buckets go on a 4 x finer grid."""
+    from benchmark.references import spider_gaussian
+
+    monkeypatch.setattr(join_query, "REFINE_RUNG", 16)
+
+    def side(n):
+        x, y = spider_gaussian.positions(
+            rng.uniform(SKEW_BBOX[0], SKEW_BBOX[2], n),
+            rng.uniform(SKEW_BBOX[1], SKEW_BBOX[3], n), SKEW_BBOX)
+        return {"ts": np.sort(rng.integers(0, 5_000, n)).astype(np.int64),
+                "x": x, "y": y, "oid": rng.integers(0, 64, n).astype(np.int64)}
+
+    conf = QueryConfiguration(QueryType.WindowBased, window_size=5,
+                              slide_step=5)
+    return (PointPointTJoinQuery(conf, SKEW_GRID, cap=16), side(3_000),
+            side(3_000), 0.006, 64)
+
+
+@pytest.mark.parametrize("window", ["uniform", "crowded_refines"])
+@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+def test_run_soa_pairs_equal_the_index_then_gather_path(rng, monkeypatch,
+                                                        backend, window):
+    """``run_soa`` hands its id lanes to the extraction as the payload, so
+    its pairs come out as ids and the dedup gathers none. Oracle — the path
+    before that, written here: every run of the extraction is repeated with
+    no payload (the points' indices), the indices are mapped to ids on the
+    host and deduplicated; the trajectory pairs have to come out array for
+    array the same, and the raw pair lists likewise, pair order included."""
+    if window == "uniform":
+        op, left, right, radius, ids = _uniform_window(rng)
+    else:
+        op, left, right, radius, ids = _crowded_window(rng, monkeypatch)
+    fn, name = join_query.window_join_program(backend)
+    runs = []
+
+    def both(*args, left_payload=None, right_payload=None, **kw):
+        got = fn(*args, left_payload=left_payload,
+                 right_payload=right_payload, **kw)
+        runs.append((got, fn(*args, **kw), left_payload, right_payload))
+        return got
+
+    monkeypatch.setattr(join_query, "window_join_program",
+                        lambda backend=None: (both, name))
+    telemetry.enable()
+    try:
+        before = dict(telemetry.snapshot().get("tjoin", {}))
+        got = []
+        for out in op.run_soa(_chunks(left), _chunks(right), radius,
+                              num_segments=ids, dtype=np.float32):
+            got.append((out, runs[-1]))
+            runs.clear()
+        after = telemetry.snapshot()["tjoin"]
+    finally:
+        telemetry.disable()
+    assert len(got) == 1 and op.last_join_backend == name
+    assert op.join_refine == (4 if window == "crowded_refines" else 1)
+    ((_s, _e, lo, ro, dd, count, overflow),
+     (res, plain, lpay, rpay)) = got[0]
+    assert lpay is not None and rpay is not None
+    lid = _host_ids(plain.left_index, lpay)
+    rid = _host_ids(plain.right_index, rpay)
+    assert np.array_equal(res.left_index, lid)
+    assert np.array_equal(res.right_index, rid)
+    assert np.array_equal(res.dist, plain.dist)
+    want = [np.asarray(a) for a in jax.jit(traj_pair_dedup_kernel)(
+        jnp.asarray(lid, jnp.int32), jnp.asarray(rid, jnp.int32),
+        plain.dist, np.int32(ids))]
+    assert overflow == 0 and count == int(want[3]) > 100
+    for a, b in zip((lo, ro, dd), want[:3]):
+        assert np.array_equal(a, b[:count])
+    assert after["id_lanes"] - before.get("id_lanes", 0) == 1
+    assert after["windows"] - before.get("windows", 0) == 1
+
+
+def test_object_path_keeps_indices_and_equals_the_reference(rng):
+    """``run`` joins batches through ``grid_hash_join_batches``, which
+    carries indices: their ids are gathered before the dedup, and each
+    window's pairs are the plain reference's; no window counts as one whose
+    extraction carried the ids."""
+    left, right = _side(rng, 1200, 9, t_max=20_000), _side(rng, 1100, 7,
+                                                           t_max=20_000)
+
+    def points(side, tag):
+        return [Point(obj_id=f"{tag}{int(o)}", timestamp=int(t), x=float(x),
+                      y=float(y))
+                for t, x, y, o in zip(side["ts"], side["x"], side["y"],
+                                      side["oid"])]
+
+    ref = tjoin_tdrive.Reference(bbox=BBOX, grid_cells=GRID.n, radius=0.3,
+                                 tol=TOL, num_ids=16)
+    telemetry.enable()
+    try:
+        before = telemetry.snapshot().get("tjoin", {}).get("id_lanes", 0)
+        seen = 0
+        for res in TJoinQuery(W10, GRID).run(
+                iter(points(left, "a")), iter(points(right, "b")), 0.3):
+            a = _in_window(left, res.start, res.end)
+            b = _in_window(right, res.start, res.end)
+            want = ref.tpairs(a["x"], a["y"], a["oid"], b["x"], b["y"],
+                              b["oid"])
+            got = sorted((int(p.obj_id[1:]), int(q.obj_id[1:]), d)
+                         for p, q, d in res.pairs)
+            lo, ro, dd = (np.array(c) for c in zip(*got)) if got else \
+                (np.empty(0, int), np.empty(0, int), np.empty(0))
+            assert ref.compare(want, lo, ro, dd, len(got), 0) == []
+            seen += len(got)
+        after = telemetry.snapshot().get("tjoin", {}).get("id_lanes", 0)
+    finally:
+        telemetry.disable()
+    assert seen > 50 and after == before
 
 
 def _trips(shift, taxis=12, fixes=60):
