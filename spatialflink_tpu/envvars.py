@@ -14,8 +14,8 @@ Hazard classes:
 
 - ``armed`` — arms faults/policies or forces failures; ambient values
   SABOTAGE any run that did not set them (the chaos/overload plans, the
-  bench failure-forcing test knobs). The CI gate scrubs these from
-  every stage.
+  standing-query serving config). The CI gate scrubs these from every
+  stage.
 - ``capture`` — selects artifact outputs (ledgers, streams, traces);
   ambient values redirect captures but never change verdicts, and gate
   stages that capture set their own.
@@ -50,14 +50,6 @@ ENV_VARS = {
                "would register ghost queries / arm QoS budgets in runs "
                "that never asked for them",
     },
-    "SFT_ABLATE": {
-        "owner": "spatialflink_tpu/ablation.py", "hazard": "armed",
-        "doc": "kernel-ablation spec (comma list, inline JSON, or "
-               "path), armed at import; substituted kernels return "
-               "cached zeros, so an ambient value silently falsifies "
-               "every measurement (the run is tainted, but the gate "
-               "must never run tainted in the first place)",
-    },
     "SFT_LEDGER_PATH": {
         "owner": "spatialflink_tpu/dag.py", "hazard": "capture",
         "doc": "run-ledger output path",
@@ -75,10 +67,6 @@ ENV_VARS = {
         "doc": "flight-recorder ring size (last-N window summaries + "
                "instants dumped to <stream>.blackbox.json on fault "
                "fire / stream seal; '0' disables, default 64)",
-    },
-    "SFT_LEDGER_DIR": {
-        "owner": "bench_suite.py", "hazard": "capture",
-        "doc": "per-config ledger directory for suite runs",
     },
     "SFT_DIAL_DEADLINE_S": {
         "owner": "spatialflink_tpu/driver.py", "hazard": "tuning",

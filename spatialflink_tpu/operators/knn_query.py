@@ -716,8 +716,7 @@ class PointPointKNNQuery(_PointStreamKNNQuery):
         flush_at_end: bool = True,
     ):
         """Wire-plane pane-carry kNN — the HEADLINE program as a shipped
-        operator path (ops/wire_knn.py; bench_suite's kNN configs run
-        this same step, so the measured program is the shipped one).
+        operator path (ops/wire_knn.py).
 
         ``slides``: iterable of (3, n_i) uint16 PLANE-MAJOR pane arrays
         in the 6 B/pt wire format (streams/wire.py) — rows x_q, y_q,

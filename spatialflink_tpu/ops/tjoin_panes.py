@@ -509,11 +509,11 @@ def tjoin_pane_step(
 def expired_pane_fields(cells_arr, valid_arr, ppw: int):
     """(cell, valid) of the pane EXPIRING at each slide of a batch whose
     carry started EMPTY: pane s - ppw, i.e. the same arrays shifted by
-    ``ppw`` slides with nothing expiring during warmup. Callers that
-    chain scans from a non-empty carry (bench_suite's warm + steady
-    split) must instead slice the expiring panes from the earlier batch
-    and pass them explicitly — this zero-fill is only correct when the
-    scan's own slides are the whole ring history."""
+    ``ppw`` slides with nothing expiring during warmup. A caller that
+    chains scans from a non-empty carry must instead slice the expiring
+    panes from the earlier batch and pass them explicitly — this
+    zero-fill is only correct when the scan's own slides are the whole
+    ring history."""
     S = cells_arr.shape[0]
     pad = min(ppw, S)
     zc = jnp.zeros((pad,) + cells_arr.shape[1:], cells_arr.dtype)

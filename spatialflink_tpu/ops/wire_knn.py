@@ -1,10 +1,8 @@
-"""Wire-plane kNN pane digest — ONE program for operator and suite.
+"""Wire-plane kNN pane digest — ONE program for the operator.
 
 The headline deployment ingests the 6 B/pt wire (streams/wire.py) fused
 straight into the kNN pane digest. This module is the single home of
-the wire→digest step; bench_suite's kNN configs and
-PointPointKNNQuery.run_wire_panes both call it, so the measured program
-IS the shipped program.
+the wire→digest step that PointPointKNNQuery.run_wire_panes calls.
 
 Two interchangeable strategies (bit-compatible candidate SETS, distance
 values within 1 ulp — Mosaic vs XLA FMA freedom; tests/test_wire_knn.py

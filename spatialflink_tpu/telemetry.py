@@ -52,7 +52,6 @@ import warnings
 from collections import deque
 from typing import Any, Dict, Optional, Tuple
 
-from spatialflink_tpu.ablation import ablation
 from spatialflink_tpu.faults import faults
 from spatialflink_tpu.mn.metrics import FixedBucketLatency, json_safe
 
@@ -496,15 +495,6 @@ class Telemetry:
         if faults.armed:
             self.emit_instant(
                 "fault_armed", plan=[r.to_dict() for r in faults.rules]
-            )
-        # Fresh capture, fresh ablation taint scope: counters reset so
-        # the taint block reflects THIS capture's substitutions; the
-        # armed marker is re-emitted for the same arm-before-enable
-        # reason as fault_armed above (SFT_ABLATE arms at import).
-        ablation.reset_counters()
-        if ablation.armed:
-            self.emit_instant(
-                "ablation_armed", kernels=sorted(ablation.kernels)
             )
 
     def disable(self):
@@ -1109,12 +1099,6 @@ class Telemetry:
         }
         if slo_block is not None:
             doc["slo"] = slo_block
-        taint = ablation.taint_block()
-        if taint is not None:
-            # Top-level mirror of the snapshot taint: gates must reject
-            # without digging into the snapshot, and a hand-edited
-            # snapshot must not untaint the document.
-            doc["tainted"] = taint
         doc, nonfinite = _sanitize_nonfinite(doc)
         if nonfinite:
             doc["nonfinite_values"] = nonfinite
@@ -1845,12 +1829,6 @@ class Telemetry:
         shard_wm = self.shard_watermark_gauges()
         if shard_wm is not None:
             out["shard_watermarks"] = shard_wm
-        # Ablation taint rides EVERY snapshot — including the ledger-
-        # stream checkpoints, so a recovered stream stays tainted and
-        # sfprof's gates keep rejecting it after a crash.
-        taint = ablation.taint_block()
-        if taint is not None:
-            out["tainted"] = taint
         return json_safe(out)
 
 
@@ -2025,8 +2003,7 @@ def instrument_jit(fn, name: Optional[str] = None):
     """Wrap a compiled callable with recompile-signature tracking and the
     per-(kernel, signature) runtime table.
 
-    ``operators/base.py:jitted`` routes every operator kernel through this;
-    bench_suite.py wraps its hand-jitted steps the same way.
+    ``operators/base.py:jitted`` routes every operator kernel through this.
     Disabled-path cost: one attribute check per call (calls here are per WINDOW, never per
     record). Enabled, each call is one ``dispatch:<kernel>`` span (the
     ``compile:<kernel>`` instant's naming) whose duration also feeds the
@@ -2038,7 +2015,7 @@ def instrument_jit(fn, name: Optional[str] = None):
 
     This is also the ``device.dispatch`` chaos injection point
     (faults.py): it lives HERE — not in ``jitted`` — so the mesh window
-    programs and bench steps that skip ``jitted`` are injectable too.
+    programs that skip ``jitted`` are injectable too.
     """
     label = name or getattr(fn, "__name__", repr(fn))
 
@@ -2048,12 +2025,6 @@ def instrument_jit(fn, name: Optional[str] = None):
         def __call__(self, *args, **kwargs):
             if faults.armed:  # chaos injection point (faults.py)
                 faults.hit("device.dispatch")
-            if ablation.armed and ablation.matches(label):
-                # Profiling-only substitution (ablation.py): cached
-                # correct-aval zeros after one real learning call.
-                # Deliberately OUTSIDE the runtime table — the numbers
-                # are wrong by construction and the capture is tainted.
-                return ablation.dispatch(label, fn, args, kwargs)
             if not telemetry.enabled:
                 return fn(*args, **kwargs)
             sig = abstract_signature(args, kwargs)

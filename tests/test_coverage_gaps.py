@@ -74,7 +74,7 @@ def test_polygon_stream_knn(rng):
 def test_polygon_stream_knn_containment_is_zero(rng):
     """A polygon containing the query point ranks first with distance 0
     (JTS point.distance(polygon) == 0 inside — DistanceFunctions.java:15-54
-    via getDistance; ADVICE round-1 medium finding)."""
+    via getDistance)."""
     polys = _squares(rng, 10, size=0.3)
     polys.append(Polygon(
         obj_id="around", timestamp=0,

@@ -696,7 +696,7 @@ def test_multi_partition_nonmonotone_ts_no_duplicates(monkeypatch):
 def test_compacted_topic_offset_gap_no_stall_no_dupes(monkeypatch):
     """Log holes (compacted-away offsets) in a multi-partition topic
     must neither stall the position nor re-deliver the post-hole
-    records every round (ADVICE r5): a fetched batch starting past the
+    records every round: a fetched batch starting past the
     requested position snaps it to the batch's base offset, and within
     the batch the position follows the offsets the broker actually
     delivered — the out-of-sequence parking applies only to the

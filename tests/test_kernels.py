@@ -219,8 +219,8 @@ def test_join_overflow_counted(rng):
 
 def test_join_overflow_ignores_padding_lanes(rng):
     """Padding (invalid) left lanes map to cell (0,0) — a real grid cell —
-    and must not claim overflow (ADVICE round-1 finding: the overflow==0
-    exactness contract has to be tight)."""
+    and must not claim overflow (the overflow==0 exactness contract has
+    to be tight)."""
     grid = UniformGrid(20, **GRID)
     r = 0.5
     # Crowd the grid-origin cell on the right side beyond cap.

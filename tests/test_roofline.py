@@ -251,7 +251,8 @@ def test_health_json_carries_roofline(tmp_path, capsys):
     out = json.loads(capsys.readouterr().out)
     assert out["failed"] == 0
     assert out["roofline"]["verdict"] == "link-bound"
-    assert out["tainted"] is None
+    assert set(out) == {
+        "ledger", "schema_problems", "checks", "failed", "roofline", "notes"}
     names = [c["name"] for c in out["checks"]]
     assert "recompile_churn_max_signatures" in names
     # Exit contract unchanged: the human and json paths agree.

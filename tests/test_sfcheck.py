@@ -98,7 +98,7 @@ def test_cli_json_breakdown_over_subtree():
     # absent (they would see an incomplete world — no tests/, missing
     # callers — and manufacture findings). The full ten-pass verdict is
     # the default no-args run (test_repo_tree_is_clean_whole_program).
-    res = _cli("--json", "spatialflink_tpu", "bench_suite.py", "tools")
+    res = _cli("--json", "spatialflink_tpu", "__graft_entry__.py", "tools")
     assert res.returncode == 0, res.stdout + res.stderr
     data = json.loads(res.stdout)
     assert data["findings"] == []
@@ -993,14 +993,14 @@ def test_cli_summary_line_prints_per_pass_counts():
 def test_no_block_until_ready_outside_telemetry():
     # __graft_entry__.py and tests/test_graft_entry.py used the no-op
     # block_until_ready as a "sync"; they now device_get. The ban covers
-    # the driver surface, bench, the whole test tree, and the PR 7
-    # additions: the SLO engine and the sfprof stream/recover modules
+    # the driver surface, the whole test tree, the SLO engine and the
+    # sfprof stream/recover modules
     # (the link probe's true-sync fetch lives in telemetry.py, the ONE
     # exempt module).
     sync = get_pass("sync-discipline")
     report = core.run_paths(
         [os.path.join(REPO, p) for p in
-         ("__graft_entry__.py", "bench_suite.py", "tests",
+         ("__graft_entry__.py", "tests",
           os.path.join("spatialflink_tpu", "slo.py"),
           os.path.join("tools", "sfprof"))],
         [sync], force_files=True,

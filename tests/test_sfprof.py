@@ -377,7 +377,9 @@ def test_report_and_health_json_on_real_ledger(tmp_path, capsys):
     assert any(r["kernel"] == "double" for r in rep["kernels"])
     assert sfprof_main(["health", path, "--json"]) == 0
     hea = json.loads(capsys.readouterr().out)
-    assert hea["failed"] == 0 and hea["tainted"] is None
+    assert hea["failed"] == 0
+    assert set(hea) == {
+        "ledger", "schema_problems", "checks", "failed", "roofline", "notes"}
     assert hea["roofline"]["verdict"] == rep["roofline"]["verdict"]
     assert {c["name"] for c in hea["checks"]} >= {
         "recompile_churn_max_signatures", "late_dropped",
@@ -470,28 +472,6 @@ def test_diff_link_annotation_never_gates(tmp_path, capsys):
     assert "DEGRADED" in out and "link" in out
     assert sfprof_main(["diff", fast, fast, "--gate"]) == 0
     assert "comparable links" in capsys.readouterr().out
-
-
-def test_diff_guards_cpu_baseline_medians(tmp_path):
-    """A candidate EPS below the CPU_BASELINE median band is a NEW
-    regression when the reference ledger was inside the band — but a
-    self-diff of an already-slow ledger stays informational (the gate
-    is monotone; acceptance: self-diff exits 0)."""
-    baseline = {"configs": {"cfg_x": 1_000_000.0},
-                "configs_resident": {}}
-    bl_path = tmp_path / "CPU_BASELINE.json"
-    bl_path.write_text(json.dumps(baseline))
-
-    def ledger_with_eps(name, eps):
-        bench = {"config": "cfg_x", "points_per_sec": eps, "value": eps}
-        return _make_ledger(tmp_path, name=name, bench=bench)
-
-    good = ledger_with_eps("good.json", 950_000.0)   # inside band
-    slow = ledger_with_eps("slow.json", 200_000.0)   # below median/2
-    args = ["--gate", "--baseline", str(bl_path), "--eps-tol", "0.5"]
-    assert sfprof_main(["diff", good, slow] + args) == 1
-    assert sfprof_main(["diff", slow, slow] + args) == 0  # pre-existing
-    assert sfprof_main(["diff", good, good] + args) == 0
 
 
 # -- CLI: health --------------------------------------------------------------

@@ -5,6 +5,7 @@ gauges, metric-registry export, and the disabled-by-default contract.
 
 import json
 import warnings
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -620,6 +621,64 @@ def test_instrument_jit_emits_one_dispatch_span_per_call():
         sum(e["dur"] for e in spans)
     # Same naming as the compile instant.
     assert len(_named("compile:leaf_kernel")) == 2
+
+
+class _Pair(NamedTuple):
+    lo: object
+    hi: object
+
+
+#: One body a kind of output pytree the served programs return.
+_OUTPUT_KINDS = {
+    "array": lambda x: x * 2,
+    "tuple": lambda x: (x * 2, x + 1),
+    "namedtuple": lambda x: _Pair(x - 1, x + 1),
+    "dict": lambda x: {"sum": x.sum(), "twice": x * 2},
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_OUTPUT_KINDS))
+@pytest.mark.parametrize("enabled", [True, False],
+                         ids=["enabled", "disabled"])
+def test_instrument_jit_passes_outputs_through(enabled, kind):
+    """The wrapper hands back the callable's own outputs — same pytree
+    type, leaf for leaf — on fresh buffers that alias neither the input
+    nor an earlier call's outputs. Enabled, each call is one
+    ``dispatch:<name>`` span and a kernel-table row appears only for a
+    new shape; disabled, nothing is recorded."""
+    jf = jax.jit(_OUTPUT_KINDS[kind])
+    if enabled:
+        telemetry.enable()
+    name = f"pass_{kind}"
+    f = instrument_jit(jf, name=name)
+    x4 = jnp.arange(4, dtype=jnp.float32)
+    x8 = jnp.arange(8, dtype=jnp.float32)
+    held = []  # earlier outputs stay alive so their buffers cannot recycle
+    for x in (x4, x4, x8):
+        got, want = f(x), jf(x)
+        assert type(got) is type(want)
+        assert (jax.tree_util.tree_structure(got)
+                == jax.tree_util.tree_structure(want))
+        leaves = jax.tree_util.tree_leaves(got)
+        for g, w in zip(leaves, jax.tree_util.tree_leaves(want)):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        ptrs = [leaf.unsafe_buffer_pointer() for leaf in leaves]
+        assert len(set(ptrs)) == len(ptrs)
+        assert x.unsafe_buffer_pointer() not in ptrs
+        assert not set(ptrs) & {leaf.unsafe_buffer_pointer()
+                                for leaf in held}
+        held.extend(leaves)
+    spans = _named(f"dispatch:{name}")
+    rows = [r for r in telemetry.kernel_table() if r["kernel"] == name]
+    if enabled:
+        assert [e["args"]["new_signature"] for e in spans] == \
+            [True, False, True]
+        assert len(rows) == 2
+        assert sorted(r["calls"] for r in rows) == [1, 2]
+    else:
+        assert spans == [] and rows == []
+        assert telemetry.events == [] and telemetry.compile_count == 0
 
 
 def test_leaves_carry_the_node_tag():

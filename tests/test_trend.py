@@ -1,8 +1,7 @@
 """Trend store (tools/sfprof/trend.py + the ``trend`` CLI): history
 ingestion across every record shape (ledgers, streams, legacy BENCH_r*
 supervisor records, bare bench records), the
-skip-with-counted-evidence contract, MAD-band gating, and taint
-rejection."""
+skip-with-counted-evidence contract, and MAD-band gating."""
 
 import json
 import os
@@ -17,7 +16,7 @@ from tools.sfprof.cli import main as sfprof_main
 
 
 def _bench(value, config="cfg_a", smoke=True, device="TFRT_CPU_0",
-           resident=None, pipeline=False, tainted=None):
+           resident=None, pipeline=False):
     out = {
         "metric": config, "value": float(value), "unit": "points/s",
         "device": device, "smoke": smoke,
@@ -25,8 +24,6 @@ def _bench(value, config="cfg_a", smoke=True, device="TFRT_CPU_0",
     }
     if resident is not None:
         out["device_resident_points_per_sec"] = float(resident)
-    if tainted is not None:
-        out["tainted"] = tainted
     return out
 
 
@@ -49,7 +46,7 @@ def _history_dir(tmp_path, values=(90e3, 100e3, 110e3, 120e3), **kw):
     return str(d)
 
 
-def _ledger(value, tainted=None, created=1000.0, **kw):
+def _ledger(value, created=1000.0, **kw):
     doc = {
         "ledger_version": 1, "created_unix": created,
         "env": {"backend": "cpu", "devices": ["TFRT_CPU_0"]},
@@ -59,13 +56,7 @@ def _ledger(value, tainted=None, created=1000.0, **kw):
         "kernels": [], "events": [],
         "bench": _bench(value, **kw),
     }
-    if tainted is not None:
-        doc["tainted"] = tainted
     return doc
-
-
-TAINT = {"kind": "ablation", "kernels": ["k"],
-         "substituted_calls": {"k": 3}, "learning_calls": {"k": 1}}
 
 
 # -- ingestion across record shapes -------------------------------------------
@@ -130,22 +121,6 @@ def test_legacy_failures_skip_with_counted_evidence(tmp_path):
     assert len(skipped) == 4
 
 
-def test_tainted_history_is_skipped_with_reason(tmp_path):
-    d = tmp_path / "hist"
-    d.mkdir()
-    _write(d, "clean.json", _ledger(100e3))
-    _write(d, "stubbed.json", _ledger(900e3, tainted=TAINT))
-    # Taint riding only the snapshot (the stream-recovery shape) must
-    # also be caught.
-    snap_tainted = _ledger(901e3)
-    snap_tainted["snapshot"]["tainted"] = TAINT
-    _write(d, "stubbed2.json", snap_tainted)
-    points, skipped = trend.ingest_paths([str(d)])
-    assert [p["value"] for p in points] == [100e3]
-    assert all("tainted: ablation" in s["reason"] for s in skipped)
-    assert len(skipped) == 2
-
-
 def test_series_keys_separate_device_smoke_and_pipeline(tmp_path):
     d = tmp_path / "hist"
     d.mkdir()
@@ -204,15 +179,6 @@ def test_trend_gate_resident_column(tmp_path):
     assert sfprof_main(["trend", hist, "--gate", bad_res]) == 1
 
 
-def test_trend_gate_rejects_tainted_candidate(tmp_path, capsys):
-    hist = _history_dir(tmp_path)
-    cand = _write(tmp_path, "stub.json",
-                  _ledger(500e3, tainted=TAINT))
-    assert sfprof_main(["trend", hist, "--gate", cand]) == 1
-    out = capsys.readouterr().out
-    assert "REJECT" in out and "tainted" in out and "ablation" in out
-
-
 def test_trend_gate_insufficient_history(tmp_path, capsys):
     hist = _history_dir(tmp_path, values=(100e3,))
     cand = _write(tmp_path, "c.json", _ledger(100e3))
@@ -224,7 +190,7 @@ def test_trend_gate_insufficient_history(tmp_path, capsys):
 
 
 def test_trend_gate_excludes_candidate_from_its_own_history(tmp_path):
-    # The SFT_LEDGER_DIR layout: the candidate sits IN the history dir.
+    # A capture directory: the candidate sits IN the history dir.
     d = tmp_path / "hist"
     d.mkdir()
     for i, v in enumerate((90e3, 100e3, 110e3, 120e3)):
@@ -234,7 +200,7 @@ def test_trend_gate_excludes_candidate_from_its_own_history(tmp_path):
 
 
 def test_twin_artifacts_of_one_capture_count_once(tmp_path):
-    """The SFT_LEDGER_DIR layout writes a ledger AND its stream per
+    """A capture directory holds a ledger AND its stream per
     capture; the stream's recovery carries the identical bench record.
     The series must count each capture once — twin double-counting
     shrinks the MAD and gates candidates against themselves."""

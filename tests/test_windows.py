@@ -105,7 +105,7 @@ def test_sliding_event_in_multiple_windows():
 def test_late_drop_counted_per_event_not_per_window():
     """Flink late-side-output semantics: one late event = one drop, even
     when it maps to several expired sliding windows; an event that still
-    lands in any live window is not dropped (ADVICE round-1 finding)."""
+    lands in any live window is not dropped."""
     asm = WindowAssembler(
         SlidingEventTimeWindows(10_000, 2_000), timestamp_fn=lambda e: e.ts
     )

@@ -1,6 +1,5 @@
-"""ops/wire_knn.py — the ONE wire→digest program shared by the shipped
-operator (run_wire_panes), bench.py's headline, and bench_suite's kNN
-configs. Pins:
+"""ops/wire_knn.py — the ONE wire→digest program of the shipped
+operator (run_wire_panes). Pins:
 
 - XLA wire step ≡ the operator SoA digest (knn_pane_digest_compact) on
   the dequantized coordinates (set equality, ≤1 ulp distances — FMA
@@ -268,7 +267,7 @@ def test_wire_panes_producer_feeds_run_wire_panes(rng):
     # Set EQUALITY, not ⊆: windows made only of empty panes (the event
     # gap) are suppressed on the wire path exactly like the SoA
     # assembler never builds them — the r5 every-window-fires deviation
-    # is resolved, not documented around (ADVICE r5).
+    # is resolved, not documented around.
     assert set(soa) == set(got), (
         f"extra: {sorted(set(got) - set(soa))} "
         f"missing: {sorted(set(soa) - set(got))}"

@@ -61,7 +61,11 @@ CASES = {
            for n in ("q2", "q5")]
     ),
     "knn": ["wire_panes.xla", "wire_panes.pallas_interpret"],
-    "join": ["run_soa.xla", "run_soa.pallas_interpret"],
+    "join": (
+        ["run_soa.xla", "run_soa.pallas_interpret"]
+        + [f"run_soa.refined.{name}.{backend}" for name, _ in GRIDS
+           for backend in ("xla", "pallas_interpret")]
+    ),
     "tjoin": ["run_soa.uniform", "run_soa.trips"],
     "range": ["run_soa.dense", "run_soa.pruned", "run_soa.pruned_compact"],
     "numerics": (
@@ -319,9 +323,15 @@ def child_knn():
 def child_join():
     """``PointPointJoinQuery.run_soa`` on both kernels: the pair set equals
     the float64 reference's outside the band of 4 · eps32 · span
-    (1.0e-6 deg on the Beijing grid) around the radius."""
+    (1.0e-6 deg on the Beijing grid) around the radius. Then, on each of
+    ``GRIDS``, one window that crowds the centre (Spider-Gaussian
+    positions, ``benchmark/references/spider_gaussian.py``) so that the
+    fullest key cell holds over 4 × ``REFINE_RUNG`` points: the buckets
+    are refined (``join_window_cells`` on the device, in float32), and the
+    pair set still equals the reference's outside the band, once each."""
     sys.path.insert(0, os.path.join(REPO, "tests"))
     from __graft_entry__ import BEIJING_GRID_ARGS
+    from benchmark.references import spider_gaussian
     from join_reference import Reference
     from spatialflink_tpu.grid import UniformGrid
     from spatialflink_tpu.operators import (
@@ -329,43 +339,77 @@ def child_join():
         QueryConfiguration,
         QueryType,
     )
+    from spatialflink_tpu.operators import join_query
+    from spatialflink_tpu.ops.compaction import max_cell_count
+
+    conf = QueryConfiguration(QueryType.WindowBased, window_size=10,
+                              slide_step=10)
+    rng = np.random.default_rng(11)
+
+    def side(grid, n, crowd=False):
+        x = rng.uniform(grid.min_x, grid.max_x, n)
+        y = rng.uniform(grid.min_y, grid.max_y, n)
+        if crowd:
+            x, y = spider_gaussian.positions(
+                x, y, (grid.min_x, grid.min_y, grid.max_x, grid.max_y))
+        return {"ts": T0_MS + np.sort(rng.integers(0, 10_000, n)),
+                "x": x, "y": y, "oid": np.arange(n, dtype=np.int64)}
+
+    def reference(grid, radius):
+        tol = 4 * float(np.finfo(np.float32).eps) * (grid.max_x - grid.min_x)
+        return Reference(bbox=(grid.min_x, grid.min_y, grid.max_x,
+                               grid.max_y),
+                         grid_cells=grid.n, radius=radius, tol=tol)
+
+    def joined(op, left, right, radius, ref):
+        got = list(op.run_soa(iter([left]), iter([right]), radius))
+        if len(got) != 1:
+            return [f"{len(got)} windows fired, expected 1"]
+        bad = []
+        start, end, li, ri, dd, count, overflow = got[0]
+        if (start, end) != (T0_MS, T0_MS + 10_000):
+            bad.append(f"window span {(start, end)}")
+        want = ref.pairs(left["x"], left["y"], right["x"], right["y"])
+        bad += ref.compare(want, li, ri, dd, int(count), int(overflow),
+                           n_right=len(right["x"]))
+        if not int(count):
+            bad.append("no pair found: the comparison is empty")
+        return bad
 
     grid = UniformGrid(**BEIJING_GRID_ARGS)
     radius, n = 0.002, 8_000
-    rng = np.random.default_rng(11)
-
-    def side():
-        return {"ts": T0_MS + np.sort(rng.integers(0, 10_000, n)),
-                "x": rng.uniform(grid.min_x, grid.max_x, n),
-                "y": rng.uniform(grid.min_y, grid.max_y, n),
-                "oid": np.arange(n, dtype=np.int64)}
-
-    left, right = side(), side()
-    tol = 4 * float(np.finfo(np.float32).eps) * (grid.max_x - grid.min_x)
-    ref = Reference(bbox=(grid.min_x, grid.min_y, grid.max_x, grid.max_y),
-                    grid_cells=100, radius=radius, tol=tol)
-    want = ref.pairs(left["x"], left["y"], right["x"], right["y"])
-    conf = QueryConfiguration(QueryType.WindowBased, window_size=10,
-                              slide_step=10)
+    left, right = side(grid, n), side(grid, n)
+    ref = reference(grid, radius)
     out = {}
     for backend in ("xla", "pallas_interpret"):
         # cap=8: under one point a cell; the default 64 only pads the
         # buckets (the ladder climbs if a cell holds more).
-        got = list(PointPointJoinQuery(conf, grid, cap=8,
-                                       join_backend=backend)
-                   .run_soa(iter([left]), iter([right]), radius))
-        bad = []
-        if len(got) != 1:
-            bad.append(f"{len(got)} windows fired, expected 1")
-        else:
-            start, end, li, ri, dd, count, overflow = got[0]
-            if (start, end) != (T0_MS, T0_MS + 10_000):
-                bad.append(f"window span {(start, end)}")
-            bad += ref.compare(want, li, ri, dd, int(count), int(overflow),
-                               n_right=n)
-            if not int(count):
-                bad.append("no pair found: the comparison is empty")
-        out[f"run_soa.{backend}"] = _verdict(bad)
+        op = PointPointJoinQuery(conf, grid, cap=8, join_backend=backend)
+        out[f"run_soa.{backend}"] = _verdict(
+            joined(op, left, right, radius, ref))
+
+    # The interpreter walks every bucket block of the refined grid: the
+    # same contract at a refinement rung of 16 (as tests/test_join_skew.py
+    # runs it) keeps a 400 x 400 bucket grid a few seconds' work.
+    join_query.REFINE_RUNG = 16
+    for name, args in GRIDS:
+        grid = UniformGrid(**args)
+        # a fifth of a key cell: a bucket grid 4 x finer still holds it
+        radius, n = grid.cell_length / 5, 40_000
+        left, right = side(grid, n, crowd=True), side(grid, n, crowd=True)
+        ref = reference(grid, radius)
+        fullest = max(
+            max_cell_count(grid.assign_cells_np(np.stack([s["x"], s["y"]], 1)),
+                           np.ones(n, bool), grid.num_cells)
+            for s in (left, right))
+        for backend in ("xla", "pallas_interpret"):
+            op = PointPointJoinQuery(conf, grid, cap=8, join_backend=backend)
+            bad = joined(op, left, right, radius, ref)
+            if fullest <= 4 * join_query.REFINE_RUNG:
+                bad.append(f"fullest key cell holds {fullest}: not crowded")
+            if op.join_refine <= 1:
+                bad.append("the buckets stayed on the key grid")
+            out[f"run_soa.refined.{name}.{backend}"] = _verdict(bad)
     return out
 
 
@@ -637,7 +681,7 @@ import pytest  # noqa: E402
 def _run_child(name):
     env = {k: v for k, v in os.environ.items()
            if k not in ("JAX_ENABLE_X64", "XLA_FLAGS", "SFT_FAULT_PLAN",
-                        "SFT_OVERLOAD_POLICY", "SFT_QSERVE", "SFT_ABLATE")}
+                        "SFT_OVERLOAD_POLICY", "SFT_QSERVE")}
     env["JAX_PLATFORMS"] = "cpu"
     p = subprocess.run([sys.executable, os.path.abspath(__file__), name],
                        cwd=REPO, env=env, capture_output=True, text=True,

@@ -66,7 +66,7 @@ def _cpu_env() -> Dict[str, str]:
     # measurement in flight.
     env["JAX_PLATFORMS"] = "cpu"
     # Scrub every hazard-class-`armed` var (ambient fault plans,
-    # overload policies, live SLO specs, bench failure-forcing knobs):
+    # overload policies, standing-query serving configs):
     # any of them left over from test iteration would sabotage a
     # healthy gate run with injected behavior. The list is DERIVED from
     # the registry (spatialflink_tpu/envvars.py), so the next armed

@@ -81,7 +81,6 @@ DEFAULT_TARGETS = (
     "spatialflink_tpu",
     "tools",
     "tests",
-    "bench_suite.py",
     "__graft_entry__.py",
 )
 

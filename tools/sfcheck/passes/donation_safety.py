@@ -62,7 +62,7 @@ class DonationSafetyPass(ProjectPass):
 
     def in_scope(self, relpath: str) -> bool:
         return (relpath.startswith("spatialflink_tpu/")
-                or relpath in ("bench_suite.py", "__graft_entry__.py"))
+                or relpath == "__graft_entry__.py")
 
     # -- donation resolution -------------------------------------------------
 

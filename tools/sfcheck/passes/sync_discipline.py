@@ -9,7 +9,7 @@ bare ``block_until_ready`` waits outside that accounting. (On the
 directly attached TPU v5e it does wait for the device — CHANGES.md
 PR 21 — so whether to keep the ban is ROADMAP C9's call, not a
 correctness matter.) The ban covers everything —
-bench_suite.py, the driver entry, the tests, the SLO engine
+the driver entry, the tests, the SLO engine
 (``spatialflink_tpu/slo.py``), the sfprof stream/recover modules, and
 the fault-tolerance layer (``spatialflink_tpu/driver.py``'s retry/
 failover paths and ``spatialflink_tpu/faults.py`` — a "sync" before a

@@ -100,8 +100,8 @@ SHAPE_SANITIZERS = frozenset({
     "max_window_cell_count",
 })
 
-#: Device-shape allocators: a tainted dimension here IS a per-window
-#: recompile (one XLA compile per distinct value).
+#: Device-shape allocators: a dimension carrying taint here IS a
+#: per-window recompile (one XLA compile per distinct value).
 JNP_SHAPE_SINKS = frozenset({
     "zeros", "ones", "full", "empty", "arange", "linspace", "eye",
 })
@@ -316,7 +316,7 @@ class _Extractor(ast.NodeVisitor):
         self.fn_stack: List[FunctionFacts] = []
         self.cls_stack: List[str] = []
         self.loop_stack: List[Tuple[int, int, bool]] = []  # (start, end, window)
-        self.tainted_stack: List[set] = []
+        self.taint_stack: List[set] = []
         self.names_used: set = set()
         #: depth of enclosing If/IfExp/except-handler within the current
         #: function — a checkpoint write/read at depth > 0 is CONDITIONAL
@@ -352,8 +352,8 @@ class _Extractor(ast.NodeVisitor):
     def _in_window_loop(self) -> bool:
         return any(w for _, _, w in self.loop_stack)
 
-    def _tainted(self) -> dict:
-        return self.tainted_stack[-1] if self.tainted_stack else {}
+    def _taint_top(self) -> dict:
+        return self.taint_stack[-1] if self.taint_stack else {}
 
     # -- taint evaluation ----------------------------------------------------
 
@@ -361,7 +361,7 @@ class _Extractor(ast.NodeVisitor):
         """A short description of why ``node`` is a data-dependent Python
         int, or None if it is not (conservatively)."""
         if isinstance(node, ast.Name):
-            return self._tainted().get(node.id)
+            return self._taint_top().get(node.id)
         if isinstance(node, ast.Call):
             d = dotted(node.func)
             term = (d or "").split(".")[-1]
@@ -395,9 +395,9 @@ class _Extractor(ast.NodeVisitor):
         return None
 
     def _record_store_taint(self, target: ast.AST, value: ast.AST):
-        if not self.tainted_stack:
+        if not self.taint_stack:
             return
-        tset = self.tainted_stack[-1]
+        tset = self.taint_stack[-1]
         if isinstance(target, ast.Name):
             why = self._taints(value)
             if why:
@@ -446,7 +446,7 @@ class _Extractor(ast.NodeVisitor):
                         fn.donate_decorator = _literal_int_tuple(kw.value)
         self.facts.functions[qual] = fn
         self.fn_stack.append(fn)
-        self.tainted_stack.append({})
+        self.taint_stack.append({})
         self._set_taint_stack.append({})
         saved_loops = self.loop_stack
         saved_cond = self._cond
@@ -460,7 +460,7 @@ class _Extractor(ast.NodeVisitor):
         self._cond = saved_cond
         self._str_loopvars = saved_slv
         self._set_taint_stack.pop()
-        self.tainted_stack.pop()
+        self.taint_stack.pop()
         self.fn_stack.pop()
 
     visit_FunctionDef = _visit_function
@@ -611,14 +611,14 @@ class _Extractor(ast.NodeVisitor):
         self._check_iter_nondet(node.iter)
         self.visit(node.iter)
         # Loop indices over runtime collections are data-dependent ints.
-        if self.tainted_stack and isinstance(node.iter, ast.Call):
+        if self.taint_stack and isinstance(node.iter, ast.Call):
             d = dotted(node.iter.func)
             if d in ("range", "enumerate"):
                 why = any(self._taints(a) for a in node.iter.args)
                 if d == "enumerate" or why:
                     for n in ast.walk(node.target):
                         if isinstance(n, ast.Name):
-                            self.tainted_stack[-1][n.id] = (
+                            self.taint_stack[-1][n.id] = (
                                 f"loop index `{n.id}`")
                             break  # first target only (the index)
         self.visit(node.target)
@@ -710,10 +710,10 @@ class _Extractor(ast.NodeVisitor):
 
     def visit_AugAssign(self, node):
         self.visit(node.value)
-        if self.tainted_stack and isinstance(node.target, ast.Name):
+        if self.taint_stack and isinstance(node.target, ast.Name):
             why = self._taints(node.value)
             if why:
-                self.tainted_stack[-1][node.target.id] = why
+                self.taint_stack[-1][node.target.id] = why
         self.visit(node.target)
 
     def visit_AnnAssign(self, node):

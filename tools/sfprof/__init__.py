@@ -14,9 +14,8 @@ ledgers into decisions:
   window, host-gap detection between window spans.
 - ``python -m tools.sfprof diff <A> <B> [--gate]`` — per-metric deltas
   with per-entry tolerance bands (wide EPS bands until per-cell spreads
-  are measured on the chip; CPU_BASELINE.json medians guard the
-  suite configs against silent regression). ``--gate`` exits nonzero on
-  regression so CI can gate.
+  are measured on the chip). ``--gate`` exits nonzero on regression so
+  CI can gate.
 - ``python -m tools.sfprof health <ledger>`` — threshold verdicts on
   recompile churn, overflow counters, late drops, watermark-lag max,
   and dropped trace events; the post-bench check next to

@@ -20,12 +20,7 @@ identical either way. Both surface the roofline bound classification
 (``tools/sfprof/roofline.py``): link/host/dispatch/compute/memory-bound
 with an ``↳`` evidence chain — a diagnosis, never a gate.
 
-Tainted captures (``tainted`` block stamped by the ablation harness,
-``spatialflink_tpu/ablation.py``) are HARD-REJECTED by ``diff --gate``
-and ``trend --gate`` with the taint named: a run whose kernels were
-stubbed out must never enter the perf record.
-
-Exit codes: 0 ok; 1 gated regression/taint (``diff --gate``,
+Exit codes: 0 ok; 1 gated regression (``diff --gate``,
 ``trend --gate``), failed health/SLO verdict, or a recovered document
 that fails schema validation; 2 unreadable/invalid input.
 """
@@ -46,11 +41,6 @@ from tools.sfprof import roofline as roofline_mod
 from tools.sfprof import slo as slo_mod
 from tools.sfprof import stream as stream_mod
 from tools.sfprof import trend as trend_mod
-
-REPO_ROOT = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-)
-DEFAULT_BASELINE = os.path.join(REPO_ROOT, "CPU_BASELINE.json")
 
 # -- shared helpers -----------------------------------------------------------
 
@@ -451,9 +441,6 @@ def _report_json(args, doc, events, bound) -> int:
             "bench": doc.get("bench"),
         }
         out["kernels"] = (doc.get("kernels") or [])[:args.top]
-        taint = trend_mod.taint_of(doc)
-        if taint is not None:
-            out["tainted"] = taint
         if snap.get("e2e"):
             out["e2e"] = snap["e2e"]
     out["straggler"] = critical_mod.straggler_line(doc, events)
@@ -522,8 +509,8 @@ def _kind(name: str) -> str:
     return "info"
 
 
-def compare(a_doc: Dict, b_doc: Dict, eps_tol: float, lat_tol: float,
-            baseline: Optional[Dict] = None) -> List[dict]:
+def compare(a_doc: Dict, b_doc: Dict, eps_tol: float,
+            lat_tol: float) -> List[dict]:
     """Per-metric rows {name, a, b, band, verdict} comparing ledger B
     (candidate) against ledger A (reference).
 
@@ -532,11 +519,7 @@ def compare(a_doc: Dict, b_doc: Dict, eps_tol: float, lat_tol: float,
     spreads are measured on the chip; latency when B exceeds A by more
     than ``lat_tol`` (fraction) plus a 1 ms absolute floor; ``compiles``
     when B > 2·A + 8 (ladder growth is legitimate, churn is not);
-    dropped/overflow counters on ANY increase. Additionally, suite
-    configs named in CPU_BASELINE.json are guarded against the recorded
-    medians: a B that falls below median·(1−eps_tol) while A was inside
-    the band is a NEW regression (self-diff of an already-slow ledger
-    stays informational, so the gate is monotone)."""
+    dropped/overflow counters on ANY increase."""
     rows: List[dict] = []
     a_m, b_m = _metrics(a_doc), _metrics(b_doc)
     for name in sorted(set(a_m) | set(b_m)):
@@ -571,42 +554,6 @@ def compare(a_doc: Dict, b_doc: Dict, eps_tol: float, lat_tol: float,
             band = "B <= A"
             verdict = "regression" if b > a else "ok"
         rows.append({"name": name, "a": a, "b": b, "band": band,
-                     "verdict": verdict})
-
-    if baseline:
-        rows.extend(_baseline_rows(a_doc, b_doc, baseline, eps_tol))
-    return rows
-
-
-def _baseline_rows(a_doc: Dict, b_doc: Dict, baseline: Dict,
-                   eps_tol: float) -> List[dict]:
-    bench_a = a_doc.get("bench") or {}
-    bench_b = b_doc.get("bench") or {}
-    cfg = bench_b.get("config")
-    checks: List[Tuple[str, Any, Any, float]] = []
-    for block, field in (("configs", "points_per_sec"),
-                         ("configs_resident",
-                          "device_resident_points_per_sec")):
-        median = (baseline.get(block) or {}).get(cfg)
-        if cfg and median:
-            checks.append((
-                f"CPU_BASELINE[{cfg}].{field}",
-                bench_a.get(field), bench_b.get(field), float(median),
-            ))
-    rows = []
-    for name, a, b, median in checks:
-        if not isinstance(b, (int, float)):
-            continue
-        lo = median * (1 - eps_tol)
-        if b >= lo:
-            verdict = "ok"
-        elif isinstance(a, (int, float)) and a < lo:
-            verdict = "info"  # pre-existing: A was already below the band
-        else:
-            verdict = "regression"
-        rows.append({"name": name, "a": a, "b": b,
-                     "band": f"B >= median*(1-{float(eps_tol):g}) = "
-                             f"{float(lo):.1f}",
                      "verdict": verdict})
     return rows
 
@@ -649,28 +596,7 @@ def cmd_diff(args) -> int:
     except (OSError, ValueError) as e:
         print(f"sfprof: cannot read ledger: {e}")
         return 2
-    # Tainted captures never enter the record: an ablation run stubbed
-    # kernels out, so its numbers are deliberately wrong — refuse to
-    # compare AT ALL (silent inclusion is how a stubbed 10x "win" would
-    # poison the next gate's reference).
-    for label, path, doc in (("A", args.a, a_doc), ("B", args.b, b_doc)):
-        taint = trend_mod.taint_of(doc)
-        if taint is not None:
-            kinds = taint.get("kind", "?")
-            detail = ",".join(taint.get("kernels") or []) or "-"
-            print(f"== sfprof diff: A={args.a}  B={args.b}")
-            print(f"REJECT: ledger {label} ({path}) is tainted "
-                  f"({kinds}: kernels={detail}) — ablated/stubbed "
-                  "captures are profiling artifacts and never gate, "
-                  "diff, or baseline")
-            return 1 if args.gate else 0
-    baseline = None
-    try:
-        with open(args.baseline) as f:
-            baseline = json.load(f)
-    except (OSError, ValueError):
-        pass  # no baseline file: skip the median guard
-    rows = compare(a_doc, b_doc, args.eps_tol, args.lat_tol, baseline)
+    rows = compare(a_doc, b_doc, args.eps_tol, args.lat_tol)
     regressions = [r for r in rows if r["verdict"] == "regression"]
     print(f"== sfprof diff: A={args.a}  B={args.b}")
     note = _link_annotation(a_doc, b_doc)
@@ -749,7 +675,6 @@ def cmd_health(args) -> int:
         checks.extend(slo_mod.evaluate(spec, doc))
     failed = sum(0 if ok else 1 for _n, _v, _b, ok in checks)
     bound = roofline_mod.classify(doc, doc.get("events") or [])
-    taint = trend_mod.taint_of(doc)
     sline = critical_mod.straggler_line(doc, doc.get("events") or [])
     if args.json:
         print(json.dumps({
@@ -762,7 +687,6 @@ def cmd_health(args) -> int:
             ],
             "failed": failed,
             "roofline": bound,
-            "tainted": taint,
             "notes": {
                 "driver": snap.get("driver") or {},
                 "overload": snap.get("overload") or {},
@@ -802,11 +726,6 @@ def cmd_health(args) -> int:
               f"p50 {float(commit.get('p50_ms') or 0):.1f} ms  "
               f"p99 {float(commit.get('p99_ms') or 0):.1f} ms over "
               f"{int(commit.get('count') or 0)} committed window(s)")
-    if taint is not None:
-        print(f"note TAINTED capture: {taint.get('kind', '?')} "
-              f"(kernels={','.join(taint.get('kernels') or []) or '-'})"
-              " — profiling artifact; diff/trend gates and baseline "
-              "writers reject it")
     # Self-healing visibility (informational — a run that SURVIVED on
     # retries/fallback is degraded, not failed; budget it via an --slo
     # spec's retry_budget/failover_budget to make it gate):
@@ -1117,23 +1036,15 @@ def cmd_trend(args) -> int:
 
 def _gate_against_trend(args, series) -> Tuple[Dict[str, Any], int]:
     """(gate block, exit code) for the ``--gate`` candidate against its
-    series. Tainted candidates are hard-rejected; a candidate with no
-    matching history passes with a loud note unless
-    ``--require-history`` (the CI mode — a missing fixture must fail,
-    not silently wave everything through)."""
+    series. A candidate with no matching history passes with a loud
+    note unless ``--require-history`` (the CI mode — a missing fixture
+    must fail, not silently wave everything through)."""
     gate: Dict[str, Any] = {"candidate": args.gate, "checks": []}
     try:
         doc, kind = trend_mod.load_candidate(args.gate)
     except (OSError, ValueError) as e:
         gate["reject"] = f"cannot read candidate: {e}"
         return gate, 2
-    taint = trend_mod.taint_of(doc)
-    if taint is not None:
-        gate["reject"] = (
-            f"candidate is tainted ({taint.get('kind', '?')}: kernels="
-            f"{','.join(taint.get('kernels') or []) or '-'}) — ablated "
-            "captures never enter the trend record")
-        return gate, 1
     pt, reason = trend_mod.point_of(doc, kind, args.gate)
     if pt is None:
         gate["reject"] = f"candidate carries no gateable EPS: {reason}"
@@ -1142,9 +1053,9 @@ def _gate_against_trend(args, series) -> Tuple[Dict[str, Any], int]:
                            trend_mod.series_key(pt)))
     pts = series.get(trend_mod.series_key(pt)) or []
     # Never gate a capture against itself: the candidate file may sit
-    # in the history dir (the SFT_LEDGER_DIR layout), and the same run
-    # may ALSO appear under another path — its sibling stream's
-    # recovery, a copied ledger — carrying the identical bench record.
+    # in the history dir, and the same run may ALSO appear under
+    # another path — its sibling stream's recovery, a copied ledger —
+    # carrying the identical bench record.
     # Exclude by path and by exact (value, resident) identity; a
     # distinct run tying both rounded values is rare and could only
     # make the gate stricter by one sample.
@@ -1222,9 +1133,6 @@ def build_parser() -> argparse.ArgumentParser:
     dif.add_argument("--lat-tol", type=float, default=1.0,
                      help="allowed fractional latency growth "
                           "(default 1.0 = 2x)")
-    dif.add_argument("--baseline", default=DEFAULT_BASELINE,
-                     help="CPU_BASELINE.json medians guarding suite "
-                          "configs (default: repo copy)")
     dif.add_argument("--verbose", action="store_true",
                      help="also print informational rows")
     dif.set_defaults(fn=cmd_diff)
@@ -1243,7 +1151,7 @@ def build_parser() -> argparse.ArgumentParser:
                           "ceiling)")
     hea.add_argument("--json", action="store_true",
                      help="one machine-readable JSON document (checks, "
-                          "roofline verdict, taint, notes) instead of "
+                          "roofline verdict, notes) instead of "
                           "human text (same exit code)")
     hea.set_defaults(fn=cmd_health)
 
@@ -1280,7 +1188,7 @@ def build_parser() -> argparse.ArgumentParser:
                           "every .json/.jsonl inside, sorted)")
     trd.add_argument("--gate", default=None, metavar="NEW_LEDGER",
                      help="candidate capture to gate against its "
-                          "series; exit 1 outside the band or tainted")
+                          "series; exit 1 outside the band")
     trd.add_argument("--config", default=None,
                      help="only series whose config name contains this "
                           "substring")

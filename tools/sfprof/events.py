@@ -41,9 +41,6 @@ INSTANT_EVENTS = frozenset({
     "overload_shedding:oldest",
     "overload_recovered:admission",
     "overload_recovered:lag",
-    # kernel-ablation harness armed (spatialflink_tpu/ablation.py) —
-    # the event that marks a capture's numbers as deliberately wrong
-    "ablation_armed",
     # qserve standing-query registry (spatialflink_tpu/qserve.py):
     # registration lifecycle + per-tenant-class admission rejections
     "qserve_registered",
@@ -85,7 +82,6 @@ _GROUPS = (
     ("dag", ("dag_node_failover:",)),
     ("qserve", ("qserve_",)),
     ("slo", ("slo_violation:", "slo_recovered:")),
-    ("ablation", ("ablation_armed",)),
     ("blackbox", ("blackbox_dumped",)),
 )
 

@@ -22,11 +22,9 @@ pipelined capture lands against pipelined history. Commit/device/time
 ride each point as attributes for the report, not the key.
 
 History hygiene is skip-with-counted-evidence, never a crash: an rc≠0
-round record (a failed capture), an unparseable tail, a
-zero-value error record, or a ``tainted`` ablation capture is skipped
-WITH its reason in the output — silence is how bad history poisons a
-gate. A tainted CANDIDATE is hard-rejected (exit 1): an ablated run
-must never enter the record as a real number.
+round record (a failed capture), an unparseable tail, or a
+zero-value error record is skipped WITH its reason in the output —
+silence is how bad history poisons a gate.
 
 Stdlib-only, no jax import (the sfprof no-cross-import rule).
 """
@@ -76,18 +74,6 @@ def _finite_pos(v: Any) -> bool:
     return v > 0 and v != float("inf")
 
 
-def taint_of(doc_or_rec: Dict[str, Any]) -> Optional[dict]:
-    """The taint block of a ledger/record, wherever it rides (top level,
-    snapshot checkpoint — the stream-recovery path — or bench block)."""
-    for block in (doc_or_rec,
-                  doc_or_rec.get("snapshot") or {},
-                  doc_or_rec.get("bench") or {}):
-        t = block.get("tainted")
-        if isinstance(t, dict):
-            return t
-    return None
-
-
 def point_from_bench(bench: Dict[str, Any], source: str,
                      created_unix: Optional[float] = None,
                      commit: Optional[str] = None,
@@ -104,9 +90,6 @@ def point_from_bench(bench: Dict[str, Any], source: str,
         value = bench.get("value")
     if not _finite_pos(value):
         return None, "zero/absent EPS (outage or error record)"
-    t = bench.get("tainted")
-    if isinstance(t, dict):
-        return None, f"tainted: {t.get('kind', '?')}"
     pipe = bench.get("pipeline") or {}
     resident = bench.get("device_resident_points_per_sec")
     if not _finite_pos(resident):
@@ -129,9 +112,6 @@ def point_from_bench(bench: Dict[str, Any], source: str,
 
 def point_from_ledger(doc: Dict[str, Any], source: str) \
         -> Tuple[Optional[dict], Optional[str]]:
-    t = taint_of(doc)
-    if t is not None:
-        return None, f"tainted: {t.get('kind', '?')}"
     env = doc.get("env") or {}
     device = (env.get("devices") or [None])[0] or env.get("backend")
     return point_from_bench(
@@ -196,7 +176,7 @@ def point_of(doc: Dict[str, Any], kind: str, source: str) \
 
 def expand_paths(paths: List[str]) -> List[str]:
     """Files named directly plus the JSON/JSONL files of any named
-    directory (one level, sorted — the SFT_LEDGER_DIR layout)."""
+    directory (one level, sorted)."""
     out: List[str] = []
     for p in paths:
         if os.path.isdir(p):
@@ -234,7 +214,7 @@ def series_key(point: Dict[str, Any]) -> Tuple:
 def build_series(points: List[dict]) -> Dict[Tuple, List[dict]]:
     """Points grouped by series key, time-ordered, with ONE entry per
     capture: a run captured as both a ledger and its sibling stream
-    (the SFT_LEDGER_DIR layout writes ``<cfg>.json`` AND
+    (a capture directory holding ``<cfg>.json`` AND
     ``<cfg>.stream.jsonl``, whose recovery carries the identical bench
     record) must count once — twin artifacts would otherwise shrink the
     MAD and let a candidate be gated partly against itself. Dedup key:
