@@ -252,11 +252,16 @@ def pack_query_geometries(
 
 
 class CellCandidates(NamedTuple):
-    """The grid index of a polygon query set (``pack_cell_candidates``)."""
+    """The grid index of a polygon query set (``pack_cell_candidates``), in
+    two layouts of the same lists: ``table``, a K-wide row a cell, and the
+    ragged form — every list one contiguous run of ``polys``, cell-major,
+    cell g's at ``polys[starts[g]:starts[g + 1]]``."""
 
     table: np.ndarray  # (num_cells + 1, K) int32; P marks an empty slot
     entries: int  # Σ list lengths: the (cell, polygon) pairs
     cells: int  # cells with a non-empty list
+    starts: np.ndarray  # (num_cells + 2,) int32 run starts, the last = entries
+    polys: np.ndarray  # (entries,) int32 polygon of each entry
 
     @property
     def slots(self) -> int:
@@ -291,6 +296,8 @@ def pack_cell_candidates(
     only of polygons in g's list. Row ``num_cells`` (out of grid) is empty;
     an empty slot holds P, one past the last polygon (``pack_cell_edges``
     keeps its far edges there). A polygon with no valid edge is in no list.
+    The same lists, ragged (``starts`` / ``polys``), are what
+    ``pack_entry_edges`` lays out for the cell-major kernel.
     """
     from spatialflink_tpu.utils.padding import next_bucket
 
@@ -329,7 +336,9 @@ def pack_cell_candidates(
     slots = next_bucket(int(counts.max(initial=0)), minimum=8)
     table = np.full((grid.num_cells + 1, slots), p, np.int32)
     table[cell, _rank_in_group(counts)] = poly
-    return CellCandidates(table, len(cell), int(np.count_nonzero(counts)))
+    starts = np.concatenate([[0], np.cumsum(counts)]).astype(np.int32)
+    return CellCandidates(table, len(cell), int(np.count_nonzero(counts)),
+                          starts, poly.astype(np.int32))
 
 
 #: where ``pack_cell_edges`` puts both ends of an empty edge slot: a
@@ -353,6 +362,29 @@ def pack_cell_edges(table: np.ndarray, verts: np.ndarray,
     holds ``FAR_EDGE`` at both ends. The minimum and the crossing count
     over a polygon's slots are those over its valid edges, in any order.
     """
+    # (cells, K, 4, E) -> (cells, 4, E, K): the K slots on the minor axis
+    return np.ascontiguousarray(
+        _polygon_edges(verts, edge_valid)[table].transpose(0, 2, 3, 1))
+
+
+def pack_entry_edges(polys: np.ndarray, verts: np.ndarray,
+                     edge_valid: np.ndarray, pad: int) -> np.ndarray:
+    """The ragged index's edge table: (entries + ``pad``, 4 · E), row i the
+    endpoint planes x1, y1, x2, y2 of polygon ``polys[i]``'s E slots one
+    after another (``polys`` as ``pack_cell_candidates`` lists them,
+    cell-major; one flat row, so that the device holds it as it is read and
+    no window relays it out), then ``pad`` rows of ``FAR_EDGE`` only — so
+    that a fixed-width slice that starts inside the table never runs off its
+    end. Edges and far slots as in ``pack_cell_edges``; ``verts`` as the
+    device will see them."""
+    rows = np.concatenate([np.asarray(polys, np.int64),
+                           np.full(pad, len(verts), np.int64)])
+    return _polygon_edges(verts, edge_valid)[rows].reshape(len(rows), -1)
+
+
+def _polygon_edges(verts: np.ndarray, edge_valid: np.ndarray) -> np.ndarray:
+    """(P + 1, 4, E): polygon i's valid edges first, in ring order, then
+    ``FAR_EDGE`` slots; row P (an empty slot's) is far slots only."""
     ev = np.asarray(edge_valid, bool)
     per_poly = ev.sum(axis=1)
     which, j = np.nonzero(ev)  # row-major: a polygon's edges in ring order
@@ -361,8 +393,7 @@ def pack_cell_edges(table: np.ndarray, verts: np.ndarray,
                     FAR_EDGE, verts.dtype)
     edges[which, 0, slot], edges[which, 1, slot] = verts[which, j].T
     edges[which, 2, slot], edges[which, 3, slot] = verts[which, j + 1].T
-    # (cells, K, 4, E) -> (cells, 4, E, K): the K slots on the minor axis
-    return np.ascontiguousarray(edges[table].transpose(0, 2, 3, 1))
+    return edges
 
 
 def _centring(grid: UniformGrid, dtype):

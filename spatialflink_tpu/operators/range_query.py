@@ -35,6 +35,7 @@ from spatialflink_tpu.operators.base import (
     jitted,
     pack_cell_candidates,
     pack_cell_edges,
+    pack_entry_edges,
     pack_query_geometries,
     pack_query_points,
     ship,
@@ -47,6 +48,10 @@ from spatialflink_tpu.ops.range import (
     range_polylines_fused,
 )
 from spatialflink_tpu.telemetry import telemetry
+
+
+#: the polygon kernels that read the grid index
+INDEXED = ("pruned", "pruned_compact", "cell_major")
 
 
 @dataclass
@@ -74,7 +79,8 @@ class _PointStreamRangeQuery(SpatialOperator):
         self._ncand = 8
         self._cand_budget = 4096
         #: the kernel the newest evaluator built: "points", "polylines",
-        #: or for a polygon set "dense", "pruned", "pruned_compact"
+        #: or for a polygon set "dense", "pruned", "pruned_compact",
+        #: "cell_major"
         self.last_range_kernel = None
 
     def _window_evaluator(self, query_set, flags, radius, dtype, mesh):
@@ -85,10 +91,13 @@ class _PointStreamRangeQuery(SpatialOperator):
 
         ``launch(common)`` dispatches the window's program and returns its
         device outputs; ``settle(out, common)`` fetches them and returns
-        host ``(keep, dist, budget_retries)``. The compact kernel's
-        overflow scalar crosses WITH keep and dist, in the one
-        ``telemetry.fetch``; while the budget did not hold, settle grows it
-        and launches again — the one re-run left: nothing else can overflow.
+        host ``(keep, dist, budget_retries, edge_slots)`` — ``edge_slots``
+        the polygon edges the program evaluated, padding included (0 for a
+        point or linestring set). The compact kernel's overflow scalar and
+        the cell-major kernel's edge count cross WITH keep and dist, in the
+        one ``telemetry.fetch``; while the budget did not hold, settle grows
+        it and launches again — the one re-run left: nothing else can
+        overflow.
 
         Polygon selection: large exact-mode query sets go through a cell →
         candidate-polygons table, built here, once per query set, in float64
@@ -100,6 +109,18 @@ class _PointStreamRangeQuery(SpatialOperator):
         costs a wider K from the start and never a re-run; ``self._ncand``
         and the gauge ``cand`` report it. Sparse candidate unions (<25%
         flag occupancy) additionally compact candidate lanes first.
+
+        Where a K-wide row would hold more than ``CELL_MAJOR_ROW`` edge
+        slots (K × E, E the most edges of a polygon of the set) the index
+        is read the other way round (``cell_major``): each cell's list a
+        contiguous run of one edge table, the window's points sorted by
+        cell on the device and a tile of them evaluated against its cells'
+        run. A K-wide row costs every point K·E edge slots and the table
+        ``cells × 4·E·K`` floats; a tile costs the steps of its cells' run,
+        and a tile whose cells list nothing costs nothing of its own — so
+        the occupancy test is not asked there: it counts the flagged cells,
+        not what their lists hold. Both read the same lists and give the
+        same kept lanes.
         Approximate mode stays dense — its keep-set ignores distances, so
         min-over-candidates dists would diverge from the dense min-over-all
         on kept lanes.
@@ -108,7 +129,7 @@ class _PointStreamRangeQuery(SpatialOperator):
 
         def settle_plain(out, common):
             keep, dist = telemetry.fetch(out)
-            return keep, dist, 0
+            return keep, dist, 0, 0
 
         if self.query_kind == "point":
             self.last_range_kernel = "points"
@@ -134,30 +155,76 @@ class _PointStreamRangeQuery(SpatialOperator):
                 mesh, range_polygons_fused, (0, 1, 2), 7, approximate=approx
             )
             qv, qe = self.device_q(verts, dtype), jnp.asarray(ev)
-            return (lambda common: polyk(*common, qv, qe, radius)), settle_plain
+            slots = len(query_set) * ev.shape[1]
+
+            def settle_dense(out, common):
+                keep, dist = telemetry.fetch(out)
+                return keep, dist, 0, common[0].shape[0] * slots
+
+            return ((lambda common: polyk(*common, qv, qe, radius)),
+                    settle_dense)
 
         from spatialflink_tpu.ops.range import (
+            CELL_MAJOR_ROW,
+            CELL_MAJOR_TILE,
+            CELL_MAJOR_WIDTH,
+            POINT_CHUNK,
+            range_polygons_cell_major_fused,
             range_polygons_pruned_compact_fused,
             range_polygons_pruned_fused,
         )
 
         # The grid index, once per query set: K is read off it here, before
         # the first window, so the program's shapes are settled in the
-        # warm-up. One counted crossing, as the rings take on the other paths.
+        # warm-up. Counted crossings, as the rings take on the other paths.
         index = pack_cell_candidates(self.grid, verts, ev, radius)
         self._ncand = index.slots
-        telemetry.record_range_index(index.slots, index.entries, index.cells)
-        cell_edges = self.device_table(pack_cell_edges(
-            index.table, center_coords(self.grid, verts, dtype), ev))
+        centred = center_coords(self.grid, verts, dtype)
+        # a K-wide row's edge slots: K polygons of E edges (the most any
+        # polygon of the set has), what every point evaluates through it
+        row_slots = index.slots * max(int(ev.sum(axis=1).max(initial=0)), 1)
+
+        if row_slots > CELL_MAJOR_ROW:
+            self.last_range_kernel = "cell_major"
+            edges = pack_entry_edges(index.polys, centred, ev,
+                                     CELL_MAJOR_WIDTH)
+            # a step: the tile against ``width`` entries of 4 · E planes
+            step_slots = CELL_MAJOR_TILE * CELL_MAJOR_WIDTH * (
+                edges.shape[1] // 4)
+            tables = [self.device_table(a) for a in (edges, index.starts)]
+            telemetry.record_range_index(
+                index.slots, index.entries, index.cells,
+                sum(a.nbytes for a in tables))
+            cellk = jitted(range_polygons_cell_major_fused, "tile", "width",
+                           "batch", "approximate")
+
+            def settle_cells(out, common):
+                keep, dist, steps = telemetry.fetch(out)
+                return keep, dist, 0, int(steps) * step_slots
+
+            return (lambda common: cellk(
+                *common, *tables, radius, tile=CELL_MAJOR_TILE,
+                width=CELL_MAJOR_WIDTH)), settle_cells
+
+        cell_edges = self.device_table(pack_cell_edges(index.table, centred,
+                                                       ev))
+        telemetry.record_range_index(index.slots, index.entries, index.cells,
+                                     cell_edges.nbytes)
 
         if float((flags > 0).mean()) >= 0.25:
             self.last_range_kernel = "pruned"
             prunedk = jitted(
                 range_polygons_pruned_fused, "point_chunk", "approximate"
             )
+
+            def settle_pruned(out, common):
+                keep, dist = telemetry.fetch(out)
+                lanes = -(-common[0].shape[0] // POINT_CHUNK) * POINT_CHUNK
+                return keep, dist, 0, lanes * row_slots
+
             return (
                 lambda common: prunedk(*common, cell_edges, radius)
-            ), settle_plain
+            ), settle_pruned
 
         self.last_range_kernel = "pruned_compact"
         compactk = jitted(
@@ -171,14 +238,16 @@ class _PointStreamRangeQuery(SpatialOperator):
 
         def settle(out, common):
             budget_retries = 0
+            edge_slots = self._cand_budget * row_slots
             while True:
                 keep, dist, b_over = telemetry.fetch(out)
                 if int(b_over) == 0:
-                    return keep, dist, budget_retries
+                    return keep, dist, budget_retries, edge_slots
                 need = self._cand_budget + int(b_over)
                 self._cand_budget = int(2 ** np.ceil(np.log2(need)))
                 budget_retries += 1
                 out = launch(common)
+                edge_slots += self._cand_budget * row_slots
 
         return launch, settle
 
@@ -245,7 +314,7 @@ class _PointStreamRangeQuery(SpatialOperator):
                 with telemetry.span("compute"):
                     out = launch(common)
                 with telemetry.span("fetch"):
-                    keep, dist, _ = settle(out, common)
+                    keep, dist, _, _ = settle(out, common)
                 idx = np.nonzero(keep)[0]
                 objs = [win.events[i] for i in idx]
                 return RangeResult(
@@ -409,7 +478,8 @@ class _PointStreamRangeQuery(SpatialOperator):
             # honest; settle's fetch is the path's one crossing back a
             # window (one more a budget re-run).
             common = (*ship(xy, valid, cell), flags_d)
-            keep, dist, budget_retries = settle(launch(common), common)
+            keep, dist, budget_retries, edge_slots = settle(launch(common),
+                                                            common)
             n = win.count
             with telemetry.span("range.select") as sp:
                 idx = np.nonzero(np.asarray(keep)[:n])[0]
@@ -421,8 +491,9 @@ class _PointStreamRangeQuery(SpatialOperator):
             telemetry.record_range(
                 points=n, lanes=len(valid), matches=len(idx),
                 cand_retries=0, budget_retries=budget_retries,
-                cand=self._ncand if kernel.startswith("pruned") else 0,
+                cand=self._ncand if kernel in INDEXED else 0,
                 budget=self._cand_budget if kernel == "pruned_compact" else 0,
+                edge_slots=edge_slots,
             )
             if win.t0_ns is not None:
                 telemetry.emit_span(

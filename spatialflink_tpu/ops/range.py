@@ -40,8 +40,26 @@ __all__ = [
     "range_points_fused",
     "range_polygons_fused",
     "range_polygons_pruned_fused",
+    "range_polygons_cell_major_fused",
     "range_polylines_fused",
 ]
+
+
+#: points a block of the pruned kernel's ``lax.map``
+POINT_CHUNK = 8192
+#: the cell-major kernel's tile (sorted points evaluated together), step
+#: (index entries a tile reads at a time) and batch (tiles a step
+#: evaluates together), the fastest of the forms timed on a TPU v5e at
+#: range_spider.flood's size (PERF.md)
+CELL_MAJOR_TILE = 512
+CELL_MAJOR_WIDTH = 16
+CELL_MAJOR_BATCH = 32
+#: a query set whose K-wide cell row would hold more edge slots than this
+#: (K, the fullest list up the bucket ladder, × E) is read cell-major
+#: (operators/range_query.py): between the two nearest readings on a TPU
+#: v5e, 256 slots where the K-wide row is 2 × faster and 512 where the
+#: cell-major program is 7.8 × faster (PERF.md)
+CELL_MAJOR_ROW = 256
 
 
 def _emit_mask(valid, flags, min_dist, radius, approximate: bool):
@@ -114,7 +132,7 @@ def range_query_polygons_pruned_kernel(
     flags: jnp.ndarray,
     cell_edges: jnp.ndarray,
     radius,
-    point_chunk: int = 8192,
+    point_chunk: int = POINT_CHUNK,
     approximate: bool = False,
 ):
     """Large-query-set point–polygon range through a grid index.
@@ -189,7 +207,7 @@ def range_query_polygons_pruned_kernel(
 
 
 def range_polygons_pruned_fused(xy, valid, cell, flags_table, cell_edges,
-                                radius, point_chunk: int = 8192,
+                                radius, point_chunk: int = POINT_CHUNK,
                                 approximate: bool = False):
     from spatialflink_tpu.ops.cells import gather_cell_flags
 
@@ -258,6 +276,148 @@ def range_polygons_pruned_compact_fused(
         xy, valid, cell, gather_cell_flags(cell, flags_table), cell_edges,
         radius, budget=budget, point_chunk=point_chunk,
     )
+
+
+def range_polygons_cell_major_fused(
+    xy: jnp.ndarray,
+    valid: jnp.ndarray,
+    cell: jnp.ndarray,
+    flags_table: jnp.ndarray,
+    entry_edges: jnp.ndarray,
+    starts: jnp.ndarray,
+    radius,
+    tile: int = CELL_MAJOR_TILE,
+    width: int = CELL_MAJOR_WIDTH,
+    batch: int = CELL_MAJOR_BATCH,
+    approximate: bool = False,
+):
+    """Point–polygon range through the ragged grid index, cell-major.
+
+    The pruned kernel gathers one K-wide row of the cell table a point: a
+    set whose fullest cell lists K polygons of E edges makes every point
+    evaluate K·E edge slots and the table ``cells × 4·E·K`` floats, however
+    short most lists are. Here every cell's list is one contiguous run of
+    ``entry_edges`` (entries + ``width``, 4 · E) — cell g's entries are
+    ``[starts[g], starts[g + 1])`` (operators/base.py:pack_cell_candidates
+    + pack_entry_edges) — and the window's points are put in cell order on
+    the device (one ``lax.sort`` by cell, an invalid lane as out of grid; x,
+    y and the lane index ride along). A tile of ``tile`` consecutive sorted
+    points then lies in the cells from its first point's to its last
+    point's, and the entries of all of them are one run, ``[starts[first],
+    starts[last + 1])``: the tile is evaluated against that run and nothing
+    else, in ``ceil(run / width)`` steps of ``(width, E, tile)`` blocks,
+    ``batch`` tiles of like steps at a time (:func:`_cell_major_tiles`) — a
+    tile whose cells list nothing costs a step only where its batch holds
+    one that takes some. Keep and distance go back to
+    lane order by a second sort, on the carried lane index (keep in the sign
+    bit of the distance): no scatter.
+
+    Exactness: the run holds every point's own list (its cell lies between
+    the tile's first and last), and whatever a step reads past the run is
+    the next cells' entries or the table's far rows — so a point is
+    evaluated against a superset of its list. Any superset of a list that
+    holds every polygon within ``radius`` (and the float32 band) gives the
+    same keep and the same kept distance: the nearest polygon is in the
+    list, and a minimum over more polygons is the same minimum. A polygon
+    listed twice changes neither. Per (point, edge) the expressions are the pruned
+    kernel's, and "0 inside a polygon, else the least edge distance, least
+    over polygons" is "0 inside any polygon, else the least distance over
+    all edges", so kept lanes agree with it — bit for bit wherever the
+    backend rounds the two layouts' arithmetic alike (XLA:CPU's vector code
+    for a broadcast edge can differ from a gathered one's in the last bits
+    of a coordinate, well inside the float32 band). A dropped lane reports
+    the least distance over its tile's run, or a value past any real
+    distance where the run is empty.
+
+    Returns (keep, min_dist, steps): a lane of a tile evaluated that tile's
+    share of ``steps · width · E`` edge slots, so the window evaluated
+    ``steps · width · E · tile`` of them, padding included.
+    """
+    n = xy.shape[0]
+    n_rows = flags_table.shape[0]  # num_cells + 1: the last is out of grid
+    pad = (-n) % tile
+    lane = jnp.arange(n + pad, dtype=jnp.int32)
+    # an invalid lane sorts as out of grid, whose flag is 0: never kept
+    cell = jnp.where(valid, cell, n_rows - 1)
+    if pad:
+        xy = jnp.concatenate([xy, jnp.zeros((pad, 2), xy.dtype)])
+        cell = jnp.concatenate([cell,
+                                jnp.full((pad,), n_rows - 1, cell.dtype)])
+    cell_s, x_s, y_s, lane_s = jax.lax.sort(
+        (cell, xy[:, 0], xy[:, 1], lane), num_keys=1)
+    n_tiles = (n + pad) // tile
+    lo = starts[cell_s.reshape(n_tiles, tile)[:, 0]]
+    hi = starts[cell_s.reshape(n_tiles, tile)[:, -1] + 1]
+    dist_s, steps = _cell_major_tiles(x_s, y_s, lo, hi, entry_edges, tile,
+                                      width, batch)
+    keep_s = _emit_mask(True, flags_table[cell_s], dist_s, radius,
+                        approximate)
+    # keep rides back in the distance's sign bit (a distance is >= 0): one
+    # payload for the sort back to lane order, both recovered exactly
+    signed = jnp.where(keep_s, dist_s, -dist_s)
+    _lane, signed = jax.lax.sort((lane_s, signed), num_keys=1)
+    return (~jnp.signbit(signed))[:n], jnp.abs(signed)[:n], jnp.sum(steps)
+
+
+def _cell_major_tiles(x_s, y_s, lo, hi, entry_edges, tile, width, batch):
+    """The tile loop. A tile's run takes ``ceil((hi - lo) / width)`` steps
+    of ``width`` entries, all E slots of each. The tiles are put in order of
+    their steps and taken ``batch`` at a time: a batch runs its longest
+    tile's steps, each step evaluating every tile of it against its own next
+    ``width`` entries — a tile past its run reads the entries after it, or
+    the table's far last row where it would run off the end, so it is still
+    evaluated against a superset of its lists. Ordering keeps a batch's
+    tiles of like steps; batching keeps the loop's iterations — device
+    operations, each one a profiler event — to about ``1 / batch`` of one
+    tile at a time. Returns (distance a sorted lane, steps evaluated: a
+    batch's longest run × ``batch``, summed)."""
+    n_tiles = lo.shape[0]
+    steps = (hi - lo + (width - 1)) // width
+    x_t, y_t = x_s.reshape(n_tiles, tile), y_s.reshape(n_tiles, tile)
+    pad = (-n_tiles) % batch
+    if pad:  # tiles of no run, ordered first; their rows are dropped
+        x_t = jnp.concatenate([x_t, jnp.zeros((pad, tile), x_t.dtype)])
+        y_t = jnp.concatenate([y_t, jnp.zeros((pad, tile), y_t.dtype)])
+        lo = jnp.concatenate([lo, jnp.zeros(pad, lo.dtype)])
+        steps = jnp.concatenate([steps, jnp.zeros(pad, steps.dtype)])
+    order = jnp.argsort(steps)
+    steps_b = steps[order].reshape(-1, batch)
+    runs = jnp.max(steps_b, axis=1)
+    big = jnp.asarray(jnp.finfo(x_s.dtype).max, x_s.dtype)
+    reach = jnp.arange(width, dtype=lo.dtype)
+
+    def batch_fn(args):
+        x_g, y_g, lo_g, run = args  # (B, T), (B, T), (B,), ()
+        xg, yg = x_g[:, None, None, :], y_g[:, None, None, :]  # (B, 1, 1, T)
+        p = jnp.stack([xg, yg], axis=-1)
+
+        def step(k, carry):
+            inside, dmin = carry
+            e = jnp.take(entry_edges, lo_g[:, None] + k * width + reach,
+                         axis=0, mode="clip").reshape(batch, width, 4, -1)
+            # (B, W, E, 1) each
+            x1, y1, x2, y2 = (e[:, :, i, :, None] for i in range(4))
+            edge_d = point_segment_distance(
+                p, jnp.stack([x1, y1], axis=-1), jnp.stack([x2, y2], axis=-1)
+            )  # (B, W, E, T)
+            crossings = ray_crosses(xg, yg, x1, y1, x2, y2)
+            odd = jnp.sum(crossings.astype(jnp.int32), axis=2) % 2 == 1
+            return (inside | jnp.any(odd, axis=1),
+                    jnp.minimum(dmin, jnp.min(edge_d, axis=(1, 2))))
+
+        inside, dmin = jax.lax.fori_loop(
+            0, run, step, (jnp.zeros((batch, tile), bool),
+                           jnp.full((batch, tile), big, x_s.dtype)))
+        return jnp.where(inside, jnp.zeros((), x_s.dtype), dmin)
+
+    dist_b = jax.lax.map(batch_fn, (
+        x_t[order].reshape(-1, batch, tile),
+        y_t[order].reshape(-1, batch, tile), lo[order].reshape(-1, batch),
+        runs,
+    ))
+    # back to tile order: the inverse permutation, one row gather
+    dist = dist_b.reshape(-1, tile)[jnp.argsort(order)][:n_tiles]
+    return dist.reshape(-1), jnp.sum(runs) * batch
 
 
 def _chunked_min_over_geoms(one_fn, verts, edge_valid, chunk):

@@ -342,9 +342,10 @@ class Telemetry:
         # record_range): counters windows / points / lanes / matches /
         # cand_retries / budget_retries / index_windows and the gauges
         # cand / budget (the cell table's slots a cell and the compact
-        # kernel's lane budget in use); the gauges index_slots /
-        # index_entries / index_cells of a polygon set's cell table
-        # (record_range_index, once an evaluator) — snapshot()["range"],
+        # kernel's lane budget in use), the counter edge_slots (polygon
+        # edges the programs evaluated); the gauges index_slots /
+        # index_entries / index_cells / index_bytes of a polygon set's grid
+        # index (record_range_index, once an evaluator) — snapshot()["range"],
         # empty until the first evaluator or window.
         self._range: Dict[str, int] = {}
         # A fired SoA window's device lanes (operators/base.py:point_lanes
@@ -1221,7 +1222,7 @@ class Telemetry:
 
     def record_range(self, points: int, lanes: int, matches: int,
                      cand_retries: int, budget_retries: int, cand: int,
-                     budget: int):
+                     budget: int, edge_slots: int):
         """One window of the SoA point range query, fetched: its
         ``points``, the ``lanes`` shipped for them (the padding bucket),
         the ``matches`` the host selected, the re-runs the compact polygon
@@ -1230,10 +1231,15 @@ class Telemetry:
         candidates off the cell table: nothing there can overflow), and the
         sizes the window ran with: ``cand`` = K, the cell table's slots a
         cell, and ``budget`` (0 where the kernel that ran has no such
-        size). Lands in ``snapshot()["range"]`` as the counters ``windows``,
-        ``points``, ``lanes``, ``matches``, ``cand_retries``,
-        ``budget_retries``, ``index_windows`` (windows answered through the
-        cell table: those with ``cand`` > 0) and the gauges ``cand``,
+        size), and the polygon ``edge_slots`` its program evaluated,
+        padding and re-runs included (counted from the kernel's plan: lanes
+        × K × E through a cell-table row; cell-major, tile × the steps of
+        each batch of tiles, a step ``width`` entries of E slots for every
+        tile of the batch; 0 for a point or linestring set). Lands in
+        ``snapshot()["range"]`` as the counters ``windows``, ``points``,
+        ``lanes``, ``matches``, ``cand_retries``, ``budget_retries``,
+        ``index_windows`` (windows answered through the cell table: those
+        with ``cand`` > 0), ``edge_slots`` and the gauges ``cand``,
         ``budget``. Per window, never per event."""
         if not self.enabled:
             return
@@ -1243,23 +1249,28 @@ class Telemetry:
                            ("lanes", lanes), ("matches", matches),
                            ("cand_retries", cand_retries),
                            ("budget_retries", budget_retries),
-                           ("index_windows", cand > 0)):
+                           ("index_windows", cand > 0),
+                           ("edge_slots", edge_slots)):
                 r[key] = r.get(key, 0) + int(n)
             r["cand"], r["budget"] = int(cand), int(budget)
 
-    def record_range_index(self, slots: int, entries: int, cells: int):
-        """The cell → candidate-polygons table of a polygon range query,
+    def record_range_index(self, slots: int, entries: int, cells: int,
+                           nbytes: int):
+        """The cell → candidate-polygons index of a polygon range query,
         once when its evaluator is built: gauges ``index_slots`` (K),
-        ``index_entries`` (Σ list lengths) and ``index_cells`` (cells with
-        a non-empty list) of ``snapshot()["range"]``. ``index_entries ÷
-        (index_cells × index_slots)`` is the share of the slots gathered in
-        non-empty rows that hold a real polygon."""
+        ``index_entries`` (Σ list lengths), ``index_cells`` (cells with
+        a non-empty list) and ``index_bytes`` (what the index the kernel
+        reads holds on the device: the K-wide edge rows, or the ragged edge
+        table and its run starts) of ``snapshot()["range"]``.
+        ``index_entries ÷ (index_cells × index_slots)`` is the share of the
+        slots gathered in non-empty rows that hold a real polygon."""
         if not self.enabled:
             return
         with self._lock:
             self._range.update(index_slots=int(slots),
                                index_entries=int(entries),
-                               index_cells=int(cells))
+                               index_cells=int(cells),
+                               index_bytes=int(nbytes))
 
     def record_soa_lanes(self, points: int, lanes: int, blocks: int):
         """One point slice made into device lanes by ``operators/base.py:

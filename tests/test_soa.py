@@ -465,9 +465,10 @@ def test_soa_point_linestring_range_matches_object_path(rng):
     assert soa == obj and soa
 
 
-def test_soa_large_polygon_set_uses_pruned_path(rng):
-    """run_soa with >=64 exact-mode polygons rides the pruned/compact
-    evaluator (parity + the operator grows persistent budgets)."""
+def _soa_and_object_paths(rng, spread, segments=4):
+    """70 exact-mode rectangles (or regular rings of ``segments`` edges
+    about the same centres) over [1, ``spread``]² against 2,000 points:
+    ``run_soa``'s and ``run``'s matches a window, and the SoA operator."""
     from spatialflink_tpu.models.objects import Polygon
     from spatialflink_tpu.operators import PointPolygonRangeQuery
 
@@ -479,10 +480,14 @@ def test_soa_large_polygon_set_uses_pruned_path(rng):
     conf = QueryConfiguration(QueryType.WindowBased, window_size=10, slide_step=10)
     polys = []
     for i in range(70):
-        cx, cy = rng.uniform(1, 3), rng.uniform(1, 3)
-        polys.append(Polygon(rings=[np.array(
-            [[cx - .1, cy - .1], [cx + .1, cy - .1], [cx + .1, cy + .1],
-             [cx - .1, cy + .1], [cx - .1, cy - .1]])]))
+        cx, cy = rng.uniform(1, spread), rng.uniform(1, spread)
+        if segments == 4:
+            ring = [[cx - .1, cy - .1], [cx + .1, cy - .1], [cx + .1, cy + .1],
+                    [cx - .1, cy + .1], [cx - .1, cy - .1]]
+        else:
+            a = 2 * np.pi * np.arange(segments + 1) / segments
+            ring = np.stack([cx + .1 * np.cos(a), cy + .1 * np.sin(a)], 1)
+        polys.append(Polygon(rings=[np.array(ring)]))
     r = 0.15
 
     op = PointPolygonRangeQuery(conf, GRID)
@@ -500,5 +505,25 @@ def test_soa_large_polygon_set_uses_pruned_path(rng):
         )
         for res in PointPolygonRangeQuery(conf, GRID).run(iter(pts), polys, r)
     }
+    return soa, obj, op
+
+
+def test_soa_large_polygon_set_uses_pruned_path(rng):
+    """run_soa with >=64 exact-mode polygons rides the pruned/compact
+    evaluator (parity + the operator grows persistent budgets)."""
+    soa, obj, op = _soa_and_object_paths(rng, 3)
+    assert op.last_range_kernel == "pruned_compact"
     assert soa == obj
     assert op._cand_budget > 64  # the growth persisted
+
+
+def test_soa_crowded_polygon_set_reads_the_index_cell_major(rng):
+    """The same 70 centres crowded into [1, 3]², each a ring of 32 edges:
+    the fullest cell lists 17-32 (K 32), so a K-wide row holds 32 × 32
+    edge slots, past the switch: ``run_soa`` reads the index cell-major
+    (whatever the flags' occupancy, 0.15 here) and matches ``run``'s
+    windows; the compact budget is never asked."""
+    soa, obj, op = _soa_and_object_paths(rng, 3, segments=32)
+    assert op.last_range_kernel == "cell_major" and op._ncand == 32
+    assert soa == obj and any(soa.values())
+    assert op._cand_budget == 64

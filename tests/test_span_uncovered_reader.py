@@ -86,6 +86,9 @@ TJOIN = ["tjoin.flood"]
 #: the crowded twin of ``join.flood`` (PR 41) runs the join's whole path, so
 #: it joined, at its end, every list ``join.flood`` is on
 SKEW = ["join_skew.flood"]
+#: geofencing against Spider's zones runs the range family's whole SoA path,
+#: so it joined, at its end, every list ``range_poly.flood`` is on
+SPIDER = ["range_spider.flood"]
 #: metric -> (what it reads, its cells, its layer)
 METRICS = {
     "wire_pane_us_per_event": ("wire.pane", WIRE, "ship_fetch"),
@@ -102,17 +105,21 @@ METRICS = {
     "join_capacity_us_per_event": ("join.capacity", JOIN + TJOIN + SKEW,
                                    "operators"),
     "join_d2h_wait_us_per_event": ("d2h.wait", JOIN + SKEW, "ship_fetch"),
-    "range_window_us_per_event": ("range.window", RANGE, "ship_fetch"),
-    "range_unspanned_us_per_event": (("range.window",), RANGE, "operators"),
-    "range_d2h_wait_us_per_event": ("d2h.wait", RANGE, "ship_fetch"),
+    "range_window_us_per_event": ("range.window", RANGE + SPIDER,
+                                  "ship_fetch"),
+    "range_unspanned_us_per_event": (("range.window",), RANGE + SPIDER,
+                                     "operators"),
+    "range_d2h_wait_us_per_event": ("d2h.wait", RANGE + SPIDER, "ship_fetch"),
     "soa_consolidate_us_per_event": ("soa.consolidate",
-                                     RANGE + JOIN + TJOIN + SKEW,
+                                     RANGE + JOIN + TJOIN + SKEW + SPIDER,
                                      "host_ingest"),
-    "soa_center_us_per_event": ("soa.center", RANGE + JOIN + TJOIN + SKEW,
+    "soa_center_us_per_event": ("soa.center",
+                                RANGE + JOIN + TJOIN + SKEW + SPIDER,
                                 "host_ingest"),
-    "soa_cells_us_per_event": ("soa.cells", RANGE + JOIN + TJOIN + SKEW,
+    "soa_cells_us_per_event": ("soa.cells",
+                               RANGE + JOIN + TJOIN + SKEW + SPIDER,
                                "host_ingest"),
-    "soa_pad_us_per_event": ("soa.pad", RANGE + JOIN + TJOIN + SKEW,
+    "soa_pad_us_per_event": ("soa.pad", RANGE + JOIN + TJOIN + SKEW + SPIDER,
                              "host_ingest"),
 }
 
@@ -200,13 +207,17 @@ def test_tjoin_metric_file_reads_what_the_program_emits(name):
 def test_benchmark_json_only_grew():
     """The 18 entries stand where they stood, in the order they came, the
     trajectory join's eight behind them, the crowded join's three after
-    those and the join's wait for its producer at the end of ``per_layer``,
-    and the file stays well inside its size limit."""
+    those, the join's wait for its producer and the Spider zones' three at
+    the end of ``per_layer``, and the file stays well inside its size
+    limit."""
     names = [m["name"] for m in spec.benchmark()["per_layer"]]
     assert len(names) == len(set(names))
-    assert set(names[-30:-12]) == set(METRICS)
-    assert set(names[-12:-4]) == set(TJOIN_METRICS)
-    assert names[-4:] == ["join_skew_extract_roofline", "join_lanes_per_pair",
-                          "join_pairs_per_window", "join_await_us_per_event"]
+    assert set(names[-33:-15]) == set(METRICS)
+    assert set(names[-15:-7]) == set(TJOIN_METRICS)
+    assert names[-7:-3] == ["join_skew_extract_roofline",
+                            "join_lanes_per_pair", "join_pairs_per_window",
+                            "join_await_us_per_event"]
+    assert names[-3:] == ["range_spider_roofline",
+                          "range_edge_slots_per_point", "range_index_bytes"]
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         assert len(f.read()) < 64 * 1024

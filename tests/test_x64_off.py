@@ -67,7 +67,8 @@ CASES = {
            for backend in ("xla", "pallas_interpret")]
     ),
     "tjoin": ["run_soa.uniform", "run_soa.trips"],
-    "range": ["run_soa.dense", "run_soa.pruned", "run_soa.pruned_compact"],
+    "range": ["run_soa.dense", "run_soa.pruned", "run_soa.pruned_compact",
+              "run_soa.cell_major"],
     "numerics": (
         [f"center_coords.{name}" for name, _ in GRIDS]
         + [f"contains_any_zone.{z}" for z in ZONE_SETS]
@@ -491,8 +492,10 @@ def child_range():
     the reference's (float32 ray casting and distances on centred
     coordinates against float64 on raw degrees)."""
     from __graft_entry__ import BEIJING_GRID_ARGS
+    from benchmark.references import spider_gaussian, spider_polygons
     from benchmark.references.range_polygons import Reference
     from spatialflink_tpu.grid import UniformGrid
+    from spatialflink_tpu.models.objects import Polygon
     from spatialflink_tpu.operators import (
         PointPolygonRangeQuery,
         QueryConfiguration,
@@ -512,10 +515,20 @@ def child_range():
     conf = QueryConfiguration(QueryType.WindowBased, window_size=10,
                               slide_step=10)
     out = {}
+    # 300 Spider zones crowding the centre (sigma 0.03) with the points: a
+    # K-wide row past the cell-major switch, rings of up to 32 segments
+    zones = [Polygon(obj_id=f"zone{i}", rings=[r]) for i, r in enumerate(
+        spider_polygons.polygons(47, 300, bbox, polysize=0.01, maxseg=32,
+                                 sigma=0.03))]
+    zx, zy = spider_gaussian.positions(window["x"], window["y"], bbox,
+                                       sigma=0.03)
     for kernel, count in (("dense", 40), ("pruned", 400),
-                          ("pruned_compact", 90)):
-        polygons = generate_query_polygons(count, *bbox, grid_size=100,
-                                           seed=count)
+                          ("pruned_compact", 90), ("cell_major", 0)):
+        if kernel == "cell_major":
+            polygons, window = zones, dict(window, x=zx, y=zy)
+        else:
+            polygons = generate_query_polygons(count, *bbox, grid_size=100,
+                                               seed=count)
         ref = Reference(bbox=bbox, grid_cells=100, radius=radius, tol=tol,
                         polygons=[[np.asarray(r) for r in p.rings]
                                   for p in polygons])
