@@ -14,9 +14,13 @@ CountBased uses count windows.
 
 from __future__ import annotations
 
+import ctypes
 import functools
+import queue
+import threading
 import time
 from typing import (
+    Any,
     Callable,
     Iterable,
     Iterator,
@@ -598,9 +602,7 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
     assembler's firing) and ``point_lanes``' three: ``soa.center`` (the
     columns centred in float64 blocks, cast on assignment into ``xy``),
     ``soa.cells`` (the cells, a block at a time, into ``cell``), ``soa.pad``
-    (the tails, ``valid``, ``oid``). The clock reading it opens at goes on
-    with the window (``win.t0_ns``), for the operator's parent span to open
-    at the same instant.
+    (the tails, ``valid``, ``oid``).
 
     ``counted=False`` leaves each window's ``counters.record_window`` to the
     caller: a stream materialised on a producer thread, whose consumer keeps
@@ -633,7 +635,6 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
             return
         t0 = time.perf_counter_ns()
         for win in fire():
-            win.t0_ns = t0
             item = batch(win)
             telemetry.emit_span(span, t0, time.perf_counter_ns() - t0,
                                 n=win.count, bucket=len(item[2]))
@@ -644,6 +645,126 @@ def soa_point_batches(grid: UniformGrid, chunks, conf: QueryConfiguration,
         if asm.take(c):
             yield from fired(asm.fire)
     yield from fired(asm.flush)
+
+
+class _Closed(Exception):
+    """Raised at the producer's next chunk pull once the loop has gone."""
+
+
+class _Raised(NamedTuple):
+    """What the producer raised, handed over in place of its item."""
+
+    error: BaseException
+
+
+_DONE = object()  # the producer's last hand-over: its items have ended
+
+_M_TOP_PAD = -2  # <malloc.h>
+_THREAD_HEAP_BYTES = 64 << 20  # glibc's largest heap of a thread's arena
+
+
+@functools.lru_cache(maxsize=None)
+def _keep_thread_heaps() -> None:
+    """Keep the producer's malloc heaps from one window to the next.
+
+    glibc gives a second thread an arena of its own, made of 64 MB heaps,
+    and unmaps a heap as soon as it is wholly free, whatever trim threshold
+    the deployment pinned: the heap a window's arrays emptied is mapped anew
+    by a later window and every page of it touched for the first time
+    (≈ 9,000 page faults every other window at 500,000 points a side; on
+    the chip's host 4–10 µs a page). A top pad of one heap keeps every heap
+    (``heap_trim`` lets a heap go only when more than the pad would be left
+    free). Process-wide, once; like any ``mallopt`` it also fixes an
+    adaptive mmap threshold where it stands. Nothing where libc has none."""
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return
+    mallopt(_M_TOP_PAD, _THREAD_HEAP_BYTES)
+
+
+def assembled_ahead(sources: Sequence[Iterable], items: Callable[..., Iterable],
+                    thread: str, span: str,
+                    ready: Callable[[Any], None]) -> Iterator[Tuple[Any, Any]]:
+    """The items of a per-window generator, made one item ahead of the loop
+    on a producer thread — the single home of the ``run_soa`` loops' overlap
+    of a window's assembly with the device's work on the window before.
+
+    ``items(*pulls)`` builds the generator from the chunk sources
+    ``sources``, each handed over as a pull of its chunks; it is called on
+    the producer thread, named ``thread`` (a daemon). Yields ``(item,
+    asked_ns)``: the ``perf_counter_ns`` at which the loop asked for the
+    item (None with telemetry off), where the operator's parent span opens.
+
+    The producer hands each item over through a one-slot queue and makes
+    item n + 1 only once the loop has taken item n: it is one window ahead,
+    never more, and assembles window n + 1 while the loop ships, runs and
+    fetches window n (``block_until_ready`` and the queues' waits let go of
+    the GIL). The loop pulls no chunk, so it never waits on a source while
+    it holds a window: window n goes out with no pull after its trigger on
+    the loop's thread, and where a source paces, the producer waits on it
+    beside the loop. The producer is for numpy only — the chunk sources,
+    the assemblers, ``point_lanes`` on the stream's own ``lane_scratch()``
+    (the lanes it returns are fresh every window, so nothing handed over
+    aliases the scratch) — and emits the assembly spans on its own thread;
+    the operator's state, every JAX call and every counter stay on the
+    loop's.
+
+    With telemetry on, the loop's thread emits ``span`` around each wait
+    for the producer's next item. ``ready(item)`` is called on it for each
+    item that was made before the loop asked for it (where the operator
+    counts the windows assembled beside the loop). What the producer raises is raised
+    on the loop's thread at the item the synchronous generator would have
+    raised it at. Closing the generator stops the producer at its next chunk
+    pull (the open windows are not flushed) and joins it before returning.
+    The producer's malloc heaps are kept from window to window
+    (``_keep_thread_heaps``)."""
+    slot: queue.Queue = queue.Queue(maxsize=1)  # producer → loop
+    asks: queue.Queue = queue.Queue()  # loop → producer: True, or False: stop
+    closed = threading.Event()
+
+    def pulled(chunks):
+        it = iter(chunks)
+        while not closed.is_set():
+            try:
+                chunk = next(it)
+            except StopIteration:
+                return
+            yield chunk
+        raise _Closed
+
+    def produce():
+        try:
+            made = iter(items(*map(pulled, sources)))
+            while asks.get():
+                slot.put(next(made, _DONE))
+        except _Closed:
+            pass
+        except BaseException as e:  # noqa: BLE001 — the loop's to raise
+            slot.put(_Raised(e))
+
+    _keep_thread_heaps()
+    producer = threading.Thread(target=produce, name=thread, daemon=True)
+    asks.put(True)
+    producer.start()
+    try:
+        while True:
+            asked = time.perf_counter_ns() if telemetry.enabled else None
+            was_ready = not slot.empty()
+            with telemetry.span(span):
+                item = slot.get()
+            if item is _DONE:
+                return
+            if isinstance(item, _Raised):
+                raise item.error
+            asks.put(True)  # the next window, while the loop runs this one
+            if was_ready:
+                ready(item)
+            yield item, asked
+    finally:
+        closed.set()
+        asks.put(False)
+        producer.join()
 
 
 @functools.lru_cache(maxsize=None)

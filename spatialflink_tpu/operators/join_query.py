@@ -17,11 +17,8 @@ PointPointJoinQuery.java:128-146).
 
 from __future__ import annotations
 
-import ctypes
 import functools
 import heapq
-import queue
-import threading
 import time
 from dataclasses import dataclass
 from typing import Iterable, Iterator, List, NamedTuple, Sequence, Tuple
@@ -31,7 +28,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from spatialflink_tpu.models.objects import LineString, Point, Polygon, SpatialObject
-from spatialflink_tpu.operators.base import SpatialOperator, jitted, ship
+from spatialflink_tpu.operators.base import (
+    SpatialOperator,
+    assembled_ahead,
+    jitted,
+    ship,
+)
 from spatialflink_tpu.ops.compaction import max_cell_count, pick_capacity
 from spatialflink_tpu.telemetry import instrument_jit, telemetry
 from spatialflink_tpu.utils.padding import next_bucket
@@ -969,42 +971,6 @@ def _record_windows(*sides) -> None:
                 counters.record_window(w[0].count, 0, 0)
 
 
-class _Closed(Exception):
-    """Raised at the producer's next chunk pull once the loop has gone."""
-
-
-class _Raised(NamedTuple):
-    """What the producer raised, handed over in place of its item."""
-
-    error: BaseException
-
-
-_DONE = object()  # the producer's last hand-over: both streams have ended
-
-_M_TOP_PAD = -2  # <malloc.h>
-_THREAD_HEAP_BYTES = 64 << 20  # glibc's largest heap of a thread's arena
-
-
-@functools.lru_cache(maxsize=None)
-def _keep_thread_heaps() -> None:
-    """Keep the producer's malloc heaps from one window to the next.
-
-    glibc gives a second thread an arena of its own, made of 64 MB heaps,
-    and unmaps a heap as soon as it is wholly free, whatever trim threshold
-    the deployment pinned: the heap a window's arrays emptied is mapped anew
-    by a later window and every page of it touched for the first time
-    (≈ 9,000 page faults every other window at 500,000 points a side; on
-    the chip's host 4–10 µs a page). A top pad of one heap keeps every heap
-    (``heap_trim`` lets a heap go only when more than the pad would be left
-    free). Process-wide, once; like any ``mallopt`` it also fixes an
-    adaptive mmap threshold where it stands. Nothing where libc has none."""
-    try:
-        mallopt = ctypes.CDLL(None).mallopt
-    except (OSError, AttributeError):
-        return
-    mallopt(_M_TOP_PAD, _THREAD_HEAP_BYTES)
-
-
 def _aligned_soa_windows(left_chunks, right_chunks, windows_l, windows_r,
                          start_l, start_r):
     """Align two per-window streams on their shared slide grid, assembled one
@@ -1019,47 +985,19 @@ def _aligned_soa_windows(left_chunks, right_chunks, windows_l, windows_r,
     ``perf_counter_ns`` at which the loop asked for the item (None with
     telemetry off), where the operator's parent span opens.
 
-    Both sides' chunk pulls, their assemblers and the merge run on a producer
-    thread, which hands each item over through a one-slot queue and makes
-    item n + 1 only once the loop has taken item n: it is one window ahead,
-    never more, and assembles window n + 1 while the loop ships, joins and
-    fetches window n (``block_until_ready`` and the queues' waits let go of
-    the GIL). The loop pulls no chunk, so it never waits on the source while
-    it holds a window: window n goes out with no pull after its trigger on
-    the loop's thread, and where the source paces, the producer waits on it
-    beside the loop. The producer runs numpy only — the chunk sources, the
-    assemblers, ``point_lanes`` on each side's own ``lane_scratch()`` (the
-    lanes it returns are fresh every window, so nothing handed over aliases
-    the scratch) — and emits the assembly spans (``join.assemble_left``,
-    ``join.assemble``, ``soa.*``) on its own thread; the operator's state,
-    every JAX call and every counter stay on the loop's.
+    Both sides' chunk pulls, their assemblers and the merge run on the
+    producer thread ``join-assembly`` of ``base.assembled_ahead``, one
+    window ahead of the loop; it emits the assembly spans
+    (``join.assemble_left``, ``join.assemble``, ``soa.*``), and the loop's
+    thread ``join.await`` around each wait for it. ``record_join_prefetch``
+    counts the two-sided windows that were ready when asked for
+    (``snapshot()["join"]["prefetched"]``). What a side raises is raised on
+    the loop's thread at the item the merge would have raised it at;
+    closing the generator stops the producer at its next chunk pull."""
 
-    With telemetry on, the loop's thread emits ``join.await`` around each
-    wait for the producer's next item, and ``record_join_prefetch`` counts
-    the two-sided windows that were ready when asked for
-    (``snapshot()["join"]["prefetched"]``). What the producer raises is
-    raised on the loop's thread at the item the merge would have raised it
-    at. Closing the generator stops the producer at its next chunk pull
-    (the open windows are not flushed) and joins it before returning. The
-    producer's malloc heaps are kept from window to window
-    (``_keep_thread_heaps``)."""
-    slot: queue.Queue = queue.Queue(maxsize=1)  # producer → loop
-    asks: queue.Queue = queue.Queue()  # loop → producer: True, or False: stop
-    closed = threading.Event()
-
-    def pulled(chunks):
-        it = iter(chunks)
-        while not closed.is_set():
-            try:
-                chunk = next(it)
-            except StopIteration:
-                return
-            yield chunk
-        raise _Closed
-
-    def merged():
-        gen_l = iter(windows_l(pulled(left_chunks)))
-        gen_r = iter(windows_r(pulled(right_chunks)))
+    def merged(lefts, rights):
+        gen_l = iter(windows_l(lefts))
+        gen_r = iter(windows_r(rights))
         wl = next(gen_l, None)
         wr = next(gen_r, None)
         while wl is not None or wr is not None:
@@ -1074,39 +1012,14 @@ def _aligned_soa_windows(left_chunks, right_chunks, windows_l, windows_r,
                 wl = next(gen_l, None)
                 wr = next(gen_r, None)
 
-    def produce():
-        items = merged()
-        try:
-            while asks.get():
-                slot.put(next(items, _DONE))
-        except _Closed:
-            pass
-        except BaseException as e:  # noqa: BLE001 — the loop's to raise
-            slot.put(_Raised(e))
+    def ready(item):
+        if item[0] == "both":
+            telemetry.record_join_prefetch()
 
-    _keep_thread_heaps()
-    producer = threading.Thread(target=produce, name="join-assembly",
-                                daemon=True)
-    asks.put(True)
-    producer.start()
-    try:
-        while True:
-            asked = time.perf_counter_ns() if telemetry.enabled else None
-            ready = not slot.empty()
-            with telemetry.span("join.await"):
-                item = slot.get()
-            if item is _DONE:
-                return
-            if isinstance(item, _Raised):
-                raise item.error
-            asks.put(True)  # the next window, while the loop runs this one
-            if ready and item[0] == "both":
-                telemetry.record_join_prefetch()
-            yield (*item, asked)
-    finally:
-        closed.set()
-        asks.put(False)
-        producer.join()
+    for item, asked in assembled_ahead(
+            (left_chunks, right_chunks), merged, "join-assembly",
+            "join.await", ready):
+        yield (*item, asked)
 
 
 @functools.lru_cache(maxsize=None)

@@ -29,6 +29,7 @@ from spatialflink_tpu.models.batch import GeometryBatch, PointBatch
 from spatialflink_tpu.models.objects import LineString, Point, Polygon, SpatialObject
 from spatialflink_tpu.operators.base import (
     SpatialOperator,
+    assembled_ahead,
     center_coords,
     count_window_batches,
     flags_for_queries,
@@ -39,6 +40,7 @@ from spatialflink_tpu.operators.base import (
     pack_query_geometries,
     pack_query_points,
     ship,
+    soa_point_batches,
     window_program,
 )
 from spatialflink_tpu.ops.range import (
@@ -451,13 +453,23 @@ class _PointStreamRangeQuery(SpatialOperator):
         linestring query sets), with run()'s exact kernel selection —
         including the pruned/compact large-polygon-set paths.
 
-        With telemetry on, one parent span ``range.window`` a window
-        (args ``n``), emitted by hand at the hand-back: from
-        ``range.assemble``'s start (``win.t0_ns``) to just before the
-        yield, so it holds ``range.assemble``, ``h2d``, ``dispatch:*``,
-        ``d2h`` and ``range.select`` and none of the consumer's time."""
-        from spatialflink_tpu.operators.base import soa_point_batches
+        The window is assembled one window ahead on the producer thread
+        ``range-assembly`` (``base.assembled_ahead``): window n + 1's chunk
+        pulls and ``soa_point_batches`` (``range.assemble``, ``soa.*``,
+        emitted on that thread) run while this loop ships, runs, fetches and
+        selects window n; the loop pulls no chunk, so a window's result goes
+        out with no pull after its trigger. The op counters, every JAX call,
+        ``range.select`` and ``record_range`` stay on the loop's thread, and
+        the results are the synchronous loop's.
 
+        With telemetry on, one parent span ``range.window`` a window (args
+        ``n``), emitted by hand at the hand-back: from the moment the loop
+        asks for the window to just before the yield, so it holds
+        ``range.await`` (the wait for the producer: near nothing when the
+        window was assembled beside the last one), ``h2d``, ``dispatch:*``,
+        ``d2h`` and ``range.select`` and none of the consumer's time;
+        ``record_range_prefetch`` counts the windows that were ready when
+        asked for (``snapshot()["range"]["prefetched"]``)."""
         if not isinstance(query_set, (list, tuple)):
             query_set = [query_set]
         flags = flags_for_queries(self.grid, radius, query_set)
@@ -467,10 +479,16 @@ class _PointStreamRangeQuery(SpatialOperator):
         kernel = self.last_range_kernel
         from spatialflink_tpu.ops.counters import count_candidates, counters
 
-        for win, xy, valid, cell, _ in soa_point_batches(
-            self.grid, chunks, self.conf, dtype, span="range.assemble"
+        def windows(pulls):
+            return soa_point_batches(self.grid, pulls, self.conf, dtype,
+                                     span="range.assemble", counted=False)
+
+        for (win, xy, valid, cell, _), asked_ns in assembled_ahead(
+            (chunks,), windows, "range-assembly", "range.await",
+            lambda _item: telemetry.record_range_prefetch(),
         ):
             if counters.enabled:
+                counters.record_window(win.count, 0, 0)
                 cand = count_candidates(flags, cell, win.count)
                 counters.record_candidates(cand, cand * len(query_set))
             # ship/fetch through telemetry: the oid lane is NOT shipped on
@@ -495,10 +513,10 @@ class _PointStreamRangeQuery(SpatialOperator):
                 budget=self._cand_budget if kernel == "pruned_compact" else 0,
                 edge_slots=edge_slots,
             )
-            if win.t0_ns is not None:
+            if asked_ns is not None:
                 telemetry.emit_span(
-                    "range.window", win.t0_ns,
-                    time.perf_counter_ns() - win.t0_ns, n=n,
+                    "range.window", asked_ns,
+                    time.perf_counter_ns() - asked_ns, n=n,
                 )
             yield win.start, win.end, matched, dists
 

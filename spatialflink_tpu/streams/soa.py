@@ -39,11 +39,6 @@ class SoaWindow:
     start: int
     end: int
     arrays: Dict[str, np.ndarray]  # each (n,), same order, incl. "ts"
-    #: ``perf_counter_ns`` at which ``soa_point_batches`` began to
-    #: materialise this window under a ``span``: where the operator's
-    #: parent span (``range.window``, ``join.window``) opens. None
-    #: wherever that span was not timed (telemetry off, no ``span``).
-    t0_ns: Optional[int] = None
 
     @property
     def count(self) -> int:

@@ -345,8 +345,10 @@ class Telemetry:
         # kernel's lane budget in use), the counter edge_slots (polygon
         # edges the programs evaluated); the gauges index_slots /
         # index_entries / index_cells / index_bytes of a polygon set's grid
-        # index (record_range_index, once an evaluator) — snapshot()["range"],
-        # empty until the first evaluator or window.
+        # index (record_range_index, once an evaluator), and prefetched
+        # (record_range_prefetch: windows the range query's producer thread
+        # had assembled when its loop asked) — snapshot()["range"], empty
+        # until the first evaluator or window.
         self._range: Dict[str, int] = {}
         # A fired SoA window's device lanes (operators/base.py:point_lanes
         # via record_soa_lanes): counters windows / points / lanes (Σ
@@ -1178,7 +1180,8 @@ class Telemetry:
     def record_join_prefetch(self):
         """One two-sided window of a join's ``run_soa`` that the producer
         thread had assembled before the loop asked for it
-        (``operators/join_query.py:_aligned_soa_windows``). Lands in
+        (``operators/join_query.py:_aligned_soa_windows``, on
+        ``operators/base.py:assembled_ahead``). Lands in
         ``snapshot()["join"]`` as the counter ``prefetched`` (the trajectory
         join's too); ``prefetched ÷ windows`` = how often assembly ran beside
         the loop. Per window, never per event."""
@@ -1253,6 +1256,19 @@ class Telemetry:
                            ("edge_slots", edge_slots)):
                 r[key] = r.get(key, 0) + int(n)
             r["cand"], r["budget"] = int(cand), int(budget)
+
+    def record_range_prefetch(self):
+        """One window of the range query's ``run_soa`` that the producer
+        thread had assembled before the loop asked for it
+        (``operators/base.py:assembled_ahead``). Lands in
+        ``snapshot()["range"]`` as the counter ``prefetched``;
+        ``prefetched ÷ windows`` = how often assembly ran beside the loop.
+        Per window, never per event."""
+        if not self.enabled:
+            return
+        with self._lock:
+            r = self._range
+            r["prefetched"] = r.get("prefetched", 0) + 1
 
     def record_range_index(self, slots: int, entries: int, cells: int,
                            nbytes: int):

@@ -299,10 +299,13 @@ def test_every_crossing_is_a_leaf_and_the_spans_hold_none(case):
     assert all(e["args"]["bucket"] >= n for e in assemble)
     assert [e["args"]["matches"] for e in select] == [
         len(d) for _s, _e, _m, d in got]
+    # the assembly is the producer thread's, every crossing the loop's
+    loop = {e["tid"] for e in select}
+    assert len(loop) == 1 and not loop & {e["tid"] for e in assemble}
     leaves = [e for e in events if e["name"] in ("h2d", "d2h")
               or e["name"].startswith("dispatch:")]
-    assert leaves and not any(
-        inside(leaf, sp) for leaf in leaves for sp in assemble + select)
+    assert leaves and {e["tid"] for e in leaves} == loop and not any(
+        inside(leaf, sp) for leaf in leaves for sp in select)
     # one ship a window whatever the re-runs; one fetch a run of the program,
     # and nothing crosses unseen
     assert len(by("h2d")) == 2 + 1  # + the query set's tables, shipped once
@@ -312,10 +315,13 @@ def test_every_crossing_is_a_leaf_and_the_spans_hold_none(case):
 
 @pytest.mark.parametrize("case", ["pruned", "compact_budget_retry"])
 def test_range_window_parent_tiles_the_window_and_no_consumer_time(case):
-    """One ``range.window`` a window, from ``range.assemble``'s own start to
-    the hand-back: every span of the loop lies inside one, the four ``soa.*``
-    passes once a window inside ``range.assemble``, each ``d2h`` holds its one
-    ``d2h.wait``, and the answers are the telemetry-off run's."""
+    """One ``range.window`` a window, from the loop's ask for it to the
+    hand-back: the wait for the producer, the ship, every run of the program,
+    each fetch and the selection lie inside it, the consumer's time does not,
+    and the answers are the telemetry-off run's. The assembly runs on the
+    producer thread: ``range.assemble`` and its four ``soa.*`` passes, once a
+    window, lie in none of the loop's parents; each ``d2h`` holds its one
+    ``d2h.wait``."""
     _make, n, _kernel, _slots, budget_retries = CASES[case]
     _op, plain, _pw, _ref = _run(case)
     naps = []
@@ -333,17 +339,27 @@ def test_range_window_parent_tiles_the_window_and_no_consumer_time(case):
         assert all(np.array_equal(m1[k], m2[k]) for k in m1)
     parents, inner = assert_parents_tile(events, "range.window", naps)
     assert [p["args"]["n"] for p in parents] == [n, n]
-    # nothing of the loop is outside a parent but the query set's one ship
-    loose = [e["name"] for e in events if e["name"] != "range.window"
+    loop = parents[0]["tid"]
+    # outside every parent on the loop's thread: the query set's one ship and
+    # the last ask, which finds the stream at an end
+    loose = [e["name"] for e in events if e["tid"] == loop
+             and e["name"] != "range.window"
              and not any(inside(e, p) for p in parents)]
-    assert loose == ["h2d"]
+    assert loose == ["h2d", "range.await"]
     passes = ["soa.consolidate", "soa.center", "soa.cells", "soa.pad"]
     assemble = [e for e in events if e["name"] == "range.assemble"]
+    assert len(assemble) == 2
+    assert {e["tid"] for e in assemble} != {loop}
+    assert len({e["tid"] for e in assemble}) == 1  # one producer thread
     for p, names, asm in zip(parents, inner, assemble):
-        assert p["ts"] == asm["ts"]  # one clock reading opens both
-        assert names.count("range.assemble") == names.count("h2d") == 1
-        assert names.count("range.select") == 1
-        mine = [e for e in events if e["name"] in passes and inside(e, asm)]
+        assert p["tid"] == loop
+        assert not set(names) & {"range.assemble", *passes}
+        for once in ("range.await", "h2d", "range.select"):
+            assert names.count(once) == 1, (once, names)
+        assert names.index("range.await") < names.index("h2d") \
+            < names.index("range.select")
+        mine = [e for e in events if e["name"] in passes and inside(e, asm)
+                and e["tid"] == asm["tid"]]
         assert sorted(e["name"] for e in mine) == sorted(passes)
         assert all(e["args"]["n"] == n for e in mine)
         assert sum(e["dur"] for e in mine) <= asm["dur"]

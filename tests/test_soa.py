@@ -103,11 +103,10 @@ def test_soa_point_batches_span_only_where_a_window_fired():
 
 @pytest.mark.parametrize("slide", [10, 5], ids=["tumbling", "sliding"])
 def test_soa_point_batches_hands_its_clock_on_and_names_its_passes(slide):
-    """Under a span the window carries the clock reading the span opened at
-    (``t0_ns``: the operator's parent span opens there too), and the four
-    passes lie inside the span, once a window — ``soa.consolidate`` once a
-    firing, so a window after the first of one firing has three. Telemetry
-    off, or no span asked for: no reading, no event, the same arrays."""
+    """Under a span each window emits one, and the four passes lie inside
+    it, once a window — ``soa.consolidate`` once a firing, so a window after
+    the first of one firing has three. Telemetry off, or no span asked for:
+    no event, the same arrays."""
     from spatialflink_tpu.operators.base import soa_point_batches
     from spatialflink_tpu.telemetry import telemetry
 
@@ -123,7 +122,6 @@ def test_soa_point_batches_hands_its_clock_on_and_names_its_passes(slide):
     plain = list(soa_point_batches(GRID, chunks, conf, np.float32,
                                    span="t.assemble"))
     assert len(telemetry.events) == kept
-    assert all(w[0].t0_ns is None for w in plain)
     telemetry.enable()
     try:
         bare = list(soa_point_batches(GRID, chunks, conf, np.float32))
@@ -133,13 +131,14 @@ def test_soa_point_batches_hands_its_clock_on_and_names_its_passes(slide):
         events = telemetry.events[n_bare:]
     finally:
         telemetry.disable()
-    assert all(w[0].t0_ns is None for w in bare)
+    assert not [e for e in telemetry.events[:n_bare]
+                if e["name"] == "t.assemble"]
     assert len(plain) == len(wins) == len(bare) >= 4
     for a, b in zip(plain, wins):
         assert (a[0].start, a[0].end) == (b[0].start, b[0].end)
         assert all(np.array_equal(u, v) for u, v in zip(a[1:4], b[1:4]))
     spans = [e for e in events if e["name"] == "t.assemble"]
-    assert [e["ts"] for e in spans] == [w[0].t0_ns // 1000 for w in wins]
+    assert [e["args"]["n"] for e in spans] == [w[0].count for w in wins]
     firings = 0
     for sp, w in zip(spans, wins):
         mine = [e for e in events if e["name"].startswith("soa.")

@@ -207,17 +207,18 @@ def test_tjoin_metric_file_reads_what_the_program_emits(name):
 def test_benchmark_json_only_grew():
     """The 18 entries stand where they stood, in the order they came, the
     trajectory join's eight behind them, the crowded join's three after
-    those, the join's wait for its producer and the Spider zones' three at
-    the end of ``per_layer``, and the file stays well inside its size
-    limit."""
+    those, the join's wait for its producer, the Spider zones' three and the
+    range query's wait for its producer at the end of ``per_layer``, and the
+    file stays well inside its size limit."""
     names = [m["name"] for m in spec.benchmark()["per_layer"]]
     assert len(names) == len(set(names))
-    assert set(names[-33:-15]) == set(METRICS)
-    assert set(names[-15:-7]) == set(TJOIN_METRICS)
-    assert names[-7:-3] == ["join_skew_extract_roofline",
+    assert set(names[-34:-16]) == set(METRICS)
+    assert set(names[-16:-8]) == set(TJOIN_METRICS)
+    assert names[-8:-4] == ["join_skew_extract_roofline",
                             "join_lanes_per_pair", "join_pairs_per_window",
                             "join_await_us_per_event"]
-    assert names[-3:] == ["range_spider_roofline",
-                          "range_edge_slots_per_point", "range_index_bytes"]
+    assert names[-4:] == ["range_spider_roofline",
+                          "range_edge_slots_per_point", "range_index_bytes",
+                          "range_await_us_per_event"]
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         assert len(f.read()) < 64 * 1024
